@@ -81,6 +81,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from multiverso_tpu import client, core, telemetry
 from multiverso_tpu.data.corpus import backend as data_backend
+from multiverso_tpu.ops import interpret_mode
 from multiverso_tpu.tables import (ArrayTable, SparseMatrixTable,
                                    make_superstep)
 from multiverso_tpu.utils import log
@@ -263,8 +264,7 @@ class LightLDA:
         # into its vocab slice, psum'd over the data axis.
         # the pallas kernel needs the Mosaic TPU backend; on a CPU mesh
         # (tests) it runs in interpreter mode
-        self._interpret = tiled and \
-            self.mesh.devices.flat[0].platform == "cpu"
+        self._interpret = tiled and interpret_mode(self.mesh)
 
         # tables (the reference's server-side state); tiled storage puts
         # one word's topic row in exactly one (8,128) int32 tile
@@ -578,7 +578,7 @@ class LightLDA:
         mp = self.mesh.shape[core.MODEL_AXIS]
         if mp == 1:
             return lambda mirror, w: jnp.take(mirror, w, axis=0)
-        from multiverso_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         d, m = core.DATA_AXIS, core.MODEL_AXIS
         vshard = self.word_topic.storage_shape[0] // mp
 
@@ -605,7 +605,7 @@ class LightLDA:
         psum'd over ICI."""
         if self.mesh.devices.size == 1:
             return fn
-        from multiverso_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         d = core.DATA_AXIS
         Pb = P(d)
         Pb3 = P(d, None, None)
@@ -625,7 +625,7 @@ class LightLDA:
         doc counts — the block layout IS the DP partition)."""
         if self.mesh.devices.size == 1:
             return fn
-        from multiverso_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         d = core.DATA_AXIS
         Pb = P(d)
 
@@ -646,7 +646,7 @@ class LightLDA:
         vocab slice, psum over the data axis. Shared by the per-sweep
         rebuild and the streamed master accumulator (one copy of the
         slice math). Returns f(z_flat, tw, msk) -> [V/mp, C, 128]."""
-        from multiverso_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         d, maxis = core.DATA_AXIS, core.MODEL_AXIS
         mp = self.mesh.shape[maxis]
         vshard = self.word_topic.storage_shape[0] // mp
@@ -719,6 +719,14 @@ class LightLDA:
         vbeta = self.V * beta
         chunk = self._eval_chunk
 
+        # each scanned chunk's lanes shard over the data axis (what the
+        # sharded word gather's shard_map takes). Stated on the
+        # [chunks, c] operands BEFORE the scan: left to infer it inside
+        # the loop, XLA:TPU fails to compile the dp x mp eval
+        # ("Reshape should have supported layout before reaching the
+        # emitter", jax 0.9.0 / libtpu 0.0.34).
+        lanes = NamedSharding(self.mesh, P(None, core.DATA_AXIS))
+
         def run(nwk3, ndk_flat, Ssum, ws, rows, m):
             c = chunk(ws.shape[0])
 
@@ -733,8 +741,8 @@ class LightLDA:
 
             tot, _ = lax.scan(
                 step, jnp.zeros((), jnp.float32),
-                (ws.reshape(-1, c), rows.reshape(-1, c),
-                 m.reshape(-1, c)))
+                tuple(lax.with_sharding_constraint(x.reshape(-1, c), lanes)
+                      for x in (ws, rows, m)))
             return tot
 
         return run
@@ -859,7 +867,7 @@ class LightLDA:
         count array: z is the only sampler state)."""
         if self.mesh.devices.size == 1:
             return fn
-        from multiverso_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         d = core.DATA_AXIS
         Pb = P(d)
 

@@ -28,6 +28,7 @@ fully replicated tables, matching the reference's default deployment shape.
 from __future__ import annotations
 
 import atexit
+import os
 import threading
 import time
 from typing import Optional, Sequence
@@ -41,6 +42,26 @@ from multiverso_tpu.utils import configure, log
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def compile_cache_dir() -> str:
+    """Where XLA's persistent compile cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set (jax reads that
+    itself — nothing is set in code), else ``<checkout>/.jax_cache``.
+    The path is part of the cache key, so it is never a temp name."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or os.path.join(_REPO_ROOT, ".jax_cache")
+
+
+def _place_compile_cache() -> None:
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    # jax skips programs that compiled in under a second by default; a
+    # warm start then depends on which side of a second each compile
+    # happened to land. Cache every program.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 class _Runtime:
@@ -94,6 +115,7 @@ def init(argv: Optional[Sequence[str]] = None, *,
         log.set_level(configure.get_flag("log_level"))
         if configure.get_flag("log_file"):
             log.set_file(configure.get_flag("log_file"))
+        _place_compile_cache()       # before the first compile
 
         coordinator = configure.get_flag("machine_file")
         if coordinator:
@@ -109,7 +131,6 @@ def init(argv: Optional[Sequence[str]] = None, *,
             # TPU), NOT from the file — matching local addresses against
             # the list is unreliable in containers. A bare ``host`` /
             # ``host:port`` value is also accepted.
-            import os
             if os.path.exists(coordinator):
                 with open(coordinator) as f:
                     machines = [m for m in (ln.strip() for ln in f)
@@ -256,6 +277,14 @@ def mesh() -> Mesh:
         init()
     assert _RT.mesh is not None
     return _RT.mesh
+
+
+def platform(m: Optional[Mesh] = None) -> str:
+    """Platform of the devices a mesh is built from (``"tpu"``,
+    ``"cpu"``, ...) — what kernel selection and Pallas interpret mode
+    key on. The process default backend can differ from it (a CPU test
+    mesh in a process whose default backend is the chip)."""
+    return (m if m is not None else mesh()).devices.flat[0].platform
 
 
 def set_mesh(m: Mesh) -> None:

@@ -7,15 +7,20 @@ No pybind11 in this image, so the ABI is flat C consumed via ctypes
 (SURVEY.md §3.5's C-ABI role, repurposed for the data plane).
 
 ``load_native()`` finds (or builds, if a toolchain is present) the shared
-library and returns a :class:`NativeData`; returns ``None`` when
-unavailable, in which case callers fall back to
-:mod:`multiverso_tpu.data.pydata`.
+library and returns a :class:`NativeData`. It returns ``None`` — and
+callers use :mod:`multiverso_tpu.data.pydata` — only where the library
+cannot exist: no ``native/Makefile`` (an installed package without the
+sources) or no ``make``/C++ compiler on the host. Where it can be
+built, a failed build, an unloadable library or an ABI mismatch that
+survives a rebuild RAISES: a word2vec run must not change pair
+generator, and speed, because of a stale ``.so``.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
@@ -265,43 +270,53 @@ _CACHED: Optional[NativeData] = None
 _TRIED = False
 
 
+def _toolchain() -> bool:
+    return os.path.exists(os.path.join(_REPO_ROOT, "native", "Makefile")) \
+        and shutil.which("make") is not None \
+        and shutil.which(os.environ.get("CXX", "g++")) is not None
+
+
+def _build(rebuild: bool) -> None:
+    # -B on rebuild: a stale .so has a fresh mtime after a copy, so
+    # plain make would consider it up to date
+    cmd = ["make", "-C", os.path.join(_REPO_ROOT, "native")] \
+        + (["-B"] if rebuild else [])
+    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+    if proc.returncode:
+        raise RuntimeError(
+            f"native data lib build failed (rc={proc.returncode}): "
+            f"{' '.join(cmd)}\n{proc.stderr[-2000:]}")
+
+
 def load_native(rebuild: bool = False) -> Optional[NativeData]:
-    """Load (building if needed) the native library; None if unavailable."""
+    """Load (building if needed) the native library; ``None`` only
+    where it cannot be built (see module docstring)."""
     global _CACHED, _TRIED
     if _CACHED is not None and not rebuild:
         return _CACHED
     if _TRIED and not rebuild:
         return None
     _TRIED = True
+    can_build = _toolchain()
     if not os.path.exists(_SO_PATH) or rebuild:
-        makefile_dir = os.path.join(_REPO_ROOT, "native")
-        if not os.path.exists(os.path.join(makefile_dir, "Makefile")):
+        if not can_build:
             return None
-        try:
-            # -B on rebuild: a stale committed .so has a fresh mtime after
-            # clone, so plain make would consider it up to date
-            cmd = ["make", "-C", makefile_dir] + (["-B"] if rebuild else [])
-            subprocess.run(cmd, check=True,
-                           capture_output=True, timeout=120)
-        except Exception as exc:
-            log.warn("native data lib build failed (%s); using Python "
-                     "fallback", exc)
-            return None
+        _build(rebuild)
     try:
         lib = ctypes.CDLL(_SO_PATH)
         lib.mv_data_abi_version.restype = ctypes.c_int32
         version = lib.mv_data_abi_version()
-        if version != ABI_VERSION:
-            if not rebuild:
-                return load_native(rebuild=True)
-            log.warn("native data lib ABI %d != expected %d", version,
-                     ABI_VERSION)
-            return None
-        _CACHED = NativeData(lib)
-        return _CACHED
     except (OSError, AttributeError) as exc:
         # AttributeError: stale .so without the version symbol
-        if not rebuild and isinstance(exc, AttributeError):
+        if can_build and not rebuild:
             return load_native(rebuild=True)
-        log.warn("cannot load %s (%s); using Python fallback", _SO_PATH, exc)
-        return None
+        raise RuntimeError(f"cannot load {_SO_PATH}: {exc}") from exc
+    if version != ABI_VERSION:
+        if can_build and not rebuild:
+            return load_native(rebuild=True)
+        raise RuntimeError(
+            f"native data lib {_SO_PATH} has ABI {version}, this "
+            f"checkout expects {ABI_VERSION}"
+            + ("" if can_build else " (no toolchain here to rebuild it)"))
+    _CACHED = NativeData(lib)
+    return _CACHED

@@ -40,6 +40,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 
+# A [512, C, 128] block of int32 count rows at K=1024 is 2 MiB; two
+# double-buffered operands of them plus the f32 posterior temporaries
+# need ~17 MiB — just past Mosaic's 16 MiB default scoped-VMEM limit
+# (the bf16/int16 production operands fit under it). v5e has 128 MiB of
+# VMEM per core; 32 MiB covers every dtype the samplers accept at the
+# block sizes _pick_tb / block_tokens produce.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2 ** 20)
+
 
 def _lane_iotas(tb: int, c: int):
     kc = jax.lax.broadcasted_iota(jnp.int32, (tb, c, LANES), 1)
@@ -55,25 +63,29 @@ def _posterior(A, W, sinv, soh_f, alpha: float, beta: float):
                        0.0) * sinv[None]
 
 
-def _two_level_draw(probs, kc, u1, u2, tb: int, c: int):
+def _two_level_draw(probs, kc, u1, u2, c: int):
     """Two-level inverse-CDF draw: chunk totals then within-chunk lanes.
     cumsum has no Pallas TPU lowering -- triangular matmuls (tiny on the
-    MXU) instead. Returns z [TB] int32."""
+    MXU) instead. Returns z [TB, 1] int32. Per-token values stay
+    [TB, 1] columns throughout: Mosaic broadcasts a column into the
+    token's [C, 128] tile, but refuses to re-lay a [TB, C] mask out as
+    [TB, C, 1]."""
     cs = probs.sum(-1)                             # [TB, C]
     ci = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     cj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     tric = (ci <= cj).astype(jnp.float32)          # [C, C]
     ccdf = jnp.dot(cs, tric, preferred_element_type=jnp.float32)
     t1 = u1 * ccdf[:, -1:]
-    sel_c = jnp.minimum((ccdf < t1).sum(1), c - 1).astype(jnp.int32)
-    csel = (kc[:, :, 0] == sel_c[:, None])         # [TB, C]
-    sub = (probs * csel[:, :, None]).sum(1)        # [TB, 128]
+    sel_c = jnp.minimum(
+        (ccdf < t1).astype(jnp.int32).sum(1, keepdims=True), c - 1)
+    sub = jnp.where(kc == sel_c[:, :, None], probs, 0.0).sum(1)
     li = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
     tril = (li <= lj).astype(jnp.float32)
     scdf = jnp.dot(sub, tril, preferred_element_type=jnp.float32)
     t2 = u2 * scdf[:, -1:]
-    lane = jnp.minimum((scdf < t2).sum(1), LANES - 1).astype(jnp.int32)
+    lane = jnp.minimum(
+        (scdf < t2).astype(jnp.int32).sum(1, keepdims=True), LANES - 1)
     return sel_c * LANES + lane
 
 
@@ -100,16 +112,18 @@ def _kernel(A_ref, W_ref, sinv_ref, zi_ref, msk_ref, u1_ref, u2_ref,
     soh = self_oh.astype(jnp.int32)
     probs = _posterior(A, W, sinv_ref[:], soh.astype(jnp.float32),
                        alpha, beta)
-    zn = _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], tb, c)
-    znew = jnp.where(one[:, 0] > 0, zn, zi[:, 0])
-    znew_ref[:] = znew[:, None]
-    new_oh = ((kk == znew[:, None, None]) & (one[:, :, None] > 0))
+    znew = jnp.where(one > 0,
+                     _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], c),
+                     zi)
+    znew_ref[:] = znew
+    new_oh = ((kk == znew[:, :, None]) & (one[:, :, None] > 0))
     nkd_ref[:] += (new_oh.astype(jnp.int32) - soh).sum(0)
 
 
 def _pick_tb(b: int, c: int) -> int:
-    """Largest multiple-of-8 divisor of b keeping ~3 [TB, C, 128] int32
-    buffers + temporaries under the 16MB VMEM budget."""
+    """Largest multiple-of-8 divisor of b (at most 512) keeping the
+    [TB, C, 128] operand blocks + temporaries inside the VMEM limit
+    :data:`_COMPILER_PARAMS` sets."""
     cap = max(8, min(512, (10 * 2 ** 20) // (c * LANES * 4 * 5)))
     tb = 8
     for cand in range(8, cap + 1, 8):
@@ -177,6 +191,7 @@ def gibbs_sample_tiled(A3: jax.Array, W3: jax.Array, sinv: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, 1), jnp.int32),
                    jax.ShapeDtypeStruct((c, LANES), jnp.int32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(A3, W3, sinv, zi[:, None], msk[:, None], u1[:, None], u2[:, None])
     return znew2[:, 0], nkd
@@ -213,10 +228,11 @@ def _docblock_kernel(ndk_ref, W_ref, sinv_ref, zi_ref, drel_ref, msk_ref,
     self_oh = ((kk == zi[:, :, None]) & (one[:, :, None] > 0))
     sohf = self_oh.astype(jnp.float32)
     probs = _posterior(A3, W, sinv_ref[:], sohf, alpha, beta)
-    zn = _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], tb, c)
-    znew = jnp.where(one[:, 0] > 0, zn, zi[:, 0])
-    znew_ref[:] = znew[:, None]
-    new_oh = ((kk == znew[:, None, None]) & (one[:, :, None] > 0))
+    znew = jnp.where(one > 0,
+                     _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], c),
+                     zi)
+    znew_ref[:] = znew
+    new_oh = ((kk == znew[:, :, None]) & (one[:, :, None] > 0))
     ohdiff = new_oh.astype(jnp.float32) - sohf     # [TB, C, 128]
     nkd_ref[:] += ohdiff.sum(0).astype(jnp.int32)
     delta = jnp.dot(E.T, ohdiff.reshape(tb, k),
@@ -256,10 +272,11 @@ def _docblock_build_kernel(W_ref, sinv_ref, zi_ref, drel_ref, msk_ref,
     A = jnp.dot(Em, ndk, preferred_element_type=jnp.float32)
     A3 = A.reshape(tb, c, LANES)
     probs = _posterior(A3, W, sinv_ref[:], sohf, alpha, beta)
-    zn = _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], tb, c)
-    znew = jnp.where(one[:, 0] > 0, zn, zi[:, 0])
-    znew_ref[:] = znew[:, None]
-    new_oh = ((kk == znew[:, None, None]) & (one[:, :, None] > 0))
+    znew = jnp.where(one > 0,
+                     _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], c),
+                     zi)
+    znew_ref[:] = znew
+    new_oh = ((kk == znew[:, :, None]) & (one[:, :, None] > 0))
     nkd_ref[:] += (new_oh.astype(jnp.int32)
                    - self_oh.astype(jnp.int32)).sum(0)
 
@@ -309,6 +326,7 @@ def gibbs_sample_docblock_build(W3: jax.Array, sinv: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, 1), jnp.int32),
                    jax.ShapeDtypeStruct((c, LANES), jnp.int32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(W3, sinv, zi[:, None], drel[:, None], msk[:, None],
       u1[:, None], u2[:, None])
@@ -372,6 +390,7 @@ def gibbs_sample_docblock(ndk_blk: jax.Array, W3: jax.Array,
                    jax.ShapeDtypeStruct((b, 1), jnp.int32),
                    jax.ShapeDtypeStruct((c, LANES), jnp.int32)],
         input_output_aliases={0: 0},
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(ndk_blk, W3, sinv, zi[:, None], drel[:, None], msk[:, None],
       u1[:, None], u2[:, None])

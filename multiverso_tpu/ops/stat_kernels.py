@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from multiverso_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 #: order of the packed stats vector's lanes
 PACKED_FIELDS = ("sum_sq", "abs_max", "nan_count", "inf_count",

@@ -1,52 +1,56 @@
 """Pallas TPU kernel engine for the server-side table hot paths, plus
-the XLA-fallback selection layer (``MVTPU_KERNELS``).
+the engine selection layer (``MVTPU_KERNELS``).
 
-Why (the PR-3 aftermath): with the worker-side client pipeline removing
-coalescing/caching/staging overheads, the hot path is the server-side
-table kernels themselves — and those were plain XLA: the fused KV probe
-materializes full bucket rows via ``jnp.take`` and pays a batch-wide
-stable ``argsort`` per dispatch, and the COO path round-trips whole
-rows through HBM. The kernels here keep the touched rows in VMEM:
+The XLA table paths materialize full bucket rows via ``jnp.take``, pay
+a batch-wide stable ``argsort`` per KV dispatch, and round-trip whole
+rows through HBM on the COO path. The kernels here keep the touched
+rows in VMEM:
 
-- **KV probe+update** (:func:`build_kv_probe_update`): probe, empty-lane
-  claim, updater apply, and scatter fused in ONE kernel. The batch is
-  host-sorted by bucket (``KVTable.prepare_add``), so each bucket's
-  lanes are CONSECUTIVE steps of the sequential TPU grid and the bucket's
-  slot rows stay resident in VMEM across them; the per-bucket empty-lane
-  rank is a run-local claims counter in SMEM — an in-kernel per-bucket
-  scan replacing the XLA path's global ``argsort``. A two-pass grid
-  (pass 0: probe + overflow count into scratch; pass 1: masked writes)
-  preserves the all-or-nothing overflow contract: ANY overflow voids the
+- **KV probe+update** (:func:`build_kv_probe_update`): a probe kernel
+  (slot claim per lane + overflow count) and a commit kernel (updater
+  apply + masked scatter). The batch is host-sorted by bucket
+  (``KVTable.prepare_add``), so each bucket's lanes are CONSECUTIVE
+  steps of the sequential TPU grid and the bucket's slot rows stay
+  resident in VMEM across them; the per-bucket empty-slot rank is a
+  run-local claimed mask — an in-kernel per-bucket scan replacing the
+  XLA path's global ``argsort``. The overflow count between the two
+  kernels preserves the all-or-nothing contract: ANY overflow voids the
   whole batch on device, bit-identical to the XLA path.
 - **KV lookup** (:func:`build_kv_lookup`): gather bucket rows by
   scalar-prefetch index map, match + pick in VMEM.
 - **Row gather / row scatter-add / COO scatter-add**
   (:func:`build_row_gather`, :func:`build_row_scatter_add`,
   :func:`build_coo_scatter_add`): matrix/sparse-table row paths. Scatter
-  batches are host-sorted by row, so each touched row is fetched once,
+  batches are host-sorted by row, so each touched block is fetched once,
   segment-summed in VMEM across its run of grid steps, and written back
   to HBM exactly once (duplicate-safe without XLA's sorted-scatter
   machinery).
 
-Correctness-critical grid semantics the scatter kernels rely on (probed
-empirically in interpret mode, documented Pallas behavior on TPU):
-consecutive grid steps whose index maps return the SAME block index keep
-the block resident (no flush/refetch between them), and with
-``input_output_aliases`` the unvisited rows of the aliased output keep
-their input content. Input blocks always read PRE-batch data (each row's
-input is fetched once, at its run start, before any flush of that row),
-which is exactly what the rank/claims equivalence argument needs.
+Correctness-critical grid semantics the scatter kernels rely on
+(documented Pallas behavior on TPU): consecutive grid steps whose index
+maps return the SAME block index keep the block resident (no
+flush/refetch between them), and with ``input_output_aliases`` the
+unvisited rows of the aliased output keep their input content. Input
+blocks always read PRE-batch data (each block's input is fetched once,
+at its run start, before any flush of that block).
 
 Selection layer (:func:`select_kernel`): every kernel registers as an
-(xla, pallas) pair behind ``MVTPU_KERNELS``:
+(xla, pallas) pair behind ``MVTPU_KERNELS``, decided ONCE per table
+from the platform of the table's mesh:
 
-- ``auto`` (default): Pallas on an accelerator backend, XLA on CPU
-  (counted in ``kernels.fallbacks{reason=cpu}``) — so tier-1 on CPU
-  exercises the fallback path by default.
-- ``pallas``: force Pallas; on CPU the kernels run under
-  ``interpret=True`` (the ``ops/lda_sampler.py`` test precedent) — so
-  tier-1 also exercises the interpreted kernels.
-- ``xla``: force the existing XLA implementations.
+- ``auto`` (default): Pallas on an accelerator mesh, XLA on a CPU mesh
+  (counted in ``kernels.fallbacks{reason=cpu}``).
+- ``pallas``: force Pallas; on a CPU mesh the kernels run under
+  ``interpret=True`` — how tier-1 exercises them.
+- ``xla``: force the XLA implementations.
+
+There is no fallback at run time: a kernel that fails to lower, compile
+or run on its platform raises to the caller. The only build-time
+decision besides the mode is :class:`UnsupportedShardingLayout` (the
+per-shard lane slicer cannot express the table's layout), counted as
+``reason=sharded_unsupported_layout``. ``tests/test_table_kernels.py``
+cross-lowers every kernel ``auto`` can select for ``"tpu"`` on the CPU
+rig.
 
 Sharded tables (mesh.size > 1) run the SAME kernels per shard inside
 ``shard_map``: a bare ``pallas_call`` has no SPMD partitioning rule, so
@@ -55,15 +59,9 @@ local buckets/rows. Host prep sorts by shard-then-bucket/row and hands
 the engine per-shard lane slices (``tables/hashing.shard_lane_slices``
 — dense, contiguous, pow2-padded lane rows with non-local lanes as
 masked padding), so there are NO cross-shard collectives inside any
-kernel; the one global interaction the KV contract needs (the
-all-or-nothing overflow drop) is a scalar sum of per-shard counts
-BETWEEN a probe-only kernel and a commit kernel. A table that registers
-no sharded Pallas form keeps XLA (``reason=sharded``); a layout the
-slicer can't shard falls back as ``reason=sharded_unsupported_layout``.
-Any Pallas failure at lowering/compile time falls back to XLA
-permanently for that kernel (``reason=error``), logged once (per
-kernel and mesh shape) — correctness over speed. Fallbacks are
-observable: ``kernels.fallbacks`` counter plus the per-engine
+kernel. A table that registers no sharded Pallas form keeps XLA
+(``reason=sharded``). Selections are observable: ``kernels.selected``
+gauges, the ``kernels.fallbacks`` counter, and the per-engine
 ``profile.calls{fn=...}`` / ``profile.calls{fn=....pallas}`` dispatch
 counts (every engine stays under ``profiled_jit``).
 
@@ -90,15 +88,16 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from multiverso_tpu import core
 from multiverso_tpu.telemetry import metrics as _metrics
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
 from multiverso_tpu.utils import log
-from multiverso_tpu.utils.jax_compat import shard_map
 
 LANES = 128
 
@@ -126,31 +125,25 @@ def kernel_mode() -> str:
     return mode
 
 
-def interpret_mode() -> bool:
-    """Pallas interpreter mode: on for CPU backends (tests), off on a
-    real accelerator — the ``ops/lda_sampler.py`` precedent."""
-    return jax.default_backend() == "cpu"
+def interpret_mode(mesh: Any = None) -> bool:
+    """Pallas interpreter mode: on for a CPU mesh (tests), off on a
+    real accelerator. Keyed on the MESH's platform (``core.platform``),
+    the same test ``select_kernel`` and LightLDA apply."""
+    return core.platform(mesh) == "cpu"
 
 
 def _mesh_axes(mesh: Any) -> tuple:
-    """((axis, size), ...) of a mesh, () when unknowable — the log and
-    latch key ingredient."""
-    try:
-        return tuple(dict(mesh.shape).items()) if mesh is not None else ()
-    except Exception:
-        return ()
+    """((axis, size), ...) of a mesh — the log and latch key
+    ingredient."""
+    return tuple(dict(mesh.shape).items()) if mesh is not None else ()
 
 
 def _note_fallback(name: str, reason: str,
                    exc: Optional[BaseException] = None,
                    mesh: Any = None) -> None:
     """Count (always) + log (once per (kernel, reason, mesh shape)) a
-    pallas→xla fallback. The log latch used to be process-wide per
-    reason, so one sharded table's fallback silenced every later
-    kernel's line — including the evidence that a later single-chip (or
-    differently-shaped) mesh took a DIFFERENT decision. Keying the
-    latch per (kernel, reason, mesh shape) keeps one line per distinct
-    story; the counter is never latched."""
+    selection that kept XLA where Pallas was asked for or implied. The
+    counter is never latched."""
     _metrics.registry().counter("kernels.fallbacks", kernel=name,
                                 reason=reason).inc()
     axes = _mesh_axes(mesh)
@@ -165,73 +158,38 @@ def _note_fallback(name: str, reason: str,
 
 
 class KernelEngine:
-    """One selected kernel: calls the Pallas engine when active, with a
-    permanent runtime fallback to the XLA engine on any failure. Holders
-    treat it exactly like the jitted callable they held before;
-    ``.engine`` ("xla"|"pallas") is the selection evidence tests and the
-    micro-bench read."""
+    """One selected kernel. Holders treat it exactly like the jitted
+    callable they held before; ``.engine`` ("xla"|"pallas") is the
+    selection evidence tests and the micro-bench read. The selection is
+    final: a failing engine raises, it is never swapped at run time."""
 
-    def __init__(self, name: str, xla: Callable,
-                 pallas: Optional[Callable] = None,
+    def __init__(self, name: str, fn: Callable, engine: str = "xla",
                  layout: str = "flat") -> None:
         self.name = name
-        self._xla = xla
-        self._pallas = pallas
+        self._fn = fn
+        self.engine = engine
         #: operand layout the engine expects: "flat" (whole-batch
         #: arrays) or "sharded" (per-shard (shards, L, ...) lane slices
-        #: from tables/hashing.shard_lane_slices). Fixed at selection
-        #: time — a sharded engine's runtime XLA fallback is the
-        #: lane-slice-accepting adapter, so the layout survives the
-        #: fallback and host prep never has to re-shape mid-stream.
+        #: from tables/hashing.shard_lane_slices)
         self.layout = layout
-        self._note_selected()
-
-    def _note_selected(self, prev: Optional[str] = None) -> None:
-        """Publish the live selection as a gauge (the /statusz kernel
-        table); a runtime fallback flips the old label off so the
-        statusz view shows ONE live engine per kernel."""
-        if prev is not None:
-            _metrics.registry().gauge(
-                "kernels.selected", kernel=self.name, engine=prev,
-                layout=self.layout).set(0)
+        # the /statusz kernel table: ONE live engine per kernel
         _metrics.registry().gauge(
-            "kernels.selected", kernel=self.name, engine=self.engine,
-            layout=self.layout).set(1)
-
-    @property
-    def engine(self) -> str:
-        return "pallas" if self._pallas is not None else "xla"
+            "kernels.selected", kernel=name, engine=engine,
+            layout=layout).set(1)
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
-        if self._pallas is None:
-            with _trace.span(f"kernel.{self.name}", engine="xla",
-                             layout=self.layout):
-                return self._xla(*args, **kwargs)
-        try:
-            with _trace.span(f"kernel.{self.name}", engine="pallas",
-                             layout=self.layout):
-                return self._pallas(*args, **kwargs)
-        except Exception as e:
-            # lowering/compile failures surface here BEFORE execution
-            # (so the donated operands are still alive for the retry);
-            # flip to XLA for good — correctness over metrics
-            self._pallas = None
-            _note_fallback(self.name, "error", e)
-            self._note_selected(prev="pallas")
-            with _trace.span(f"kernel.{self.name}", engine="xla",
-                             layout=self.layout):
-                return self._xla(*args, **kwargs)
+        with _trace.span(f"kernel.{self.name}", engine=self.engine,
+                         layout=self.layout):
+            return self._fn(*args, **kwargs)
 
     # AOT passthrough, matching _ProfiledJit's debugging surface
     def lower(self, *args: Any, **kwargs: Any):
-        target = self._pallas if self._pallas is not None else self._xla
-        return target.lower(*args, **kwargs)
+        return self._fn.lower(*args, **kwargs)
 
 
 def select_kernel(name: str, *, xla: Callable,
                   pallas: Optional[Callable[[], Callable]] = None,
                   pallas_sharded: Optional[Callable[[], Callable]] = None,
-                  xla_sharded: Optional[Callable[[], Callable]] = None,
                   mesh: Any = None) -> KernelEngine:
     """Register one hot-path kernel behind the engine knob.
 
@@ -241,63 +199,169 @@ def select_kernel(name: str, *, xla: Callable,
     path pay nothing). On a sharded ``mesh`` (size > 1) selection goes
     to ``pallas_sharded`` instead — the shard_map-wrapped per-shard
     engine whose operands are the lane slices of
-    ``tables/hashing.shard_lane_slices`` — with ``xla_sharded`` (a
-    factory for an adapter accepting the SAME lane-sliced operands) as
-    its runtime-fallback target; both are built only when the sharded
-    engine wins. A sharded mesh with no ``pallas_sharded`` keeps XLA
-    (``reason=sharded``); a ``pallas_sharded`` build that raises
+    ``tables/hashing.shard_lane_slices``. A sharded mesh with no
+    ``pallas_sharded`` keeps XLA (``reason=sharded``); a
+    ``pallas_sharded`` build that raises
     :class:`UnsupportedShardingLayout` keeps XLA as
-    ``reason=sharded_unsupported_layout``.
+    ``reason=sharded_unsupported_layout``. Any other build failure
+    raises.
     """
     mode = kernel_mode()
     sharded = mesh is not None and getattr(mesh, "size", 1) > 1
     if mode == "xla" or (pallas is None and pallas_sharded is None):
         return KernelEngine(name, xla)
-    if mode == "auto" and jax.default_backend() == "cpu":
+    if mode == "auto" and core.platform(mesh) == "cpu":
         _note_fallback(name, "cpu", mesh=mesh)
         return KernelEngine(name, xla)
-    if sharded:
-        if pallas_sharded is None:
-            _note_fallback(name, "sharded", mesh=mesh)
-            return KernelEngine(name, xla)
-        try:
-            built = pallas_sharded()
-            fallback = xla_sharded() if xla_sharded is not None else xla
-        except UnsupportedShardingLayout as e:
-            _note_fallback(name, "sharded_unsupported_layout", e,
-                           mesh=mesh)
-            return KernelEngine(name, xla)
-        except Exception as e:
-            _note_fallback(name, "error", e, mesh=mesh)
-            return KernelEngine(name, xla)
-        return KernelEngine(name, fallback, built, layout="sharded")
-    try:
-        built = pallas()
-    except Exception as e:       # a build-time failure is also a fallback
-        _note_fallback(name, "error", e, mesh=mesh)
+    if not sharded:
+        return KernelEngine(name, pallas(), "pallas")
+    if pallas_sharded is None:
+        _note_fallback(name, "sharded", mesh=mesh)
         return KernelEngine(name, xla)
-    return KernelEngine(name, xla, built)
+    try:
+        built = pallas_sharded()
+    except UnsupportedShardingLayout as e:
+        _note_fallback(name, "sharded_unsupported_layout", e, mesh=mesh)
+        return KernelEngine(name, xla)
+    return KernelEngine(name, built, "pallas", layout="sharded")
+
+
+# -- block geometry shared by every kernel ---------------------------------
+#
+# Mosaic accepts a block only when its last two dims are multiples of
+# (8, 128) or span the array. A flat ``(R, C)`` table can therefore not
+# be addressed one ``(1, C)`` row at a time: the table-side block is the
+# ALIGNED 8-ROW GROUP holding the row and the kernel picks the row inside
+# it (a dynamic sublane index). Tiled ``(R, C/128, 128)`` rows and
+# ``(B, S, ·)`` bucket rows already span their last two dims. Per-lane
+# batch operands ride as ``(n, 1, C)`` with ``(1, 1, C)`` blocks; per-lane
+# SCALARS (ids, columns, values, key words, write gates) ride SMEM as
+# scalar-prefetch operands, which is also what lets the kernels branch
+# on them.
+
+SUBLANES = 8
+# Lanes per pallas_call. Per-lane scalars live in SMEM (1 MiB on v5e;
+# at most five int32 arrays per kernel here → 320 KiB); longer batches
+# run as consecutive calls over static chunks (sorted runs that straddle
+# a chunk edge re-read what the previous call wrote — same result).
+LANE_CAP = 16384
+
+
+def _lane_chunks(n: int):
+    return [(s, min(LANE_CAP, n - s)) for s in range(0, n, LANE_CAP)]
+
+
+def _table_block(tiles: int, num_cols: int) -> pl.BlockSpec:
+    """Table-side block holding row ``ids[i]`` (``ids`` = the FIRST
+    scalar-prefetch operand): the row itself in tiled storage, its
+    aligned 8-row group in flat ``(R, C)`` storage."""
+    if tiles:
+        return pl.BlockSpec((1, tiles, LANES),
+                            lambda i, ids, *_: (ids[i], 0, 0),
+                            memory_space=pltpu.VMEM)
+    return pl.BlockSpec((SUBLANES, num_cols),
+                        lambda i, ids, *_: (ids[i] // SUBLANES, 0),
+                        memory_space=pltpu.VMEM)
+
+
+def _row_at(rid, tiles: int):
+    """Index of row ``rid`` inside its resident :func:`_table_block`."""
+    if tiles:
+        return ...
+    return (pl.ds(rid % SUBLANES, 1), slice(None))
+
+
+def _new_block(ids_ref, i, tiles: int):
+    """True on the first grid step of a run of lanes sharing one
+    :func:`_table_block` (ids sorted → one run per block)."""
+    cur, prev = ids_ref[i], ids_ref[jnp.maximum(i - 1, 0)]
+    if not tiles:
+        cur, prev = cur // SUBLANES, prev // SUBLANES
+    return jnp.logical_or(i == 0, cur != prev)
+
+
+def _lane_shape(n: int, tiles: int, num_cols: int) -> tuple:
+    return (n, tiles, LANES) if tiles else (n, 1, num_cols)
+
+
+def _lane_block(tiles: int, num_cols: int) -> pl.BlockSpec:
+    """Batch-side block: lane ``i``'s row of a :func:`_lane_shape`
+    operand — ``ref[0]`` has the shape of ``table[_row_at(...)]``."""
+    return pl.BlockSpec(_lane_shape(1, tiles, num_cols),
+                        lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM)
+
+
+def _lane_at(tiles: int):
+    return ... if tiles else 0
 
 
 # -- KV lookup -------------------------------------------------------------
 
 
-def _kv_lookup_kernel(bkt_ref, keys_ref, vals_ref, q_ref, picked_ref,
-                      found_ref, *, vdim: int):
+def _key_words(query):
+    """(n, 2) uint32 keys → the two (n,) SMEM word planes."""
+    return query[:, 0], query[:, 1]
+
+
+def _key_row(hi, lo, slots: int):
+    """The scalar key (hi, lo) broadcast to a (1, S, 2) bucket row."""
+    word = jax.lax.broadcasted_iota(jnp.int32, (1, slots, 2), 2)
+    return jnp.where(word == 0, hi, lo)
+
+
+def _slot_iota(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _both_words(eq):
+    """(1, S, 2) per-word equality → (1, S, 1) whole-key equality."""
+    return eq.astype(jnp.int32).sum(-1, keepdims=True) == 2
+
+
+def _match_slot(row, hi, lo, slots: int):
+    """Scalar slot of bucket ``row`` (1, S, 2) holding key (hi, lo), or
+    -1. Everything downstream rebuilds its one-hot from this scalar, so
+    no mask ever changes layout between the keys' (S on sublanes) and
+    the scalar values' (S on lanes) blocks."""
+    eq = _both_words(row == _key_row(hi, lo, slots))
+    return jnp.max(jnp.where(eq, _slot_iota((1, slots, 1)), -1))
+
+
+def _kv_block(slots: int, vdim: int) -> pl.BlockSpec:
+    """Values/state block of bucket ``bkt[i]``: ``(1, S, D)`` for vector
+    values, the aligned 8-bucket group of ``(B, S)`` scalar values."""
+    if vdim:
+        return pl.BlockSpec((1, slots, vdim),
+                            lambda i, bkt, *_: (bkt[i], 0, 0),
+                            memory_space=pltpu.VMEM)
+    return _table_block(0, slots)
+
+
+def _keys_block(slots: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, slots, 2), lambda i, bkt, *_: (bkt[i], 0, 0),
+                        memory_space=pltpu.VMEM)
+
+
+_SMEM_OUT = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _kv_lookup_kernel(bkt, qhi, qlo, keys_ref, vals_ref, picked_ref,
+                      found_ref, *, slots: int, vdim: int):
     """One lane: match the query against its bucket's slot rows (VMEM)
     and pick the matched value. Same pick formula as the XLA path
-    (where-sum over matching lanes), so NaN payloads round-trip
+    (where-sum over the matching slot), so NaN payloads round-trip
     identically."""
-    row = keys_ref[...]                               # (1, S, 2) uint32
-    q = q_ref[...]                                    # (1, 2)
-    match = (row == q[:, None, :]).all(-1)            # (1, S)
-    found = match.any(axis=1, keepdims=True)          # (1, 1)
-    vals = vals_ref[...]                              # (1, S[, D])
-    m = match if vals.ndim == 2 else match[:, :, None]
-    picked = jnp.where(m, vals, 0).sum(axis=1,
-                                       keepdims=(vdim == 0))
-    picked_ref[...] = picked
-    found_ref[...] = found.astype(jnp.int32)
+    i = pl.program_id(0)
+    slot = _match_slot(keys_ref[...], qhi[i], qlo[i], slots)
+    found_ref[i] = (slot >= 0).astype(jnp.int32)
+    if vdim:
+        vals = vals_ref[...]                              # (1, S, D)
+        picked_ref[0] = jnp.where(_slot_iota(vals.shape) == slot,
+                                  vals, 0).sum(axis=1)
+    else:
+        vals = vals_ref[_row_at(bkt[i], 0)]               # (1, S)
+        picked_ref[i] = jnp.sum(jnp.where(_slot_iota(vals.shape) == slot,
+                                          vals, 0))
 
 
 def build_kv_lookup(*, slots: int, value_dim: int, default_value: float,
@@ -305,42 +369,40 @@ def build_kv_lookup(*, slots: int, value_dim: int, default_value: float,
     """(keys_arr, values_arr, query, buckets) -> (picked, found) —
     signature-compatible with ``KVTable``'s XLA ``lookup``."""
     vdim = int(value_dim)
+    kern = functools.partial(_kv_lookup_kernel, slots=slots, vdim=vdim)
+
+    def lookup_chunk(keys_arr, values_arr, qhi, qlo, buckets):
+        b = buckets.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[_keys_block(slots), _kv_block(slots, vdim)],
+            out_specs=[_lane_block(0, vdim) if vdim else _SMEM_OUT,
+                       _SMEM_OUT],
+        )
+        return pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((b, 1, vdim) if vdim else (b,),
+                                     values_arr.dtype),
+                jax.ShapeDtypeStruct((b,), jnp.int32)],
+            interpret=interpret,
+        )(buckets, qhi, qlo, keys_arr, values_arr)
 
     def lookup(keys_arr, values_arr, query, buckets):
-        b = query.shape[0]
-        vblk = (1, slots, vdim) if vdim else (1, slots)
-        vmap = (lambda i, bkt: (bkt[i], 0, 0)) if vdim \
-            else (lambda i, bkt: (bkt[i], 0))
-        oshape = (b, vdim) if vdim else (b, 1)
-        omap = lambda i, bkt: (i, 0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, slots, 2), lambda i, bkt: (bkt[i], 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec(vblk, vmap, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 2), omap, memory_space=pltpu.VMEM),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, oshape[1]), omap,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1), omap, memory_space=pltpu.VMEM),
-            ],
-        )
-        picked, found = pl.pallas_call(
-            functools.partial(_kv_lookup_kernel, vdim=vdim),
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct(oshape, values_arr.dtype),
-                       jax.ShapeDtypeStruct((b, 1), jnp.int32)],
-            interpret=interpret,
-        )(buckets, keys_arr, values_arr, query)
-        found_b = found[:, 0] != 0
-        if vdim == 0:
-            picked = picked[:, 0]
-            fill = found_b
-        else:
+        b = buckets.shape[0]
+        qhi, qlo = _key_words(query)
+        outs = [lookup_chunk(keys_arr, values_arr, qhi[s:s + m],
+                             qlo[s:s + m], buckets[s:s + m])
+                for s, m in _lane_chunks(b)]
+        picked = jnp.concatenate([o[0] for o in outs])
+        found_b = jnp.concatenate([o[1] for o in outs]) != 0
+        if vdim:
+            picked = picked.reshape(b, vdim)
             fill = found_b[:, None]
+        else:
+            fill = found_b
         picked = jnp.where(fill, picked,
                            jnp.asarray(default_value, picked.dtype))
         return picked, found_b
@@ -348,139 +410,204 @@ def build_kv_lookup(*, slots: int, value_dim: int, default_value: float,
     return lookup
 
 
-# -- KV fused probe + updater apply + scatter ------------------------------
+# -- KV probe + updater apply + scatter ------------------------------------
+#
+# Two kernels with one scalar between them: PROBE claims a slot per lane
+# and counts overflows; COMMIT applies the updater into the claimed
+# slots, gated on the batch-wide overflow count (ANY overflow voids the
+# WHOLE batch — the table must stay untouched for the raise). On a
+# sharded mesh each shard runs both over its own lanes and the count is
+# a jnp.sum across shards between the two shard_maps: the one global
+# interaction the KV contract needs, outside any kernel.
+
+_EMPTY_WORD = 0xFFFFFFFF
 
 
-def _probe_lane(row, q, valid_l, claims, *, slots: int):
-    """Probe one lane against its resident bucket row — the lane math
-    shared by the fused two-pass kernel (pass 0) and the sharded
-    probe-only kernel. Picks the matching lane, else the (claims+1)-th
-    empty lane of the ORIGINAL row — the claims counter is the
-    run-local scan that replaces the XLA path's global argsort rank
-    (equivalent count: claims == min(rank, n_empty), and both miss past
-    n_empty). Returns ``(slot (1, 1), claim_inc, over_inc)``;
-    ``slot == slots`` encodes a dropped lane."""
-    match = (row == q[:, None, :]).all(-1)            # (1, S)
-    matched = match.any(axis=1, keepdims=True)        # (1, 1)
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, slots), 1)
-    empty = (row == jnp.uint32(0xFFFFFFFF)).all(-1)   # (1, S)
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (slots, slots), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (slots, slots), 1)
-           ).astype(jnp.float32)
-    ecs = jnp.dot(empty.astype(jnp.float32), tri,
-                  preferred_element_type=jnp.float32)  # incl. cumsum
-    hit = empty & (ecs == (claims + 1).astype(jnp.float32))
-    placed = hit.any(axis=1, keepdims=True)
-    new = valid_l & ~matched
-    oh = jnp.where(matched, match, hit) & valid_l      # (1, S)
-    ok = (matched | placed) & valid_l
-    slot = jnp.sum(jnp.where(oh, lane_iota, 0), axis=1, keepdims=True)
-    slot = jnp.where(ok, slot, jnp.int32(slots))
-    claim_inc = (new & placed)[0, 0].astype(jnp.int32)
-    over_inc = (new & ~placed)[0, 0].astype(jnp.int32)
-    return slot, claim_inc, over_inc
+def _kv_probe_kernel(bkt, qhi, qlo, valid, carry, keys_ref, claimed_in,
+                     slot_ref, nover_ref, claimed_ref, *, slots: int):
+    """One grid step per bucket-sorted lane: the matching slot, else the
+    first empty slot of the ORIGINAL row no earlier lane of this bucket
+    run has claimed — the run-local scan that replaces the XLA path's
+    global argsort rank (k-th new key of a bucket takes its k-th empty
+    slot on both; both miss past the last). ``slot == slots`` encodes a
+    dropped lane. ``carry``/``claimed_in`` continue a run across a
+    chunk edge (:data:`LANE_CAP`)."""
+    i = pl.program_id(0)
+    prev = jnp.where(i == 0, carry[0], bkt[jnp.maximum(i - 1, 0)])
 
-
-def _apply_write(oh, q, d, opt_row, vals_in, state_in, keys_out,
-                 vals_out, state_out, *, vdim: int, updater: Any,
-                 state_treedef: Any):
-    """Masked one-hot updater apply into the resident (aliased) bucket
-    block — the write math shared by the fused kernel (pass 1) and the
-    sharded commit kernel. An all-False ``oh`` (1, S) drops the write;
-    old values read the PRE-batch inputs (dup keys per batch are
-    rejected upstream, so each slot is written at most once)."""
-    keys_out[...] = jnp.where(oh[:, :, None], q[:, None, :],
-                              keys_out[...])
-    if vdim:
-        ohv = oh[:, :, None]
-        old = jnp.where(ohv, vals_in[...], 0).sum(axis=1)       # (1, D)
-        old_state = [jnp.where(ohv, s[...], 0).sum(axis=1)
-                     for s in state_in]
-    else:
-        old = jnp.where(oh, vals_in[...], 0).sum(axis=1,
-                                                 keepdims=True)
-        old_state = [jnp.where(oh, s[...], 0).sum(axis=1,
-                                                  keepdims=True)
-                     for s in state_in]
-    opt = AddOption(learning_rate=opt_row[0, 0], momentum=opt_row[0, 1],
-                    rho=opt_row[0, 2], lam=opt_row[0, 3],
-                    step=opt_row[0, 4])
-    upd, new_state = updater.apply(
-        old, jax.tree.unflatten(state_treedef, old_state), d, opt)
-    new_leaves = jax.tree.leaves(new_state)
-    if vdim:
-        vals_out[...] = jnp.where(
-            oh[:, :, None], upd[:, None, :].astype(vals_out.dtype),
-            vals_out[...])
-        for so, ns in zip(state_out, new_leaves):
-            so[...] = jnp.where(oh[:, :, None],
-                                ns[:, None, :].astype(so.dtype),
-                                so[...])
-    else:
-        vals_out[...] = jnp.where(oh, upd.astype(vals_out.dtype),
-                                  vals_out[...])
-        for so, ns in zip(state_out, new_leaves):
-            so[...] = jnp.where(oh, ns.astype(so.dtype), so[...])
-
-
-def _kv_probe_kernel(*refs, slots: int, vdim: int, nstate: int,
-                     updater: Any, state_treedef: Any):
-    """Two-pass sequential grid over (pass, lane) — see module doc.
-    Requires the batch sorted by bucket (host prep does it)."""
-    bkt = refs[0]
-    keys_in, vals_in = refs[1], refs[2]
-    state_in = refs[3:3 + nstate]
-    q_ref, d_ref, v_ref, o_ref = refs[3 + nstate:7 + nstate]
-    keys_out, vals_out = refs[7 + nstate], refs[8 + nstate]
-    state_out = refs[9 + nstate:9 + 2 * nstate]
-    nover_ref = refs[9 + 2 * nstate]
-    slot_ref, claims_ref = refs[10 + 2 * nstate], refs[11 + 2 * nstate]
-
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-    new_run = jnp.logical_or(
-        i == 0, bkt[i] != bkt[jnp.maximum(i - 1, 0)])
-
-    @pl.when(jnp.logical_and(p == 0, i == 0))
+    @pl.when(i == 0)
     def _():
-        nover_ref[0, 0] = jnp.int32(0)
+        nover_ref[0] = jnp.int32(0)
+        claimed_ref[...] = claimed_in[...]
 
-    @pl.when(new_run)
+    @pl.when(bkt[i] != prev)
     def _():
-        # run start: reset the per-bucket claims scan, and copy the
-        # bucket's rows input→output so (a) pass-0 flushes write back
-        # identical data and (b) pass-1's masked slot writes merge into
-        # the original row (the aliased buffer keeps unvisited rows)
-        claims_ref[0] = jnp.int32(0)
+        claimed_ref[...] = jnp.zeros_like(claimed_ref)
+
+    row = keys_ref[...]                               # (1, S, 2) uint32
+    lanes = _slot_iota((1, slots, 1))
+    mslot = _match_slot(row, qhi[i], qlo[i], slots)
+    free = _both_words(row == jnp.uint32(_EMPTY_WORD)) \
+        & (claimed_ref[...] == 0)
+    eslot = jnp.min(jnp.where(free, lanes, slots))    # == slots: full
+    live = valid[i] > 0
+    matched = mslot >= 0
+    new = jnp.logical_and(live, jnp.logical_not(matched))
+    placed = eslot < slots
+    slot_ref[i] = jnp.where(live, jnp.where(matched, mslot, eslot), slots)
+
+    @pl.when(jnp.logical_and(new, placed))
+    def _():
+        claimed_ref[...] = jnp.where(lanes == eslot, 1, claimed_ref[...])
+
+    nover_ref[0] = nover_ref[0] + jnp.logical_and(
+        new, jnp.logical_not(placed)).astype(jnp.int32)
+
+
+def _kv_probe(keys_arr, buckets, qhi, qlo, valid, *, slots: int,
+              interpret: bool):
+    """(slot per lane (b,), overflow count) for one bucket-sorted lane
+    range over ``keys_arr`` — read-only."""
+    kern = functools.partial(_kv_probe_kernel, slots=slots)
+    claimed_spec = pl.BlockSpec((1, slots, 1), lambda i, *_: (0, 0, 0),
+                                memory_space=pltpu.VMEM)
+    claimed = jnp.zeros((1, slots, 1), jnp.int32)
+    carry = jnp.full((1,), -1, jnp.int32)
+    slot_chunks, n_over = [], jnp.int32(0)
+    for s, m in _lane_chunks(buckets.shape[0]):
+        bkt = buckets[s:s + m]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(m,),
+            in_specs=[_keys_block(slots), claimed_spec],
+            out_specs=[_SMEM_OUT, _SMEM_OUT, claimed_spec],
+        )
+        slot, nover, claimed = pl.pallas_call(
+            kern, grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((m,), jnp.int32),
+                       jax.ShapeDtypeStruct((1,), jnp.int32),
+                       jax.ShapeDtypeStruct((1, slots, 1), jnp.int32)],
+            interpret=interpret,
+        )(bkt, qhi[s:s + m], qlo[s:s + m], valid[s:s + m], carry,
+          keys_arr, claimed)
+        carry = bkt[-1:]
+        slot_chunks.append(slot)
+        n_over = n_over + nover[0]
+    return jnp.concatenate(slot_chunks), n_over
+
+
+def _kv_commit_kernel(*refs, slots: int, vdim: int, nstate: int,
+                      updater: Any, state_treedef: Any):
+    """Masked one-hot updater apply into the claimed slots of the
+    resident (aliased) bucket blocks; ``gate != 0`` turns the whole
+    batch into a no-op that writes every visited bucket back
+    bit-identically. Old values read the PRE-batch inputs (dup keys per
+    batch are rejected upstream, so each slot is written at most
+    once)."""
+    bkt, qhi, qlo, slot_ref, gate = refs[:5]
+    keys_in, vals_in = refs[5], refs[6]
+    state_in = refs[7:7 + nstate]
+    d_ref, opt_ref = refs[7 + nstate], refs[8 + nstate]
+    keys_out, vals_out = refs[9 + nstate], refs[10 + nstate]
+    state_out = refs[11 + nstate:11 + 2 * nstate]
+
+    i = pl.program_id(0)
+
+    # run starts copy input→output so masked slot writes merge into the
+    # original rows (the aliased buffer keeps unvisited rows); keys and
+    # vector values are blocked per bucket, scalar values per 8-bucket
+    # group — each copies on ITS block's run start
+    @pl.when(_new_block(bkt, i, 1))
+    def _():
         keys_out[...] = keys_in[...]
-        vals_out[...] = vals_in[...]
-        for si, so in zip(state_in, state_out):
-            so[...] = si[...]
+        if vdim:
+            vals_out[...] = vals_in[...]
+            for si, so in zip(state_in, state_out):
+                so[...] = si[...]
 
-    row = keys_in[...]                                # (1, S, 2) uint32
-    q = q_ref[...]                                    # (1, 2)
-    valid_l = v_ref[...] > 0                          # (1, 1)
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, slots), 1)
+    if not vdim:
+        @pl.when(_new_block(bkt, i, 0))
+        def _():
+            vals_out[...] = vals_in[...]
+            for si, so in zip(state_in, state_out):
+                so[...] = si[...]
 
-    @pl.when(p == 0)
-    def _():
-        claims = claims_ref[0]
-        slot, claim_inc, over_inc = _probe_lane(row, q, valid_l, claims,
-                                                slots=slots)
-        slot_ref[i, 0] = slot[0, 0]
-        claims_ref[0] = claims + claim_inc
-        nover_ref[0, 0] = nover_ref[0, 0] + over_inc
+    slot = jnp.where(gate[0] == 0, slot_ref[i], slots)
+    keys_out[...] = jnp.where(
+        _slot_iota((1, slots, 2)) == slot,
+        _key_row(qhi[i], qlo[i], slots), keys_out[...])
 
-    @pl.when(p == 1)
-    def _():
-        # apply: masked one-hot writes; the whole batch drops when ANY
-        # lane overflowed (the table must stay untouched for the raise)
-        slot = slot_ref[i, 0]
-        good = jnp.logical_and(slot < slots, nover_ref[0, 0] == 0)
-        oh = (lane_iota == slot) & good                   # (1, S)
-        _apply_write(oh, q, d_ref[...], o_ref[...], vals_in, state_in,
-                     keys_out, vals_out, state_out, vdim=vdim,
-                     updater=updater, state_treedef=state_treedef)
+    at = ... if vdim else _row_at(bkt[i], 0)
+    oh = _slot_iota((1, slots, vdim) if vdim else (1, slots)) == slot
+
+    def pick(ref):
+        old = jnp.where(oh, ref[at], 0)
+        return old.sum(axis=1) if vdim else old.sum(axis=1,
+                                                    keepdims=True)
+
+    opt = jax.tree.unflatten(_OPTION_TREE,
+                             [opt_ref[k:k + 1, :] for k in range(5)])
+    upd, new_state = updater.apply(
+        pick(vals_in),
+        jax.tree.unflatten(state_treedef, [pick(s) for s in state_in]),
+        d_ref[0], opt)
+
+    def put(ref, new):
+        new = new[:, None, :] if vdim else new
+        ref[at] = jnp.where(oh, new.astype(ref.dtype), ref[at])
+
+    put(vals_out, upd)
+    for so, ns in zip(state_out, jax.tree.leaves(new_state)):
+        put(so, ns)
+
+
+_OPTION_TREE = jax.tree.structure(AddOption())
+
+
+def _option_column(option: AddOption):
+    """The AddOption as an (8, 1) f32 VMEM operand: row k is leaf k as
+    a (1, 1) vector, so updaters do vector math on it (Mosaic's scalar
+    core has no pow/sqrt)."""
+    col = jnp.stack([jnp.asarray(leaf, jnp.float32)
+                     for leaf in jax.tree.leaves(option)])
+    return jnp.zeros((8, 1), jnp.float32).at[:5, 0].set(col)
+
+
+def _kv_commit(keys_arr, values_arr, state_leaves, buckets, qhi, qlo,
+               slot, gate, deltas, opt, *, slots: int, vdim: int,
+               updater: Any, state_treedef: Any, interpret: bool):
+    """Apply one bucket-sorted lane range into (keys, values, *state),
+    in place. ``deltas`` (b, max(vdim, 1))."""
+    nstate = len(state_leaves)
+    kern = functools.partial(
+        _kv_commit_kernel, slots=slots, vdim=vdim, nstate=nstate,
+        updater=updater, state_treedef=state_treedef)
+    width = deltas.shape[1]
+    vspec = _kv_block(slots, vdim)
+    table_specs = [_keys_block(slots), vspec] + [vspec] * nstate
+    # operands 5.. (keys, values, state) alias their outputs in place —
+    # one HBM buffer, unvisited rows untouched
+    aliases = {5 + j: j for j in range(2 + nstate)}
+    outs = [keys_arr, values_arr, *state_leaves]
+    for s, m in _lane_chunks(buckets.shape[0]):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(m,),
+            in_specs=table_specs + [
+                _lane_block(0, width),
+                pl.BlockSpec((8, 1), lambda i, *_: (0, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=table_specs,
+        )
+        outs = pl.pallas_call(
+            kern, grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype)
+                       for o in outs],
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(buckets[s:s + m], qhi[s:s + m], qlo[s:s + m], slot[s:s + m],
+          gate, *outs, deltas[s:s + m].reshape(m, 1, width), opt)
+    return outs
 
 
 def build_kv_probe_update(*, slots: int, value_dim: int, updater: Any,
@@ -492,75 +619,21 @@ def build_kv_probe_update(*, slots: int, value_dim: int, updater: Any,
     by bucket (``prepare_add`` guarantees it)."""
     vdim = int(value_dim)
     treedef = jax.tree.structure(state_template)
-    nstate = len(jax.tree.leaves(state_template))
-    kern = functools.partial(_kv_probe_kernel, slots=slots, vdim=vdim,
-                             nstate=nstate, updater=updater,
-                             state_treedef=treedef)
 
     def probe_update(keys_arr, values_arr, state, buckets, query,
                      deltas, valid, option):
         b = buckets.shape[0]
-        state_leaves = jax.tree.leaves(state)
-        d2 = deltas.reshape(b, vdim) if vdim else deltas.reshape(b, 1)
-        v2 = valid.astype(jnp.int32).reshape(b, 1)
-        opt = jnp.zeros((1, 8), jnp.float32)
-        opt = opt.at[0, 0].set(option.learning_rate)
-        opt = opt.at[0, 1].set(option.momentum)
-        opt = opt.at[0, 2].set(option.rho)
-        opt = opt.at[0, 3].set(option.lam)
-        opt = opt.at[0, 4].set(option.step.astype(jnp.float32))
-
-        if vdim:
-            vblk = (1, slots, vdim)
-            vmap = lambda p, i, bkt: (bkt[i], 0, 0)
-        else:
-            vblk = (1, slots)
-            vmap = lambda p, i, bkt: (bkt[i], 0)
-        lane = lambda p, i, bkt: (i, 0)
-        const = lambda p, i, bkt: (0, 0)
-        kblk = pl.BlockSpec((1, slots, 2),
-                            lambda p, i, bkt: (bkt[i], 0, 0),
-                            memory_space=pltpu.VMEM)
-        vspec = pl.BlockSpec(vblk, vmap, memory_space=pltpu.VMEM)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(2, b),
-            in_specs=(
-                [kblk, vspec] + [vspec] * nstate
-                + [pl.BlockSpec((1, 2), lane, memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, d2.shape[1]), lane,
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, 1), lane, memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, 8), const,
-                                memory_space=pltpu.VMEM)]),
-            out_specs=(
-                [kblk, vspec] + [vspec] * nstate
-                + [pl.BlockSpec((1, 1), const,
-                                memory_space=pltpu.VMEM)]),
-            scratch_shapes=[pltpu.VMEM((b, 1), jnp.int32),
-                            pltpu.SMEM((1,), jnp.int32)],
-        )
-        # operands 1..2+nstate (keys, values, state) alias their outputs
-        # in place — one HBM buffer, unvisited rows untouched
-        aliases = {1 + j: j for j in range(2 + nstate)}
-        outs = pl.pallas_call(
-            kern,
-            grid_spec=grid_spec,
-            out_shape=(
-                [jax.ShapeDtypeStruct(keys_arr.shape, keys_arr.dtype),
-                 jax.ShapeDtypeStruct(values_arr.shape,
-                                      values_arr.dtype)]
-                + [jax.ShapeDtypeStruct(s.shape, s.dtype)
-                   for s in state_leaves]
-                + [jax.ShapeDtypeStruct((1, 1), jnp.int32)]),
-            input_output_aliases=aliases,
-            interpret=interpret,
-        )(buckets, keys_arr, values_arr, *state_leaves, query, d2, v2,
-          opt)
-        new_keys, new_vals = outs[0], outs[1]
-        new_state = jax.tree.unflatten(treedef, outs[2:2 + nstate])
-        n_over = outs[2 + nstate][0, 0]
-        return new_keys, new_vals, new_state, n_over
+        qhi, qlo = _key_words(query)
+        slot, n_over = _kv_probe(keys_arr, buckets, qhi, qlo,
+                                 valid.astype(jnp.int32), slots=slots,
+                                 interpret=interpret)
+        outs = _kv_commit(
+            keys_arr, values_arr, jax.tree.leaves(state), buckets, qhi,
+            qlo, slot, n_over.reshape(1), deltas.reshape(b, max(vdim, 1)),
+            _option_column(option), slots=slots, vdim=vdim,
+            updater=updater, state_treedef=treedef, interpret=interpret)
+        return (outs[0], outs[1],
+                jax.tree.unflatten(treedef, outs[2:]), n_over)
 
     return probe_update
 
@@ -568,136 +641,153 @@ def build_kv_probe_update(*, slots: int, value_dim: int, updater: Any,
 # -- matrix / sparse row paths ---------------------------------------------
 
 
-def _row_block(tiles: int, num_cols: int):
-    """(block shape, gather index map, lane count) for a row of flat
-    ``(R, C)`` or tiled ``(R, C/128, 128)`` storage."""
-    if tiles:
-        return ((1, tiles, LANES),
-                lambda i, ids: (ids[i], 0, 0))
-    return ((1, num_cols), lambda i, ids: (ids[i], 0))
-
-
-def _gather_kernel(ids_ref, p_ref, o_ref):
-    o_ref[...] = p_ref[...].reshape(o_ref.shape)
+def _gather_kernel(ids_ref, p_ref, o_ref, *, tiles: int):
+    i = pl.program_id(0)
+    o_ref[_lane_at(tiles)] = p_ref[_row_at(ids_ref[i], tiles)]
 
 
 def build_row_gather(*, num_cols: int, tiles: int,
                      interpret: bool) -> Callable:
     """(param, ids) -> rows [n, num_cols] — the ``jnp.take`` row gather
     as a scalar-prefetch-indexed VMEM copy."""
-    blk, imap = _row_block(tiles, num_cols)
+    kern = functools.partial(_gather_kernel, tiles=tiles)
 
     def gather(param, ids):
         n = ids.shape[0]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((1, num_cols),
-                                   lambda i, ids: (i, 0),
-                                   memory_space=pltpu.VMEM),
-        )
-        return pl.pallas_call(
-            _gather_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n, num_cols), param.dtype),
-            interpret=interpret,
-        )(ids, param)
+        rows = []
+        for s, m in _lane_chunks(n):
+            grid_spec = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(m,),
+                in_specs=[_table_block(tiles, num_cols)],
+                out_specs=_lane_block(tiles, num_cols),
+            )
+            rows.append(pl.pallas_call(
+                kern,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct(
+                    _lane_shape(m, tiles, num_cols), param.dtype),
+                interpret=interpret,
+            )(ids[s:s + m], param))
+        return jnp.concatenate(rows).reshape(n, num_cols)
 
     return gather
 
 
-def _row_scatter_kernel(ids_ref, p_ref, d_ref, o_ref):
+def _row_scatter_kernel(*refs, tiles: int, masked: bool):
+    ids_ref = refs[0]
+    p_ref, d_ref, o_ref = refs[1 + masked:]
     i = pl.program_id(0)
-    first = jnp.logical_or(
-        i == 0, ids_ref[i] != ids_ref[jnp.maximum(i - 1, 0)])
 
-    @pl.when(first)
+    @pl.when(_new_block(ids_ref, i, tiles))
     def _():
         o_ref[...] = p_ref[...]
-    o_ref[...] = o_ref[...] + d_ref[...].reshape(o_ref.shape).astype(
-        o_ref.dtype)
+
+    row = _row_at(ids_ref[i], tiles)
+
+    def add():
+        o_ref[row] = o_ref[row] + d_ref[_lane_at(tiles)].astype(
+            o_ref.dtype)
+
+    if masked:
+        pl.when(refs[1][i] > 0)(add)
+    else:
+        add()
 
 
-def build_row_scatter_add(*, num_cols: int, tiles: int,
-                          interpret: bool) -> Callable:
-    """(param, ids, deltas) -> param — duplicate-safe row scatter-add.
-    Requires ``ids`` sorted (host prep); each touched row is fetched
-    once, its duplicates segment-summed in the resident VMEM block, and
-    written back to HBM once."""
-    blk, imap = _row_block(tiles, num_cols)
+def build_row_scatter_add(*, num_cols: int, tiles: int, interpret: bool,
+                          masked: bool = False) -> Callable:
+    """(param, ids, deltas[, valid]) -> param — duplicate-safe row
+    scatter-add. Requires ``ids`` sorted (host prep); each touched
+    block is fetched once, its lanes segment-summed in the resident
+    VMEM block, and written back to HBM once. ``masked`` adds a
+    per-lane write gate: invalid lanes still walk the grid (their block
+    copies through bit-exact), so foreign/padding lanes can ride a
+    shard's dense lane range — the shard_map builder and the in-trace
+    functional form both wrap the masked kernel."""
+    kern = functools.partial(_row_scatter_kernel, tiles=tiles,
+                             masked=masked)
+    table = _table_block(tiles, num_cols)
 
-    def scatter_add(param, ids, deltas):
+    def scatter_add(param, ids, deltas, *valid):
         n = ids.shape[0]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, num_cols),
-                                   lambda i, ids: (i, 0),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-        )
-        return pl.pallas_call(
-            _row_scatter_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(param.shape, param.dtype),
-            input_output_aliases={1: 0},
-            interpret=interpret,
-        )(ids, param, deltas)
+        deltas = deltas.reshape(_lane_shape(n, tiles, num_cols))
+        gates = [v.astype(jnp.int32) for v in valid]
+        for s, m in _lane_chunks(n):
+            grid_spec = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1 + masked,
+                grid=(m,),
+                in_specs=[table, _lane_block(tiles, num_cols)],
+                out_specs=table,
+            )
+            param = pl.pallas_call(
+                kern,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct(param.shape, param.dtype),
+                input_output_aliases={1 + masked: 0},
+                interpret=interpret,
+            )(ids[s:s + m], *(g[s:s + m] for g in gates), param,
+              deltas[s:s + m])
+        return param
 
     return scatter_add
 
 
-def _coo_kernel(rows_ref, p_ref, c_ref, v_ref, o_ref, *, tiles: int,
-                num_cols: int):
+def _coo_kernel(*refs, tiles: int, num_cols: int, masked: bool):
+    rows_ref, cols_ref, vals_ref = refs[:3]
+    p_ref, o_ref = refs[3 + masked:]
     i = pl.program_id(0)
-    first = jnp.logical_or(
-        i == 0, rows_ref[i] != rows_ref[jnp.maximum(i - 1, 0)])
 
-    @pl.when(first)
+    @pl.when(_new_block(rows_ref, i, tiles))
     def _():
         o_ref[...] = p_ref[...]
-    col = c_ref[0, 0]
+
     if tiles:
         kc = jax.lax.broadcasted_iota(jnp.int32, (1, tiles, LANES), 1)
         kl = jax.lax.broadcasted_iota(jnp.int32, (1, tiles, LANES), 2)
-        oh = (kc * LANES + kl) == col
+        col = kc * LANES + kl
     else:
-        oh = jax.lax.broadcasted_iota(jnp.int32, (1, num_cols), 1) == col
-    o_ref[...] = o_ref[...] + jnp.where(
-        oh, v_ref[0, 0].astype(o_ref.dtype), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, num_cols), 1)
+    row = _row_at(rows_ref[i], tiles)
+
+    def add():
+        o_ref[row] = o_ref[row] + jnp.where(col == cols_ref[i],
+                                            vals_ref[i], 0)
+
+    if masked:
+        pl.when(refs[3][i] > 0)(add)
+    else:
+        add()
 
 
-def build_coo_scatter_add(*, num_cols: int, tiles: int,
-                          interpret: bool) -> Callable:
-    """(param, rows, cols, vals) -> param — the COO sparse Add.
+def build_coo_scatter_add(*, num_cols: int, tiles: int, interpret: bool,
+                          masked: bool = False) -> Callable:
+    """(param, rows, cols, vals[, valid]) -> param — the COO sparse Add.
     Requires ``rows`` sorted (host prep): one VMEM-resident run per
-    touched row, one HBM write per touched row."""
-    blk, imap = _row_block(tiles, num_cols)
-    kern = functools.partial(_coo_kernel, tiles=tiles,
-                             num_cols=num_cols)
+    touched block, one HBM write per touched block. ``masked`` as in
+    :func:`build_row_scatter_add`."""
+    kern = functools.partial(_coo_kernel, tiles=tiles, num_cols=num_cols,
+                             masked=masked)
+    table = _table_block(tiles, num_cols)
 
-    def coo(param, rows, cols, vals):
-        n = rows.shape[0]
-        lane = lambda i, ids: (i, 0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, 1), lane,
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, 1), lane,
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-        )
-        return pl.pallas_call(
-            kern,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(param.shape, param.dtype),
-            input_output_aliases={1: 0},
-            interpret=interpret,
-        )(rows, param, cols.reshape(n, 1), vals.reshape(n, 1))
+    def coo(param, rows, cols, vals, *valid):
+        lanes = [rows, cols, vals.astype(param.dtype)] \
+            + [v.astype(jnp.int32) for v in valid]
+        for s, m in _lane_chunks(rows.shape[0]):
+            grid_spec = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3 + masked,
+                grid=(m,),
+                in_specs=[table],
+                out_specs=table,
+            )
+            param = pl.pallas_call(
+                kern,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct(param.shape, param.dtype),
+                input_output_aliases={3 + masked: 0},
+                interpret=interpret,
+            )(*(a[s:s + m] for a in lanes), param)
+        return param
 
     return coo
 
@@ -708,70 +798,16 @@ def build_coo_scatter_add(*, num_cols: int, tiles: int,
 # local rows/buckets. Operands arrive as the (shards, L, ...) lane
 # slices of tables/hashing.shard_lane_slices — shard s's grid walks row
 # s, a dense bucket/row-sorted lane range whose non-local tail is
-# masked padding — so no kernel ever communicates across shards. The
-# one global interaction the KV contract needs (ANY overflow voids the
-# WHOLE batch) is a scalar jnp.sum of per-shard overflow counts between
-# the probe and commit shard_maps, outside any kernel.
+# masked padding — so no kernel ever communicates across shards.
 
 
-def _kv_probe_only_kernel(bkt, keys_ref, q_ref, v_ref, slot_ref,
-                          nover_ref, claims_ref, *, slots: int):
-    """Sharded probe pass: one grid step per LOCAL lane, emitting the
-    claimed slot per lane plus this shard's overflow count. The commit
-    decision (the all-or-nothing drop) needs the GLOBAL count, so the
-    write-back lives in :func:`_kv_commit_kernel`, gated on the scalar
-    sum the wrapper computes between the two shard_maps."""
-    i = pl.program_id(0)
-    new_run = jnp.logical_or(
-        i == 0, bkt[i] != bkt[jnp.maximum(i - 1, 0)])
-
-    @pl.when(i == 0)
-    def _():
-        nover_ref[0, 0] = jnp.int32(0)
-
-    @pl.when(new_run)
-    def _():
-        claims_ref[0] = jnp.int32(0)
-
-    claims = claims_ref[0]
-    slot, claim_inc, over_inc = _probe_lane(
-        keys_ref[...], q_ref[...], v_ref[...] > 0, claims, slots=slots)
-    slot_ref[0, 0] = slot[0, 0]
-    claims_ref[0] = claims + claim_inc
-    nover_ref[0, 0] = nover_ref[0, 0] + over_inc
-
-
-def _kv_commit_kernel(*refs, slots: int, vdim: int, nstate: int,
-                      updater: Any, state_treedef: Any):
-    """Sharded commit pass: masked one-hot writes of the slots claimed
-    by :func:`_kv_probe_only_kernel`, gated on the replicated GLOBAL
-    overflow count (gate != 0 → the whole batch is a no-op and every
-    visited bucket writes back its pre-batch rows bit-identically)."""
-    bkt = refs[0]
-    keys_in, vals_in = refs[1], refs[2]
-    state_in = refs[3:3 + nstate]
-    q_ref, d_ref, slot_ref, gate_ref, o_ref = refs[3 + nstate:8 + nstate]
-    keys_out, vals_out = refs[8 + nstate], refs[9 + nstate]
-    state_out = refs[10 + nstate:10 + 2 * nstate]
-
-    i = pl.program_id(0)
-    new_run = jnp.logical_or(
-        i == 0, bkt[i] != bkt[jnp.maximum(i - 1, 0)])
-
-    @pl.when(new_run)
-    def _():
-        keys_out[...] = keys_in[...]
-        vals_out[...] = vals_in[...]
-        for si, so in zip(state_in, state_out):
-            so[...] = si[...]
-
-    slot = slot_ref[0, 0]
-    good = jnp.logical_and(slot < slots, gate_ref[0, 0] == 0)
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (1, slots), 1)
-    oh = (lane_iota == slot) & good
-    _apply_write(oh, q_ref[...], d_ref[...], o_ref[...], vals_in,
-                 state_in, keys_out, vals_out, state_out, vdim=vdim,
-                 updater=updater, state_treedef=state_treedef)
+def _shards_of(mesh: Any, axis: str, lead: int, what: str) -> int:
+    shards = int(dict(mesh.shape)[axis])
+    if lead % shards:
+        raise UnsupportedShardingLayout(
+            f"{what}={lead} not divisible by {shards} "
+            f"{axis!r}-axis shards")
+    return shards
 
 
 def build_kv_probe_update_sharded(*, slots: int, value_dim: int,
@@ -783,136 +819,52 @@ def build_kv_probe_update_sharded(*, slots: int, value_dim: int,
     ``buckets`` (shards, L) LOCAL bucket ids sorted per shard,
     ``query`` (shards, L, 2), ``deltas`` (shards, L[, D]), ``valid``
     (shards, L) — ``KVTable.prepare_add`` emits them through
-    ``shard_lane_slices``. Probe and commit are separate per-shard
-    kernels with the global overflow sum between them (module doc)."""
-    shards = int(dict(mesh.shape)[axis])
-    if num_buckets % shards:
-        raise UnsupportedShardingLayout(
-            f"num_buckets={num_buckets} not divisible by {shards} "
-            f"{axis!r}-axis shards")
+    ``shard_lane_slices``."""
+    shards = _shards_of(mesh, axis, num_buckets, "num_buckets")
     vdim = int(value_dim)
     treedef = jax.tree.structure(state_template)
     nstate = len(jax.tree.leaves(state_template))
-    probe_kern = functools.partial(_kv_probe_only_kernel, slots=slots)
-    commit_kern = functools.partial(
-        _kv_commit_kernel, slots=slots, vdim=vdim, nstate=nstate,
-        updater=updater, state_treedef=treedef)
     kspec = P(axis, None, None)
     vspec = P(axis, None, None) if vdim else P(axis, None)
     lanes2 = P(axis, None)
     lanes3 = P(axis, None, None)
-    rep2 = P(None, None)
+
+    def probe_body(keys_blk, bkt_blk, q_blk, v_blk):
+        qhi, qlo = _key_words(q_blk[0])
+        slot, nover = _kv_probe(keys_blk, bkt_blk[0], qhi, qlo, v_blk[0],
+                                slots=slots, interpret=interpret)
+        return slot[None], nover.reshape(1)
+
+    def commit_body(keys_blk, vals_blk, *rest):
+        state_blks = rest[:nstate]
+        bkt_blk, q_blk, d_blk, slot_blk, gate, opt = rest[nstate:]
+        qhi, qlo = _key_words(q_blk[0])
+        return tuple(_kv_commit(
+            keys_blk, vals_blk, list(state_blks), bkt_blk[0], qhi, qlo,
+            slot_blk[0], gate, d_blk[0], opt, slots=slots, vdim=vdim,
+            updater=updater, state_treedef=treedef, interpret=interpret))
 
     def probe_update(keys_arr, values_arr, state, buckets, query,
                      deltas, valid, option):
         L = buckets.shape[1]
-        state_leaves = jax.tree.leaves(state)
-        d3 = deltas.reshape(shards, L, vdim) if vdim \
-            else deltas.reshape(shards, L, 1)
-        v3 = valid.astype(jnp.int32).reshape(shards, L, 1)
-        opt = jnp.zeros((1, 8), jnp.float32)
-        opt = opt.at[0, 0].set(option.learning_rate)
-        opt = opt.at[0, 1].set(option.momentum)
-        opt = opt.at[0, 2].set(option.rho)
-        opt = opt.at[0, 3].set(option.lam)
-        opt = opt.at[0, 4].set(option.step.astype(jnp.float32))
-
-        lane = lambda i, bkt: (i, 0)
-        const = lambda i, bkt: (0, 0)
-
-        def probe_body(keys_blk, bkt_blk, q_blk, v_blk):
-            grid_spec = pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(L,),
-                in_specs=[pl.BlockSpec((1, slots, 2),
-                                       lambda i, bkt: (bkt[i], 0, 0),
-                                       memory_space=pltpu.VMEM),
-                          pl.BlockSpec((1, 2), lane,
-                                       memory_space=pltpu.VMEM),
-                          pl.BlockSpec((1, 1), lane,
-                                       memory_space=pltpu.VMEM)],
-                out_specs=[pl.BlockSpec((1, 1), lane,
-                                        memory_space=pltpu.VMEM),
-                           pl.BlockSpec((1, 1), const,
-                                        memory_space=pltpu.VMEM)],
-                scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-            )
-            slot, nover = pl.pallas_call(
-                probe_kern, grid_spec=grid_spec,
-                out_shape=[jax.ShapeDtypeStruct((L, 1), jnp.int32),
-                           jax.ShapeDtypeStruct((1, 1), jnp.int32)],
-                interpret=interpret,
-            )(bkt_blk[0], keys_blk, q_blk[0], v_blk[0])
-            return slot[None], nover[None]
-
         slot, nover = shard_map(
             probe_body, mesh=mesh,
-            in_specs=(kspec, lanes2, lanes3, lanes3),
-            out_specs=(lanes3, lanes3), check_vma=False,
-        )(keys_arr, buckets, query, v3)
+            in_specs=(kspec, lanes2, lanes3, lanes2),
+            out_specs=(lanes2, P(axis)), check_vma=False,
+        )(keys_arr, buckets, query, valid.astype(jnp.int32))
         # the ONE global interaction: the all-or-nothing overflow gate
         n_over = jnp.sum(nover).astype(jnp.int32)
-        gate = n_over.reshape(1, 1)
-
-        def commit_body(keys_blk, vals_blk, *rest):
-            state_blks = rest[:nstate]
-            bkt_blk, q_blk, d_blk, slot_blk, gate_blk, opt_blk = \
-                rest[nstate:]
-            if vdim:
-                vblk = (1, slots, vdim)
-                vmap = lambda i, bkt: (bkt[i], 0, 0)
-            else:
-                vblk = (1, slots)
-                vmap = lambda i, bkt: (bkt[i], 0)
-            kblk = pl.BlockSpec((1, slots, 2),
-                                lambda i, bkt: (bkt[i], 0, 0),
-                                memory_space=pltpu.VMEM)
-            vsp = pl.BlockSpec(vblk, vmap, memory_space=pltpu.VMEM)
-            grid_spec = pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(L,),
-                in_specs=(
-                    [kblk, vsp] + [vsp] * nstate
-                    + [pl.BlockSpec((1, 2), lane,
-                                    memory_space=pltpu.VMEM),
-                       pl.BlockSpec((1, d_blk.shape[-1]), lane,
-                                    memory_space=pltpu.VMEM),
-                       pl.BlockSpec((1, 1), lane,
-                                    memory_space=pltpu.VMEM),
-                       pl.BlockSpec((1, 1), const,
-                                    memory_space=pltpu.VMEM),
-                       pl.BlockSpec((1, 8), const,
-                                    memory_space=pltpu.VMEM)]),
-                out_specs=[kblk, vsp] + [vsp] * nstate,
-            )
-            aliases = {1 + j: j for j in range(2 + nstate)}
-            outs = pl.pallas_call(
-                commit_kern, grid_spec=grid_spec,
-                out_shape=(
-                    [jax.ShapeDtypeStruct(keys_blk.shape,
-                                          keys_blk.dtype),
-                     jax.ShapeDtypeStruct(vals_blk.shape,
-                                          vals_blk.dtype)]
-                    + [jax.ShapeDtypeStruct(s.shape, s.dtype)
-                       for s in state_blks]),
-                input_output_aliases=aliases,
-                interpret=interpret,
-            )(bkt_blk[0], keys_blk, vals_blk, *state_blks, q_blk[0],
-              d_blk[0], slot_blk[0], gate_blk, opt_blk)
-            return tuple(outs)
-
         outs = shard_map(
             commit_body, mesh=mesh,
             in_specs=(kspec, vspec) + (vspec,) * nstate
-            + (lanes2, lanes3, lanes3, lanes3, rep2, rep2),
+            + (lanes2, lanes3, lanes3, lanes2, P(None), P(None, None)),
             out_specs=(kspec, vspec) + (vspec,) * nstate,
             check_vma=False,
-        )(keys_arr, values_arr, *state_leaves, buckets, query, d3,
-          slot, gate, opt)
-        new_keys, new_vals = outs[0], outs[1]
-        new_state = jax.tree.unflatten(treedef,
-                                       list(outs[2:2 + nstate]))
-        return new_keys, new_vals, new_state, n_over
+        )(keys_arr, values_arr, *jax.tree.leaves(state), buckets, query,
+          deltas.reshape(shards, L, max(vdim, 1)), slot,
+          n_over.reshape(1), _option_column(option))
+        return (outs[0], outs[1],
+                jax.tree.unflatten(treedef, list(outs[2:])), n_over)
 
     return probe_update
 
@@ -927,11 +879,7 @@ def build_kv_lookup_sharded(*, slots: int, value_dim: int,
     indices unpermuting the per-shard lane rows back to caller order
     (``KVTable.get_jax`` builds all three). Wraps the flat lookup
     kernel per shard."""
-    shards = int(dict(mesh.shape)[axis])
-    if num_buckets % shards:
-        raise UnsupportedShardingLayout(
-            f"num_buckets={num_buckets} not divisible by {shards} "
-            f"{axis!r}-axis shards")
+    _shards_of(mesh, axis, num_buckets, "num_buckets")
     vdim = int(value_dim)
     inner = build_kv_lookup(slots=slots, value_dim=value_dim,
                             default_value=default_value,
@@ -965,11 +913,7 @@ def build_row_gather_sharded(*, num_cols: int, tiles: int,
     """(param, ids, inv) -> rows [len(inv), num_cols]: per-shard local
     gathers of the lane-sliced ``ids`` (shards, L) of LOCAL row ids,
     unpermuted by the flat ``inv`` map."""
-    shards = int(dict(mesh.shape)[axis])
-    if lead % shards:
-        raise UnsupportedShardingLayout(
-            f"lead={lead} not divisible by {shards} "
-            f"{axis!r}-axis shards")
+    _shards_of(mesh, axis, lead, "lead")
     inner = build_row_gather(num_cols=num_cols, tiles=tiles,
                              interpret=interpret)
     pspec = P(axis, None, None) if tiles else P(axis, None)
@@ -987,136 +931,24 @@ def build_row_gather_sharded(*, num_cols: int, tiles: int,
     return gather
 
 
-def _row_scatter_masked_kernel(ids_ref, p_ref, d_ref, v_ref, o_ref):
-    i = pl.program_id(0)
-    first = jnp.logical_or(
-        i == 0, ids_ref[i] != ids_ref[jnp.maximum(i - 1, 0)])
-
-    @pl.when(first)
-    def _():
-        o_ref[...] = p_ref[...]
-    ok = v_ref[0, 0] > 0
-    o_ref[...] = jnp.where(
-        ok,
-        o_ref[...] + d_ref[...].reshape(o_ref.shape).astype(o_ref.dtype),
-        o_ref[...])
-
-
-def build_row_scatter_add_masked(*, num_cols: int, tiles: int,
-                                 interpret: bool) -> Callable:
-    """(param, ids, deltas, valid) -> param — the sorted row
-    scatter-add with a per-lane write gate. Invalid lanes still walk
-    the grid (their row copies through bit-exact), so foreign/padding
-    lanes can ride a shard's dense lane range: the shard_map builder
-    and the in-trace functional form both wrap THIS kernel."""
-    blk, imap = _row_block(tiles, num_cols)
-
-    def scatter_add(param, ids, deltas, valid):
-        n = ids.shape[0]
-        lane = lambda i, ids: (i, 0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, num_cols), lane,
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, 1), lane,
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-        )
-        return pl.pallas_call(
-            _row_scatter_masked_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(param.shape, param.dtype),
-            input_output_aliases={1: 0},
-            interpret=interpret,
-        )(ids, param, deltas.reshape(n, num_cols),
-          valid.astype(jnp.int32).reshape(n, 1))
-
-    return scatter_add
-
-
 def build_row_scatter_add_sharded(*, num_cols: int, tiles: int,
                                   interpret: bool, mesh: Any, axis: str,
                                   lead: int) -> Callable:
     """(param, ids, deltas, valid) -> param with lane-sliced operands
     (shards, L[, C]) of LOCAL row ids: each shard scatter-adds only its
     valid lanes into its local row block."""
-    shards = int(dict(mesh.shape)[axis])
-    if lead % shards:
-        raise UnsupportedShardingLayout(
-            f"lead={lead} not divisible by {shards} "
-            f"{axis!r}-axis shards")
-    inner = build_row_scatter_add_masked(num_cols=num_cols, tiles=tiles,
-                                         interpret=interpret)
+    _shards_of(mesh, axis, lead, "lead")
+    inner = build_row_scatter_add(num_cols=num_cols, tiles=tiles,
+                                  interpret=interpret, masked=True)
     pspec = P(axis, None, None) if tiles else P(axis, None)
 
     def body(p_blk, ids_blk, d_blk, v_blk):
         return inner(p_blk, ids_blk[0], d_blk[0], v_blk[0])
 
-    sm = shard_map(body, mesh=mesh,
-                   in_specs=(pspec, P(axis, None), P(axis, None, None),
-                             P(axis, None)),
-                   out_specs=pspec, check_vma=False)
-
-    def scatter_add(param, ids, deltas, valid):
-        return sm(param, ids, deltas, valid)
-
-    return scatter_add
-
-
-def _coo_masked_kernel(rows_ref, p_ref, c_ref, v_ref, m_ref, o_ref, *,
-                       tiles: int, num_cols: int):
-    i = pl.program_id(0)
-    first = jnp.logical_or(
-        i == 0, rows_ref[i] != rows_ref[jnp.maximum(i - 1, 0)])
-
-    @pl.when(first)
-    def _():
-        o_ref[...] = p_ref[...]
-    col = c_ref[0, 0]
-    if tiles:
-        kc = jax.lax.broadcasted_iota(jnp.int32, (1, tiles, LANES), 1)
-        kl = jax.lax.broadcasted_iota(jnp.int32, (1, tiles, LANES), 2)
-        oh = (kc * LANES + kl) == col
-    else:
-        oh = jax.lax.broadcasted_iota(jnp.int32, (1, num_cols), 1) == col
-    ok = m_ref[0, 0] > 0
-    o_ref[...] = jnp.where(
-        ok,
-        o_ref[...] + jnp.where(oh, v_ref[0, 0].astype(o_ref.dtype), 0),
-        o_ref[...])
-
-
-def build_coo_scatter_add_masked(*, num_cols: int, tiles: int,
-                                 interpret: bool) -> Callable:
-    """(param, rows, cols, vals, valid) -> param — the sorted COO
-    scatter-add with a per-lane write gate (see
-    :func:`build_row_scatter_add_masked` for why masked lanes walk)."""
-    blk, imap = _row_block(tiles, num_cols)
-    kern = functools.partial(_coo_masked_kernel, tiles=tiles,
-                             num_cols=num_cols)
-
-    def coo(param, rows, cols, vals, valid):
-        n = rows.shape[0]
-        lane = lambda i, ids: (i, 0)
-        lane_spec = pl.BlockSpec((1, 1), lane, memory_space=pltpu.VMEM)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-                      lane_spec, lane_spec, lane_spec],
-            out_specs=pl.BlockSpec(blk, imap, memory_space=pltpu.VMEM),
-        )
-        return pl.pallas_call(
-            kern, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(param.shape, param.dtype),
-            input_output_aliases={1: 0},
-            interpret=interpret,
-        )(rows, param, cols.reshape(n, 1), vals.reshape(n, 1),
-          valid.astype(jnp.int32).reshape(n, 1))
-
-    return coo
+    return shard_map(body, mesh=mesh,
+                     in_specs=(pspec, P(axis, None), P(axis, None, None),
+                               P(axis, None)),
+                     out_specs=pspec, check_vma=False)
 
 
 def build_coo_scatter_add_sharded(*, num_cols: int, tiles: int,
@@ -1124,27 +956,18 @@ def build_coo_scatter_add_sharded(*, num_cols: int, tiles: int,
                                   lead: int) -> Callable:
     """(param, rows, cols, vals, valid) -> param with lane-sliced
     operands (shards, L) of LOCAL row ids."""
-    shards = int(dict(mesh.shape)[axis])
-    if lead % shards:
-        raise UnsupportedShardingLayout(
-            f"lead={lead} not divisible by {shards} "
-            f"{axis!r}-axis shards")
-    inner = build_coo_scatter_add_masked(num_cols=num_cols, tiles=tiles,
-                                         interpret=interpret)
+    _shards_of(mesh, axis, lead, "lead")
+    inner = build_coo_scatter_add(num_cols=num_cols, tiles=tiles,
+                                  interpret=interpret, masked=True)
     pspec = P(axis, None, None) if tiles else P(axis, None)
     lanes2 = P(axis, None)
 
     def body(p_blk, r_blk, c_blk, v_blk, m_blk):
         return inner(p_blk, r_blk[0], c_blk[0], v_blk[0], m_blk[0])
 
-    sm = shard_map(body, mesh=mesh,
-                   in_specs=(pspec, lanes2, lanes2, lanes2, lanes2),
-                   out_specs=pspec, check_vma=False)
-
-    def coo(param, rows, cols, vals, valid):
-        return sm(param, rows, cols, vals, valid)
-
-    return coo
+    return shard_map(body, mesh=mesh,
+                     in_specs=(pspec, lanes2, lanes2, lanes2, lanes2),
+                     out_specs=pspec, check_vma=False)
 
 
 # -- functional forms for superstep bodies ---------------------------------
@@ -1152,8 +975,7 @@ def build_coo_scatter_add_sharded(*, num_cols: int, tiles: int,
 # Traceable inside an outer jit (a bare pallas_call is a first-class
 # primitive): fused supersteps use the SAME gather/scatter engine by
 # calling these from their bodies. Engine choice is made at trace time
-# from MVTPU_KERNELS + backend; there is no runtime fallback inside a
-# trace, so `auto` only picks Pallas off-CPU. Scatter inputs are sorted
+# from MVTPU_KERNELS + the mesh's platform. Scatter inputs are sorted
 # in-trace (a batch-sized argsort — still far smaller than the XLA
 # scatter's full sorted-segment machinery over table rows).
 #
@@ -1175,8 +997,8 @@ def kernel_mesh_scope(mesh: Any, axis: str):
     dispatch shards tables over. ``FusedSuperstep`` wraps its jitted
     dispatch in this scope; a body tracing :func:`gather_rows` /
     :func:`row_scatter_add` / :func:`coo_scatter_add` inside it gets
-    the sharded wrappers (on single-device meshes the scope is a
-    no-op)."""
+    the sharded wrappers (on single-device meshes only the platform is
+    read from it)."""
     token = _KERNEL_MESH.set((mesh, axis))
     try:
         yield
@@ -1184,23 +1006,18 @@ def kernel_mesh_scope(mesh: Any, axis: str):
         _KERNEL_MESH.reset(token)
 
 
-def _scope_mesh():
+def _functional_engine():
+    """(use pallas?, interpret?, sharded scope or None) for a
+    functional form traced now: the scope's mesh when a superstep
+    installed one, else the runtime mesh."""
     scope = _KERNEL_MESH.get()
-    if scope is None:
-        return None
-    mesh, _axis = scope
-    if getattr(mesh, "size", 1) <= 1:
-        return None
-    return scope
-
-
-def _functional_pallas() -> bool:
+    mesh = scope[0] if scope is not None else None
+    cpu = core.platform(mesh) == "cpu"
     mode = kernel_mode()
-    if mode == "xla":
-        return False
-    if mode == "pallas":
-        return True
-    return jax.default_backend() != "cpu"
+    use = mode == "pallas" or (mode == "auto" and not cpu)
+    if scope is not None and getattr(mesh, "size", 1) <= 1:
+        scope = None
+    return use, cpu, scope
 
 
 def _layout(param) -> tuple:
@@ -1213,11 +1030,12 @@ def _layout(param) -> tuple:
 
 @functools.lru_cache(maxsize=64)
 def _cached(builder: Callable, num_cols: int, tiles: int,
-            interpret: bool) -> Callable:
-    return builder(num_cols=num_cols, tiles=tiles, interpret=interpret)
+            interpret: bool, **kw: Any) -> Callable:
+    return builder(num_cols=num_cols, tiles=tiles, interpret=interpret,
+                   **kw)
 
 
-def _sharded_gather_rows(param, ids, mesh, axis):
+def _sharded_gather_rows(param, ids, interpret, mesh, axis):
     """In-trace sharded gather: each shard gathers its local hits
     (foreign lanes read row 0, masked to zero) and the masked partial
     rows psum across the model axis — outside any kernel."""
@@ -1229,7 +1047,7 @@ def _sharded_gather_rows(param, ids, mesh, axis):
         rows = jnp.take(param, ids, axis=0)
         return rows.reshape(ids.shape[0], num_cols)
     rps = param.shape[0] // shards
-    inner = _cached(build_row_gather, num_cols, tiles, interpret_mode())
+    inner = _cached(build_row_gather, num_cols, tiles, interpret)
     pspec = P(axis, None, None) if tiles else P(axis, None)
 
     def body(p_blk, ids_blk):
@@ -1245,11 +1063,11 @@ def _sharded_gather_rows(param, ids, mesh, axis):
     return sm(param, ids.astype(jnp.int32))
 
 
-def _sharded_row_scatter_add(param, ids, deltas, mesh, axis):
+def _sharded_row_scatter_add(param, ids, deltas, interpret, mesh, axis):
     """In-trace sharded scatter-add: sorted lanes, foreign lanes mapped
     to the shard's LAST local row and masked off by the write gate (a
-    no-op run only re-copies the pre-batch row, so a later real run of
-    that row stays correct)."""
+    no-op run only re-copies the pre-batch block, so a later real run of
+    that block stays correct)."""
     num_cols, tiles = _layout(param)
     shards = int(dict(mesh.shape)[axis])
     if param.shape[0] % shards:
@@ -1258,8 +1076,8 @@ def _sharded_row_scatter_add(param, ids, deltas, mesh, axis):
         d = deltas.reshape((ids.shape[0],) + param.shape[1:])
         return param.at[ids].add(d.astype(param.dtype))
     rps = param.shape[0] // shards
-    inner = _cached(build_row_scatter_add_masked, num_cols, tiles,
-                    interpret_mode())
+    inner = _cached(build_row_scatter_add, num_cols, tiles, interpret,
+                    masked=True)
     pspec = P(axis, None, None) if tiles else P(axis, None)
     order = jnp.argsort(ids, stable=True)
     sids = jnp.take(ids, order).astype(jnp.int32)
@@ -1271,7 +1089,7 @@ def _sharded_row_scatter_add(param, ids, deltas, mesh, axis):
         lo = s * rps
         mine = (ids_blk >= lo) & (ids_blk < lo + rps)
         lids = jnp.where(mine, ids_blk - lo, rps - 1).astype(jnp.int32)
-        return inner(p_blk, lids, d_blk, mine.astype(jnp.int32))
+        return inner(p_blk, lids, d_blk, mine)
 
     sm = shard_map(body, mesh=mesh,
                    in_specs=(pspec, P(None), P(None, None)),
@@ -1279,7 +1097,8 @@ def _sharded_row_scatter_add(param, ids, deltas, mesh, axis):
     return sm(param, sids, sdel)
 
 
-def _sharded_coo_scatter_add(param, rows, cols, vals, mesh, axis):
+def _sharded_coo_scatter_add(param, rows, cols, vals, interpret, mesh,
+                             axis):
     """In-trace sharded COO scatter-add — same foreign-lane mapping as
     :func:`_sharded_row_scatter_add`."""
     num_cols, tiles = _layout(param)
@@ -1292,8 +1111,8 @@ def _sharded_coo_scatter_add(param, rows, cols, vals, mesh, axis):
                 vals.astype(param.dtype))
         return param.at[rows, cols].add(vals.astype(param.dtype))
     rps = param.shape[0] // shards
-    inner = _cached(build_coo_scatter_add_masked, num_cols, tiles,
-                    interpret_mode())
+    inner = _cached(build_coo_scatter_add, num_cols, tiles, interpret,
+                    masked=True)
     pspec = P(axis, None, None) if tiles else P(axis, None)
     order = jnp.argsort(rows, stable=True)
     srows = jnp.take(rows, order).astype(jnp.int32)
@@ -1305,7 +1124,7 @@ def _sharded_coo_scatter_add(param, rows, cols, vals, mesh, axis):
         lo = s * rps
         mine = (r_blk >= lo) & (r_blk < lo + rps)
         lrows = jnp.where(mine, r_blk - lo, rps - 1).astype(jnp.int32)
-        return inner(p_blk, lrows, c_blk, v_blk, mine.astype(jnp.int32))
+        return inner(p_blk, lrows, c_blk, v_blk, mine)
 
     sm = shard_map(body, mesh=mesh,
                    in_specs=(pspec, P(None), P(None), P(None)),
@@ -1317,13 +1136,13 @@ def gather_rows(param, ids):
     """Row gather ``param[ids]`` → ``[n, num_cols]`` through the
     selected engine (superstep-body form)."""
     num_cols, tiles = _layout(param)
-    if not _functional_pallas():
+    use, interpret, scope = _functional_engine()
+    if not use:
         rows = jnp.take(param, ids, axis=0)
         return rows.reshape(ids.shape[0], num_cols)
-    scope = _scope_mesh()
     if scope is not None:
-        return _sharded_gather_rows(param, ids, *scope)
-    fn = _cached(build_row_gather, num_cols, tiles, interpret_mode())
+        return _sharded_gather_rows(param, ids, interpret, *scope)
+    fn = _cached(build_row_gather, num_cols, tiles, interpret)
     return fn(param, ids.astype(jnp.int32))
 
 
@@ -1331,15 +1150,15 @@ def row_scatter_add(param, ids, deltas):
     """Duplicate-safe ``param.at[ids].add(deltas)`` through the selected
     engine (superstep-body form; sorts in-trace)."""
     num_cols, tiles = _layout(param)
-    if not _functional_pallas():
+    use, interpret, scope = _functional_engine()
+    if not use:
         d = deltas.reshape((ids.shape[0],) + param.shape[1:])
         return param.at[ids].add(d.astype(param.dtype))
-    scope = _scope_mesh()
     if scope is not None:
-        return _sharded_row_scatter_add(param, ids, deltas, *scope)
+        return _sharded_row_scatter_add(param, ids, deltas, interpret,
+                                        *scope)
     order = jnp.argsort(ids, stable=True)
-    fn = _cached(build_row_scatter_add, num_cols, tiles,
-                 interpret_mode())
+    fn = _cached(build_row_scatter_add, num_cols, tiles, interpret)
     return fn(param, jnp.take(ids, order).astype(jnp.int32),
               jnp.take(deltas.reshape(ids.shape[0], num_cols), order,
                        axis=0))
@@ -1349,17 +1168,17 @@ def coo_scatter_add(param, rows, cols, vals):
     """COO ``param[rows[i], cols[i]] += vals[i]`` through the selected
     engine (superstep-body form; sorts in-trace)."""
     num_cols, tiles = _layout(param)
-    if not _functional_pallas():
+    use, interpret, scope = _functional_engine()
+    if not use:
         if tiles:
             return param.at[rows, cols // LANES, cols % LANES].add(
                 vals.astype(param.dtype))
         return param.at[rows, cols].add(vals.astype(param.dtype))
-    scope = _scope_mesh()
     if scope is not None:
-        return _sharded_coo_scatter_add(param, rows, cols, vals, *scope)
+        return _sharded_coo_scatter_add(param, rows, cols, vals,
+                                        interpret, *scope)
     order = jnp.argsort(rows, stable=True)
-    fn = _cached(build_coo_scatter_add, num_cols, tiles,
-                 interpret_mode())
+    fn = _cached(build_coo_scatter_add, num_cols, tiles, interpret)
     return fn(param, jnp.take(rows, order).astype(jnp.int32),
               jnp.take(cols, order).astype(jnp.int32),
               jnp.take(vals, order))
@@ -1367,12 +1186,11 @@ def coo_scatter_add(param, rows, cols, vals):
 
 __all__ = [
     "KernelEngine", "UnsupportedShardingLayout",
-    "build_coo_scatter_add", "build_coo_scatter_add_masked",
-    "build_coo_scatter_add_sharded", "build_kv_lookup",
-    "build_kv_lookup_sharded", "build_kv_probe_update",
-    "build_kv_probe_update_sharded", "build_row_gather",
-    "build_row_gather_sharded", "build_row_scatter_add",
-    "build_row_scatter_add_masked", "build_row_scatter_add_sharded",
+    "build_coo_scatter_add", "build_coo_scatter_add_sharded",
+    "build_kv_lookup", "build_kv_lookup_sharded",
+    "build_kv_probe_update", "build_kv_probe_update_sharded",
+    "build_row_gather", "build_row_gather_sharded",
+    "build_row_scatter_add", "build_row_scatter_add_sharded",
     "coo_scatter_add", "gather_rows", "interpret_mode",
     "kernel_mesh_scope", "kernel_mode", "row_scatter_add",
     "select_kernel",

@@ -131,7 +131,7 @@ def pipeline_apply(stage_params: Any, x: jax.Array,
         lambda leaf: P(*((axis,) + (None,) * (leaf.ndim - 1))),
         stage_params)
     x_spec = P(*((None,) * x_mb.ndim))
-    from multiverso_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     def build():
         return shard_map(local, mesh=mesh,

@@ -124,7 +124,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
     spec = P(None, axis, None, None)
-    from multiverso_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from multiverso_tpu.telemetry.profiling import cached_profiled_jit
     # keyed on everything `local` closes over (+ mesh for shard_map):
     # same ring program → same profiled wrapper → one compile, one
@@ -173,7 +173,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return bwd(o)
 
     spec = P(None, axis, None, None)
-    from multiverso_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from multiverso_tpu.telemetry.profiling import cached_profiled_jit
     fn = cached_profiled_jit(
         ("ulysses_attention", mesh, axis, causal, n, scale),

@@ -12,6 +12,15 @@ ports, pids, and the authoritative partition map — which
 ``client/router.py``'s ``connect_fleet_file`` and the
 ``/statusz?fleet=1`` aggregator both consume.
 
+Chips. A chip belongs to one process at a time. A plain server takes
+every chip the host shows it (one process driving them all through one
+mesh). The fleet launcher and ``--grow`` give each process they start
+exactly ONE chip before it imports jax (``TPU_VISIBLE_CHIPS`` +
+one-chip process bounds), record it as ``chip`` in the fleet file, never
+initialise a backend themselves, and refuse — non-zero, in words — a
+request for more chip-holding processes than the host has free chips.
+Servers are CPU servers only where ``JAX_PLATFORMS=cpu`` says so.
+
 Flags:
 
 ``--address unix:/path | tcp:host:port | shm:///path [, ...]``
@@ -98,11 +107,13 @@ Admin ops (run against a LIVE fleet, addressed by ``--fleet-file``):
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
 import sys
 import time
+from typing import Iterable, List, Optional
 
 
 def _rank_address(addr: str, rank: int) -> str:
@@ -125,6 +136,65 @@ def _replica_address(addr: str, rank: int, n: int, idx: int) -> str:
         p = int(port or 0)
         return f"tcp:{host}:{p + rank + n * idx if p else 0}"
     return f"{addr}.{rank}f{idx}"
+
+
+# -- one process for each chip ---------------------------------------------
+#
+# A chip belongs to one process at a time: a server process that calls
+# core.init() -> jax.devices() claims every chip it can see, so the
+# second member of a fleet would die on libtpu's lockfile. The launcher
+# hands each process it starts exactly one chip BEFORE that process
+# imports jax, and itself never initialises a backend.
+
+
+def _holds_chips() -> bool:
+    """Whether a server started from this environment initialises an
+    accelerator backend: it does unless ``JAX_PLATFORMS`` pins it to
+    the CPU (the test rigs and CPU benches do, explicitly)."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu"
+
+
+def _host_chips() -> List[int]:
+    """This host's chips by libtpu index, found WITHOUT a backend:
+    ``TPU_VISIBLE_CHIPS`` when the caller already restricted this
+    process, else one per accelerator device node (``/dev/vfio/N`` on
+    v5e hosts, ``/dev/accelN`` on older ones)."""
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "").strip()
+    if visible:
+        return [int(c) for c in visible.split(",") if c.strip()]
+    nodes = glob.glob("/dev/accel[0-9]*") \
+        or glob.glob("/dev/vfio/[0-9]*")
+    return list(range(len(nodes)))
+
+
+def _assign_chips(n_procs: int,
+                  taken: Iterable[int] = ()) -> List[Optional[int]]:
+    """One distinct free chip per process to start (``None`` each when
+    the servers are CPU servers). More processes than free chips is
+    refused here, in words, not by libtpu in the children."""
+    if not _holds_chips():
+        return [None] * n_procs
+    taken = set(taken)
+    free = [c for c in _host_chips() if c not in taken]
+    if n_procs > len(free):
+        raise SystemExit(
+            f"{n_procs} chip-holding server process(es) requested but "
+            f"this host has {len(free)} free chip(s) "
+            f"({len(taken)} already held by fleet members): one "
+            "process per chip. Start fewer members/followers, or pin "
+            "JAX_PLATFORMS=cpu for CPU servers.")
+    return free[:n_procs]
+
+
+def _chip_env(env: dict, chip: Optional[int]) -> dict:
+    """``env`` for a child that may see exactly ``chip`` (libtpu
+    0.0.34: the visible-chip list alone still takes the host-wide
+    lockfile; the two 1,1,1 bounds make the child a one-chip host)."""
+    if chip is None:
+        return env
+    return dict(env, TPU_VISIBLE_CHIPS=str(chip),
+                TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_BOUNDS="1,1,1")
 
 
 def _write_ready(path: str, content: str) -> None:
@@ -211,8 +281,9 @@ def _fleet_main(args, partition) -> int:
             specs.append((rank, idx,
                           [_replica_address(a, rank, n, idx)
                            for a in addresses]))
+    chips = _assign_chips(len(specs))
     procs, ready_files = [], []
-    for rank, idx, addrs in specs:
+    for (rank, idx, addrs), chip in zip(specs, chips):
         tag = f"r{rank}" if idx is None else f"r{rank}f{idx}"
         ready = f"{fleet_file}.{tag}.ready"
         try:
@@ -241,7 +312,7 @@ def _fleet_main(args, partition) -> int:
             cmd += ["--qos", args.qos]
         if args.queue is not None:
             cmd += ["--queue", str(args.queue)]
-        procs.append(subprocess.Popen(cmd, env=env))
+        procs.append(subprocess.Popen(cmd, env=_chip_env(env, chip)))
 
     def _kill_all(sig=signal.SIGTERM):
         for p in procs:
@@ -282,7 +353,8 @@ def _fleet_main(args, partition) -> int:
                else f"{args.name}-{rank}f{idx}",
                "addresses": [p for p in parts
                              if not p.startswith("statusz:")],
-               "statusz_port": statusz_port, "pid": procs[i].pid}
+               "statusz_port": statusz_port, "pid": procs[i].pid,
+               "chip": chips[i]}
         if idx is None:
             row["rank"] = rank
             row["replicas"] = []
@@ -374,8 +446,13 @@ def _reshard_main(args, partition, grow: bool) -> int:
                       for a in addresses] for idx in range(1, r)]
         specs = [(None, [_rank_address(a, n) for a in addresses])] \
             + list(zip(range(1, r), fol_addrs))
+        chips = _assign_chips(
+            len(specs),
+            taken=[row["chip"] for m in rows
+                   for row in [m] + list(m.get("replicas") or ())
+                   if row.get("chip") is not None])
         ready_files = []
-        for idx, addrs in specs:
+        for (idx, addrs), chip in zip(specs, chips):
             tag = f"r{n}" if idx is None else f"r{n}f{idx}"
             ready = f"{fleet_file}.{tag}.ready"
             try:
@@ -408,7 +485,7 @@ def _reshard_main(args, partition, grow: bool) -> int:
             mlog = open(f"{fleet_file}.{tag}.log", "ab")
             try:
                 procs.append(subprocess.Popen(
-                    cmd, env=env, start_new_session=True,
+                    cmd, env=_chip_env(env, chip), start_new_session=True,
                     stdin=subprocess.DEVNULL, stdout=mlog,
                     stderr=mlog))
             finally:
@@ -439,7 +516,8 @@ def _reshard_main(args, partition, grow: bool) -> int:
                     else f"{args.name}-{n}f{idx}",
                     "addresses": [p for p in parts
                                   if not p.startswith("statusz:")],
-                    "statusz_port": port, "pid": procs[i].pid}
+                    "statusz_port": port, "pid": procs[i].pid,
+                    "chip": chips[i]}
         new_row = _row(0, None)
         new_row.update(rank=n, replicas=[
             dict(_row(i, idx), idx=idx)

@@ -35,7 +35,7 @@ before any data op flows.
 
 jax-free BY DESIGN (stdlib + numpy + the numpy-only hashing module):
 the client router runs in bare worker processes, and the fleet-statusz
-scraper runs on the statusz HTTP thread of a possibly-wedged process.
+scraper runs on the statusz HTTP thread of a possibly-stuck process.
 File-path loadable like ``server/wire.py``.
 """
 
@@ -399,6 +399,7 @@ def promote_in_doc(doc: Dict[str, Any], rank: int,
                                           member.get("addresses"))
             member["statusz_port"] = rep.get("statusz_port")
             member["pid"] = rep.get("pid")
+            member["chip"] = rep.get("chip")
             member["promoted_from"] = idx
         member["replicas"] = [r for r in reps if r.get("idx") != idx]
     return out

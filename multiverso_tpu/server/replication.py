@@ -46,7 +46,7 @@ end* rather than reinvented:
   follower only stalls the primary for the tight replication retry
   deadline (``MVTPU_REPL_DEADLINE_S``), then its link is dropped and
   the primary moves on: replication degrades loudly
-  (``replication.link_down``), it never wedges the shard.
+  (``replication.link_down``), it never blocks the shard.
 
 Follower staleness is measured in generations against ``pgen`` — the
 primary generation stamped on every repl frame, noted at the

@@ -520,7 +520,13 @@ class TableServer:
                 # FollowerState survives as the apply history)
                 repl["role"] = "primary"
         mig = self._migration
+        devices = list(core.mesh().devices.flat)
         return {"name": self.name, "address": self.address,
+                # the devices this server's tables live on, as jax
+                # reports them: a client can tell a chip from a CPU
+                "platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "devices": [int(d.id) for d in devices],
                 "connections": n_conns, "tables": len(self._tables),
                 "migration": mig.status() if mig is not None else None,
                 "ops": self._ops, "fuse": self._fuse,

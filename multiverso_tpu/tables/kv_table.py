@@ -210,7 +210,8 @@ class KVTable:
         # the Pallas engines slice state like values (model axis only);
         # data-axis-refined state (shard_update) and subclasses that
         # re-sort lanes at dispatch (tiered) keep the XLA closures
-        allow_pallas = self.ALLOW_PALLAS and not self.shard_update
+        allow_pallas = self.ALLOW_PALLAS and not self.shard_update \
+            and self.dtype.itemsize == 4    # (8, 128) 32-bit blocks
 
         def probe_update(keys_arr, values_arr, state, buckets, query,
                          deltas, valid, option):
@@ -277,104 +278,52 @@ class KVTable:
             return jnp.sum(~(keys_arr == jnp.uint32(0xFFFFFFFF))
                            .all(-1))
 
-        # the sharded XLA adapters: lane-sliced (shards, L, ...) operands
-        # flattened shard-major with bucket ids globalized (local +
-        # s*bps). Shard-major flattening of the per-shard bucket-sorted
-        # slices stays GLOBALLY bucket-sorted (each shard's padding
-        # parks on its local max bucket bps-1 → global (s+1)*bps-1,
-        # still below the next shard's first bucket), so the XLA
-        # argsort-rank tie-break sees the same lane order as the flat
-        # path and the results are bit-identical. These are both the
-        # runtime-fallback target of the sharded Pallas engine and the
-        # MVTPU_KERNELS=xla comparison lane the parity tests drive.
-        bps = self._buckets_per_shard
-        offs = jnp.arange(self._shards, dtype=jnp.int32)[:, None] * bps
-
-        def lookup_sharded(keys_arr, values_arr, query, buckets, inv):
-            gb = (buckets + offs).reshape(-1)
-            picked, found = lookup(keys_arr, values_arr,
-                                   query.reshape(-1, 2), gb)
-            return (jnp.take(picked, inv, axis=0),
-                    jnp.take(found, inv, axis=0))
-
-        def probe_update_sharded(keys_arr, values_arr, state, buckets,
-                                 query, deltas, valid, option):
-            shards, lanes = buckets.shape
-            gb = (buckets + offs).reshape(-1)
-            d = deltas.reshape((shards * lanes,) + deltas.shape[2:])
-            return probe_update(keys_arr, values_arr, state, gb,
-                                query.reshape(-1, 2), d,
-                                valid.reshape(-1), option)
-
         # profiled: profile.calls{fn=kv.lookup/kv.apply.<name>} are the
         # Get/Add dispatch counts the client pipeline's coalescing and
         # caching claims are asserted against. All paths register
-        # behind the kernel engine (MVTPU_KERNELS): the XLA closures
-        # above stay the fallback, the Pallas engine (same signatures,
-        # bit-equal results — tests/test_table_kernels.py) keeps each
-        # bucket's slot rows in VMEM and replaces the batch-wide argsort
-        # with the in-kernel per-bucket scan; on a multi-device mesh the
-        # sharded forms run the same per-shard grids under shard_map.
-        # The Pallas engine's dispatches land on
-        # profile.calls{fn=....pallas}.
+        # behind the kernel engine (MVTPU_KERNELS): the Pallas engine
+        # (same signatures, bit-equal results —
+        # tests/test_table_kernels.py) keeps each bucket's slot rows in
+        # VMEM and replaces the batch-wide argsort with the in-kernel
+        # per-bucket scan; on a multi-device mesh the sharded forms run
+        # the same per-shard grids under shard_map. The Pallas engine's
+        # dispatches land on profile.calls{fn=....pallas}.
+        kw = dict(slots=self.slots, value_dim=self.value_dim,
+                  interpret=tk.interpret_mode(self.mesh))
+        sharded_kw = dict(mesh=self.mesh, axis=core.MODEL_AXIS,
+                          num_buckets=self.num_buckets)
+
+        def engines(op, build, build_sharded, jit_kw, **build_kw):
+            if not allow_pallas:
+                return {}
+            name = f"kv.{op}.{self.name}.pallas"
+            return dict(
+                pallas=lambda: profiled_jit(
+                    build(**kw, **build_kw), name=name, **jit_kw),
+                pallas_sharded=lambda: profiled_jit(
+                    build_sharded(**kw, **build_kw, **sharded_kw),
+                    name=name, **jit_kw))
+
+        lookup_kw = dict(out_shardings=(replicated, replicated))
         self._lookup = tk.select_kernel(
             f"kv.lookup.{self.name}",
-            xla=profiled_jit(
-                lookup, name=f"kv.lookup.{self.name}",
-                out_shardings=(replicated, replicated)),
-            pallas=None if not allow_pallas else lambda: profiled_jit(
-                tk.build_kv_lookup(
-                    slots=self.slots, value_dim=self.value_dim,
-                    default_value=self.default_value,
-                    interpret=tk.interpret_mode()),
-                name=f"kv.lookup.{self.name}.pallas",
-                out_shardings=(replicated, replicated)),
-            pallas_sharded=None if not allow_pallas else lambda: profiled_jit(
-                tk.build_kv_lookup_sharded(
-                    slots=self.slots, value_dim=self.value_dim,
-                    default_value=self.default_value,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS,
-                    num_buckets=self.num_buckets),
-                name=f"kv.lookup.{self.name}.pallas",
-                out_shardings=(replicated, replicated)),
-            xla_sharded=lambda: profiled_jit(
-                lookup_sharded, name=f"kv.lookup.{self.name}",
-                out_shardings=(replicated, replicated)),
-            mesh=self.mesh)
+            xla=profiled_jit(lookup, name=f"kv.lookup.{self.name}",
+                             **lookup_kw),
+            mesh=self.mesh, **engines(
+                "lookup", tk.build_kv_lookup, tk.build_kv_lookup_sharded,
+                lookup_kw, default_value=self.default_value))
+        apply_kw = dict(
+            donate_argnums=(0, 1, 2),
+            out_shardings=(self._key_sharding, self._val_sharding,
+                           state_sh, scalar_sh))
         self._probe_update = tk.select_kernel(
             f"kv.apply.{self.name}",
-            xla=profiled_jit(
-                probe_update, name=f"kv.apply.{self.name}",
-                donate_argnums=(0, 1, 2),
-                out_shardings=(self._key_sharding, self._val_sharding,
-                               state_sh, scalar_sh)),
-            pallas=None if not allow_pallas else lambda: profiled_jit(
-                tk.build_kv_probe_update(
-                    slots=self.slots, value_dim=self.value_dim,
-                    updater=self.updater, state_template=self.state,
-                    interpret=tk.interpret_mode()),
-                name=f"kv.apply.{self.name}.pallas",
-                donate_argnums=(0, 1, 2),
-                out_shardings=(self._key_sharding, self._val_sharding,
-                               state_sh, scalar_sh)),
-            pallas_sharded=None if not allow_pallas else lambda: profiled_jit(
-                tk.build_kv_probe_update_sharded(
-                    slots=self.slots, value_dim=self.value_dim,
-                    updater=self.updater, state_template=self.state,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS,
-                    num_buckets=self.num_buckets),
-                name=f"kv.apply.{self.name}.pallas",
-                donate_argnums=(0, 1, 2),
-                out_shardings=(self._key_sharding, self._val_sharding,
-                               state_sh, scalar_sh)),
-            xla_sharded=lambda: profiled_jit(
-                probe_update_sharded, name=f"kv.apply.{self.name}",
-                donate_argnums=(0, 1, 2),
-                out_shardings=(self._key_sharding, self._val_sharding,
-                               state_sh, scalar_sh)),
-            mesh=self.mesh)
+            xla=profiled_jit(probe_update, name=f"kv.apply.{self.name}",
+                             **apply_kw),
+            mesh=self.mesh, **engines(
+                "apply", tk.build_kv_probe_update,
+                tk.build_kv_probe_update_sharded, apply_kw,
+                updater=self.updater, state_template=self.state))
         self._count_live = count_live
 
     def _buckets_of(self, keys: np.ndarray) -> np.ndarray:

@@ -118,81 +118,51 @@ class MatrixTable(Table):
                 state, new_st, st_rows)
             return param, state
 
-        # sharded XLA adapters: lane-sliced (shards, L, ...) operands
-        # with LOCAL row ids globalized (local + s*rps). Invalid lanes
-        # redirect to the global scratch row — the masked Pallas
-        # kernels gate those writes instead, so the logical rows stay
-        # bit-identical across engines (the scratch row is garbage by
-        # contract on every path). These serve as both the sharded
-        # engine's runtime-fallback target and the MVTPU_KERNELS=xla
-        # parity lane.
-        rps = self._rows_per_shard
-        offs = jnp.arange(self._shards, dtype=jnp.int32)[:, None] * rps
-
-        def gather_sharded(param, ids, inv):
-            rows = jnp.take(param, (ids + offs).reshape(-1), axis=0)
-            return jnp.take(rows, inv, axis=0)
-
-        def scatter_add_sharded(param, ids, deltas, valid):
-            gids = jnp.where(valid, ids + offs,
-                             self._scratch_row).reshape(-1)
-            d = deltas.reshape(-1, self.num_cols)
-            return param.at[gids].add(d.astype(param.dtype))
-
         # profiled: profile.calls{fn=table.{gather,scatter_add,
         # apply_rows}.<name>} count the row-path dispatches the client
         # pipeline's row coalescing / caching are measured against.
         # Gather and scatter-add register behind the kernel engine
-        # (MVTPU_KERNELS) with the XLA closures above as fallback
-        # (per-shard shard_map grids on multi-device meshes);
-        # apply_rows (stateful row updates) stays XLA-only.
+        # (MVTPU_KERNELS; per-shard shard_map grids on multi-device
+        # meshes); apply_rows (stateful row updates) stays XLA-only.
         self._gather_rows = tk.select_kernel(
             f"table.gather.{self.name}",
             xla=profiled_jit(
                 gather_rows, name=f"table.gather.{self.name}",
                 out_shardings=replicated),
-            pallas=lambda: profiled_jit(
-                tk.build_row_gather(num_cols=self.num_cols, tiles=0,
-                                    interpret=tk.interpret_mode()),
-                name=f"table.gather.{self.name}.pallas",
-                out_shardings=replicated),
-            pallas_sharded=lambda: profiled_jit(
-                tk.build_row_gather_sharded(
-                    num_cols=self.num_cols, tiles=0,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS, lead=self.padded_shape[0]),
-                name=f"table.gather.{self.name}.pallas",
-                out_shardings=replicated),
-            xla_sharded=lambda: profiled_jit(
-                gather_sharded, name=f"table.gather.{self.name}",
-                out_shardings=replicated),
-            mesh=self.mesh)
+            mesh=self.mesh, **self._pallas_rows(
+                "gather", tk.build_row_gather,
+                tk.build_row_gather_sharded, 0,
+                out_shardings=replicated))
         self._scatter_add = tk.select_kernel(
             f"table.scatter_add.{self.name}",
             xla=profiled_jit(
                 scatter_add, name=f"table.scatter_add.{self.name}",
                 donate_argnums=(0,)),
-            pallas=lambda: profiled_jit(
-                tk.build_row_scatter_add(num_cols=self.num_cols, tiles=0,
-                                         interpret=tk.interpret_mode()),
-                name=f"table.scatter_add.{self.name}.pallas",
-                donate_argnums=(0,)),
-            pallas_sharded=lambda: profiled_jit(
-                tk.build_row_scatter_add_sharded(
-                    num_cols=self.num_cols, tiles=0,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS, lead=self.padded_shape[0]),
-                name=f"table.scatter_add.{self.name}.pallas",
-                donate_argnums=(0,)),
-            xla_sharded=lambda: profiled_jit(
-                scatter_add_sharded,
-                name=f"table.scatter_add.{self.name}",
-                donate_argnums=(0,)),
-            mesh=self.mesh)
+            mesh=self.mesh, **self._pallas_rows(
+                "scatter_add", tk.build_row_scatter_add,
+                tk.build_row_scatter_add_sharded, 0,
+                donate_argnums=(0,)))
         self._gather_apply_scatter = profiled_jit(
             gather_apply_scatter, name=f"table.apply_rows.{self.name}",
             donate_argnums=(0, 1),
             out_shardings=(self.sharding, state_sh))
+
+    def _pallas_rows(self, op: str, build, build_sharded, tiles: int,
+                     **jit_kw) -> dict:
+        """The ``pallas``/``pallas_sharded`` factories of one row kernel
+        for :func:`tk.select_kernel` — none for a dtype the kernels'
+        (8, 128) 32-bit blocks cannot hold."""
+        if self.dtype.itemsize != 4:
+            return {}
+        name = f"table.{op}.{self.name}.pallas"
+        kw = dict(num_cols=self.num_cols, tiles=tiles,
+                  interpret=tk.interpret_mode(self.mesh))
+        return dict(
+            pallas=lambda: profiled_jit(build(**kw), name=name, **jit_kw),
+            pallas_sharded=lambda: profiled_jit(
+                build_sharded(**kw, mesh=self.mesh, axis=core.MODEL_AXIS,
+                              lead=self.padded_shape[0]),
+                name=name, **jit_kw))
 
     def _pad_ids(self, ids: np.ndarray,
                  deltas: Optional[np.ndarray] = None, *,

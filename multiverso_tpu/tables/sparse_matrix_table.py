@@ -119,22 +119,6 @@ class SparseMatrixTable(MatrixTable):
             d3 = deltas.reshape(ids.shape[0], c, LANES)
             return param.at[ids].add(d3.astype(param.dtype))
 
-        # sharded XLA adapters over the tiled layout (lane-sliced local
-        # ids globalized; invalid lanes → global scratch row — see
-        # matrix_table.py for the parity argument)
-        rps = self._rows_per_shard
-        offs = jnp.arange(self._shards, dtype=jnp.int32)[:, None] * rps
-
-        def gather_sharded(param, ids, inv):
-            rows = jnp.take(param, (ids + offs).reshape(-1), axis=0)
-            return jnp.take(rows.reshape(-1, n_cols), inv, axis=0)
-
-        def scatter_add_sharded(param, ids, deltas, valid):
-            gids = jnp.where(valid, ids + offs,
-                             self._scratch_row).reshape(-1)
-            d3 = deltas.reshape(-1, c, LANES)
-            return param.at[gids].add(d3.astype(param.dtype))
-
         # tiled layouts re-register behind the kernel engine with
         # tiles=c (one logical row = one (8,128) tile — the layout the
         # Pallas row kernels want)
@@ -143,44 +127,19 @@ class SparseMatrixTable(MatrixTable):
             xla=profiled_jit(
                 gather_rows, name=f"table.gather.{self.name}",
                 out_shardings=replicated),
-            pallas=lambda: profiled_jit(
-                tk.build_row_gather(num_cols=n_cols, tiles=c,
-                                    interpret=tk.interpret_mode()),
-                name=f"table.gather.{self.name}.pallas",
-                out_shardings=replicated),
-            pallas_sharded=lambda: profiled_jit(
-                tk.build_row_gather_sharded(
-                    num_cols=n_cols, tiles=c,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS, lead=self.padded_shape[0]),
-                name=f"table.gather.{self.name}.pallas",
-                out_shardings=replicated),
-            xla_sharded=lambda: profiled_jit(
-                gather_sharded, name=f"table.gather.{self.name}",
-                out_shardings=replicated),
-            mesh=self.mesh)
+            mesh=self.mesh, **self._pallas_rows(
+                "gather", tk.build_row_gather,
+                tk.build_row_gather_sharded, c,
+                out_shardings=replicated))
         self._scatter_add = tk.select_kernel(
             f"table.scatter_add.{self.name}",
             xla=profiled_jit(
                 scatter_add, name=f"table.scatter_add.{self.name}",
                 donate_argnums=(0,)),
-            pallas=lambda: profiled_jit(
-                tk.build_row_scatter_add(num_cols=n_cols, tiles=c,
-                                         interpret=tk.interpret_mode()),
-                name=f"table.scatter_add.{self.name}.pallas",
-                donate_argnums=(0,)),
-            pallas_sharded=lambda: profiled_jit(
-                tk.build_row_scatter_add_sharded(
-                    num_cols=n_cols, tiles=c,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS, lead=self.padded_shape[0]),
-                name=f"table.scatter_add.{self.name}.pallas",
-                donate_argnums=(0,)),
-            xla_sharded=lambda: profiled_jit(
-                scatter_add_sharded,
-                name=f"table.scatter_add.{self.name}",
-                donate_argnums=(0,)),
-            mesh=self.mesh)
+            mesh=self.mesh, **self._pallas_rows(
+                "scatter_add", tk.build_row_scatter_add,
+                tk.build_row_scatter_add_sharded, c,
+                donate_argnums=(0,)))
         # _gather_apply_scatter is unreachable: stateless updaters only
 
     # -- jitted sparse kernels --------------------------------------------
@@ -194,28 +153,6 @@ class SparseMatrixTable(MatrixTable):
             def coo_scatter_add(param, rows, cols, vals):
                 return param.at[rows, cols].add(vals.astype(param.dtype))
 
-        # sharded XLA adapter: lane-sliced (shards, L) COO triples with
-        # local row ids; invalid lanes → global scratch row. Shard-major
-        # flattening of the row-sorted batch stays globally sorted, so
-        # duplicate (row, col) pairs accumulate in the same order as the
-        # flat scatter — bit-parity with the Pallas run scans.
-        rps = self._rows_per_shard
-        offs = jnp.arange(self._shards, dtype=jnp.int32)[:, None] * rps
-
-        if self.tiled:
-            def coo_sharded(param, rows, cols, vals, valid):
-                gr = jnp.where(valid, rows + offs,
-                               self._scratch_row).reshape(-1)
-                fc = cols.reshape(-1)
-                return param.at[gr, fc // LANES, fc % LANES].add(
-                    vals.reshape(-1).astype(param.dtype))
-        else:
-            def coo_sharded(param, rows, cols, vals, valid):
-                gr = jnp.where(valid, rows + offs,
-                               self._scratch_row).reshape(-1)
-                return param.at[gr, cols.reshape(-1)].add(
-                    vals.reshape(-1).astype(param.dtype))
-
         # profiled: the COO Add dispatch count (client coalescing of
         # sparse adds is asserted against profile.calls on this name).
         # Registered behind the kernel engine: the Pallas COO kernel
@@ -227,24 +164,10 @@ class SparseMatrixTable(MatrixTable):
                 coo_scatter_add,
                 name=f"table.coo_scatter_add.{self.name}",
                 donate_argnums=(0,)),
-            pallas=lambda: profiled_jit(
-                tk.build_coo_scatter_add(
-                    num_cols=self.num_cols, tiles=self.tiles,
-                    interpret=tk.interpret_mode()),
-                name=f"table.coo_scatter_add.{self.name}.pallas",
-                donate_argnums=(0,)),
-            pallas_sharded=lambda: profiled_jit(
-                tk.build_coo_scatter_add_sharded(
-                    num_cols=self.num_cols, tiles=self.tiles,
-                    interpret=tk.interpret_mode(), mesh=self.mesh,
-                    axis=core.MODEL_AXIS, lead=self.padded_shape[0]),
-                name=f"table.coo_scatter_add.{self.name}.pallas",
-                donate_argnums=(0,)),
-            xla_sharded=lambda: profiled_jit(
-                coo_sharded,
-                name=f"table.coo_scatter_add.{self.name}",
-                donate_argnums=(0,)),
-            mesh=self.mesh)
+            mesh=self.mesh, **self._pallas_rows(
+                "coo_scatter_add", tk.build_coo_scatter_add,
+                tk.build_coo_scatter_add_sharded, self.tiles,
+                donate_argnums=(0,)))
 
         replicated = NamedSharding(self.mesh, P(None))
         n_cols = self.num_cols

@@ -1,9 +1,9 @@
 """Compile/runtime introspection: where did the wall-clock go BEFORE
 the first step ran, and what does the compiled program cost?
 
-The r01-r05 hangs had no compile timeline — a run wedged during
-``jax.jit`` tracing, XLA compilation, or backend init looks identical
-to one wedged in a collective. :func:`profiled_jit` splits that out:
+A run stuck in ``jax.jit`` tracing, XLA compilation, or backend init
+looks identical from outside to one stuck in a collective.
+:func:`profiled_jit` splits that out:
 
 - a drop-in ``jax.jit`` replacement that, on each NEW input signature,
   runs the explicit AOT pipeline (``lower()`` then ``compile()``),
@@ -22,11 +22,13 @@ to one wedged in a collective. :func:`profiled_jit` splits that out:
   - ``profile.memory.*{fn=...}`` gauges from XLA memory analysis
     (argument/output/temp/generated-code bytes) where available.
 
-  The compiled executable is cached per signature and called directly
-  (jit's own cache never sees a second compile). Tracer inputs (the
-  wrapper invoked inside an outer jit/grad trace) and any AOT-call
-  mismatch fall back to the plain jitted path — profiling must never
-  change program semantics, only observe them.
+  The compiled executable is cached per signature — avals AND input
+  shardings, because an AOT executable accepts exactly the shardings
+  it was compiled for — and called directly (jit's own cache never
+  sees a second compile). Tracer inputs (the wrapper invoked inside an
+  outer jit/grad trace) take the plain jitted path. A lowering, compile
+  or execution error raises to the caller: there is no second attempt
+  through another path.
 
 - :func:`record_device_memory` — live-buffer count/bytes
   (``jax.live_arrays``) and per-device allocator stats
@@ -39,8 +41,9 @@ to one wedged in a collective. :func:`profiled_jit` splits that out:
   unset, the context is free.
 
 jax is imported lazily (call time, never module import): the report CLI
-and the bench's jax-free pre-probe phase import the telemetry package,
-and must not pay — or hang on — a backend init.
+and the jax-free parents of ``chip_smoke.py`` and the fleet launcher
+import the telemetry package, and must not initialise a backend (one
+process holds a chip).
 """
 
 from __future__ import annotations
@@ -56,19 +59,10 @@ from multiverso_tpu.telemetry import trace as _trace
 
 
 def _leaf_sig(leaf: Any) -> Any:
-    """A hashable signature for one argument leaf: aval for arrays and
-    scalars (shape/dtype/weak_type — what jit keys on), repr for
-    anything else (static config objects)."""
-    import jax
-
-    try:
-        from jax.api_util import shaped_abstractify
-        return shaped_abstractify(leaf)
-    except Exception:
-        try:
-            return (jax.numpy.shape(leaf), jax.numpy.result_type(leaf))
-        except Exception:
-            return ("static", repr(leaf))
+    """A hashable signature for one argument leaf: aval (shape/dtype/
+    weak_type — what jit keys on) plus the sharding of a device array."""
+    from jax.api_util import shaped_abstractify
+    return shaped_abstractify(leaf), getattr(leaf, "sharding", None)
 
 
 class _ProfiledJit:
@@ -82,7 +76,6 @@ class _ProfiledJit:
         self.name = name
         self._jit = jax.jit(fn, **jit_kw)
         self._compiled: Dict[Tuple, Any] = {}
-        self._fallback = False
         # per-dispatch counter (cached object — the registry lookup is a
         # lock + dict probe, too hot for a per-call path): together with
         # profile.compiles this is the evidence the client pipeline's
@@ -121,57 +114,37 @@ class _ProfiledJit:
         return compiled
 
     def _record_cost(self, reg, compiled) -> None:
-        """XLA cost/memory analysis where the backend reports it (the
-        shapes differ across jax versions: dict or [dict])."""
-        try:
-            cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
-            if cost.get("flops"):
-                reg.gauge("profile.flops", fn=self.name) \
-                    .set(float(cost["flops"]))
-            if cost.get("bytes accessed"):
-                reg.gauge("profile.bytes_accessed", fn=self.name) \
-                    .set(float(cost["bytes accessed"]))
-        except Exception:
-            pass
-        try:
-            ma = compiled.memory_analysis()
-            for attr, key in (("argument_size_in_bytes", "args"),
-                              ("output_size_in_bytes", "out"),
-                              ("temp_size_in_bytes", "temp"),
-                              ("generated_code_size_in_bytes", "code")):
-                v = getattr(ma, attr, None)
-                if v:
-                    reg.gauge(f"profile.memory.{key}_bytes",
-                              fn=self.name).set(float(v))
-        except Exception:
-            pass
+        """XLA cost/memory analysis of the compiled program."""
+        cost = compiled.cost_analysis() or {}
+        for key, metric in (("flops", "profile.flops"),
+                            ("bytes accessed", "profile.bytes_accessed")):
+            if cost.get(key):
+                reg.gauge(metric, fn=self.name).set(float(cost[key]))
+        ma = compiled.memory_analysis()
+        for attr, key in (("argument_size_in_bytes", "args"),
+                          ("output_size_in_bytes", "out"),
+                          ("temp_size_in_bytes", "temp"),
+                          ("generated_code_size_in_bytes", "code")):
+            v = getattr(ma, attr, None)
+            if v:
+                reg.gauge(f"profile.memory.{key}_bytes",
+                          fn=self.name).set(float(v))
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         import jax
 
-        # counted on EVERY path (AOT, tracer, fallback): the counter
-        # means "dispatches requested", not "AOT executions"
+        # counted on EVERY path (AOT, tracer): the counter means
+        # "dispatches requested", not "AOT executions"
         self._calls.inc()
-        if self._fallback or any(
-                isinstance(l, jax.core.Tracer)
-                for l in jax.tree.leaves((args, kwargs))):
-            # inside an outer trace (grad/jit-of-jit) or after an AOT
-            # mismatch: the plain path, zero observational interference
+        if any(isinstance(l, jax.core.Tracer)
+               for l in jax.tree.leaves((args, kwargs))):
+            # inside an outer trace (grad/jit-of-jit): the plain path
             return self._jit(*args, **kwargs)
-        try:
-            sig = self._sig(args, kwargs)
-            compiled = self._compiled.get(sig)
-            if compiled is None:
-                compiled = self._compile(sig, args, kwargs)
-            return compiled(*args, **kwargs)
-        except Exception:
-            # an AOT corner this wrapper didn't anticipate (committed-
-            # sharding mismatch, exotic static args): permanently hand
-            # this wrapper back to plain jit — correctness over metrics
-            self._fallback = True
-            return self._jit(*args, **kwargs)
+        sig = self._sig(args, kwargs)
+        compiled = self._compiled.get(sig)
+        if compiled is None:
+            compiled = self._compile(sig, args, kwargs)
+        return compiled(*args, **kwargs)
 
     # AOT introspection passthroughs, so holders of the wrapper keep
     # the jitted function's surface for debugging

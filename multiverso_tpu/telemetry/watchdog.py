@@ -1,10 +1,9 @@
 """Stall watchdog: a daemon-thread heartbeat that turns a hung run into
 a post-mortem instead of an empty log.
 
-Every bench round so far (BENCH_r01-r05) died ``rc=124`` with "hang,
-killed after 180s" and NO stack, NO device state, NO compile timeline —
-the telemetry spine records what healthy runs do, but nothing diagnosed
-a wedged one. This module closes that gap:
+A run killed by its caller's timeout leaves NO stack, NO device state,
+NO compile timeline — the telemetry spine records what healthy runs do,
+but nothing diagnoses a stuck one. This module closes that gap:
 
 - :class:`Watchdog` — a daemon thread armed with ``deadline_s``;
   instrumented code calls :meth:`Watchdog.beat` (or the module-level
@@ -18,8 +17,8 @@ a wedged one. This module closes that gap:
      every metric series (``series.json``, report-renderable), and a
      manifest,
   3. **kill** — after dumping, ``os._exit(SELF_TERMINATE_RC)`` so a
-     wedged process dies fast with its diagnostics on disk instead of
-     hanging into a driver timeout that leaves nothing.
+     stuck process dies fast with its diagnostics on disk instead of
+     hanging into a caller's timeout that leaves nothing.
 
   The configured ``action`` is the HIGHEST rung taken (default
   ``dump``; override per-watchdog or via ``MVTPU_WATCHDOG_ACTION``).
@@ -33,11 +32,10 @@ a wedged one. This module closes that gap:
 
 STANDALONE BY DESIGN: this file imports ONLY stdlib at module level and
 resolves the sibling metrics/trace modules through ``sys.modules`` at
-dump time. That lets ``bench.py`` load it by file path in the jax-free
-pre-probe phase (same trick as its metrics binding), and lets the chip
-probe CHILD — whose whole job is surviving a wedged ``import jax`` —
-arm a watchdog with nothing else importable. A dump with no metrics or
-trace module loaded still writes thread stacks + manifest.
+dump time. That lets a jax-free process (a launcher, a wire client)
+load it by file path and arm a watchdog with nothing else importable.
+A dump with no metrics or trace module loaded still writes thread
+stacks + manifest.
 """
 
 from __future__ import annotations
@@ -52,9 +50,9 @@ import time
 from typing import Iterator, List, Optional
 
 DUMP_KIND = "mvtpu.watchdog.dump.v1"
-# EX_SOFTWARE, distinct from the driver's timeout rc=124 and the bench
-# probe's rc=2 — a capture showing 70 means "the watchdog shot a wedged
-# process AFTER writing its post-mortem"
+# EX_SOFTWARE, distinct from a caller's timeout rc=124 — a capture
+# showing 70 means "the watchdog shot a stuck process AFTER writing its
+# post-mortem"
 SELF_TERMINATE_RC = 70
 ACTIONS = ("warn", "dump", "kill")
 
@@ -69,7 +67,7 @@ def _now() -> float:
 def _warn(msg: str) -> None:
     """Stderr, not utils.log: the logger lives behind the package
     __init__ (which imports jax) and a watchdog must stay loadable —
-    and audible — in a process where jax is exactly what's wedged."""
+    and audible — in a process where jax is exactly what's stuck."""
     stamp = time.strftime("%Y-%m-%d %H:%M:%S", time.localtime())
     print(f"[WARN] [{stamp}] [{os.getpid()}] {msg}", file=sys.stderr,
           flush=True)
@@ -334,7 +332,7 @@ class Watchdog:
                 pass
         # per-queue depth/age gauges + the last SLO violations: the
         # backpressure and tail-latency evidence a stall post-mortem
-        # starts from (which worker queue was wedged, and was the SLO
+        # starts from (which worker queue was stuck, and was the SLO
         # monitor already screaming before the heartbeat died)
         queues = {}
         if metrics is not None:

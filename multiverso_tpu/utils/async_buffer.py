@@ -65,7 +65,7 @@ class ASyncBuffer(Generic[T]):
                 item = (self._fill_fn(idx), None)
             except BaseException as exc:  # propagate to consumer
                 item = (None, exc)
-            # bounded offer: an unconditional put would wedge the worker
+            # bounded offer: an unconditional put would block the worker
             # forever when the consumer stops draining after stop()
             while not self._stopped:
                 try:

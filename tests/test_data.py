@@ -1,6 +1,8 @@
 """Data pipeline tests: native backend vs Python fallback parity, corpus
 semantics, Huffman validity, pair generation, LDA CSR reading."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -298,3 +300,51 @@ class TestSynthetic:
         assert len(offsets) == 21
         assert wids.max() < 50
         assert (wcnts > 0).all()
+
+
+class TestNativeLoadIsNotOptionalWhereItCanBeBuilt:
+    """With a Makefile and a compiler present, a failed build or an ABI
+    mismatch that survives a rebuild RAISES: the word2vec pair generator
+    must not quietly become the Python one."""
+
+    @pytest.fixture()
+    def fresh(self, monkeypatch, tmp_path):
+        from multiverso_tpu.data import native as nat
+        self.real_so = nat._SO_PATH if native is not None else None
+        monkeypatch.setattr(nat, "_CACHED", None)
+        monkeypatch.setattr(nat, "_TRIED", False)
+        monkeypatch.setattr(nat, "_SO_PATH", str(tmp_path / "missing.so"))
+        return nat
+
+    def test_failed_build_raises(self, fresh, monkeypatch):
+        monkeypatch.setattr(fresh, "_toolchain", lambda: True)
+
+        def boom(rebuild):
+            raise RuntimeError("native data lib build failed (rc=2)")
+        monkeypatch.setattr(fresh, "_build", boom)
+        with pytest.raises(RuntimeError, match="build failed"):
+            fresh.load_native()
+
+    def test_abi_mismatch_after_rebuild_raises(self, fresh, monkeypatch):
+        import shutil
+        if self.real_so is None:
+            pytest.skip("no native toolchain here")
+        monkeypatch.setattr(fresh, "_toolchain", lambda: True)
+        builds = []
+
+        def fake_build(rebuild):
+            builds.append(rebuild)
+            # like the linker: a NEW file, never a write into one that
+            # is already mapped into this process
+            if os.path.exists(fresh._SO_PATH):
+                os.unlink(fresh._SO_PATH)
+            shutil.copy(self.real_so, fresh._SO_PATH)
+        monkeypatch.setattr(fresh, "_build", fake_build)
+        monkeypatch.setattr(fresh, "ABI_VERSION", 10 ** 6)
+        with pytest.raises(RuntimeError, match="ABI"):
+            fresh.load_native()
+        assert builds == [False, True]     # one rebuild, then it raises
+
+    def test_none_only_where_it_cannot_be_built(self, fresh, monkeypatch):
+        monkeypatch.setattr(fresh, "_toolchain", lambda: False)
+        assert fresh.load_native() is None

@@ -216,3 +216,81 @@ class TestFleetFaultTolerance:
                 fc.close()
             except Exception:
                 pass                            # rank 0's close may fail
+
+
+class TestOneProcessPerChip:
+    """The launcher hands each chip-holding process exactly one chip
+    before it imports jax, and refuses one process too many in words —
+    instead of a libtpu lockfile error in some child, or a CPU server
+    nobody asked for."""
+
+    @pytest.fixture()
+    def launcher(self, monkeypatch):
+        from multiverso_tpu.server import __main__ as launcher
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+        monkeypatch.setattr(
+            launcher.glob, "glob",
+            lambda pat: [f"/dev/vfio/{i}" for i in range(4)]
+            if pat.startswith("/dev/vfio") else [])
+        return launcher
+
+    def test_distinct_chips_and_one_chip_environment(self, launcher):
+        chips = launcher._assign_chips(4)
+        assert chips == [0, 1, 2, 3]
+        envs = [launcher._chip_env({"KEEP": "1"}, c) for c in chips]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == list("0123")
+        for e in envs:
+            assert e["KEEP"] == "1"
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_one_too_many_is_refused_in_words(self, launcher):
+        with pytest.raises(SystemExit) as e:
+            launcher._assign_chips(5)
+        assert "5 chip-holding" in str(e.value)
+        assert "one process per chip" in str(e.value)
+        # --grow: chips already held by fleet members are not free
+        assert launcher._assign_chips(1, taken=[0, 1, 2]) == [3]
+        with pytest.raises(SystemExit, match="0 free chip"):
+            launcher._assign_chips(1, taken=[0, 1, 2, 3])
+
+    def test_a_restricted_launcher_hands_out_only_its_own(self, launcher,
+                                                          monkeypatch):
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+        assert launcher._assign_chips(2) == [2, 3]
+        with pytest.raises(SystemExit, match="one process per chip"):
+            launcher._assign_chips(3)
+
+    def test_cpu_servers_only_when_pinned_explicitly(self, launcher,
+                                                     monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert launcher._assign_chips(8) == [None] * 8
+        env = {"A": "b"}
+        assert launcher._chip_env(env, None) is env
+        # "tpu,cpu" (the chip host's setting) still holds chips
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert launcher._assign_chips(2) == [0, 1]
+
+    def test_fleet_refuses_before_starting_anything(self, tmp_path):
+        """End to end through the CLI on this chip-less host with no
+        CPU pin: non-zero exit, that sentence, no fleet file, and the
+        launcher itself never initialised a backend to find out."""
+        import os
+        import subprocess
+        import sys
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS")}
+        env["PYTHONPATH"] = repo
+        fleet_file = tmp_path / "fleet.json"
+        proc = subprocess.run(
+            [sys.executable, "-m", "multiverso_tpu.server", "--fleet", "2",
+             "--address", f"unix:{tmp_path}/f.sock",
+             "--fleet-file", str(fleet_file)],
+            env=env, cwd=repo, capture_output=True, text=True, timeout=120)
+        assert proc.returncode != 0
+        assert "2 chip-holding server process(es) requested" in proc.stderr
+        assert "one process per chip" in proc.stderr
+        assert "Unable to initialize backend" not in proc.stderr
+        assert not fleet_file.exists()

@@ -113,7 +113,7 @@ class TestWatchdog:
         assert len(os.listdir(str(tmp_path / "dumps"))) == 2
 
     def test_kill_action_terminates_after_dump(self, tmp_path):
-        """The kill rung: a wedged process must die with
+        """The kill rung: a stuck process must die with
         SELF_TERMINATE_RC, post-mortem already on disk."""
         dumps = str(tmp_path / "dumps")
         src = (
@@ -133,9 +133,9 @@ class TestWatchdog:
         assert os.path.exists(os.path.join(dumps, entry, "stacks.txt"))
 
     def test_standalone_no_package_no_jax(self, tmp_path):
-        """The bench probe-child contract: watchdog.py loaded by file
+        """The jax-free-process contract: watchdog.py loaded by file
         path must dump WITHOUT multiverso_tpu or jax ever importing
-        (a wedged `import jax` is exactly what it instruments)."""
+        (a stuck `import jax` is exactly what it instruments)."""
         dumps = str(tmp_path / "dumps")
         src = (
             "import importlib.util, sys, time;"
@@ -179,6 +179,26 @@ class TestWatchdog:
 
 
 # -- compile/memory profiling ----------------------------------------------
+
+
+class _FailingJit:
+    """A jitted-function stand-in whose AOT path fails the way Mosaic
+    does, recording which entry points the wrapper touched."""
+
+    def __init__(self, log, executable=None):
+        self.log = log
+        self.executable = executable
+
+    def lower(self, *args, **kwargs):
+        self.log.append("lower")
+        if self.executable is None:
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+        exe = self.executable
+        return type("Lowered", (), {"compile": lambda self: exe})()
+
+    def __call__(self, *args, **kwargs):
+        self.log.append("call")
+        return "plain-jit-result"
 
 
 class TestProfiledJit:
@@ -225,6 +245,61 @@ class TestProfiledJit:
         # plain jitted path, not try to AOT-compile tracers
         g = jax.grad(lambda x: pf(x, jnp.ones(3)).sum())(jnp.zeros(3))
         np.testing.assert_allclose(np.asarray(g), np.ones(3))
+
+    def test_compile_and_runtime_errors_reach_the_caller(self,
+                                                         monkeypatch):
+        """No second attempt through plain jit: an accelerator-shaped
+        failure in lower/compile (Mosaic refusing a kernel) or in the
+        compiled call raises, and raises again on the next call — the
+        wrapper never latches onto another path."""
+        import jax
+        import jax.numpy as jnp
+
+        pf = telemetry.profiled_jit(lambda x: x + 1, name="t.raise")
+        plain_calls = []
+        monkeypatch.setattr(pf, "_jit", _FailingJit(plain_calls))
+        for _ in range(2):
+            with pytest.raises(RuntimeError, match="Mosaic failed"):
+                pf(jnp.ones(4))
+        assert plain_calls == ["lower", "lower"]     # never __call__
+
+        class Exe:
+            def cost_analysis(self):
+                return {}
+
+            def memory_analysis(self):
+                return None
+
+            def __call__(self, *a, **k):
+                raise jax.errors.JaxRuntimeError("INTERNAL: device halt")
+
+        pf2 = telemetry.profiled_jit(lambda x: x + 1, name="t.raise2")
+        plain_calls2 = []
+        monkeypatch.setattr(pf2, "_jit", _FailingJit(plain_calls2, Exe()))
+        for _ in range(2):
+            with pytest.raises(jax.errors.JaxRuntimeError,
+                               match="device halt"):
+                pf2(jnp.ones(4))
+        assert "call" not in plain_calls2
+
+    def test_new_input_sharding_is_a_new_signature(self, devices):
+        """An AOT executable takes exactly the shardings it was
+        compiled for. The signature keys on them, so a differently
+        sharded input compiles its own program (as jit would) instead
+        of failing the call — the case the removed blanket retry used
+        to absorb."""
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.asarray(devices[:2]), ("x",))
+        pf = telemetry.profiled_jit(lambda x: x * 2.0, name="t.shard")
+        host = np.arange(8, dtype=np.float32)
+        a = jax.device_put(host, NamedSharding(mesh, P("x")))
+        b = jax.device_put(host, NamedSharding(mesh, P()))
+        for arr in (a, b, a):
+            np.testing.assert_allclose(np.asarray(pf(arr)), host * 2)
+        assert metrics.snapshot()["counters"][
+            "profile.compiles{fn=t.shard}"] == 2
 
     def test_superstep_is_profiled_on_mesh(self, mesh8):
         """The acceptance metric: a real fused superstep on the CPU

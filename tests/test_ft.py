@@ -584,9 +584,7 @@ class TestKillResumeEquivalence:
         """LDA: z + doc counts + tables all ride the manager; a run
         resumed at a sweep boundary matches the uninterrupted one
         (counts are integers — equality is exact). Pure-DP mesh like
-        the other LDA tests: the gibbs sampler on a model-parallel
-        mesh is a pre-existing XLA aliasing failure (see the xfail in
-        test_placement.py)."""
+        the other LDA tests."""
         from multiverso_tpu.apps.lightlda import LDAConfig, LightLDA
         from multiverso_tpu.ft.checkpoint import RunCheckpointManager
         from multiverso_tpu.tables import reset_tables

@@ -5,6 +5,7 @@ batch-parallel TPU sampler must mix like sequential Gibbs)."""
 import numpy as np
 import pytest
 
+from multiverso_tpu import core
 from multiverso_tpu.apps.lightlda import LDAConfig, LightLDA, load_docs
 from multiverso_tpu.data.corpus import synthetic_docs
 from multiverso_tpu.tables import base as table_base
@@ -404,13 +405,6 @@ def _run_docblock(mesh, docs, name, batch_tokens=2048):
     return app
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing LDA model-parallel numeric mismatch: the "
-           "doc-blocked sampler on a dp x mp mesh drifts from the "
-           "pure-DP oracle (~10% of word-topic counts differ); "
-           "tracking: audit the sharded gather/psum vs the dp-only "
-           "path for a draw-order or staleness divergence")
 def test_docblock_model_parallel_matches_dp(devices, docs):
     """The model-axis sharding (vocab-sliced word table, sharded gather +
     psum) must be EXACTLY the dp-only computation: every partial-gather
@@ -497,13 +491,6 @@ def test_docblock_streamed_matches_inmemory(mesh_dp8, docs):
     np.testing.assert_allclose(app.ll_history, ref.ll_history, rtol=1e-6)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing LDA model-parallel numeric mismatch: the "
-           "STREAMED doc-blocked sampler on a dp x mp mesh diverges "
-           "from the streamed pure-DP oracle (same root cause as "
-           "test_docblock_model_parallel_matches_dp); tracking: same "
-           "audit")
 def test_docblock_streamed_model_parallel(devices, docs):
     """Streamed mode on a dp x mp mesh equals the streamed pure-DP run
     (sharded master-delta scatters are integer-exact)."""
@@ -632,3 +619,48 @@ def test_stream_blocks_requires_docblock(mesh_dp8):
                  LDAConfig(num_topics=128, sampler="tiled",
                            stream_blocks=True),
                  mesh=mesh_dp8, name="lda_sb_bad")
+
+
+def test_dp_mp_eval_compiles_for_a_v5e_2x2():
+    """The doc-blocked eval on a data=2 x model=2 mesh, compiled ahead
+    of time for a described v5e 2x2 (no chip needed). On the first
+    four-chip run XLA:TPU refused it ("Reshape should have supported
+    layout before reaching the emitter") until the scanned chunks'
+    sharding was stated before the loop (_chunked_ll); dp-only and
+    mp-only meshes never showed it."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:       # noqa: BLE001
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2),
+                (core.DATA_AXIS, core.MODEL_AXIS))
+    K, V, tiles, B = 1024, 50_000, 8, 307_200      # chip_smoke's widths
+    vpad = V + 2
+    app = types.SimpleNamespace(
+        mesh=mesh, K=K, V=V, alpha=50.0 / K, beta=0.01,
+        word_topic=types.SimpleNamespace(storage_shape=(vpad, tiles, 128)))
+    app._eval_chunk = lambda n: LightLDA._eval_chunk(app, n)
+    run = LightLDA._chunked_ll(app, LightLDA._build_word_gather(app))
+
+    def loglik(nwk3, ndk, nk, ws, rows, mask):
+        return run(nwk3, ndk.reshape(-1, tiles, 128),
+                   nk[:K].astype(jnp.float32), ws.reshape(-1),
+                   rows.reshape(-1), mask.reshape(-1).astype(jnp.float32))
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    lanes = sds((1, B), jnp.int32, P(None, core.DATA_AXIS))
+    jax.jit(loglik).trace(
+        sds((vpad, tiles, 128), jnp.int32, P(core.MODEL_AXIS, None, None)),
+        sds((3000, 16, tiles, 128), jnp.int16),
+        sds((K,), jnp.int32, P(core.MODEL_AXIS)),
+        lanes, lanes, lanes).lower().compile()

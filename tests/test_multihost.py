@@ -45,8 +45,8 @@ def test_p_process_cpu_cluster(nprocs):
     # the P children compile IDENTICAL programs: share XLA binaries via
     # the persistent cache (measured ~10% off the P=4 wall on the
     # 1-core CI host; also carries across the [2] and [4] runs)
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-        tempfile.gettempdir(), "mvtpu_test_jax_cache")
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
+        tempfile.gettempdir(), "mvtpu_test_jax_cache"))
     env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0.1"
     env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
     env["PYTHONPATH"] = os.pathsep.join(

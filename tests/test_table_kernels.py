@@ -10,6 +10,9 @@ lookups. Selection/fallback is asserted through the telemetry spine:
 ``profile.calls{fn=...}`` dispatch counts.
 """
 
+import functools
+import zlib
+
 import numpy as np
 import pytest
 
@@ -49,15 +52,25 @@ def _engine_pair(monkeypatch, build):
     return tx, tp
 
 
-def _assert_kv_equal(tx, tp, where=""):
+# Two lowerings of one float expression may round differently: XLA
+# fuses and reassociates adam's divide/sqrt/pow chain, the kernel
+# evaluates it op by op. A few float32 ulps, fixed before looking at any
+# run; everything whose arithmetic is exact in both (keys, slot choice,
+# integer-valued adds) stays bit-equal.
+FLOAT_RTOL = 8 * float(np.finfo(np.float32).eps)
+
+
+def _assert_kv_equal(tx, tp, where="", rtol=0.0):
     assert np.array_equal(np.asarray(tx.keys), np.asarray(tp.keys)), \
         f"keys diverged {where}"
-    assert np.array_equal(np.asarray(tx.values), np.asarray(tp.values)), \
-        f"values diverged {where}"
+    np.testing.assert_allclose(
+        np.asarray(tp.values), np.asarray(tx.values), rtol=rtol, atol=0,
+        err_msg=f"values diverged {where}")
     for lx, lp in zip(jax.tree.leaves(tx.state),
                       jax.tree.leaves(tp.state)):
-        assert np.array_equal(np.asarray(lx), np.asarray(lp)), \
-            f"updater state diverged {where}"
+        np.testing.assert_allclose(
+            np.asarray(lp), np.asarray(lx), rtol=rtol, atol=0,
+            err_msg=f"updater state diverged {where}")
 
 
 class TestKVParity:
@@ -69,7 +82,10 @@ class TestKVParity:
         """Randomized add/lookup stream: cross-batch duplicate keys
         (re-probe the matched slot), non-pow2 batch lengths (padding
         lanes), missing-key gets — final triple bit-equal."""
-        rng = np.random.default_rng(hash((updater, value_dim)) % 2**32)
+        # (hash() of a str is salted per process: seeding from it made
+        # this test's data, and its outcome, differ run to run)
+        rng = np.random.default_rng(
+            zlib.crc32(f"{updater}/{value_dim}".encode()))
         tx, tp = _engine_pair(monkeypatch, lambda m: KVTable(
             2048, value_dim=value_dim, slots_per_bucket=8,
             updater=updater, mesh=mesh1,
@@ -86,7 +102,8 @@ class TestKVParity:
             tp.add(keys, deltas)
         tx.wait()
         tp.wait()
-        _assert_kv_equal(tx, tp, f"({updater}, {value_dim})")
+        rtol = FLOAT_RTOL if updater == "adam" else 0.0
+        _assert_kv_equal(tx, tp, f"({updater}, {value_dim})", rtol)
         assert len(tx) == len(tp)
         # lookups: mix of present and missing keys, duplicates allowed
         q = rng.choice(np.arange(1, 600, dtype=np.uint64), size=19,
@@ -94,7 +111,7 @@ class TestKVParity:
         vx, fx = tx.get(q)
         vp, fp = tp.get(q)
         assert np.array_equal(fx, fp)
-        assert np.array_equal(vx, vp)
+        np.testing.assert_allclose(vp, vx, rtol=rtol, atol=0)
 
     def test_overflow_drops_whole_batch_on_both_engines(self, mesh1,
                                                         monkeypatch):
@@ -313,28 +330,62 @@ class TestSelection:
         assert pal_calls.value == p0 + 1
         assert xla_calls.value == x0
 
-    def test_runtime_error_falls_back_permanently(self, mesh1,
-                                                  monkeypatch):
+    def test_engine_failure_raises_and_selection_stays(self, mesh1,
+                                                       monkeypatch):
+        """An accelerator-shaped failure (Mosaic refusing a kernel at
+        its first call) reaches the caller: the engine is not swapped,
+        XLA is not called, nothing is counted as a fallback."""
         monkeypatch.setenv("MVTPU_KERNELS", "pallas")
         calls = {"pallas": 0, "xla": 0}
 
         def bad_pallas(*a):
             calls["pallas"] += 1
-            raise RuntimeError("lowering failed")
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
 
-        def good_xla(*a):
+        def xla(*a):
             calls["xla"] += 1
             return "xla-result"
 
-        before = self._fallbacks("unit.kernel", "error")
-        eng = tk.select_kernel("unit.kernel", xla=good_xla,
+        reg = telemetry.registry()
+        eng = tk.select_kernel("unit.kernel", xla=xla,
                                pallas=lambda: bad_pallas, mesh=mesh1)
         assert eng.engine == "pallas"
-        assert eng(1, 2) == "xla-result"       # transparent fallback
-        assert eng.engine == "xla"             # ...and permanent
-        assert eng(1, 2) == "xla-result"
-        assert calls == {"pallas": 1, "xla": 2}
-        assert self._fallbacks("unit.kernel", "error") == before + 1
+        for _ in range(2):
+            with pytest.raises(RuntimeError, match="Mosaic failed"):
+                eng(1, 2)
+        assert eng.engine == "pallas"
+        assert calls == {"pallas": 2, "xla": 0}
+        assert not [k for k in reg.snapshot()["counters"]
+                    if "kernel=unit.kernel" in k and "reason=error" in k]
+
+    def test_build_failure_raises(self, mesh1, mesh_mp2, monkeypatch):
+        """A factory that fails to BUILD raises too — only
+        UnsupportedShardingLayout is a decision."""
+        monkeypatch.setenv("MVTPU_KERNELS", "pallas")
+
+        def bad_factory():
+            raise ValueError("block shape refused")
+
+        with pytest.raises(ValueError, match="block shape refused"):
+            tk.select_kernel("unit.badbuild", xla=lambda: "x",
+                             pallas=bad_factory, mesh=mesh1)
+        with pytest.raises(ValueError, match="block shape refused"):
+            tk.select_kernel("unit.badbuild", xla=lambda: "x",
+                             pallas=lambda: (lambda: "p"),
+                             pallas_sharded=bad_factory, mesh=mesh_mp2)
+
+    def test_selection_keys_on_the_mesh_platform(self, mesh1,
+                                                 monkeypatch):
+        """`auto` and interpret mode read the MESH's platform, not the
+        process default backend — one test, in one place
+        (core.platform), shared with LightLDA."""
+        monkeypatch.setenv("MVTPU_KERNELS", "auto")
+        assert tk.interpret_mode(mesh1) is True
+        monkeypatch.setattr(core, "platform", lambda m=None: "tpu")
+        assert tk.interpret_mode(mesh1) is False
+        eng = tk.select_kernel("unit.platform", xla=lambda: "x",
+                               pallas=lambda: (lambda: "p"), mesh=mesh1)
+        assert eng.engine == "pallas" and eng() == "p"
 
     def test_unknown_mode_is_auto(self, monkeypatch):
         monkeypatch.setenv("MVTPU_KERNELS", "turbo")
@@ -513,7 +564,11 @@ class TestShardedParity:
             t.wait()
             outs[mode] = (t.get(), float(aux))
         assert np.array_equal(outs["xla"][0], outs["pallas"][0])
-        assert outs["xla"][1] == outs["pallas"][1]
+        # g.sum() is a float reduction over a gather two lowerings are
+        # free to order differently (it was compared with == and failed
+        # every run: -2.2081804 vs -2.2081809)
+        assert outs["pallas"][1] == pytest.approx(
+            outs["xla"][1], rel=FLOAT_RTOL * 16)
 
 
 class TestSuperstepBodies:
@@ -548,7 +603,213 @@ class TestSuperstepBodies:
             t.wait()
             outs[mode] = (t.get(), float(aux))
         assert np.array_equal(outs["xla"][0], outs["pallas"][0])
-        assert outs["xla"][1] == outs["pallas"][1]
+        assert outs["pallas"][1] == pytest.approx(
+            outs["xla"][1], rel=FLOAT_RTOL * 16)
+
+
+def _tpu_topology():
+    """A described (not attached) v5e 2x2, or None where libtpu cannot
+    describe one — then only the cross-lowering half runs."""
+    try:
+        from jax.experimental import topologies
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:       # noqa: BLE001 — any refusal means "no"
+        return None
+
+
+def _kernel_cases():
+    """(id, build(mesh) -> (fn, arg shapes+dtypes+specs)) for every
+    Pallas kernel `auto` can select on a TPU: the five table kernels
+    (flat, masked, sharded), the in-trace functional forms, and the
+    three LDA sampler kernels — at chip_smoke.py's widths."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from multiverso_tpu.ops import lda_sampler as ls
+    from multiverso_tpu.updaters import AddOption, get_updater
+
+    f32, i32, u32, b1 = jnp.float32, jnp.int32, jnp.uint32, jnp.bool_
+    M = core.MODEL_AXIS
+    n, L, sh = 2048, 1024, 2
+    cases = []
+
+    def add(name, build):
+        # flat kernels compile for ONE device (a bare pallas_call has no
+        # partitioning rule); the shard_map forms for a 2x2 mesh
+        cases.append(pytest.param(build, name.endswith("-sharded"),
+                                  id=name))
+
+    for tag, R, C, T, dt in (("flat", 10_002, 100, 0, f32),
+                             ("tiled", 50_002, 1024, 8, i32)):
+        pshape = (R, T, 128) if T else (R, C)
+        pspec = P(M, None, None) if T else P(M, None)
+        kw = dict(num_cols=C, tiles=T, interpret=False)
+        add(f"gather-{tag}", lambda m, kw=kw, pshape=pshape, dt=dt: (
+            tk.build_row_gather(**kw),
+            [(pshape, dt, P()), ((n,), i32, P())]))
+        for masked in (False, True):
+            valid = [((n,), b1, P())] if masked else []
+            add(f"scatter-{tag}{'-masked' if masked else ''}",
+                lambda m, kw=kw, pshape=pshape, dt=dt, C=C,
+                masked=masked, valid=valid: (
+                    tk.build_row_scatter_add(**kw, masked=masked),
+                    [(pshape, dt, P()), ((n,), i32, P()),
+                     ((n, C), dt, P())] + valid))
+            add(f"coo-{tag}{'-masked' if masked else ''}",
+                lambda m, kw=kw, pshape=pshape, dt=dt, masked=masked,
+                valid=valid: (
+                    tk.build_coo_scatter_add(**kw, masked=masked),
+                    [(pshape, dt, P()), ((n,), i32, P()),
+                     ((n,), i32, P()), ((n,), dt, P())] + valid))
+        skw = dict(axis=M, lead=R)
+        lanes = ((sh, L), i32, P(M, None))
+        vmask = ((sh, L), b1, P(M, None))
+        add(f"gather-{tag}-sharded",
+            lambda m, kw=kw, skw=skw, pshape=pshape, pspec=pspec, dt=dt: (
+                tk.build_row_gather_sharded(**kw, **skw, mesh=m),
+                [(pshape, dt, pspec), lanes, ((n,), i32, P())]))
+        add(f"scatter-{tag}-sharded",
+            lambda m, kw=kw, skw=skw, pshape=pshape, pspec=pspec, dt=dt,
+            C=C: (tk.build_row_scatter_add_sharded(**kw, **skw, mesh=m),
+                  [(pshape, dt, pspec), lanes,
+                   ((sh, L, C), dt, P(M, None, None)), vmask]))
+        add(f"coo-{tag}-sharded",
+            lambda m, kw=kw, skw=skw, pshape=pshape, pspec=pspec, dt=dt: (
+                tk.build_coo_scatter_add_sharded(**kw, **skw, mesh=m),
+                [(pshape, dt, pspec), lanes, lanes,
+                 ((sh, L), dt, P(M, None)), vmask]))
+
+    NB, SL = 8192, 8
+    opt = [((), f32, P())] * 4 + [((), i32, P())]
+    for vd, updater in ((0, "default"), (8, "adagrad"), (0, "adam")):
+        vshape = (NB, SL, vd) if vd else (NB, SL)
+        vspec = P(M, None, None) if vd else P(M, None)
+        upd = get_updater(updater)
+        state = jax.eval_shape(upd.init_state,
+                               jax.ShapeDtypeStruct(vshape, f32))
+        nstate = len(jax.tree.leaves(state))
+        kw = dict(slots=SL, value_dim=vd, interpret=False)
+        ukw = dict(updater=upd, state_template=state)
+        skw = dict(axis=M, num_buckets=NB)
+        kshape = ((NB, SL, 2), u32)
+        tag = f"{updater}-vdim{vd}"
+
+        def unflatten(fn, nstate=nstate, state=state):
+            # positional wrapper: (keys, vals, *state, ..., *option)
+            def call(keys, vals, *rest):
+                st = jax.tree.unflatten(jax.tree.structure(state),
+                                        rest[:nstate])
+                rest = rest[nstate:]
+                return fn(keys, vals, st, *rest[:4],
+                          AddOption(*rest[4:]))
+            return call
+
+        if updater != "adam":
+            add(f"kv-lookup-{tag}", lambda m, kw=kw, vshape=vshape: (
+                tk.build_kv_lookup(**kw, default_value=0.0),
+                [kshape + (P(),), (vshape, f32, P()),
+                 ((n, 2), u32, P()), ((n,), i32, P())]))
+            add(f"kv-lookup-{tag}-sharded",
+                lambda m, kw=kw, skw=skw, vshape=vshape, vspec=vspec: (
+                    tk.build_kv_lookup_sharded(**kw, **skw, mesh=m,
+                                               default_value=0.0),
+                    [kshape + (P(M, None, None),), (vshape, f32, vspec),
+                     ((sh, L, 2), u32, P(M, None, None)),
+                     ((sh, L), i32, P(M, None)), ((n,), i32, P())]))
+        dshape = (n, vd) if vd else (n,)
+        add(f"kv-apply-{tag}",
+            lambda m, kw=kw, ukw=ukw, vshape=vshape, nstate=nstate,
+            dshape=dshape, unflatten=unflatten: (
+                unflatten(tk.build_kv_probe_update(**kw, **ukw)),
+                [kshape + (P(),), (vshape, f32, P())]
+                + [(vshape, f32, P())] * nstate
+                + [((n,), i32, P()), ((n, 2), u32, P()),
+                   (dshape, f32, P()), ((n,), b1, P())] + opt))
+        sdshape = (sh, L, vd) if vd else (sh, L)
+        add(f"kv-apply-{tag}-sharded",
+            lambda m, kw=kw, ukw=ukw, skw=skw, vshape=vshape,
+            vspec=vspec, nstate=nstate, sdshape=sdshape,
+            unflatten=unflatten: (
+                unflatten(tk.build_kv_probe_update_sharded(
+                    **kw, **ukw, **skw, mesh=m)),
+                [kshape + (P(M, None, None),), (vshape, f32, vspec)]
+                + [(vshape, f32, vspec)] * nstate
+                + [((sh, L), i32, P(M, None)),
+                   ((sh, L, 2), u32, P(M, None, None)),
+                   (sdshape, f32, P(M, *([None] * (len(sdshape) - 1)))),
+                   ((sh, L), b1, P(M, None))] + opt))
+
+    def functional(m):
+        def body(p, ids, deltas, cols, vals):
+            with tk.kernel_mesh_scope(m, M):
+                g = tk.gather_rows(p, ids)
+                p = tk.row_scatter_add(p, ids, g + deltas)
+                return tk.coo_scatter_add(p, ids, cols, vals)
+        return body, [((10_002, 100), f32, P(M, None)), ((n,), i32, P()),
+                      ((n, 100), f32, P()), ((n,), i32, P()),
+                      ((n,), f32, P())]
+    add("functional-forms-sharded", functional)
+
+    # LightLDA samplers at measure_lda's widths: K=1024 (C=8), 512-token
+    # blocks, 16 docs a block; W from the bf16 mirror (production) and
+    # from the int32 master (the operands that overflow Mosaic's default
+    # 16 MiB scoped VMEM)
+    C, TB, MAXD, NBK = 8, 512, 16, 4
+    B = NBK * TB
+    tok = [((B,), i32, P())] * 3 + [((B,), f32, P())] * 2
+    for wdt, wtag in ((jnp.bfloat16, "bf16"), (i32, "i32")):
+        w3 = ((B, C, 128), wdt, P())
+        sinv = ((C, 128), f32, P())
+        add(f"lda-docblock-{wtag}", lambda m, w3=w3, sinv=sinv: (
+            functools.partial(ls.gibbs_sample_docblock, alpha=0.05,
+                              beta=0.01, tb=TB),
+            [((NBK, MAXD, C, 128), jnp.int16, P()), w3, sinv] + tok))
+        add(f"lda-docblock-build-{wtag}", lambda m, w3=w3, sinv=sinv: (
+            functools.partial(ls.gibbs_sample_docblock_build, alpha=0.05,
+                              beta=0.01, tb=TB, maxd=MAXD),
+            [w3, sinv] + tok))
+        add(f"lda-tiled-{wtag}", lambda m, w3=w3, sinv=sinv: (
+            functools.partial(ls.gibbs_sample_tiled, alpha=0.05,
+                              beta=0.01),
+            [((B, C, 128), i32, P()), w3, sinv,
+             ((B,), i32, P()), ((B,), i32, P()),
+             ((B,), f32, P()), ((B,), f32, P())]))
+    return cases
+
+
+class TestTpuLowering:
+    """The guard that needs no chip: every Pallas kernel `auto` can
+    select on a TPU must get through the Pallas->Mosaic lowering for
+    the "tpu" platform on this CPU rig (block shapes, memory spaces),
+    and — where libtpu can describe a v5e — through Mosaic's own
+    compile (layouts, shape casts, SMEM and scoped-VMEM budgets). At
+    the parent of PR 21 every table kernel failed the first half and
+    every LDA kernel the second; the chip had never been asked."""
+
+    @pytest.fixture(scope="class")
+    def topology(self):
+        return _tpu_topology()
+
+    @pytest.mark.parametrize("build,sharded", _kernel_cases())
+    def test_lowers_and_compiles_for_tpu(self, build, sharded, topology,
+                                         monkeypatch):
+        from jax.sharding import Mesh, NamedSharding
+        monkeypatch.setenv("MVTPU_KERNELS", "pallas")
+        monkeypatch.setattr(core, "platform", lambda m=None: "tpu")
+        dims = (2, 2) if sharded else (1, 1)
+
+        def traced(devices):
+            mesh = Mesh(np.asarray(devices[:dims[0] * dims[1]])
+                        .reshape(dims), (core.DATA_AXIS, core.MODEL_AXIS))
+            fn, specs = build(mesh)
+            return jax.jit(fn).trace(*[
+                jax.ShapeDtypeStruct(shape, dt,
+                                     sharding=NamedSharding(mesh, sp))
+                for shape, dt, sp in specs])
+
+        traced(jax.devices("cpu")).lower(lowering_platforms=("tpu",))
+        if topology is not None:
+            traced(topology.devices).lower().compile()
 
 
 class TestHashingHoist:

@@ -89,6 +89,12 @@ class TestRoundtrips:
             st = c.server_status()
             assert st["name"] == "twire" and st["tables"] >= 1
             assert st["connections"] >= 1
+            # the status names the devices the tables live on, as jax
+            # reports them (chip_smoke.py's client asserts "tpu" here)
+            devs = list(core.mesh().devices.flat)
+            assert st["platform"] == devs[0].platform == "cpu"
+            assert st["device_kind"] == devs[0].device_kind
+            assert st["devices"] == [d.id for d in devs]
         from multiverso_tpu.server import table_server
         assert any(row["name"] == "twire"
                    for row in table_server.status_all())
