@@ -412,139 +412,146 @@ class LightLDA:
         if TB % 8 or B % TB:
             raise ValueError(f"block_tokens {TB} must be a multiple of 8 "
                              f"dividing batch_tokens {B}")
-        order = np.argsort(token_docs, kind="stable")
-        tw, td = token_words[order], token_docs[order]
-        doc_ids, doc_starts = np.unique(td, return_index=True) \
-            if len(td) else (np.zeros(0, np.int64), np.zeros(0, np.int64))
-        doc_ends = np.append(doc_starts[1:], len(td)) if len(td) \
-            else doc_starts
-        lens = doc_ends - doc_starts
-        if len(lens) and lens.max() > TB:
-            raise ValueError(f"a document has {lens.max()} tokens > "
-                             f"block_tokens {TB}")
-        # greedy whole-doc block assignment (sequential by nature; a
-        # plain scalar loop over doc LENGTHS — the token-level copy
-        # below is fully vectorized so web-scale corpora pack in seconds)
-        n_real = len(doc_ids)
-        blk = np.empty(n_real, np.int64)
-        row = np.empty(n_real, np.int64)
-        off = np.empty(n_real, np.int64)
-        b = 0
-        cur_r = cur_tok = 0
-        for di, ln in enumerate(lens.tolist()):
-            if cur_tok + ln > TB or cur_r >= MAXD:
-                b += 1
-                cur_r = cur_tok = 0
-            blk[di], row[di], off[di] = b, cur_r, cur_tok
-            cur_r += 1
-            cur_tok += ln
-        n_blocks = (b + 1) if n_real else 1
-        nbs = B // TB                       # blocks per scan step
-        per_call = S * nbs
-        self._per_call = per_call
-        self._tb, self._maxd = TB, MAXD
-        local = c.stream_blocks and c.local_corpus
-        if local:
-            # per-process corpus shard: this process packs its docs into
-            # ONLY the block slots its devices own (the reference's
-            # workers-each-own-their-DataBlocks model); the other
-            # processes fill the rest of the global block space
-            self._own_offs = self._owned_call_offsets()
-            self._own_per_call = cap = len(self._own_offs)
-            n_calls = -(-n_blocks // cap)
-            if jax.process_count() > 1:
-                from multiverso_tpu.parallel.multihost import (
-                    allgather_i64, validate_single_owner)
-                mask = np.zeros(per_call, np.int32)
-                mask[self._own_offs] = 1
-                validate_single_owner(mask, "local_corpus")
-                n_calls = int(allgather_i64([n_calls]).max())
-        else:
-            cap = per_call
-            n_calls = -(-n_blocks // cap)
-        nb_alloc = n_calls * cap            # blocks on THIS process
-        nb_pad = n_calls * per_call         # GLOBAL padded block count
-        self.calls_per_sweep = n_calls
-        self._nb_pad = nb_pad
+        # the host's part: sort, greedy block assignment, the padded
+        # stream and its placement on the device
+        with telemetry.span("lda.setup.pack"):
+            order = np.argsort(token_docs, kind="stable")
+            tw, td = token_words[order], token_docs[order]
+            # assignments() undoes the sort; a doc-contiguous corpus
+            # (the usual) sorts to itself and keeps nothing
+            self._doc_order = None if np.array_equal(td, token_docs) \
+                else order
+            doc_ids, doc_starts = np.unique(td, return_index=True) \
+                if len(td) else (np.zeros(0, np.int64), np.zeros(0, np.int64))
+            doc_ends = np.append(doc_starts[1:], len(td)) if len(td) \
+                else doc_starts
+            lens = doc_ends - doc_starts
+            if len(lens) and lens.max() > TB:
+                raise ValueError(f"a document has {lens.max()} tokens > "
+                                 f"block_tokens {TB}")
+            # greedy whole-doc block assignment (sequential by nature; a
+            # plain scalar loop over doc LENGTHS — the token-level copy
+            # below is fully vectorized so web-scale corpora pack in seconds)
+            n_real = len(doc_ids)
+            blk = np.empty(n_real, np.int64)
+            row = np.empty(n_real, np.int64)
+            off = np.empty(n_real, np.int64)
+            b = 0
+            cur_r = cur_tok = 0
+            for di, ln in enumerate(lens.tolist()):
+                if cur_tok + ln > TB or cur_r >= MAXD:
+                    b += 1
+                    cur_r = cur_tok = 0
+                blk[di], row[di], off[di] = b, cur_r, cur_tok
+                cur_r += 1
+                cur_tok += ln
+            n_blocks = (b + 1) if n_real else 1
+            nbs = B // TB                       # blocks per scan step
+            per_call = S * nbs
+            self._per_call = per_call
+            self._tb, self._maxd = TB, MAXD
+            local = c.stream_blocks and c.local_corpus
+            if local:
+                # per-process corpus shard: this process packs its docs into
+                # ONLY the block slots its devices own (the reference's
+                # workers-each-own-their-DataBlocks model); the other
+                # processes fill the rest of the global block space
+                self._own_offs = self._owned_call_offsets()
+                self._own_per_call = cap = len(self._own_offs)
+                n_calls = -(-n_blocks // cap)
+                if jax.process_count() > 1:
+                    from multiverso_tpu.parallel.multihost import (
+                        allgather_i64, validate_single_owner)
+                    mask = np.zeros(per_call, np.int32)
+                    mask[self._own_offs] = 1
+                    validate_single_owner(mask, "local_corpus")
+                    n_calls = int(allgather_i64([n_calls]).max())
+            else:
+                cap = per_call
+                n_calls = -(-n_blocks // cap)
+            nb_alloc = n_calls * cap            # blocks on THIS process
+            nb_pad = n_calls * per_call         # GLOBAL padded block count
+            self.calls_per_sweep = n_calls
+            self._nb_pad = nb_pad
 
-        tw_p = np.full((nb_alloc, TB), self._scratch_word, np.int32)
-        drel_p = np.full((nb_alloc, TB), MAXD - 1, np.int32)
-        mask_p = np.zeros((nb_alloc, TB), np.int32)
-        # -1 = document with zero tokens (never packed into any block);
-        # doc_topics()/store() must yield zero rows for those, not some
-        # other document's counts
-        self._blk_of_doc = np.full(self.num_docs, -1, np.int64)
-        self._row_of_doc = np.full(self.num_docs, -1, np.int64)
-        if n_real:
-            # each doc's tokens land at (blk, off + position-within-doc)
-            tok_within = np.arange(len(td), dtype=np.int64) \
-                - np.repeat(doc_starts, lens)
-            flat = np.repeat(blk * TB + off, lens) + tok_within
-            tw_p.reshape(-1)[flat] = tw
-            drel_p.reshape(-1)[flat] = np.repeat(row, lens)
-            mask_p.reshape(-1)[flat] = 1
-            self._blk_of_doc[doc_ids] = blk
-            self._row_of_doc[doc_ids] = row
-        fill = mask_p.sum() / max(nb_alloc * TB, 1)
-        self.packing_fill = float(fill)
-        log.info("lda doc_blocked: %d blocks (%d/call, %.0f%% fill)",
-                 nb_alloc, cap, 100 * fill)
+            tw_p = np.full((nb_alloc, TB), self._scratch_word, np.int32)
+            drel_p = np.full((nb_alloc, TB), MAXD - 1, np.int32)
+            mask_p = np.zeros((nb_alloc, TB), np.int32)
+            # -1 = document with zero tokens (never packed into any block);
+            # doc_topics()/store() must yield zero rows for those, not some
+            # other document's counts
+            self._blk_of_doc = np.full(self.num_docs, -1, np.int64)
+            self._row_of_doc = np.full(self.num_docs, -1, np.int64)
+            if n_real:
+                # each doc's tokens land at (blk, off + position-within-doc)
+                tok_within = np.arange(len(td), dtype=np.int64) \
+                    - np.repeat(doc_starts, lens)
+                flat = np.repeat(blk * TB + off, lens) + tok_within
+                tw_p.reshape(-1)[flat] = tw
+                drel_p.reshape(-1)[flat] = np.repeat(row, lens)
+                mask_p.reshape(-1)[flat] = 1
+                self._blk_of_doc[doc_ids] = blk
+                self._row_of_doc[doc_ids] = row
+            fill = mask_p.sum() / max(nb_alloc * TB, 1)
+            self.packing_fill = float(fill)
+            log.info("lda doc_blocked: %d blocks (%d/call, %.0f%% fill)",
+                     nb_alloc, cap, 100 * fill)
 
-        # init z — shared by both residency modes so the streamed and
-        # in-memory runs are bit-identical for the same seed. local mode
-        # instead hashes (seed, GLOBAL block, position) so the draw for
-        # a given slot is independent of the process layout
-        if local:
-            z0 = _hash_z(c.seed, self._global_of_local(
-                np.arange(nb_alloc, dtype=np.int64)), TB, self.K)
-        else:
-            rng = np.random.default_rng(c.seed)
-            z0 = rng.integers(0, self.K, (nb_pad, TB)).astype(np.int32)
+            # init z — shared by both residency modes so the streamed and
+            # in-memory runs are bit-identical for the same seed. local mode
+            # instead hashes (seed, GLOBAL block, position) so the draw for
+            # a given slot is independent of the process layout
+            if local:
+                z0 = _hash_z(c.seed, self._global_of_local(
+                    np.arange(nb_alloc, dtype=np.int64)), TB, self.K)
+            else:
+                rng = np.random.default_rng(c.seed)
+                z0 = rng.integers(0, self.K, (nb_pad, TB)).astype(np.int32)
 
-        if c.stream_blocks:
-            # OUT-OF-CORE: stream/z/doc-counts stay host-resident (the
-            # reference's disk-streamed DataBlocks); mask is derived on
-            # device (tw == scratch_word <=> padded lane)
-            self._tw_host = tw_p
-            self._drel_host = drel_p
-            self._z_host = z0
-            self._z_synced = True    # init z is globally consistent
-            self._ndk = None
-            # inverse packing map for doc_topics(): (block, row) -> doc
-            self._doc_of_row = np.full((nb_alloc, MAXD), -1, np.int64)
-            valid = self._blk_of_doc >= 0
-            self._doc_of_row[self._blk_of_doc[valid],
-                             self._row_of_doc[valid]] = \
-                np.nonzero(valid)[0]
-            return
+            if c.stream_blocks:
+                # OUT-OF-CORE: stream/z/doc-counts stay host-resident (the
+                # reference's disk-streamed DataBlocks); mask is derived on
+                # device (tw == scratch_word <=> padded lane)
+                self._tw_host = tw_p
+                self._drel_host = drel_p
+                self._z_host = z0
+                self._z_synced = True    # init z is globally consistent
+                self._ndk = None
+                # inverse packing map for doc_topics(): (block, row) -> doc
+                self._doc_of_row = np.full((nb_alloc, MAXD), -1, np.int64)
+                valid = self._blk_of_doc >= 0
+                self._doc_of_row[self._blk_of_doc[valid],
+                                 self._row_of_doc[valid]] = \
+                    np.nonzero(valid)[0]
+                return
 
-        # per-call staging: [S, B] lanes + per-step block offsets
-        spec = P(None, core.DATA_AXIS)
-        rows_flat = (np.arange(nb_pad)[:, None] * MAXD
-                     + drel_p).astype(np.int32)
-        self._calls = []
-        self._loglik_rows = []   # eval-only gather rows (not a fused
-        #                          operand: the sweep never needs them)
-        for call in range(n_calls):
-            lo = call * per_call
-            sl = slice(lo, lo + per_call)
-            shp = (S, B)
-            self._calls.append((
-                self._place(tw_p[sl].reshape(shp), spec),
-                self._place(drel_p[sl].reshape(shp), spec),
-                self._place(mask_p[sl].reshape(shp).astype(np.int32),
-                            spec),
-                self._place(np.arange(lo, lo + per_call, nbs,
-                                      dtype=np.int32), P())))
-            self._loglik_rows.append(
-                self._place(rows_flat[sl].reshape(shp), spec))
+            # per-call staging: [S, B] lanes + per-step block offsets
+            spec = P(None, core.DATA_AXIS)
+            rows_flat = (np.arange(nb_pad)[:, None] * MAXD
+                         + drel_p).astype(np.int32)
+            self._calls = []
+            self._loglik_rows = []   # eval-only gather rows (not a fused
+            #                          operand: the sweep never needs them)
+            for call in range(n_calls):
+                lo = call * per_call
+                sl = slice(lo, lo + per_call)
+                shp = (S, B)
+                self._calls.append((
+                    self._place(tw_p[sl].reshape(shp), spec),
+                    self._place(drel_p[sl].reshape(shp), spec),
+                    self._place(mask_p[sl].reshape(shp).astype(np.int32),
+                                spec),
+                    self._place(np.arange(lo, lo + per_call, nbs,
+                                          dtype=np.int32), P())))
+                self._loglik_rows.append(
+                    self._place(rows_flat[sl].reshape(shp), spec))
 
-        # full flat stream for the per-sweep word-count rebuild
-        self._tw_flat = self._place(tw_p.reshape(-1), P())
-        self._mask_flat = self._place(mask_p.reshape(-1), P())
+            # full flat stream for the per-sweep word-count rebuild
+            self._tw_flat = self._place(tw_p.reshape(-1), P())
+            self._mask_flat = self._place(mask_p.reshape(-1), P())
 
-        self._z = self._place(z0, P())
-        drel_dev = self._place(drel_p, P())
+            self._z = self._place(z0, P())
+            drel_dev = self._place(drel_p, P())
         tiles = self.K // 128
 
         @jax.jit
@@ -560,8 +567,11 @@ class LightLDA:
             nk = nk.at[zf].add(m_flat)
             return nwk, ndk.reshape(nb_pad, MAXD, tiles, 128), nk
 
-        nwk, ndk, nk = build(self._z, self._tw_flat, self._mask_flat,
-                             drel_dev)
+        # the device's part, fenced so the span holds it (build's own
+        # compile included: a bare jit, not in profile.compile.seconds)
+        with telemetry.span("lda.setup.counts"):
+            nwk, ndk, nk = jax.block_until_ready(build(
+                self._z, self._tw_flat, self._mask_flat, drel_dev))
         self.word_topic.put_raw(nwk)
         self._ndk = ndk
         self.summary.put_raw(nk)
@@ -799,24 +809,51 @@ class LightLDA:
         self._build_stale_helpers()
         gather_w = self._gather_w
 
-        def scan_body(wstale, carry, inp):
-            nk, ndk, z = carry
-            w, drel, msk, off, key = inp
-            ndk_c = lax.dynamic_slice_in_dim(ndk, off, nbs)
-            zi = lax.dynamic_slice_in_dim(z, off, nbs).reshape(B)
-            W3 = gather_w(wstale, w.reshape(B))
+        # each phase under a program scope, so the compiled ops carry its
+        # name (profiling.op_scopes): lda.carry is what the scan hands on
+        # (this step's window of z, in and out), lda.doc_counts the
+        # doc-topic window and the topic totals
+        scope = telemetry.scope
+
+        @scope("lda.carry")
+        def z_window(z, off):
+            return lax.dynamic_slice_in_dim(z, off, nbs).reshape(B)
+
+        @scope("lda.carry")
+        def z_update(z, znew, off):
+            return lax.dynamic_update_slice_in_dim(
+                z, znew.reshape(nbs, TB), off, 0)
+
+        @scope("lda.doc_counts")
+        def counts_window(ndk, off):
+            return lax.dynamic_slice_in_dim(ndk, off, nbs)
+
+        @scope("lda.doc_counts")
+        def counts_update(ndk, ndk_c, nk, nkd, off):
+            return (lax.dynamic_update_slice_in_dim(ndk, ndk_c, off, 0),
+                    nk.at[:K].add(nkd.reshape(-1)))
+
+        @scope("lda.sample")
+        def sample(ndk_c, W3, nk, zi, drel, msk, key):
             sinv = 1.0 / (nk[:K].astype(jnp.float32).reshape(tiles, 128)
                           + vbeta)
             k1, k2 = jax.random.split(key)
             u1 = jax.random.uniform(k1, (B,))
             u2 = jax.random.uniform(k2, (B,))
-            ndk_c, znew, nkd = sampler_call(
-                ndk_c, W3, sinv, zi, drel.reshape(B), msk.reshape(B),
-                u1, u2)
-            ndk = lax.dynamic_update_slice_in_dim(ndk, ndk_c, off, 0)
-            z = lax.dynamic_update_slice_in_dim(
-                z, znew.reshape(nbs, TB), off, 0)
-            nk = nk.at[:K].add(nkd.reshape(-1))
+            return sampler_call(ndk_c, W3, sinv, zi, drel.reshape(B),
+                                msk.reshape(B), u1, u2)
+
+        gather_words = scope("lda.gather_words")(gather_w)
+
+        def scan_body(wstale, carry, inp):
+            nk, ndk, z = carry
+            w, drel, msk, off, key = inp
+            ndk_c = counts_window(ndk, off)
+            zi = z_window(z, off)
+            W3 = gather_words(wstale, w.reshape(B))
+            ndk_c, znew, nkd = sample(ndk_c, W3, nk, zi, drel, msk, key)
+            ndk, nk = counts_update(ndk, ndk_c, nk, nkd, off)
+            z = z_update(z, znew, off)
             return (nk, ndk, z), ()
 
         self._db_scan_body = scan_body
@@ -1150,7 +1187,8 @@ class LightLDA:
         self._z_synced = True
 
     def _sweep_streamed(self) -> None:
-        wstale = self._to_stale(self.word_topic.raw())
+        with telemetry.span("lda.to_stale"):
+            wstale = self._to_stale(self.word_topic.raw())
         per_call, TB = self._per_call, self._tb
         # fresh accumulator: after the sweep it IS the new master
         # (counts telescope — see the superstep body)
@@ -1185,7 +1223,9 @@ class LightLDA:
         for k, dev in self._stream_calls():
             key = jax.random.fold_in(self._key, self._calls_done)
             self._calls_done += 1
-            (acc,), z_out = self._fused_stream((acc,), wstale, dev, key)
+            with telemetry.span("lda.dispatch"):
+                (acc,), z_out = self._fused_stream((acc,), wstale, dev,
+                                                   key)
             try:
                 z_out.copy_to_host_async()
             except AttributeError:
@@ -1535,10 +1575,17 @@ class LightLDA:
     # -- training ----------------------------------------------------------
 
     def sweep(self) -> None:
-        """One full sampling pass over the corpus."""
-        if self._docblock and self.config.stream_blocks:
-            self._sweep_streamed()
-            return
+        """One full sampling pass over the corpus. ``lda.sweep`` is the
+        host's time to ISSUE it (dispatch is asynchronous): the mirror
+        cast (``lda.to_stale``), every superstep call (``lda.dispatch``)
+        and the master rebuild (``lda.rebuild``) nest inside it."""
+        with telemetry.span("lda.sweep"):
+            if self._docblock and self.config.stream_blocks:
+                self._sweep_streamed()
+            else:
+                self._sweep_resident()
+
+    def _sweep_resident(self) -> None:
         mh = self.config.sampler == "mh"
         if mh:
             wcdf = self._build_wcdf(self.word_topic.raw())
@@ -1546,31 +1593,34 @@ class LightLDA:
             # param buffer is donated by the first superstep call)
             nwk_stale = self.word_topic.raw() + 0
         if self._stale:
-            wstale = self._to_stale(self.word_topic.raw())
+            with telemetry.span("lda.to_stale"):
+                wstale = self._to_stale(self.word_topic.raw())
         for call in self._calls:
             key = jax.random.fold_in(self._key, self._calls_done)
             self._calls_done += 1
-            if mh:
-                ws, ds, idxs, msks = call
-                (self._ndk, self._z), _ = self._fused_mh(
-                    (self._ndk, self._z), wcdf, nwk_stale,
-                    ws, ds, idxs, msks, key)
-            elif self._stale:
-                (self._ndk, self._z), _ = self._fused(
-                    (self._ndk, self._z), wstale, *call, key)
-            else:
-                (self._ndk, self._z), _ = self._fused(
-                    (self._ndk, self._z), *call, key)
+            with telemetry.span("lda.dispatch"):
+                if mh:
+                    ws, ds, idxs, msks = call
+                    (self._ndk, self._z), _ = self._fused_mh(
+                        (self._ndk, self._z), wcdf, nwk_stale,
+                        ws, ds, idxs, msks, key)
+                elif self._stale:
+                    (self._ndk, self._z), _ = self._fused(
+                        (self._ndk, self._z), wstale, *call, key)
+                else:
+                    (self._ndk, self._z), _ = self._fused(
+                        (self._ndk, self._z), *call, key)
         if self._stale:
             # fold the sweep's moves into the int32 master (the
             # reference's block-end Add of accumulated deltas)
-            if self._docblock:
-                nwk = self._rebuild(self._z, self._tw_flat,
-                                    self._mask_flat)
-            else:
-                nwk = self._rebuild(self._z, self._tw_dev,
-                                    self._mask_dev)
-            self.word_topic.put_raw(nwk)
+            with telemetry.span("lda.rebuild"):
+                if self._docblock:
+                    nwk = self._rebuild(self._z, self._tw_flat,
+                                        self._mask_flat)
+                else:
+                    nwk = self._rebuild(self._z, self._tw_dev,
+                                        self._mask_dev)
+                self.word_topic.put_raw(nwk)
 
     def train(self, num_iterations: Optional[int] = None) -> float:
         """Run Gibbs sweeps; returns the final per-token log-likelihood.
@@ -1597,15 +1647,8 @@ class LightLDA:
                 it = min(self._resume_sweeps, iters)
                 self._resume_sweeps = 0
                 continue
-            t_sweep = time.perf_counter()
-            with telemetry.span("lda.sweep"):
-                self.sweep()
-            telemetry.step_timeline(
-                "lda", it, tokens=self.num_tokens,
-                dispatch_s=time.perf_counter() - t_sweep)
-            telemetry.histogram(
-                "app.step.seconds", telemetry.LATENCY_BUCKETS,
-                app="lda").observe(time.perf_counter() - t_sweep)
+            self.sweep()        # times itself: the lda.sweep span
+            telemetry.step_timeline("lda", it, tokens=self.num_tokens)
             telemetry.beat()    # flight recorder: a heartbeat per sweep
             self._sweep_done = it + 1
             if self.run_ckpt is not None:
@@ -1690,6 +1733,38 @@ class LightLDA:
             return out
         return np.asarray(self._ndk[: self.num_docs]).reshape(
             self.num_docs, self.K)
+
+    def assignments(self) -> np.ndarray:
+        """int32[num_tokens]: every token's current topic, in the order
+        the corpus was handed to the constructor — whatever the sampler
+        mode did to the stream (doc sort and block packing, or the fixed
+        shuffle). One read of z from the device.
+
+        Multi-process ``stream_blocks``: a COLLECTIVE, like
+        :meth:`doc_topics` (the lazy z sync); under ``local_corpus`` the
+        result covers this process's own shard."""
+        if not self._docblock:
+            # z lives in the shuffled stream's index space: position j
+            # holds the token the seed's permutation drew from perm[j]
+            perm = np.random.default_rng(
+                self.config.seed ^ 0x5EED).permutation(len(self._mask))
+            out = np.empty(len(perm), np.int32)
+            out[perm] = np.asarray(self._z)
+            return out[: self.num_tokens]
+        if self.config.stream_blocks:
+            self._sync_z_host()
+            real = self._tw_host != self._scratch_word
+            z = self._z_host[real]
+        else:
+            real = np.asarray(self._mask_flat).astype(bool)
+            z = np.asarray(self._z).reshape(-1)[real]
+        # the packer keeps the doc-sorted order, so the real lanes in
+        # packed order ARE the sorted stream
+        if self._doc_order is None:
+            return z
+        out = np.empty_like(z)
+        out[self._doc_order] = z
+        return out
 
     def word_topics(self) -> np.ndarray:
         """[V, K] word-topic counts from the table (a bounded-staleness

@@ -329,30 +329,22 @@ class LogisticRegression:
             xs = np.stack([X[order[s:s + c.minibatch_size]] for s in grp])
             ys = np.stack([y[order[s:s + c.minibatch_size]] for s in grp])
             xd, yd = self._shard_scan(xs, ys)
-            t_step = time.perf_counter()
+            # the step record links to the span (its ``parent``), whose
+            # ``dur_s`` / ``span.seconds`` series is the one timing
             with telemetry.span("logreg.superstep"):
                 _, lg = self._fused_scan((), xd, yd)
-            telemetry.step_timeline(
-                "logreg", step_no, samples=S * c.minibatch_size,
-                dispatch_s=time.perf_counter() - t_step)
-            telemetry.histogram(
-                "app.step.seconds", telemetry.LATENCY_BUCKETS,
-                app="logreg").observe(time.perf_counter() - t_step)
+                telemetry.step_timeline(
+                    "logreg", step_no, samples=S * c.minibatch_size)
             telemetry.beat()
             step_no += 1
             losses.extend(lg)
         for s in full[len(full) - len(full) % S:] + tail:
             idx = order[s:s + c.minibatch_size]
             xs, ys = self._shard_batch(X[idx], y[idx])
-            t_step = time.perf_counter()
             with telemetry.span("logreg.step"):
                 _, loss = self._fused((), xs, ys)
-            telemetry.step_timeline(
-                "logreg", step_no, samples=len(idx),
-                dispatch_s=time.perf_counter() - t_step)
-            telemetry.histogram(
-                "app.step.seconds", telemetry.LATENCY_BUCKETS,
-                app="logreg").observe(time.perf_counter() - t_step)
+                telemetry.step_timeline("logreg", step_no,
+                                        samples=len(idx))
             telemetry.beat()
             step_no += 1
             losses.append(loss)

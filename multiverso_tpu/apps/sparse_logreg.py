@@ -237,17 +237,13 @@ class SparseLogisticRegression:
             losses = []
             for s in range(0, n, c.minibatch_size):
                 idx = order[s:s + c.minibatch_size]
-                t_step = time.perf_counter()
+                # the step record links to the span (its ``parent``),
+                # whose ``dur_s`` is the one timing
                 with telemetry.span("sparse_logreg.step"):
                     losses.append(self.train_batch(
                         [rows[i] for i in idx], y[idx]))
-                telemetry.step_timeline(
-                    "sparse_logreg", step_no, samples=len(idx),
-                    dispatch_s=time.perf_counter() - t_step)
-                telemetry.histogram(
-                    "app.step.seconds", telemetry.LATENCY_BUCKETS,
-                    app="sparse_logreg").observe(
-                    time.perf_counter() - t_step)
+                    telemetry.step_timeline("sparse_logreg", step_no,
+                                            samples=len(idx))
                 telemetry.beat()
                 step_no += 1
             loss = float(np.mean(losses))

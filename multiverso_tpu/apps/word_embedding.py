@@ -36,6 +36,7 @@ TPU design (the whole point — nothing here is a translation):
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from functools import partial
 from typing import Iterator, Optional, Tuple
@@ -159,6 +160,19 @@ def table_sample(key, table: jax.Array, shape):
     return jnp.take(table, idx, axis=0)
 
 
+# the phases of the fused body that both objectives share, each under a
+# program scope: the compiled ops carry the name (profiling.op_scopes)
+@telemetry.scope("w2v.gather_out")
+def _gather_out(w_out, ids):
+    return jnp.take(w_out, ids, axis=0)                       # [B, n, D]
+
+
+@telemetry.scope("w2v.scatter_out")
+def _scatter_out(w_out, ids, grad_u):
+    return w_out.at[ids.reshape(-1)].add(
+        -grad_u.reshape(-1, grad_u.shape[-1]).astype(w_out.dtype))
+
+
 class WordEmbedding:
     """The app: two MatrixTables + the fused scan superstep."""
 
@@ -173,15 +187,18 @@ class WordEmbedding:
         if c.subsample is not None:
             corpus.set_subsample(c.subsample)
         v, d = corpus.vocab_size, c.embedding_dim
-        rng = np.random.default_rng(c.seed)
-        # reference init: input embeddings ~ U(-0.5/dim, 0.5/dim), output 0
-        w_in_init = rng.uniform(-0.5 / d, 0.5 / d, (v, d)).astype(c.dtype)
-        self.w_in = MatrixTable(v, d, c.dtype, init_value=w_in_init,
-                                updater="default", mesh=self.mesh,
-                                name=f"{name}_in")
-        self.w_out = MatrixTable(v, d, c.dtype, init_value=0,
-                                 updater="default", mesh=self.mesh,
-                                 name=f"{name}_out")
+        with telemetry.span("w2v.setup.init_tables"):
+            rng = np.random.default_rng(c.seed)
+            # reference init: input embeddings ~ U(-0.5/dim, 0.5/dim),
+            # output 0
+            w_in_init = rng.uniform(-0.5 / d, 0.5 / d,
+                                    (v, d)).astype(c.dtype)
+            self.w_in = MatrixTable(v, d, c.dtype, init_value=w_in_init,
+                                    updater="default", mesh=self.mesh,
+                                    name=f"{name}_in")
+            self.w_out = MatrixTable(v, d, c.dtype, init_value=0,
+                                     updater="default", mesh=self.mesh,
+                                     name=f"{name}_out")
         self._scratch = self.w_in.padded_shape[0] - 1  # masked-lane row
         # MVTPU_STALENESS: embeddings() (logging/eval — nearest,
         # similarity, analogy; never fed back into training) serves from
@@ -192,31 +209,33 @@ class WordEmbedding:
         # replicated ON THE MESH (a bare jnp.asarray would land them on the
         # process default device, which may be a different platform)
         rep = partial(core.place, mesh=self.mesh)
-        if c.objective == "ns":
-            if c.ns_sampler == "table":
-                self._ns_table = rep(build_unigram_table(
-                    corpus.unigram_probs(c.unigram_power),
-                    c.ns_table_size))
-            elif c.ns_sampler == "alias":
-                p, a = build_alias(corpus.unigram_probs(c.unigram_power))
-                self._alias_prob = rep(p)
-                self._alias_idx = rep(a)
+        with telemetry.span("w2v.setup.vocab_tables"):
+            if c.objective == "ns":
+                if c.ns_sampler == "table":
+                    self._ns_table = rep(build_unigram_table(
+                        corpus.unigram_probs(c.unigram_power),
+                        c.ns_table_size))
+                elif c.ns_sampler == "alias":
+                    p, a = build_alias(
+                        corpus.unigram_probs(c.unigram_power))
+                    self._alias_prob = rep(p)
+                    self._alias_idx = rep(a)
+                else:
+                    raise ValueError(f"ns_sampler must be 'table' or "
+                                     f"'alias', got {c.ns_sampler!r}")
+            elif c.objective == "hs":
+                codes, points, lengths = corpus.huffman(c.max_code_len)
+                L = c.max_code_len
+                # mask beyond each word's code length; park masked lanes
+                # on the scratch row so the scatter is shape-static
+                msk = np.arange(L)[None, :] < lengths[:, None]
+                pts = np.where(msk, points[:, :L], self._scratch)
+                self._hs_points = rep(pts.astype(np.int32))
+                self._hs_codes = rep(codes[:, :L].astype(np.float32))
+                self._hs_mask = rep(msk.astype(np.float32))
             else:
-                raise ValueError(f"ns_sampler must be 'table' or "
-                                 f"'alias', got {c.ns_sampler!r}")
-        elif c.objective == "hs":
-            codes, points, lengths = corpus.huffman(c.max_code_len)
-            L = c.max_code_len
-            # mask beyond each word's code length; park masked lanes on the
-            # scratch row so the scatter is shape-static
-            msk = np.arange(L)[None, :] < lengths[:, None]
-            pts = np.where(msk, points[:, :L], self._scratch)
-            self._hs_points = rep(pts.astype(np.int32))
-            self._hs_codes = rep(codes[:, :L].astype(np.float32))
-            self._hs_mask = rep(msk.astype(np.float32))
-        else:
-            raise ValueError(f"objective must be 'ns' or 'hs', "
-                             f"got {c.objective!r}")
+                raise ValueError(f"objective must be 'ns' or 'hs', "
+                                 f"got {c.objective!r}")
         if c.model not in ("skipgram", "cbow"):
             raise ValueError(f"model must be 'skipgram' or 'cbow', "
                              f"got {c.model!r}")
@@ -278,74 +297,96 @@ class WordEmbedding:
         """Shared NS inner math: v [B,D] input vectors vs target ids [B].
         Returns (w_out', grad wrt v [B,D], mean loss)."""
         c = self.config
-        if c.ns_sampler == "table":
-            negs = table_sample(key, self._ns_table,
-                                (v.shape[0], c.negative))
-        else:
-            negs = alias_sample(key, self._alias_prob, self._alias_idx,
-                                (v.shape[0], c.negative))
-        ids = jnp.concatenate([tgt[:, None], negs], axis=1)   # [B, 1+K]
-        u = jnp.take(w_out, ids, axis=0)                      # [B, 1+K, D]
-        logits = jnp.einsum("bd,bkd->bk", v, u)
-        labels = jnp.zeros_like(logits).at[:, 0].set(1.0)
-        sig = jax.nn.sigmoid(logits)
-        # binary CE on (pos, negs); analytic grad dL/dlogit = sig - label
-        loss = -jnp.mean(
-            jnp.sum(labels * jax.nn.log_sigmoid(logits)
-                    + (1.0 - labels) * jax.nn.log_sigmoid(-logits), axis=1))
-        g = (sig - labels) * lr                               # [B, 1+K]
-        grad_v = jnp.einsum("bk,bkd->bd", g, u)
-        grad_u = g[:, :, None] * v[:, None, :]                # [B,1+K,D]
-        w_out = w_out.at[ids.reshape(-1)].add(
-            -grad_u.reshape(-1, u.shape[-1]).astype(w_out.dtype))
-        return w_out, grad_v, loss
+
+        @telemetry.scope("w2v.negatives")
+        def target_ids(tgt, key):
+            if c.ns_sampler == "table":
+                negs = table_sample(key, self._ns_table,
+                                    (tgt.shape[0], c.negative))
+            else:
+                negs = alias_sample(key, self._alias_prob,
+                                    self._alias_idx,
+                                    (tgt.shape[0], c.negative))
+            return jnp.concatenate([tgt[:, None], negs], axis=1)
+
+        @telemetry.scope("w2v.math")
+        def math(v, u, lr):
+            logits = jnp.einsum("bd,bkd->bk", v, u)
+            labels = jnp.zeros_like(logits).at[:, 0].set(1.0)
+            sig = jax.nn.sigmoid(logits)
+            # binary CE on (pos, negs); analytic grad dL/dlogit = sig - label
+            loss = -jnp.mean(
+                jnp.sum(labels * jax.nn.log_sigmoid(logits)
+                        + (1.0 - labels) * jax.nn.log_sigmoid(-logits),
+                        axis=1))
+            g = (sig - labels) * lr                           # [B, 1+K]
+            grad_v = jnp.einsum("bk,bkd->bd", g, u)
+            grad_u = g[:, :, None] * v[:, None, :]            # [B,1+K,D]
+            return loss, grad_v, grad_u
+
+        ids = target_ids(tgt, key)                            # [B, 1+K]
+        u = _gather_out(w_out, ids)                           # [B, 1+K, D]
+        loss, grad_v, grad_u = math(v, u, lr)
+        return _scatter_out(w_out, ids, grad_u), grad_v, loss
 
     def _hs_step(self, w_out, v, tgt, lr):
         """Hierarchical-softmax inner math along the Huffman path."""
         pts = jnp.take(self._hs_points, tgt, axis=0)          # [B, L]
         code = jnp.take(self._hs_codes, tgt, axis=0)          # [B, L] 0/1
         msk = jnp.take(self._hs_mask, tgt, axis=0)            # [B, L]
-        u = jnp.take(w_out, pts, axis=0)                      # [B, L, D]
-        logits = jnp.einsum("bd,bld->bl", v, u)
-        sig = jax.nn.sigmoid(logits)
-        # label = code bit: P(go-right) modeled by sigmoid
-        loss = -jnp.sum(msk * (code * jax.nn.log_sigmoid(logits)
-                               + (1 - code) * jax.nn.log_sigmoid(-logits))
-                        ) / jnp.maximum(jnp.sum(msk), 1.0)
-        g = (sig - code) * msk * lr                           # [B, L]
-        grad_v = jnp.einsum("bl,bld->bd", g, u)
-        grad_u = g[:, :, None] * v[:, None, :]
-        w_out = w_out.at[pts.reshape(-1)].add(
-            -grad_u.reshape(-1, u.shape[-1]).astype(w_out.dtype))
-        return w_out, grad_v, loss
+
+        @telemetry.scope("w2v.math")
+        def math(v, u, lr):
+            logits = jnp.einsum("bd,bld->bl", v, u)
+            sig = jax.nn.sigmoid(logits)
+            # label = code bit: P(go-right) modeled by sigmoid
+            loss = -jnp.sum(
+                msk * (code * jax.nn.log_sigmoid(logits)
+                       + (1 - code) * jax.nn.log_sigmoid(-logits))
+            ) / jnp.maximum(jnp.sum(msk), 1.0)
+            g = (sig - code) * msk * lr                       # [B, L]
+            grad_v = jnp.einsum("bl,bld->bd", g, u)
+            grad_u = g[:, :, None] * v[:, None, :]
+            return loss, grad_v, grad_u
+
+        u = _gather_out(w_out, pts)                           # [B, L, D]
+        loss, grad_v, grad_u = math(v, u, lr)
+        return _scatter_out(w_out, pts, grad_u), grad_v, loss
 
     def _build_superstep(self) -> None:
         c = self.config
         cbow = c.model == "cbow"
 
+        @telemetry.scope("w2v.gather_in")
+        def gather_in(w_in, src):
+            if not cbow:
+                return jnp.take(w_in, src, axis=0), None, None   # [B, D]
+            # src [B, 2w] context ids (scratch row = padding)
+            ctx_mask = (src != self._scratch).astype(w_in.dtype)
+            n_ctx = jnp.maximum(ctx_mask.sum(axis=1, keepdims=True), 1.0)
+            vecs = jnp.take(w_in, src, axis=0)                # [B, 2w, D]
+            return (jnp.einsum("bwd,bw->bd", vecs, ctx_mask) / n_ctx,
+                    ctx_mask, n_ctx)
+
+        @telemetry.scope("w2v.scatter_in")
+        def scatter_in(w_in, src, grad_v, ctx_mask, n_ctx):
+            if not cbow:
+                return w_in.at[src].add(-grad_v.astype(w_in.dtype))
+            # spread the input-side gradient over the context words
+            gctx = (grad_v / n_ctx)[:, None, :] * ctx_mask[:, :, None]
+            return w_in.at[src.reshape(-1)].add(
+                -gctx.reshape(-1, gctx.shape[-1]).astype(w_in.dtype))
+
         def scan_body(carry, inp):
             w_in, w_out = carry
             src, tgt, key, lr = inp
-            if cbow:
-                # src [B, 2w] context ids (scratch row = padding), tgt [B]
-                ctx_mask = (src != self._scratch).astype(w_in.dtype)
-                n_ctx = jnp.maximum(ctx_mask.sum(axis=1, keepdims=True), 1.0)
-                vecs = jnp.take(w_in, src, axis=0)            # [B, 2w, D]
-                v = jnp.einsum("bwd,bw->bd", vecs, ctx_mask) / n_ctx
-            else:
-                v = jnp.take(w_in, src, axis=0)               # [B, D]
+            v, ctx_mask, n_ctx = gather_in(w_in, src)
             if c.objective == "ns":
                 w_out, grad_v, loss = self._pos_neg_step(
                     w_out, v, tgt, key, lr)
             else:
                 w_out, grad_v, loss = self._hs_step(w_out, v, tgt, lr)
-            if cbow:
-                # spread the input-side gradient over the context words
-                gctx = (grad_v / n_ctx)[:, None, :] * ctx_mask[:, :, None]
-                w_in = w_in.at[src.reshape(-1)].add(
-                    -gctx.reshape(-1, gctx.shape[-1]).astype(w_in.dtype))
-            else:
-                w_in = w_in.at[src].add(-grad_v.astype(w_in.dtype))
+            w_in = scatter_in(w_in, src, grad_v, ctx_mask, n_ctx)
             return (w_in, w_out), loss
 
         def body(params, states, locals_, options, pairs, key, lrs):
@@ -474,85 +515,96 @@ class WordEmbedding:
         # the plan a periodic store persists: the original schedule when
         # resumed, else this run's own estimate
         self._train_plan = self._sched_plan or est_calls
-        srcs_buf, tgts_buf = [], []
+        S = c.steps_per_call
+        buf: list = []              # one call's (src, tgt) batches
         losses, call_no = [], 0
         t0 = time.perf_counter()
         # host pair generation overlaps device compute (the reference's
-        # ParameterLoader/ASyncBuffer pipelining role, SURVEY.md §4.5)
+        # ParameterLoader/ASyncBuffer pipelining role, SURVEY.md §4.5);
+        # named, so the producer thread times itself (w2v.pairs.produce,
+        # w2v.pairs.backpressure)
         from multiverso_tpu.utils.async_buffer import prefetch_iterator
-        for src, tgt in prefetch_iterator(self._batches(),
-                                          depth=2 * c.steps_per_call):
-            srcs_buf.append(src)
-            tgts_buf.append(tgt)
-            if len(srcs_buf) < c.steps_per_call:
-                continue
-            loss = self._dispatch(np.stack(srcs_buf), np.stack(tgts_buf),
-                                  call_no, est_calls)
-            losses.append(loss)
-            srcs_buf, tgts_buf = [], []
-            call_no += 1
-            if telemetry.health.maybe_rollback(self) is not None:
-                # divergence rollback: tables + the step cursor are
-                # back at the last clean generation (LR decay and the
-                # fold_in key sequence re-align through _step_no). The
-                # pair stream itself cannot rewind — training resumes
-                # on fresh batches from the restored parameters, which
-                # for a stochastic stream is equivalent to a replay.
-                # Checked BEFORE maybe_save so a diverged state is
-                # never committed as a generation.
-                continue
-            if self.run_ckpt is not None:
-                # run-level manager (preferred over the bespoke prefix
-                # dump): atomically-committed generations, keep-K
-                # retention, overlapped writes; collective — every
-                # process reaches the same call_no in lockstep
-                self.run_ckpt.maybe_save(
-                    self._step_no // c.steps_per_call, self.run_state)
-            elif c.checkpoint_interval > 0 and c.checkpoint_prefix \
-                    and call_no % c.checkpoint_interval == 0:
-                # legacy periodic mid-train dump (SURVEY §6.4's
-                # flag-driven trigger); collective
-                self.store(c.checkpoint_prefix)
-            if total_steps is not None \
-                    and call_no * c.steps_per_call >= total_steps:
-                break
-        if call_no == 0 and srcs_buf:
+        batches = prefetch_iterator(self._batches(), depth=2 * S,
+                                    name="w2v.pairs")
+        try:
+            while True:
+                # what this call waited for data/ (the producer runs
+                # ahead while the device works, so mostly the first)
+                with telemetry.span("w2v.wait_data"):
+                    buf = list(itertools.islice(batches, S))
+                if len(buf) < S:
+                    break
+                losses.append(self._dispatch(*self._stack(buf), call_no,
+                                             est_calls))
+                buf = []
+                call_no += 1
+                if telemetry.health.maybe_rollback(self) is not None:
+                    # divergence rollback: tables + the step cursor are
+                    # back at the last clean generation (LR decay and the
+                    # fold_in key sequence re-align through _step_no). The
+                    # pair stream itself cannot rewind — training resumes
+                    # on fresh batches from the restored parameters, which
+                    # for a stochastic stream is equivalent to a replay.
+                    # Checked BEFORE maybe_save so a diverged state is
+                    # never committed as a generation.
+                    continue
+                if self.run_ckpt is not None:
+                    # run-level manager (preferred over the bespoke prefix
+                    # dump): atomically-committed generations, keep-K
+                    # retention, overlapped writes; collective — every
+                    # process reaches the same call_no in lockstep
+                    self.run_ckpt.maybe_save(
+                        self._step_no // S, self.run_state)
+                elif c.checkpoint_interval > 0 and c.checkpoint_prefix \
+                        and call_no % c.checkpoint_interval == 0:
+                    # legacy periodic mid-train dump (SURVEY §6.4's
+                    # flag-driven trigger); collective
+                    self.store(c.checkpoint_prefix)
+                if total_steps is not None and call_no * S >= total_steps:
+                    break
+        finally:
+            batches.close()         # cancels the producer thread
+        if call_no == 0 and buf:
             # corpus smaller than one superstep: pad by cycling the
             # buffered batches to the static scan length (slight pair
             # over-weighting beats training nothing / a full recompile)
             log.warn("w2v corpus yields < %d batches; cycling %d to fill "
-                     "one superstep", c.steps_per_call, len(srcs_buf))
-            reps = [srcs_buf[i % len(srcs_buf)]
-                    for i in range(c.steps_per_call)]
-            rept = [tgts_buf[i % len(tgts_buf)]
-                    for i in range(c.steps_per_call)]
-            losses.append(self._dispatch(np.stack(reps), np.stack(rept),
-                                         0, est_calls))
+                     "one superstep", S, len(buf))
+            losses.append(self._dispatch(
+                *self._stack([buf[i % len(buf)] for i in range(S)]),
+                0, est_calls))
             call_no = 1
         # trailing partial buffer is otherwise dropped (like per-batch
         # remainders): a shorter scan length would force a full XLA
         # recompile for one leftover call's worth of pairs
-        self.w_in.wait()
-        dt = time.perf_counter() - t0
+        with telemetry.span("w2v.fence"):
+            self.w_in.wait()
+            dt = time.perf_counter() - t0
+            # ONE device->host transfer for the whole loss list instead
+            # of a blocking fetch per scalar (per-fetch cost not measured
+            # on the current host)
+            self.loss_history = [float(l) for l in
+                                 np.asarray(jnp.stack(losses))] \
+                if losses else []
         # count the work actually dispatched: with total_steps (or a
         # short corpus) the full-corpus token count would overstate
         # throughput by corpus_batches/steps_run
-        pairs_done = call_no * c.steps_per_call * c.batch_size
+        pairs_done = call_no * S * c.batch_size
         est_ppt = (c.window + 1) if c.model == "skipgram" else 1.0
         words = pairs_done / est_ppt
         telemetry.counter("w2v.pairs").inc(pairs_done)
         telemetry.emit("w2v.words_per_sec", words / dt, "words/s")
-        # ONE device->host transfer for the whole loss list instead of
-        # a blocking fetch per scalar (per-fetch cost not measured on
-        # the current host)
-        self.loss_history = [float(l) for l in
-                             np.asarray(jnp.stack(losses))] \
-            if losses else []
         final = float(np.mean(self.loss_history[-10:])) \
             if losses else float("nan")
         log.info("w2v train done: %d calls, loss=%.4f, %.0f words/s",
                  call_no, final, words / dt)
         return final
+
+    @staticmethod
+    def _stack(batches: list) -> Tuple[np.ndarray, np.ndarray]:
+        """One call's (src, tgt) batches as two [S, B, ...] arrays."""
+        return (np.stack([b[0] for b in batches]),
+                np.stack([b[1] for b in batches]))
 
     def _dispatch(self, srcs: np.ndarray, tgts: np.ndarray,
                   call_no: int, est_calls: int) -> jax.Array:
@@ -570,16 +622,15 @@ class WordEmbedding:
         lrs = np.maximum(np.linspace(lr_hi, lr_lo, s), floor) \
             .astype(np.float32)
         key = jax.random.fold_in(self._key, call_no)
-        pd = self._place(srcs, tgts)
-        t_step = time.perf_counter()
+        with telemetry.span("w2v.place"):
+            pd = self._place(srcs, tgts)
+        # the host side of the fused dispatch; the step record links to
+        # the span (its ``parent``), whose ``dur_s`` is the one timing
         with telemetry.span("w2v.superstep"):
             _, loss = self._fused((), pd, key,
                                   core.place(lrs, mesh=self.mesh))
-        telemetry.step_timeline("w2v", call_no, pairs=s * c.batch_size,
-                                dispatch_s=time.perf_counter() - t_step)
-        telemetry.histogram(
-            "app.step.seconds", telemetry.LATENCY_BUCKETS,
-            app="w2v").observe(time.perf_counter() - t_step)
+            telemetry.step_timeline("w2v", call_no,
+                                    pairs=s * c.batch_size)
         telemetry.beat()    # flight recorder: one heartbeat per dispatch
         self._step_no += s
         return loss

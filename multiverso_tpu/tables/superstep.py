@@ -67,6 +67,7 @@ from multiverso_tpu.ops.table_kernels import (coo_scatter_add,
                                               row_scatter_add)
 from multiverso_tpu.tables.base import Handle, Table
 from multiverso_tpu.telemetry import health as _health
+from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.telemetry.profiling import profiled_jit
 from multiverso_tpu.updaters import AddOption
 
@@ -133,7 +134,10 @@ class FusedSuperstep:
         # sharded meshes: the scope tells the in-trace functional kernels
         # which mesh/axis to shard_map their Pallas grids over (tracing
         # sees only abstract values — the mesh can't be inferred there)
-        with tk.kernel_mesh_scope(self.tables[0].mesh, core.MODEL_AXIS):
+        # the compiled call alone: the caller's span (lda.dispatch,
+        # w2v.superstep) less this one is the table layer's bookkeeping
+        with tk.kernel_mesh_scope(self.tables[0].mesh, core.MODEL_AXIS), \
+                _trace.span("superstep.run"):
             new_params, new_states, new_locals, aux = self._run(
                 params, states, locals_, opts, *inputs)
         for t, p, s in zip(self.tables, new_params, new_states):

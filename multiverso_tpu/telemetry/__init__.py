@@ -5,10 +5,12 @@ and a report CLI.
 - :mod:`multiverso_tpu.telemetry.metrics` — Counter/Gauge/Histogram in
   a process-wide registry; JSONL event sink (``MVTPU_METRICS_JSONL``),
   JSON snapshots, Prometheus text export.
-- :mod:`multiverso_tpu.telemetry.trace` — nestable :func:`span` context
-  manager + per-superstep :func:`step_timeline`, JSONL trace files
-  (``MVTPU_TRACE_JSONL`` / ``MVTPU_TRACE_DIR``), ``jax.named_scope``
-  composition.
+- :mod:`multiverso_tpu.telemetry.trace` — :func:`span`, the one timing
+  primitive with three outputs (a ``jax.profiler.TraceAnnotation`` on
+  any profile being taken, the ``span.seconds{name=…}`` histogram, a
+  JSONL record when ``MVTPU_TRACE_JSONL`` / ``MVTPU_TRACE_DIR`` set a
+  sink); :func:`scope` (``jax.named_scope``) names the ops of a traced
+  body; per-superstep :func:`step_timeline`.
 - :mod:`multiverso_tpu.telemetry.aggregate` — :func:`gather_metrics` /
   :func:`fleet_snapshot` all-gather per-host snapshots through the mesh
   (single-host fallback: local only).
@@ -35,7 +37,8 @@ and a report CLI.
   :func:`profiled_jit` (lowering/compile wall time + XLA cost/memory
   analysis per jitted function), :func:`record_device_memory`
   (live-buffer and allocator gauges), :func:`profile_window`
-  (``MVTPU_PROFILE_DIR``-gated ``jax.profiler`` capture).
+  (``MVTPU_PROFILE_DIR``-gated ``jax.profiler`` capture),
+  :func:`op_scopes` (compiled instruction -> program scope).
 - ``python -m multiverso_tpu.telemetry.report <file>`` — render any
   telemetry artifact as a table, Perfetto-loadable Chrome trace
   (``--chrome-trace``), or hot list (``--top N``).
@@ -59,12 +62,13 @@ from multiverso_tpu.telemetry.metrics import (LATENCY_BUCKETS, Counter,
                                               registry, snapshot,
                                               snapshot_quantile,
                                               write_snapshot)
-from multiverso_tpu.telemetry.profiling import (profile_window,
+from multiverso_tpu.telemetry.profiling import (op_scopes,
+                                                profile_window,
                                                 profiled_jit,
                                                 record_device_memory)
 from multiverso_tpu.telemetry.trace import (adopt, current_request,
                                             link, new_request_id,
-                                            read_trace, request,
+                                            read_trace, request, scope,
                                             set_trace_file, span,
                                             step_timeline)
 from multiverso_tpu.telemetry.watchdog import (Watchdog,
@@ -87,7 +91,7 @@ __all__ = [
     "LATENCY_BUCKETS", "log_spaced_bounds", "snapshot_quantile",
     "counter", "gauge", "histogram", "emit", "host_index", "registry",
     "snapshot", "write_snapshot",
-    "span", "step_timeline", "set_trace_file", "read_trace",
+    "span", "scope", "step_timeline", "set_trace_file", "read_trace",
     "request", "new_request_id", "current_request", "link", "adopt",
     "gather_metrics", "merge_snapshots", "fleet_snapshot",
     "Watchdog", "beat", "maybe_watchdog", "active_watchdogs",
@@ -95,4 +99,5 @@ __all__ = [
     "HealthMonitor", "maybe_health_monitor",
     "StatuszServer", "maybe_statusz", "publish_fleet",
     "profiled_jit", "profile_window", "record_device_memory",
+    "op_scopes",
 ]

@@ -252,6 +252,9 @@ class MetricRegistry:
 
     def __init__(self) -> None:
         self._metrics: Dict[Tuple[str, LabelItems], object] = {}
+        #: bumped by :meth:`reset`: a holder of a metric OBJECT (a span's
+        #: histogram) compares it to know its object left the registry
+        self.generation = 0
         self._lock = threading.Lock()
         self._jsonl: Optional[TextIO] = None
         self._jsonl_path: Optional[str] = None
@@ -269,13 +272,15 @@ class MetricRegistry:
                     f"{type(m).__name__}, not {cls.__name__}")
             return m
 
-    def counter(self, name: str, **labels) -> Counter:
+    # ``name`` is positional-only: a label may itself be called "name"
+    # (``span.seconds{name=lda.sweep}``)
+    def counter(self, name: str, /, **labels) -> Counter:
         return self._get(Counter, name, labels)
 
-    def gauge(self, name: str, **labels) -> Gauge:
+    def gauge(self, name: str, /, **labels) -> Gauge:
         return self._get(Gauge, name, labels)
 
-    def histogram(self, name: str,
+    def histogram(self, name: str, /,
                   bounds: Tuple[float, ...] = DEFAULT_BUCKETS,
                   **labels) -> Histogram:
         return self._get(Histogram, name, labels, bounds=bounds)
@@ -380,6 +385,7 @@ class MetricRegistry:
         """Drop all metrics (tests); the JSONL sink stays configured."""
         with self._lock:
             self._metrics.clear()
+            self.generation += 1
 
 
 _REGISTRY = MetricRegistry()
@@ -392,15 +398,16 @@ def registry() -> MetricRegistry:
     return _REGISTRY
 
 
-def counter(name: str, **labels) -> Counter:
+def counter(name: str, /, **labels) -> Counter:
     return _REGISTRY.counter(name, **labels)
 
 
-def gauge(name: str, **labels) -> Gauge:
+def gauge(name: str, /, **labels) -> Gauge:
     return _REGISTRY.gauge(name, **labels)
 
 
-def histogram(name: str, bounds: Tuple[float, ...] = DEFAULT_BUCKETS,
+def histogram(name: str, /,
+              bounds: Tuple[float, ...] = DEFAULT_BUCKETS,
               **labels) -> Histogram:
     return _REGISTRY.histogram(name, bounds, **labels)
 

@@ -20,7 +20,13 @@ looks identical from outside to one stuck in a collective.
   - ``profile.flops{fn=...}`` / ``profile.bytes_accessed{fn=...}``
     gauges from XLA cost analysis where the backend reports them,
   - ``profile.memory.*{fn=...}`` gauges from XLA memory analysis
-    (argument/output/temp/generated-code bytes) where available.
+    (argument/output/temp/generated-code bytes) where available,
+  - ``profile.scope.ops{fn=...,scope=...}`` gauges: how many of the
+    compiled module's instructions carry each program scope
+    (:func:`multiverso_tpu.telemetry.trace.scope`) in their ``op_name``;
+    :func:`op_scopes` returns the instruction-level map, which is what
+    lets a device trace's ``jit_run/fusion.62`` be read as
+    ``w2v.scatter_out``.
 
   The compiled executable is cached per signature — avals AND input
   shardings, because an AOT executable accepts exactly the shardings
@@ -50,12 +56,88 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import sys
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
 from multiverso_tpu.telemetry import metrics as _metrics
 from multiverso_tpu.telemetry import trace as _trace
+
+
+UNSCOPED = "unscoped"
+# fn -> {"module": <HLO module name>, "scopes": {instruction: scope}};
+# process-wide like the registry, and outliving the wrappers: a
+# benchmark reads it after the program's tables are freed
+_OP_SCOPES: Dict[str, dict] = {}
+_HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+# one instruction per line: ``[ROOT ]%name = shape opcode(...), ...``
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ([^\n]*)$", re.M)
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a program scope as :func:`trace.scope` writes it: the path segment
+# ``jit(<app>.<phase>)`` of the op_name (jax's own — ``jit(run)``,
+# ``jit(_take)``, ``while``, ``scatter-add`` — are never dotted words)
+_SCOPE_SEGMENT = re.compile(
+    r"^jit\(([a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+)\)$")
+
+
+def parse_op_scopes(hlo_text: str) -> Tuple[str, Dict[str, str]]:
+    """``(module name, {instruction name: scope})`` of one compiled
+    module's text: the innermost program scope in the instruction's
+    ``op_name``, :data:`UNSCOPED` where it has none. A fusion carries
+    the metadata XLA left on it, its root's."""
+    found = _HLO_MODULE.search(hlo_text)
+    module = found.group(1) if found else ""
+    scopes: Dict[str, str] = {}
+    for name, rest in _HLO_INSTRUCTION.findall(hlo_text):
+        op_name = _HLO_OP_NAME.search(rest)
+        segments = op_name.group(1).split("/") if op_name else ()
+        found = filter(None, map(_SCOPE_SEGMENT.match, reversed(segments)))
+        scopes[name] = next((m.group(1) for m in found), UNSCOPED)
+    return module, scopes
+
+
+def _record_scopes(fn: str, compiled: Any) -> None:
+    """Keep ``fn``'s instruction -> scope map (one text dump a compile).
+    Two programs of one ``fn`` (two signatures) merge; an instruction
+    name they scope differently is unscoped."""
+    with _trace.span("profile.op_scopes"):
+        module, scopes = parse_op_scopes(compiled.as_text())
+    held = _OP_SCOPES.setdefault(fn, {"module": module, "scopes": {}})
+    merge_op_scopes(held["scopes"], scopes)
+    if not _names_something(held["scopes"]):
+        return
+    counts: Dict[str, int] = {}
+    for scope in held["scopes"].values():
+        counts[scope] = counts.get(scope, 0) + 1
+    reg = _metrics.registry()
+    for scope, n in counts.items():
+        reg.gauge("profile.scope.ops", fn=fn, scope=scope).set(n)
+
+
+def _names_something(scopes: Dict[str, str]) -> bool:
+    return any(scope != UNSCOPED for scope in scopes.values())
+
+
+def merge_op_scopes(into: Dict[str, str], more: Dict[str, str]) -> None:
+    """Fold ``more`` into ``into``; a name the two scope differently
+    becomes :data:`UNSCOPED` (two programs may share a module name, and
+    with it their instructions' names)."""
+    for name, scope in more.items():
+        if into.setdefault(name, scope) != scope:
+            into[name] = UNSCOPED
+
+
+def op_scopes() -> Dict[str, dict]:
+    """``{fn: {"module": name, "scopes": {instruction: scope}}}`` for
+    every program :func:`profiled_jit` compiled in this process whose
+    compiled text names at least one program scope. ``module`` is the
+    name a device trace files the ops under (``jit_run``)."""
+    return {fn: {"module": held["module"],
+                 "scopes": dict(held["scopes"])}
+            for fn, held in _OP_SCOPES.items()
+            if _names_something(held["scopes"])}
 
 
 def _leaf_sig(leaf: Any) -> Any:
@@ -110,6 +192,7 @@ class _ProfiledJit:
         reg.gauge("profile.lower.last_s", fn=self.name).set(lower_s)
         reg.gauge("profile.compile.last_s", fn=self.name).set(compile_s)
         self._record_cost(reg, compiled)
+        _record_scopes(self.name, compiled)
         self._compiled[sig] = compiled
         return compiled
 

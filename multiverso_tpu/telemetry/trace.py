@@ -1,14 +1,26 @@
-"""Span tracing: nestable wall-clock spans written as a JSONL trace.
+"""Span tracing: one :func:`span` primitive with three outputs.
 
 The host-side complement of the device profiler (PAPER/SURVEY §6.1's
 "per-step wall-clock dashboard + ``jax.profiler.trace`` hooks"): a
-:func:`span` context manager times a region, records its parent via a
-thread-local stack (ids are a process-monotonic counter — no
-randomness, no clocks beyond ``time``), and appends one JSON record per
-span to the configured trace file. Spans also enter a
-``jax.named_scope`` when jax is already importable, so a concurrent
-``jax.profiler.trace`` device capture shows the same names on the
-compiled ops — one vocabulary across host and device timelines.
+:func:`span` context manager times a region and
+
+1. enters a ``jax.profiler.TraceAnnotation`` when jax is already loaded,
+   so the span is an event on the host plane of any profile being taken,
+   on the PROFILE's clock, beside the device's ops (with no profile
+   being taken the annotation is a flag test);
+2. adds its duration to the registry histogram
+   ``span.seconds{name=<name>}`` — always, so an untraced run still
+   says where the host's time went;
+3. appends one JSON record to the trace file when a sink is set,
+   with its parent from a thread-local stack (ids are a
+   process-monotonic counter — no randomness).
+
+Span names are literals from a small vocabulary (``w2v.wait_data``,
+``lda.dispatch``, ``table.get``): never built from keys, ids or sizes,
+because each name is a registry series. :func:`scope` is the device-side
+half of the vocabulary: a decorator for the phases INSIDE a traced
+body, whose name lands in the compiled ops' metadata
+(:func:`multiverso_tpu.telemetry.profiling.op_scopes` reads it back).
 
 Record shapes (one JSON object per line):
 
@@ -33,19 +45,49 @@ Sink configuration: :func:`set_trace_file`, or ``MVTPU_TRACE_JSONL``
 (a file path), or ``MVTPU_TRACE_DIR`` (a directory; the file becomes
 ``trace-<pid>.jsonl`` inside it — per-process files, safe multi-host).
 ``MVTPU_TRACE_MAX_MB`` size-caps the sink with a keep-1 rollover.
-With no sink, spans still nest and time but write nothing, so hot-path
-instrumentation costs one perf_counter pair when tracing is off.
+With no sink a span still nests, annotates and observes but builds no
+record: it costs two ``perf_counter`` calls and one ``observe``.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import json
 import os
+import sys
 import threading
 import time
-from typing import Iterator, List, Optional, TextIO, Tuple
+from typing import Dict, Iterator, List, Optional, TextIO, Tuple
+
+
+def _sibling(name: str):
+    """A stdlib-only module of this package WITHOUT a package import:
+    ``client/transport.py`` loads this file by path in jax-free worker
+    processes, where ``import multiverso_tpu`` (and with it jax) must
+    not happen. Registered under its canonical name, so a later package
+    import finds the same module (and the same registry)."""
+    modname = f"multiverso_tpu.telemetry.{name}"
+    mod = sys.modules.get(modname)
+    if mod is not None:
+        return mod
+    import importlib.util
+    if "multiverso_tpu" in sys.modules:
+        return importlib.import_module(modname)
+    spec = importlib.util.spec_from_file_location(modname, os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[modname] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        sys.modules.pop(modname, None)
+        raise
+    return mod
+
+
+_metrics = _sibling("metrics")
 
 _IDS = itertools.count(1)
 _REQS = itertools.count(1)
@@ -98,10 +140,7 @@ def _emit(rec: dict) -> None:
     # identity stamps: host/pid pick the Perfetto process track (and
     # correlate with snapshots, log lines, and watchdog dumps); tid
     # separates concurrent host threads so span nesting stays true
-    from multiverso_tpu.telemetry.metrics import (host_index,
-                                                  rotate_jsonl,
-                                                  sink_max_bytes)
-    rec.setdefault("host", host_index())
+    rec.setdefault("host", _metrics.host_index())
     rec.setdefault("pid", os.getpid())
     rec.setdefault("tid", threading.get_ident())
     global _FILE
@@ -109,24 +148,76 @@ def _emit(rec: dict) -> None:
         if _FILE is not None:
             _FILE.write(json.dumps(rec) + "\n")
             _FILE.flush()
-            limit = sink_max_bytes()
+            limit = _metrics.sink_max_bytes()
             if limit and _PATH and _FILE.tell() >= limit:
-                _FILE = rotate_jsonl(_PATH, _FILE)
+                _FILE = _metrics.rotate_jsonl(_PATH, _FILE)
 
 
-def _named_scope(name: str):
-    """jax.named_scope(name) when jax is already loaded — the span name
-    then tags device ops inside a concurrent profiler capture. Never
-    IMPORTS jax (the report CLI and pure-host tools must not pay, or
-    fail, a backend init)."""
-    import sys
+SPAN_SECONDS = "span.seconds"
+# name -> (registry generation, histogram): a span holds the histogram
+# object, so the hot path skips the registry's lock and label sort
+_SPAN_HISTS: Dict[str, Tuple[int, "_metrics.Histogram"]] = {}
+
+
+def _span_histogram(name: str) -> "_metrics.Histogram":
+    reg = _metrics.registry()
+    held = _SPAN_HISTS.get(name)
+    if held is None or held[0] != reg.generation:
+        held = _SPAN_HISTS[name] = (reg.generation, reg.histogram(
+            SPAN_SECONDS, _metrics.LATENCY_BUCKETS, name=name))
+    return held[1]
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` when jax is already loaded
+    — the span is then an event on the host plane of a profile being
+    taken. Never IMPORTS jax (the report CLI and pure-host tools must
+    not pay, or fail, a backend init)."""
     jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            return jax.named_scope(name)
-        except Exception:  # pragma: no cover - defensive
-            pass
-    return contextlib.nullcontext()
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+def scope(name: str):
+    """Decorator naming the device ops a function traces: for use on
+    the phases INSIDE a jitted body (``@telemetry.scope("w2v.math")``).
+    The names share the spans' vocabulary (``w2v.scatter_out``,
+    ``lda.sample``) and come back from the compiled text through
+    :func:`multiverso_tpu.telemetry.profiling.op_scopes`.
+
+    The function becomes a nested ``jax.jit`` called ``name``: XLA
+    inlines the call, so the compiled program is the one it was, with
+    ``jit(<name>)`` in its ops' ``op_name``. Not ``jax.named_scope``:
+    that lives in debug info only, which jax strips from the persistent
+    compile cache's key — a cache filled by older source then hands
+    back an executable without the names (seen on the chip, PERF.md
+    PR 25). A function's name is part of the key."""
+    import jax
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args):
+            return fn(*args)
+        scoped.__name__ = scoped.__qualname__ = name
+        return jax.jit(scoped)
+    return wrap
+
+
+def _span_record(name: str, sid: int, parent: Optional[int], ts: float,
+                 dur_s: float, attrs: dict) -> dict:
+    rec = {"kind": "span", "name": name, "id": sid,
+           "parent": parent, "ts": ts, "dur_s": dur_s}
+    rid = getattr(_TLS, "request", None)
+    if rid is not None:
+        rec["req"] = rid
+    if parent is None:
+        rparent = getattr(_TLS, "rparent", None)
+        if rparent is not None:
+            rec["rparent"] = rparent
+    if attrs:
+        rec["attrs"] = attrs
+    return rec
 
 
 @contextlib.contextmanager
@@ -136,26 +227,18 @@ def span(name: str, **attrs) -> Iterator[int]:
     st = _stack()
     parent = st[-1] if st else None
     st.append(sid)
-    ts = time.time()
+    hist = _span_histogram(name)
     t0 = time.perf_counter()
     try:
-        with _named_scope(name):
+        with _annotation(name):
             yield sid
     finally:
         dur = time.perf_counter() - t0
         st.pop()
-        rec = {"kind": "span", "name": name, "id": sid,
-               "parent": parent, "ts": ts, "dur_s": dur}
-        rid = getattr(_TLS, "request", None)
-        if rid is not None:
-            rec["req"] = rid
-        if parent is None:
-            rparent = getattr(_TLS, "rparent", None)
-            if rparent is not None:
-                rec["rparent"] = rparent
-        if attrs:
-            rec["attrs"] = attrs
-        _emit(rec)
+        hist.observe(dur)
+        if _FILE is not None:
+            _emit(_span_record(name, sid, parent, time.time() - dur,
+                               dur, attrs))
 
 
 def emit_span(name: str, ts: float, dur_s: float, **attrs) -> int:
@@ -166,18 +249,7 @@ def emit_span(name: str, ts: float, dur_s: float, **attrs) -> int:
     sid = next(_IDS)
     st = _stack()
     parent = st[-1] if st else None
-    rec = {"kind": "span", "name": name, "id": sid,
-           "parent": parent, "ts": float(ts), "dur_s": float(dur_s)}
-    rid = getattr(_TLS, "request", None)
-    if rid is not None:
-        rec["req"] = rid
-    if parent is None:
-        rparent = getattr(_TLS, "rparent", None)
-        if rparent is not None:
-            rec["rparent"] = rparent
-    if attrs:
-        rec["attrs"] = attrs
-    _emit(rec)
+    _emit(_span_record(name, sid, parent, float(ts), float(dur_s), attrs))
     return sid
 
 
@@ -186,8 +258,7 @@ def emit_span(name: str, ts: float, dur_s: float, **attrs) -> int:
 def new_request_id() -> str:
     """Mint a request id: ``r<host>-<pid>-<counter>`` — unique across a
     fleet, no randomness (the trace layer's id discipline)."""
-    from multiverso_tpu.telemetry.metrics import host_index
-    return f"r{host_index()}-{os.getpid()}-{next(_REQS)}"
+    return f"r{_metrics.host_index()}-{os.getpid()}-{next(_REQS)}"
 
 
 def current_request() -> Optional[str]:
@@ -267,11 +338,11 @@ def wire_context() -> dict:
     request id (minted fresh when no request scope is open — the server
     side still gets a groupable tree), the innermost span id as the
     cross-process parent, and this process's (host, pid) identity."""
-    from multiverso_tpu.telemetry.metrics import host_index
     rid = getattr(_TLS, "request", None)
     if rid is None:
         rid = new_request_id()
-    ctx = {"req": rid, "host": host_index(), "pid": os.getpid()}
+    ctx = {"req": rid, "host": _metrics.host_index(),
+           "pid": os.getpid()}
     st = _stack()
     if st:
         ctx["span"] = st[-1]

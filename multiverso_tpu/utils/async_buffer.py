@@ -13,6 +13,8 @@ the common shape for feeding a jitted train loop.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import queue
 import threading
 from typing import Callable, Generic, Iterable, Iterator, Optional, TypeVar
@@ -115,34 +117,51 @@ class ASyncBuffer(Generic[T]):
             self._thread.join(timeout=5.0)
 
 
-def prefetch_iterator(it: Iterable[T], depth: int = 2) -> Iterator[T]:
+def prefetch_iterator(it: Iterable[T], depth: int = 2,
+                      name: Optional[str] = None) -> Iterator[T]:
     """Run ``it`` on a background thread, buffering up to ``depth`` items.
 
     Closing the generator (``break`` in the consumer, ``.close()``, GC)
     cancels the producer thread so the source iterator is released.
+
+    A ``name`` makes the producer thread time itself as two spans:
+    ``<name>.produce`` around each ``next()`` of the source and
+    ``<name>.backpressure`` around each offer to the queue (long only
+    when the queue stood full). Unnamed callers record nothing.
     """
     q: "queue.Queue[object]" = queue.Queue(maxsize=depth)
     _END = object()
     cancel = threading.Event()
+    if name is None:
+        produce = backpressure = contextlib.nullcontext
+    else:
+        # lazy import, as ASyncBuffer's gauges: this module stays
+        # importable without the telemetry package initialised
+        from multiverso_tpu.telemetry.trace import span
+        produce = functools.partial(span, f"{name}.produce")
+        backpressure = functools.partial(span, f"{name}.backpressure")
 
     def _put_cancellable(item) -> bool:
         """Offer to the queue until accepted or the consumer cancels;
         an unconditional blocking put would deadlock the producer thread
         forever when the consumer stops draining with a full queue."""
-        while not cancel.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
+        with backpressure():
+            while not cancel.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
     def work() -> None:
         try:
-            for item in it:
-                if not _put_cancellable(item):
+            source = iter(it)
+            while True:
+                with produce():
+                    item = next(source, _END)
+                if not _put_cancellable(item) or item is _END:
                     return
-            _put_cancellable(_END)
         except BaseException as exc:
             _put_cancellable(exc)
 

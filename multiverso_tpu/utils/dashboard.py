@@ -13,10 +13,11 @@ MONITOR_BEGIN/END macros.
 
 TPU profiler integration (SURVEY.md §6.1: "per-step wall-clock dashboard
 + `jax.profiler.trace` hooks; name-tag compiled regions with
-`jax.named_scope`"): ``profile(name)`` wraps the region in a
-``jax.named_scope`` (host-side begin; tags device ops traced inside it)
-and :func:`trace` captures a TensorBoard-loadable device trace of any
-code block.
+`jax.named_scope`"): ``profile(name)`` wraps the region in a telemetry
+span (a ``jax.profiler.TraceAnnotation``: a host event of any profile
+being taken; ops are named from INSIDE a traced body, with
+``telemetry.scope``) and :func:`trace` captures a TensorBoard-loadable
+device trace of any code block.
 
 BACK-COMPAT SHIM over :mod:`multiverso_tpu.telemetry`: the Monitor API
 and record shapes are unchanged, but every ``profile`` region also
@@ -81,11 +82,10 @@ class Dashboard:
 
     @contextlib.contextmanager
     def profile(self, name: str) -> Iterator[Monitor]:
-        """Time a region AND tag any ops traced inside it: the region
-        runs under a telemetry span, which enters ``jax.named_scope``
-        when jax is loaded — a `jax.profiler` device trace shows the
-        dashboard's monitor names on the compiled ops, and the span
-        lands in the telemetry trace + latency histogram."""
+        """Time a region under a telemetry span: a `jax.profiler`
+        capture shows the monitor's name as a host event on its own
+        clock, and the span lands in the telemetry trace (when a sink
+        is set) and the ``span.seconds`` histogram."""
         mon = self.monitor(name)
         start = time.perf_counter()
         try:

@@ -270,6 +270,9 @@ class TestProfiledJit:
             def memory_analysis(self):
                 return None
 
+            def as_text(self):
+                return "HloModule jit_fake\n"
+
             def __call__(self, *a, **k):
                 raise jax.errors.JaxRuntimeError("INTERNAL: device halt")
 
