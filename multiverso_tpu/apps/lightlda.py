@@ -47,13 +47,17 @@ invariant- and convergence-tested):
    XLA gather/scatter. Data-parallel across chips via shard_map
    (per-chip blocks + psum'd summary deltas).
 
-Every sampler runs on dp x mp meshes. For the tiled family the word
-table (and the stale modes' bf16 mirror) stays row-sharded over the
-model axis — the reference's Meta vocab-slicing role: per-step word-row
-gathers are partial-gather + psum over the model axis (exact — each row
-lives in one shard) and the per-sweep master rebuild scatters each
+Every sampler runs on dp x mp meshes. For the tiled family the int32
+word table stays row-sharded over the model axis — the reference's Meta
+vocab-slicing role — and the per-sweep master rebuild scatters each
 chip's data shard into its vocab slice, psum'd over the data axis, so
-no chip ever materialises the full [V, K].
+no chip ever materialises the full int32 [V, K]. The stale modes' bf16
+mirror is the worker's per-sweep CACHE of that table: the cast
+all-gathers it over the model axis once a sweep, every chip holds it
+whole (2*V*K bytes), and the sweep's word-row gather is a plain local
+in-bounds read — no mask, no collective inside the superstep. Eval reads
+the sharded int32 master through the partial-gather + psum form (exact —
+each row lives in one shard).
 
 Counts live in:
 - ``SparseMatrixTable [V, K] int32`` — word-topic counts (row-sharded
@@ -191,6 +195,30 @@ def _predictive_ll(A, W, S, m, alpha, beta, K, vbeta):
     return (ll * m).sum()
 
 
+def _require_mirror_fits(whole: int, sliced: int, stats) -> None:
+    """Refuse a replicated bf16 mirror that cannot fit beside what the
+    chip already holds. ``stats`` is the device's ``memory_stats()``
+    (None where the backend reports none: nothing to check against)."""
+    if not stats or "bytes_limit" not in stats:
+        return
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    if whole > free:
+        raise ValueError(
+            f"the stale sweep gathers from a whole bf16 word-topic mirror "
+            f"on every chip: {whole} bytes (its vocab slice alone: "
+            f"{sliced} bytes), and {free} bytes of the chip are free; a "
+            f"model this large needs an owned-row exchange (PERF.md §7)")
+
+
+def _take_word_rows(table3, w):
+    """``table3[w]`` for word ids that are in bounds by construction:
+    every id is a word < V (checked in ``LightLDA.__init__``) or the
+    packers' scratch word, the last storage row. So no fill mask:
+    ``jnp.take``'s default mode costs a select over the whole
+    [B, C, 128] result."""
+    return jnp.take(table3, w, axis=0, mode="clip")
+
+
 class LightLDA:
     """The app: count tables + the fused Gibbs-sweep superstep."""
 
@@ -204,6 +232,15 @@ class LightLDA:
         self.K = c.num_topics
         self.num_docs = int(token_docs.max()) + 1 if len(token_docs) else 1
         self.num_tokens = len(token_words)
+        # the owner of the in-range invariant every word-row gather and
+        # scatter leans on (out-of-range indices raise nothing on the
+        # device: reads clamp, updates drop)
+        if len(token_words) and not (0 <= int(token_words.min())
+                                     and int(token_words.max()) < self.V):
+            raise ValueError(
+                f"token_words must lie in [0, vocab_size={self.V}); got "
+                f"ids from {int(token_words.min())} to "
+                f"{int(token_words.max())}")
         if c.sampler == "mh" and len(token_docs) \
                 and np.any(np.diff(token_docs) < 0):
             # doc_start offsets (MH doc proposal) assume a doc-contiguous
@@ -255,13 +292,15 @@ class LightLDA:
         # local_corpus=True each process passes and packs ONLY its own
         # doc shard, so host RAM also scales 1/P — the reference's
         # workers-each-read-their-own-DataBlocks model.
-        # tiled samplers support dp x mp meshes: the word-topic table and
-        # its bf16 mirror stay row-sharded over the model axis (each chip
-        # holds a [V/mp] vocab slice — the reference's Meta vocab-slicing
-        # role); per-step word-row gathers are partial-gather + psum over
-        # the model axis (exact: each row lives in exactly one shard) and
-        # the per-sweep master rebuild scatters each chip's data shard
-        # into its vocab slice, psum'd over the data axis.
+        # tiled samplers support dp x mp meshes: the int32 word-topic
+        # table stays row-sharded over the model axis (each chip holds a
+        # [V/mp] vocab slice — the reference's Meta vocab-slicing role)
+        # and the per-sweep master rebuild scatters each chip's data
+        # shard into its vocab slice, psum'd over the data axis. The
+        # stale modes' bf16 mirror is replicated over the model axis once
+        # a sweep (_build_stale_helpers), so the sweep's word-row gather
+        # is a local read; eval gathers from the sharded master
+        # (_build_word_gather).
         # the pallas kernel needs the Mosaic TPU backend; on a CPU mesh
         # (tests) it runs in interpreter mode
         self._interpret = tiled and interpret_mode(self.mesh)
@@ -577,17 +616,19 @@ class LightLDA:
         self.summary.put_raw(nk)
 
     def _build_word_gather(self):
-        """``take(mirror, w)`` with the word table row-sharded over the
-        model axis: each chip gathers the rows its vocab slice owns and
-        the partials psum over ICI — exact (a row lives in exactly one
-        shard), no chip ever materialises the full [V, K]. This is the
-        TPU shape of the reference's Meta vocab-slicing: a worker fetches
-        word rows per slice instead of holding the whole model.
-        Works for any [*, C, 128] storage dtype (bf16 mirror, int32
-        master for eval). mp == 1 degenerates to a plain gather."""
+        """``take(nwk3, w)`` from the int32 MASTER, row-sharded over the
+        model axis — the eval gather (the sweep reads the replicated
+        mirror instead, see :meth:`_build_stale_helpers`): each chip
+        gathers the rows its vocab slice owns and the partials psum over
+        ICI — exact (a row lives in exactly one shard), no chip ever
+        materialises the full int32 [V, K]. This is the TPU shape of the
+        reference's Meta vocab-slicing: a worker fetches word rows per
+        slice instead of holding the whole model. Works for any
+        [*, C, 128] storage dtype. mp == 1 degenerates to the plain
+        in-bounds gather."""
         mp = self.mesh.shape[core.MODEL_AXIS]
         if mp == 1:
-            return lambda mirror, w: jnp.take(mirror, w, axis=0)
+            return _take_word_rows
         from jax import shard_map
         d, m = core.DATA_AXIS, core.MODEL_AXIS
         vshard = self.word_topic.storage_shape[0] // mp
@@ -681,13 +722,23 @@ class LightLDA:
         """Per-sweep word-count helpers shared by the stale modes: the
         bf16 gather mirror and the int32 master rebuild from z (z may be
         the flat stream or the blocked packing — flattened either way).
-        Both keep the word table sharded over the model axis: the mirror
-        is an elementwise cast (sharding-preserving) and the rebuild
-        scatters each chip's DATA shard of the stream into its own vocab
-        slice, psum'd over the data axis — no chip ever holds [V, K]."""
-        mp = self.mesh.shape[core.MODEL_AXIS]
 
-        @jax.jit
+        The int32 master stays sharded over the model axis: the rebuild
+        scatters each chip's DATA shard of the stream into its own vocab
+        slice, psum'd over the data axis — no chip ever holds the int32
+        [V, K]. The mirror is the worker's per-sweep cache of it (the
+        stale-words model): ``to_stale`` casts the chip's slice and
+        all-gathers it over the model axis, ONCE a sweep, so every chip
+        holds the whole bf16 [V, K] and the gather inside the superstep
+        (:func:`_take_word_rows`) is a plain local read — no ownership
+        mask, no collective. A
+        chip's word-table bytes are 4*V*K/mp + 2*V*K; a model whose
+        whole mirror cannot fit is refused here (it wants an owned-row
+        exchange, PERF.md §7, not this path)."""
+        mp = self.mesh.shape[core.MODEL_AXIS]
+        self._account_mirror()
+
+        @partial(jax.jit, out_shardings=NamedSharding(self.mesh, P()))
         def to_stale(nwk3):
             return nwk3.astype(jnp.bfloat16)
 
@@ -706,7 +757,16 @@ class LightLDA:
 
         self._to_stale = to_stale
         self._rebuild = rebuild
-        self._gather_w = self._build_word_gather()
+
+    def _account_mirror(self) -> None:
+        """Set ``lda.mirror.bytes_per_chip`` and refuse a mirror that
+        cannot fit beside what the chip holds by now (tables, and the
+        corpus where it is resident)."""
+        whole = 2 * int(np.prod(self.word_topic.storage_shape))
+        _require_mirror_fits(
+            whole, whole // self.mesh.shape[core.MODEL_AXIS],
+            self.mesh.local_devices[0].memory_stats())
+        telemetry.gauge("lda.mirror.bytes_per_chip").set(whole)
 
     def _eval_chunk(self, n: int) -> int:
         """Largest chunk of ~64k tokens that divides ``n`` and keeps the
@@ -766,11 +826,7 @@ class LightLDA:
         under model parallelism."""
         K = self.K
         tiles = K // 128
-        # reuse the training gather when a stale mode built one — eval
-        # and training must gather identically
-        gather_w = getattr(self, "_gather_w", None) or \
-            self._build_word_gather()
-        run = self._chunked_ll(gather_w)
+        run = self._chunked_ll(self._build_word_gather())
 
         @jax.jit
         def loglik(nwk3, ndk, nk, ws, rows, mask):
@@ -807,7 +863,6 @@ class LightLDA:
                                   u2, alpha=alpha, beta=beta, tb=TB,
                                   interpret=interpret))
         self._build_stale_helpers()
-        gather_w = self._gather_w
 
         # each phase under a program scope, so the compiled ops carry its
         # name (profiling.op_scopes): lda.carry is what the scan hands on
@@ -843,7 +898,7 @@ class LightLDA:
             return sampler_call(ndk_c, W3, sinv, zi, drel.reshape(B),
                                 msk.reshape(B), u1, u2)
 
-        gather_words = scope("lda.gather_words")(gather_w)
+        gather_words = scope("lda.gather_words")(_take_word_rows)
 
         def scan_body(wstale, carry, inp):
             nk, ndk, z = carry
@@ -940,7 +995,6 @@ class LightLDA:
                 W3, sinv, zi, drel, msk, u1, u2, alpha=alpha, beta=beta,
                 tb=TB, maxd=MAXD, interpret=interpret))
         self._build_stale_helpers()
-        gather_w = self._gather_w
         accumulate = self._build_master_accumulate()
         self._stage_sharding = NamedSharding(
             self.mesh, P(None, None, core.DATA_AXIS))
@@ -956,7 +1010,7 @@ class LightLDA:
             nk, z = carry
             w, drel, msk, off, key = inp
             zi = lax.dynamic_slice_in_dim(z, off, nbs).reshape(B)
-            W3 = gather_w(wstale, w.reshape(B))
+            W3 = _take_word_rows(wstale, w.reshape(B))
             sinv = 1.0 / (nk[:K].astype(jnp.float32).reshape(tiles, 128)
                           + vbeta)
             k1, k2 = jax.random.split(key)
@@ -1009,7 +1063,7 @@ class LightLDA:
             return ndk.at[rows, zf // 128, zf % 128].add(
                 m.astype(jnp.int16))
 
-        run = self._chunked_ll(gather_w)
+        run = self._chunked_ll(self._build_word_gather())
 
         @jax.jit
         def loglik_stream(nwk3, nk, stacked):
@@ -1187,8 +1241,7 @@ class LightLDA:
         self._z_synced = True
 
     def _sweep_streamed(self) -> None:
-        with telemetry.span("lda.to_stale"):
-            wstale = self._to_stale(self.word_topic.raw())
+        wstale = self._refresh_mirror()
         per_call, TB = self._per_call, self._tb
         # fresh accumulator: after the sweep it IS the new master
         # (counts telescope — see the superstep body)
@@ -1405,16 +1458,15 @@ class LightLDA:
             return nk, ndk3, z, zi, znew
 
         if stale:
-            # word rows from the per-sweep bf16 mirror (sharded gather —
-            # the mirror stays a vocab slice per chip); no per-step
-            # word-count scatters (master rebuilt from z at sweep end)
+            # word rows from the per-sweep bf16 mirror (a local read:
+            # every chip holds it whole); no per-step word-count
+            # scatters (master rebuilt from z at sweep end)
             self._build_stale_helpers()
-            gather_w = self._gather_w
 
             def scan_body(wstale, carry, inp):
                 nk, ndk3, z = carry
                 w, d, off, msk, key = inp
-                W3 = gather_w(wstale, w)
+                W3 = _take_word_rows(wstale, w)
                 nk, ndk3, z, _, _ = sample_and_update(
                     nk, ndk3, z, W3, w, d, off, msk, key)
                 return (nk, ndk3, z), ()
@@ -1585,6 +1637,15 @@ class LightLDA:
             else:
                 self._sweep_resident()
 
+    def _refresh_mirror(self) -> jax.Array:
+        """The sweep's bf16 word-row cache, whole on every chip: one
+        all-gather over the model axis where that axis has chips."""
+        with telemetry.span("lda.to_stale"):
+            wstale = self._to_stale(self.word_topic.raw())
+        if self.mesh.shape[core.MODEL_AXIS] > 1:
+            telemetry.counter("lda.mirror.replications").inc()
+        return wstale
+
     def _sweep_resident(self) -> None:
         mh = self.config.sampler == "mh"
         if mh:
@@ -1593,8 +1654,7 @@ class LightLDA:
             # param buffer is donated by the first superstep call)
             nwk_stale = self.word_topic.raw() + 0
         if self._stale:
-            with telemetry.span("lda.to_stale"):
-                wstale = self._to_stale(self.word_topic.raw())
+            wstale = self._refresh_mirror()
         for call in self._calls:
             key = jax.random.fold_in(self._key, self._calls_done)
             self._calls_done += 1

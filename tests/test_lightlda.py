@@ -406,10 +406,12 @@ def _run_docblock(mesh, docs, name, batch_tokens=2048):
 
 
 def test_docblock_model_parallel_matches_dp(devices, docs):
-    """The model-axis sharding (vocab-sliced word table, sharded gather +
-    psum) must be EXACTLY the dp-only computation: every partial-gather
-    row lives in one shard and the rebuild psum is integer, so z and all
-    counts are bit-identical between a pure-DP mesh and a dp x mp mesh."""
+    """The model-axis sharding (vocab-sliced int32 word table, its bf16
+    mirror all-gathered over the model axis once a sweep and read
+    locally) must be EXACTLY the dp-only computation: the replicated
+    mirror holds the same rows, the rebuild psum is integer and eval's
+    partial-gather rows each live in one shard, so z and all counts are
+    bit-identical between a pure-DP mesh and a dp x mp mesh."""
     from multiverso_tpu import core
     mesh_dp = core.init(devices=devices, data_parallel=8, model_parallel=1)
     ref = _run_docblock(mesh_dp, docs, "lda_mp_ref")
@@ -431,8 +433,9 @@ def test_docblock_model_parallel_matches_dp(devices, docs):
 
 def test_tiled_stale_model_parallel(mesh8, docs):
     """sampler='tiled' + stale_words on a 4x2 mesh: invariants hold and
-    mixing reaches the exact-Gibbs band (the word table and bf16 mirror
-    are vocab-sliced over the model axis)."""
+    mixing reaches the exact-Gibbs band (the int32 word table is
+    vocab-sliced over the model axis, its bf16 mirror whole on every
+    chip)."""
     tw, td, V = docs
     app = LightLDA(tw, td, V,
                    LDAConfig(num_topics=128, batch_tokens=512,
@@ -493,7 +496,8 @@ def test_docblock_streamed_matches_inmemory(mesh_dp8, docs):
 
 def test_docblock_streamed_model_parallel(devices, docs):
     """Streamed mode on a dp x mp mesh equals the streamed pure-DP run
-    (sharded master-delta scatters are integer-exact)."""
+    (sharded master-delta scatters are integer-exact; the sweep reads
+    the replicated mirror, eval the sharded master)."""
     from multiverso_tpu import core
     tw, td, V = docs
     kw = dict(num_topics=128, batch_tokens=2048, steps_per_call=2,
@@ -621,9 +625,25 @@ def test_stream_blocks_requires_docblock(mesh_dp8):
                  mesh=mesh_dp8, name="lda_sb_bad")
 
 
-def test_dp_mp_eval_compiles_for_a_v5e_2x2():
-    """The doc-blocked eval on a data=2 x model=2 mesh, compiled ahead
-    of time for a described v5e 2x2 (no chip needed). On the first
+@pytest.fixture(scope="module")
+def mesh_v5e_2x2():
+    """data=2 x model=2 over a DESCRIBED v5e 2x2: programs compile for
+    the chip here, nothing runs (no chip needed)."""
+    from jax.sharding import Mesh
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:       # noqa: BLE001
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    return Mesh(np.asarray(topo.devices).reshape(2, 2),
+                (core.DATA_AXIS, core.MODEL_AXIS))
+
+
+def test_dp_mp_eval_compiles_for_a_v5e_2x2(mesh_v5e_2x2):
+    """The doc-blocked eval on a data=2 x model=2 mesh (the sharded
+    gather from the int32 master: partial gather + psum over the model
+    axis), compiled ahead of time for a described v5e 2x2. On the first
     four-chip run XLA:TPU refused it ("Reshape should have supported
     layout before reaching the emitter") until the scanned chunks'
     sharding was stated before the loop (_chunked_ll); dp-only and
@@ -632,15 +652,8 @@ def test_dp_mp_eval_compiles_for_a_v5e_2x2():
 
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception:       # noqa: BLE001
-        pytest.skip("libtpu cannot describe a v5e topology here")
-    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2),
-                (core.DATA_AXIS, core.MODEL_AXIS))
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = mesh_v5e_2x2
     K, V, tiles, B = 1024, 50_000, 8, 307_200      # chip_smoke's widths
     vpad = V + 2
     app = types.SimpleNamespace(
@@ -664,3 +677,95 @@ def test_dp_mp_eval_compiles_for_a_v5e_2x2():
         sds((3000, 16, tiles, 128), jnp.int16),
         sds((K,), jnp.int32, P(core.MODEL_AXIS)),
         lanes, lanes, lanes).lower().compile()
+
+
+def test_docblock_superstep_for_a_v5e_2x2_reduces_no_word_rows(mesh_v5e_2x2):
+    """The doc-blocked sweep compiled ahead of time for a described v5e
+    2x2 at chip_smoke's widths: ``to_stale`` casts and all-gathers the
+    mirror over the model axis (bf16, once), and the superstep gathers
+    from it locally — no all-reduce over gathered ``bf16[.., 8, 128]``
+    rows, no select under ``lda.gather_words``. Before, every step
+    psum'd its masked partial rows over the model axis (2 KB a lane)."""
+    import re
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = mesh_v5e_2x2
+    K, V, tiles, B, TB, MAXD = 1024, 50_000, 8, 307_200, 512, 16
+    vpad, nb = V + 2, 4 * (B // TB)
+    app = types.SimpleNamespace(
+        mesh=mesh, K=K, V=V, alpha=50.0 / K, beta=0.01, _tb=TB,
+        _interpret=False, _account_mirror=lambda: None,  # no chip to ask
+        config=LDAConfig(num_topics=K, batch_tokens=B, block_tokens=TB,
+                         block_docs=MAXD, sampler="tiled",
+                         doc_blocked=True),
+        word_topic=types.SimpleNamespace(storage_shape=(vpad, tiles, 128)))
+    for method in ("_wrap_docblock_dp", "_build_stale_helpers",
+                   "_build_vocab_slice_scatter"):
+        setattr(app, method,
+                types.MethodType(getattr(LightLDA, method), app))
+    LightLDA._build_docblock_kernel(app)
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    stale = app._to_stale.trace(sds(
+        (vpad, tiles, 128), jnp.int32,
+        P(core.MODEL_AXIS, None, None))).lower().compile()
+    gathers = [ln for ln in stale.as_text().splitlines()
+               if " all-gather(" in ln or " all-gather-start(" in ln]
+    assert len(gathers) == 1 and f"bf16[{vpad},8,128]" in gathers[0], gathers
+    (mirror_sharding,) = jax.tree.leaves(stale.output_shardings)
+    assert mirror_sharding.is_fully_replicated
+
+    def run(nk, ndk, z, wstale, ws, drels, msks, offs, key):
+        keys = jax.random.split(key, ws.shape[0])
+        (nk, ndk, z), _ = lax.scan(
+            lambda cy, inp: app._db_scan_body(wstale, cy, inp),
+            (nk, ndk, z), (ws, drels, msks, offs, keys))
+        return nk, ndk, z
+
+    lanes = sds((1, B), jnp.int32, P(None, core.DATA_AXIS))
+    text = jax.jit(run, donate_argnums=(0, 1, 2)).trace(
+        sds((K,), jnp.int32), sds((nb, MAXD, tiles, 128), jnp.int16),
+        sds((nb, TB), jnp.int32), sds((vpad, tiles, 128), jnp.bfloat16),
+        lanes, lanes, lanes, sds((1,), jnp.int32),
+        sds((2,), jnp.uint32)).lower().compile().as_text()
+    assert "tpu_custom_call" in text        # the Mosaic kernel is in it
+    assert not re.search(r"= bf16\[[0-9,]*8,128\]\S* all-reduce", text)
+    scoped = [ln for ln in text.splitlines()
+              if "jit(lda.gather_words)" in ln]
+    assert [ln for ln in scoped if " gather(" in ln]
+    assert not [ln for ln in scoped
+                if " select(" in ln or " all-reduce" in ln
+                or " all-gather" in ln]
+
+
+@pytest.mark.parametrize("bad", [-1, "V"])
+def test_word_ids_outside_the_vocabulary_are_refused(mesh_dp8, docs, bad):
+    """Out-of-range indices raise nothing on the device (reads clamp,
+    updates drop), so the constructor owns the check every word-row
+    gather and scatter leans on."""
+    tw, td, V = docs
+    tw = tw.copy()
+    tw[7] = V if bad == "V" else bad
+    with pytest.raises(ValueError, match=r"token_words must lie in \[0, "):
+        LightLDA(tw, td, V, LDAConfig(num_topics=8, batch_tokens=512,
+                                      steps_per_call=4),
+                 mesh=mesh_dp8, name="lda_bad_word")
+
+
+def test_a_mirror_that_cannot_fit_is_refused_with_both_sizes():
+    from multiverso_tpu.apps.lightlda import _require_mirror_fits
+    _require_mirror_fits(1 << 30, 1 << 29, None)     # backend says nothing
+    _require_mirror_fits(1 << 30, 1 << 29,
+                         {"bytes_limit": 3 << 30, "bytes_in_use": 1 << 30})
+    with pytest.raises(ValueError) as e:
+        _require_mirror_fits(1 << 30, 1 << 29,
+                             {"bytes_limit": 3 << 30,
+                              "bytes_in_use": (2 << 30) + 1})
+    assert str(1 << 30) in str(e.value) and str(1 << 29) in str(e.value)

@@ -6,6 +6,7 @@ text, and the names the benchmark's accepted readers lean on hold."""
 
 import glob
 import os
+import re
 import threading
 
 import jax
@@ -321,6 +322,86 @@ def test_module_and_kernel_names_the_readers_lean_on(mesh1):
         sds((c, 128), np.float32), *[sds((nb * tb,), np.int32)] * 3,
         *[sds((nb * tb,), np.float32)] * 2)
     assert "@gibbs_sample_docblock" in lowered.as_text()
+
+
+# -- (f) the sweep's word-row gather is one plain local read -------------------
+
+_COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+    r"(-start|-done)?\(")
+
+
+def _superstep_args(lda):
+    """What ``sweep()`` hands ``superstep.lda_docblock`` for its first
+    call (``lower`` donates nothing)."""
+    wstale = lda._to_stale(lda.word_topic.raw())
+    return ((lda.summary.param,), (lda.summary.state,),
+            (lda._ndk, lda._z), (lda.summary._resolve_option(None),),
+            wstale, *lda._calls[0], lda._key)
+
+
+def test_no_collective_under_gather_words_on_a_dp_mp_mesh(mesh8):
+    from multiverso_tpu import core
+    lda, _, _ = _lda(mesh8, batch_tokens=1024)
+    args = _superstep_args(lda)
+    mirror = args[4]
+    # the mirror is the chip's whole per-sweep cache; the table it
+    # caches stays vocab-sliced
+    assert mirror.dtype == jax.numpy.bfloat16
+    assert mirror.sharding.is_fully_replicated
+    assert lda.word_topic.raw().sharding.spec[0] == core.MODEL_AXIS
+    lines = lda._fused._run.lower(*args).compile().as_text().splitlines()
+    scoped = [ln for ln in lines if "jit(lda.gather_words)" in ln]
+    assert [ln for ln in scoped if " gather(" in ln]
+    assert not [ln for ln in scoped if _COLLECTIVE.search(ln)]
+    # the pattern does see this mesh's collectives: the summary delta's
+    # psum over the data axis sits under lda.sample
+    assert [ln for ln in lines if _COLLECTIVE.search(ln)
+            and "jit(lda.sample)" in ln]
+
+
+def _primitives_under(jaxpr, scope, inside=False):
+    """Names of the primitives inside the nested jit called ``scope``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        here = inside or eqn.params.get("name") == scope
+        if inside:
+            out.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _primitives_under(sub, scope, here)
+    return out
+
+
+def test_gather_words_holds_no_fill_mask(mesh1):
+    lda, _, _ = _lda(mesh1)
+    jaxpr = lda._fused._run._jit.trace(*_superstep_args(lda)).jaxpr
+    under = _primitives_under(jaxpr.jaxpr, "lda.gather_words")
+    assert "gather" in under
+    # jnp.take's default (fill) mode wraps the gather in an in-bounds
+    # mask and a select over the whole [B, C, 128] result
+    assert "select_n" not in under, under
+    assert "psum" not in under and "axis_index" not in under
+
+
+@pytest.mark.parametrize("shape", ["1x1", "4x2"])
+def test_mirror_replications_counted_once_a_sweep(shape, request):
+    if shape == "1x1":
+        lda, _, _ = _lda(request.getfixturevalue("mesh1"))
+    else:
+        lda, _, _ = _lda(request.getfixturevalue("mesh8"),
+                         batch_tokens=1024)
+    snap = metrics.snapshot()
+    assert snap["gauges"]["lda.mirror.bytes_per_chip"] == \
+        2 * np.prod(lda.word_topic.storage_shape)
+    lda.sweep()
+    lda.train(num_iterations=2)
+    counted = metrics.snapshot()["counters"].get(
+        "lda.mirror.replications", 0)
+    assert counted == (3 if shape == "4x2" else 0)
+    assert _count("lda.to_stale") == 3
 
 
 # -- the assignments accessor -------------------------------------------------
