@@ -91,10 +91,11 @@ class Cell:
                  f"{self.app.calls_per_sweep} calls a sweep")
         self._jax = jax
         # the first sweeps: they compile, and the check follows them
-        self.z_first = [self._assignments()]
+        # (assignments() reads z in the order the corpus was handed over)
+        self.z_first = [self.app.assignments()]
         for _ in range(int(self.traffic["checked_sweeps"])):
             self._sweep()
-            self.z_first.append(self._assignments())
+            self.z_first.append(self.app.assignments())
 
     def _sweep(self) -> None:
         jax = self._jax
@@ -104,15 +105,6 @@ class Cell:
             # the fence: the rebuilt word table and the summary table
             jax.block_until_ready((self.app.word_topic.raw(),
                                    self.app.summary.raw()))
-
-    def _assignments(self) -> np.ndarray:
-        """Assignments in the corpus's own order. The packer keeps the
-        doc-sorted order of the stream, so the packed stream's real
-        lanes ARE the corpus; ``check`` verifies that on the words."""
-        if not hasattr(self, "_lanes"):
-            self._lanes = np.asarray(self.app._mask_flat).astype(bool)
-            self._packed_words = np.asarray(self.app._tw_flat)[self._lanes]
-        return np.asarray(self.app._z).reshape(-1)[self._lanes]
 
     # -- the window ----------------------------------------------------------
 
@@ -144,7 +136,7 @@ class Cell:
         import jax.numpy as jnp
 
         app = self.app
-        self.z_last = self._assignments()
+        self.z_last = self.app.assignments()
         self.nwk_prog = app.word_topic.get()
         self.nk_prog = app.summary.get()
         rows = app._blk_of_doc * app._maxd + app._row_of_doc
@@ -161,7 +153,6 @@ class Cell:
         D, V, K = s["docs"], s["vocab_size"], s["num_topics"]
         priors = dict(alpha=float(s["alpha"]), beta=float(s["beta"]))
         t0 = time.perf_counter()
-        order_bad = int((self._packed_words != self.words).sum())
         w, d, m = (jnp.asarray(x)
                    for x in ref.pad_stream(self.words, self.docs))
 
@@ -171,10 +162,13 @@ class Cell:
             return jnp.asarray(zp.reshape(w.shape))
 
         sizes = dict(D=D, V=V, K=K)
-        # 1. the tables the window left, against counts of its own z
+        # 1. the tables the window left, against counts of its own z.
+        #    The word table agrees only where z[i] IS the topic of
+        #    words[i]: the order assignments() promises
         ndk, nwk, nk = ref.counts(dev(self.z_last), w, d, m, **sizes)
-        bad = int((np.asarray(ndk) != self.ndk_prog[:, :K]).sum()) \
-            + int((np.asarray(nwk) != self.nwk_prog[:V, :K]).sum()) \
+        order_bad = int((np.asarray(nwk) != self.nwk_prog[:V, :K]).sum())
+        bad = order_bad \
+            + int((np.asarray(ndk) != self.ndk_prog[:, :K]).sum()) \
             + int((np.asarray(nk) != self.nk_prog[:K]).sum())
         del ndk, nwk, nk
         # 2. the first sweeps, followed by the reference from its own

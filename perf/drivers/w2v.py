@@ -85,14 +85,11 @@ class Cell:
             mesh=mesh)
         self.log(f"corpus and WordEmbedding built in "
                  f"{time.perf_counter() - t0:.1f} s")
-        # host generation alone, over the pairs the checked calls use
+        # the pairs the checked calls use, drawn as the program draws them
         S, B = s["steps_per_call"], s["batch_size"]
-        t0 = time.perf_counter()
         it = self.app.corpus.skipgram_batches(
             B, window=s["window"], seed=self.prog_seed, epochs=1)
         first = [next(it) for _ in range(S)]
-        self.gen_words_per_s = S * B / (s["window"] + 1) \
-            / (time.perf_counter() - t0)
         it.close()
         self.pairs = (np.stack([a for a, _ in first]).astype(np.int32),
                       np.stack([b for _, b in first]).astype(np.int32))
@@ -149,11 +146,14 @@ class Cell:
         done = len(self.app.loss_history)
         pairs = done * S * B
         words = pairs / (s["window"] + 1)
+        # a corpus word is a training token: the cell's rate under its
+        # own name and under the one that names no app (PERF.md, 2)
+        rate = words / elapsed
         return {"attempted": calls, "failed": calls - done,
-                "metrics": {"w2v_words_per_s": words / elapsed},
+                "metrics": {"w2v_words_per_s": rate,
+                            "train_tokens_per_s": rate},
                 "work": {"pairs": pairs, "calls": done},
-                "values": {"w2v_gen_words_per_s": self.gen_words_per_s,
-                           "window_s": elapsed}}
+                "values": {"window_s": elapsed}}
 
     # -- what correct compares ---------------------------------------------
 

@@ -9,6 +9,10 @@ around the window), ``work`` (counts of the window's work, from the
 driver), ``values`` (numbers the driver measured itself), ``sizes`` (the
 configuration's ``program`` block), ``device_kind`` and ``chips``.
 
+A work model (``work_model`` of ``op_roofline`` and ``step_mfu``) is one
+of ``perf/work_models.py`` or, for a trainer added later, a file
+``perf/work/<name>.py`` with ``work(sizes, work) -> {"bytes", "flops"}``.
+
 A reader that finds nothing to read returns ``None`` and the harness
 leaves the metric out of the line; it never returns 0 for a share of a
 roofline or of a peak.
@@ -25,8 +29,19 @@ from perf import peaks, program, reduce_trace, work_models
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
+def work_model(name: str) -> Callable[[dict, dict], dict]:
+    if name in work_models.MODELS:
+        return work_models.MODELS[name]
+    own = os.path.join(HERE, "work", f"{name}.py")
+    if not os.path.exists(own):
+        raise KeyError(
+            f"no work model {name!r}: neither in perf/work_models.py "
+            f"(has: {sorted(work_models.MODELS)}) nor a file {own}")
+    return program.load_module(f"perf_work_{name}", own).work
+
+
 def _least(ctx: dict, model: str) -> float:
-    work = work_models.MODELS[model](ctx["sizes"], ctx["work"])
+    work = work_model(model)(ctx["sizes"], ctx["work"])
     return peaks.least_seconds(work, ctx["device_kind"],
                                ctx["chips"])["seconds"]
 
@@ -69,6 +84,17 @@ def collective_exposed_share(ctx: dict, spec: dict) -> Optional[float]:
     return 100.0 * t / ctx["trace"]["window_s"]
 
 
+def module_launches(ctx: dict, spec: dict) -> Optional[float]:
+    """Programs the first device launched in the window (every event of
+    its ``XLA Modules`` line, whoever dispatched it) by a count of the
+    work."""
+    n = sum(ctx["trace"]["module_launches"].values())
+    per = float(ctx["work"].get(spec["per"], 0))
+    if n <= 0 or per <= 0:
+        return None
+    return n / per
+
+
 def _registry_delta(ctx: dict, kind: str, name: str):
     """Difference over the window of every series of ``name``."""
     def pick(snap):
@@ -104,8 +130,8 @@ def value(ctx: dict, spec: dict) -> Optional[float]:
 
 KINDS: Dict[str, Callable[[dict, dict], Optional[float]]] = {
     f.__name__: f for f in (op_roofline, step_mfu, op_share, idle_share,
-                            collective_exposed_share, registry_rate,
-                            registry_mean, value)}
+                            collective_exposed_share, module_launches,
+                            registry_rate, registry_mean, value)}
 
 
 def load_metric(name: str) -> dict:

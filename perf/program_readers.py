@@ -2,6 +2,9 @@
 (``perf/layer_metrics/<name>.py``) share: the arithmetic over what the
 PROGRAM records about itself.
 
+- the names of the program's spans, from the registry's
+  ``span.seconds{name=...}`` series: what the run itself recorded, so a
+  trainer added later needs no list here.
 - set-up's layers: the TOTAL of a registry histogram after the window
   (``ctx["after"]``). The window records none of the set-up names, so a
   total is set-up's own.
@@ -17,7 +20,8 @@ metric out of the line.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, Optional
+import re
+from typing import Dict, Iterable, List, Optional
 
 SPAN_SECONDS = "span.seconds"
 UNSCOPED = "unscoped"
@@ -26,6 +30,14 @@ UNSCOPED = "unscoped"
 def span_series(name: str) -> str:
     """The registry key of a program span's histogram."""
     return f"{SPAN_SECONDS}{{name={name}}}"
+
+
+def span_names(snapshot: dict) -> List[str]:
+    """Every program span a registry snapshot holds a histogram of: the
+    names the trace's reduction may give an idle gap to."""
+    found = (re.fullmatch(re.escape(SPAN_SECONDS) + r"\{name=([^,}]+)\}",
+                          key) for key in snapshot["histograms"])
+    return sorted(m.group(1) for m in found if m)
 
 
 def histogram_total_s(ctx: dict, metrics: Iterable[str]

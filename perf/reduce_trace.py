@@ -1,7 +1,13 @@
 """Reduction of a profiler trace (``.xplane.pb``) to the benchmark's
 device numbers: per device the busy and idle time of the window, device
-time by operation name, the longest idle gaps with the host span that
-was open when each began, and the exposed part of the collectives.
+time by operation name, the longest idle gaps with the innermost host
+span that covers most of each, the exposed part of the collectives, and the
+programs the first device launched.
+
+A gap is named by a host span of the harness (``bench.*``, on any
+thread) or of the program: the names the run's own registry recorded
+(``span_names``), on the thread that holds ``bench.window`` alone — a
+producer thread's span overlaps gaps it has no part in.
 
 Read with nothing but jax (``jax.profiler.ProfileData``). The window is
 the host span ``bench.window`` that ``perf/run.py`` wraps the measured
@@ -15,6 +21,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 import re
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -126,18 +133,25 @@ def _leaves(events: List[Tuple[float, float, str]]
             if i + 1 == len(ordered) or ordered[i + 1][0] >= e[1]]
 
 
-def host_spans(pd, prefix: str = SPAN_PREFIX
+def host_spans(pd, names: Iterable[str] = (), prefix: str = SPAN_PREFIX
                ) -> List[Tuple[float, float, str]]:
+    """The harness's spans (``prefix``) on every host thread, and the
+    spans called one of ``names`` on a thread that holds the window."""
+    names = frozenset(names)
     spans = []
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(prefix):
-                    spans.append((ev.start_ns, ev.start_ns
-                                  + ev.duration_ns, ev.name))
-    return sorted(spans)
+            found = [(ev.start_ns, ev.start_ns + ev.duration_ns, name)
+                     for ev in line.events for name in (ev.name,)
+                     if name.startswith(prefix) or name in names]
+            if not any(s[2] == WINDOW_SPAN for s in found):
+                found = [s for s in found if s[2].startswith(prefix)]
+            spans += found
+    # by start, the longer first: of two spans that start together the
+    # inner one comes later, as it does when it starts later
+    return sorted(spans, key=lambda s: (s[0], -s[1], s[2]))
 
 
 def op_name(event_name: str) -> str:
@@ -147,20 +161,25 @@ def op_name(event_name: str) -> str:
 
 
 def _span_over(spans, starts, gap: Interval) -> str:
-    """The benchmark span that covers most of the idle gap: what the
-    host was doing while the device waited (the later-started, so the
-    inner, span on ties)."""
-    best, best_cover = "(no span open)", 0.0
+    """What the host was doing while the device waited: the innermost
+    span that covers more than half of the idle gap (``spans`` come by
+    start, the outer first, so the last such one); where none does, the
+    span that covers most of it."""
+    half = (gap[1] - gap[0]) / 2.0
+    inner, best, best_cover = None, "(no span open)", 0.0
     for a, b, name in spans[:bisect.bisect_right(starts, gap[1])]:
         cover = min(b, gap[1]) - max(a, gap[0])
+        if cover > half:
+            inner = name
         if cover > 0.0 and cover >= best_cover:
             best, best_cover = name, cover
-    return best
+    return inner or best
 
 
-def reduce(pd, top: int = 10) -> dict:
-    """The numbers of one traced window; times in seconds."""
-    spans = host_spans(pd)
+def reduce(pd, top: int = 10, span_names: Iterable[str] = ()) -> dict:
+    """The numbers of one traced window; times in seconds. Given no
+    ``span_names`` the gaps go to ``bench.*`` spans alone."""
+    spans = host_spans(pd, span_names)
     wins = [(a, b) for a, b, n in spans if n == WINDOW_SPAN]
     devices = [p for p in pd.planes if p.name.startswith("/device:TPU:")
                and any(ln.name == OPS_LINE for ln in p.lines)]
@@ -180,8 +199,9 @@ def reduce(pd, top: int = 10) -> dict:
     starts = [s[0] for s in inner]
 
     per_device = []
-    gaps: List[Tuple[float, str]] = []
+    gaps: List[Interval] = []
     exposed = 0.0
+    launches: Dict[str, int] = {}
     for plane in devices:
         mods = []
         ops = []
@@ -196,6 +216,10 @@ def reduce(pd, top: int = 10) -> dict:
                     if c:
                         ops.append((c[0], c[1], op_name(e.name)))
         mod_starts = [m[0] for m in mods]
+        if plane is devices[0]:
+            for a, _, name in mods:
+                if window[0] <= a < window[1]:
+                    launches[name] = launches.get(name, 0) + 1
 
         def named(a: float, name: str) -> str:
             i = bisect.bisect_right(mod_starts, a) - 1
@@ -209,8 +233,7 @@ def reduce(pd, top: int = 10) -> dict:
                            "window_s": window_s,
                            "idle_share_pct": 100.0 * (1.0 - busy_s
                                                       / window_s)})
-        for a, b in subtract([window], busy):
-            gaps.append(((b - a) / 1e9, _span_over(inner, starts, (a, b))))
+        gaps += subtract([window], busy)
         leaves = _leaves(ops)
         coll = union((a, b) for a, b, n in leaves if COLLECTIVE.search(n))
         comp = union((a, b) for a, b, n in leaves
@@ -228,7 +251,11 @@ def reduce(pd, top: int = 10) -> dict:
         for k, v in d.pop("ops").items():
             merged[k] = merged.get(k, 0.0) + v / n
     busy_mean = sum(d["busy_s"] for d in per_device) / n
-    gaps.sort(reverse=True)
+    # only the gaps that can reach the list are named: every span is
+    # weighed against each, and a window holds thousands of both
+    cut = min(heapq.nlargest(top, (b - a for a, b in gaps)), default=0.0)
+    longest = sorted((((b - a) / 1e9, _span_over(inner, starts, (a, b)))
+                      for a, b in gaps if b - a >= cut), reverse=True)
     return {
         "window_s": window_s,
         "busy_s": busy_mean,
@@ -237,9 +264,10 @@ def reduce(pd, top: int = 10) -> dict:
         "op_seconds": merged,
         "device_ops": [[k, v] for k, v in sorted(
             merged.items(), key=lambda kv: -kv[1])[:top]],
-        "idle_gaps": [[name, s] for s, name in gaps[:top]],
+        "idle_gaps": [[name, s] for s, name in longest[:top]],
         "collective_exposed_s": exposed / n,
         "spans": sorted({s[2] for s in spans}),
+        "module_launches": launches,
     }
 
 

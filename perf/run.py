@@ -15,6 +15,32 @@ program, decides ``correct`` against the plain reference, and prints ONE
 JSON object as the last line of stdout. Without a TPU holding the chips
 the cell asks for it exits non-zero and prints no result.
 
+A driver (``perf/drivers/<name>.py``, named by the configuration's
+``driver`` key) brings one class, ``Cell``. It is given, by keyword:
+``config`` (the configuration's file), ``traffic`` (the mix's file),
+``seed``, ``seconds``, ``chips``, ``devices`` (that many of jax's),
+``tiny`` (true in a rehearsal: the driver's own small sizes) and ``log``
+(a line to stderr). It provides, called in this order:
+
+- ``setup()``: build the ONE object the window will drive, from the
+  seed; drive it through its checked first steps and every shape the
+  window uses. All of it is ``setup_s``.
+- ``registry_snapshot()``: the program's counters and histograms
+  (``perf/program.py``), taken before and after the window.
+- ``window(seconds) -> {"metrics", "attempted", "failed", "work",
+  "values"}``: the cell's end-to-end metrics other than ``setup_s`` by
+  name; ``work`` is the counts of the window's work that the work models
+  take, ``values`` whatever a ``value`` reader reads.
+- ``collect()``: bring what ``correct`` compares to the host and free the
+  program (``program.free``); the memory peak has been read before it.
+- ``check() -> [{"name", "value", "limit"}]``: the plain reference
+  (``perf/reference/``) runs HERE, after ``collect`` freed the program,
+  in no metric; a value over its limit, or ``None``, is not correct.
+- ``close()``: always called; stops what the driver started.
+
+``config["program"]`` is what the work models (``perf/work_models.py``,
+``perf/work/<name>.py``) and the readers get as ``sizes``.
+
 ``--rehearse-cpu`` is a debugging aid for a host without a chip: the
 driver's own tiny sizes on virtual CPU devices. Its line says
 ``"platform": "cpu"`` and ``"rehearsal": true`` and carries no metric.
@@ -192,9 +218,10 @@ def main(argv=None) -> int:
     if args.rehearse_cpu:
         line["rehearsal"] = True
     elif trace_on:
-        from perf import layer_readers, reduce_trace
+        from perf import layer_readers, program_readers, reduce_trace
         trace = reduce_trace.reduce(
-            reduce_trace.load(reduce_trace.find_xplane(TRACE_DIR)))
+            reduce_trace.load(reduce_trace.find_xplane(TRACE_DIR)),
+            span_names=program_readers.span_names(after))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         ctx = {"trace": trace, "before": before, "after": after,
                "work": result["work"], "values": result.get("values", {}),

@@ -16,8 +16,8 @@ from conftest import ROOT, benchmark
 ACCEPTED = ["lda_step_mfu", "lda_sampler_roofline", "lda_rebuild_share",
             "lda_dispatches_per_sweep", "lda_device_idle_share",
             "lda_collective_exposed_share", "w2v_step_mfu",
-            "w2v_superstep_roofline", "w2v_gen_words_per_s",
-            "w2v_device_idle_share"]
+            "w2v_superstep_roofline", "w2v_device_idle_share"]
+RETIRED = ["w2v_gen_words_per_s"]      # PR 27: PERF.md, Findings
 W2V = ["w2v_gnews300_train"]
 LDA = ["lda_nytimes_dp1", "lda_nytimes_2x2"]
 # metric -> (span it reads, cells)
@@ -53,6 +53,7 @@ def test_new_metrics_are_appended_files_and_entries():
     per_layer = benchmark()["per_layer"]
     names = [m["name"] for m in per_layer]
     assert names[:len(ACCEPTED)] == ACCEPTED     # nothing moved or gone
+    assert not set(RETIRED) & set(names)
     assert sorted(names[len(ACCEPTED):]) == sorted({**SPAN_MS, **OWN})
     cells = {m["name"]: m["workloads"] for m in per_layer}
     here = os.path.join(ROOT, "perf", "layer_metrics")
@@ -67,6 +68,17 @@ def test_new_metrics_are_appended_files_and_entries():
         assert os.path.exists(os.path.join(here, f"{name}.py"))
         assert layer_readers.load_metric(name)["unit"] == (
             "s" if name in SETUP_S else "%")
+
+
+def test_span_names_are_what_the_registry_recorded():
+    snap = make_ctx(after={
+        program_readers.span_series("lda.dispatch"): hist(3, 1.0),
+        program_readers.span_series("w2v.pairs.produce"): hist(1, 1.0),
+        "profile.compile.seconds{fn=run}": hist(1, 2.0),
+        "span.seconds": hist(1, 1.0)})["after"]
+    assert program_readers.span_names(snap) == ["lda.dispatch",
+                                                "w2v.pairs.produce"]
+    assert program_readers.span_names(make_ctx()["after"]) == []
 
 
 @pytest.mark.parametrize("name", sorted(SPAN_MS))

@@ -1,6 +1,7 @@
 """The reduction on a small trace recorded on the chip (a v5e, PR 24):
 five runs of one jitted matmul, three before and two after a 20 ms
-sleep, under ``bench.*`` spans."""
+sleep, under ``bench.*`` spans; and on two written traces (text protos):
+two chips with collectives, and one chip under the program's own spans."""
 
 import os
 
@@ -93,3 +94,70 @@ def test_collectives_by_both_names():
     for name in ("fusion.62", "gibbs_sample_docblock.1", "copy.24",
                  "multiply_reduce_fusion.4", "sort"):
         assert not rt.COLLECTIVE.search(name), name
+
+
+SPANS = os.path.join(os.path.dirname(FIXTURE), "program_spans.textproto")
+REGISTRY = ["lda.sweep", "lda.dispatch", "w2v.pairs.produce"]
+
+
+@pytest.fixture(scope="module")
+def spans_trace():
+    """A written trace of one chip over a 20 us window (2..22 us). The
+    device idles at 2..4, 10..11, 12..15 and 21..21.5 us. The window's
+    thread holds ``bench.lda.sweep`` (2..21.2), ``bench.lda.sync``
+    (21.2..22) and, inside the sweep, the program's ``lda.sweep``
+    (2.1..21.1), ``lda.dispatch`` (2.2..4.5) and ``lda.fold_in``
+    (12.1..14.9); another thread holds ``w2v.pairs.produce``
+    (9.5..11.5)."""
+    return rt.load(SPANS)
+
+
+def test_gaps_go_to_the_programs_innermost_span(spans_trace):
+    r = rt.reduce(spans_trace, span_names=REGISTRY)
+    # 12..15: lda.fold_in is over it, but the registry holds no such
+    # name; 2..4: nine tenths under lda.dispatch, all of it under the two
+    # sweep spans around that; 10..11: the producer thread's span covers
+    # it whole and claims nothing; 21..21.5: three fifths under the sync
+    assert r["idle_gaps"] == [["lda.sweep", pytest.approx(3e-6)],
+                              ["lda.dispatch", pytest.approx(2e-6)],
+                              ["lda.sweep", pytest.approx(1e-6)],
+                              ["bench.lda.sync", pytest.approx(5e-7)]]
+    assert "w2v.pairs.produce" not in r["spans"]
+    assert "lda.fold_in" not in r["spans"]
+    named = rt.reduce(spans_trace, span_names=REGISTRY + ["lda.fold_in"])
+    assert named["idle_gaps"][0] == ["lda.fold_in", pytest.approx(3e-6)]
+
+
+def test_no_names_given_reads_the_harness_spans_alone(spans_trace):
+    r = rt.reduce(spans_trace)
+    assert [g[0] for g in r["idle_gaps"]] == ["bench.lda.sweep"] * 3 \
+        + ["bench.lda.sync"]
+    assert set(r["spans"]) == {"bench.window", "bench.lda.sweep",
+                               "bench.lda.sync"}
+    # the device numbers do not depend on who names the gaps
+    named = rt.reduce(spans_trace, span_names=REGISTRY)
+    for key in ("busy_s", "window_s", "idle_share_pct", "op_seconds",
+                "device_ops", "collective_exposed_s", "module_launches"):
+        assert r[key] == named[key], key
+    assert r["busy_s"] == pytest.approx(13.5e-6)
+    assert [g[1] for g in r["idle_gaps"]] == \
+        [g[1] for g in named["idle_gaps"]]
+
+
+def test_only_the_gaps_that_reach_the_list_are_named(spans_trace):
+    r = rt.reduce(spans_trace, top=2, span_names=REGISTRY)
+    assert r["idle_gaps"] == [["lda.sweep", pytest.approx(3e-6)],
+                              ["lda.dispatch", pytest.approx(2e-6)]]
+
+
+def test_module_launches_of_the_window(spans_trace, reduced):
+    # jit_warm starts before the window; jit_rebuild starts inside it and
+    # ends after: launched in the window
+    r = rt.reduce(spans_trace)
+    assert r["module_launches"] == {"jit_run": 2, "jit_fold_in": 1,
+                                    "jit_rebuild": 1}
+    # the chip's trace: three of the five runs start inside the window
+    assert reduced["module_launches"] == {"jit_busy": 3}
+    two = rt.reduce(rt.load(os.path.join(os.path.dirname(FIXTURE),
+                                         "two_chips.textproto")))
+    assert two["module_launches"] == {"jit_step": 1}     # the first chip's
