@@ -92,7 +92,10 @@ class PipelineMLPTrainer:
             idx = rng.integers(0, len(x), batch_size)
             self.params, loss = self._step(
                 self.params, jnp.asarray(x[idx]), jnp.asarray(y[idx]))
-            losses.append(loss)
+            # one program in flight: on the CPU's virtual devices the
+            # collectives of two queued steps can wait on each other for
+            # good when the host is busy (the process then aborts)
+            losses.append(jax.block_until_ready(loss))
         return np.asarray(jax.device_get(jnp.stack(losses)))
 
 

@@ -1,0 +1,654 @@
+"""A decoder language model trained through Table / Updater / superstep.
+
+Every parameter lives in a :class:`~multiverso_tpu.tables.base.Table`
+with updater ``adam``; one :class:`FusedSuperstep` a step computes the
+loss and its gradients and folds each gradient into its table through
+``table.updater.apply`` — the same pure function ``Table.add`` uses. The
+embedding is a ``MatrixTable`` read by row gather and written by row
+scatter-add of the token gradients, word2vec's forms.
+
+The model is built from the keys of a published ``config.json``
+(:class:`LMConfig`): which attention a layer has (``kv_lora_rank`` set:
+multi-head latent attention, :mod:`multiverso_tpu.ops.latent_attention`)
+and which feed-forward block (a dense SwiGLU for the first
+``first_k_dense_replace`` layers, then shared experts beside routed
+ones, :mod:`multiverso_tpu.ops.moe`) are read from it, as is every
+width. ``ep_size`` / ``ep_rank`` say that this chip is one of a group
+that shares each layer: ``n_routed_experts`` is then the number of
+experts HELD HERE (the router keeps ``n_routed_experts * ep_size``
+outputs), ``vocab_size`` the rows of the vocabulary held here
+(``vocab_shard`` shards: ids, logits and loss are over the slice). The
+chip runs without the group's exchange; nothing stands in for it.
+
+Tables (the count stays in the tens): ``embed`` [V, D] (MatrixTable),
+``head`` [V, D], ``norms`` [3 L + 1, D]; a layer: ``l{i}.attn``
+[D, H (nope + rope) | rank + rope | H v] (``w_q | w_kv_a | w_o``, the
+last stored out x in), ``l{i}.kv_b`` [rank, H (nope + v)]; a dense
+layer ``l{i}.mlp`` [3, D, F] (gate, up, down stored [D, F]); an expert
+layer ``l{i}.router`` [D, E], ``l{i}.shared`` [3, D, shared F],
+``l{i}.experts`` [held, 3, D, F] — the leading dimension is the expert,
+which is what a table shards over the model axis.
+:func:`table_layout` says where every tensor of a published role lies.
+
+Precision: tables, gradients and Adam's moments float32; matrix products
+on bfloat16 operands with float32 accumulation; norms, rotary, router
+logits and softmax, attention softmax and the loss float32. The forward
+pass keeps only the residual entering each layer; the backward pass
+goes a layer at a time, recomputes the layer, and folds each table's
+gradient into the table (Adam) before the layer below starts, so no more
+than a layer's gradients are alive at once. Inside a layer the attention
+goes a block of queries at a time, the dense and shared feed-forward and
+the output head a group of sequences at a time, the routed experts a
+block of sorted rows at a time.
+"""
+
+from __future__ import annotations
+
+import collections
+import dataclasses
+import time
+from functools import partial
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from multiverso_tpu import core, telemetry
+from multiverso_tpu.data.packing import Batch, pack_documents, real_tokens
+from multiverso_tpu.ops import latent_attention as mla
+from multiverso_tpu.ops import moe
+from multiverso_tpu.tables import MatrixTable
+from multiverso_tpu.tables.base import Table
+from multiverso_tpu.tables.superstep import make_superstep
+from multiverso_tpu.updaters import AddOption
+from multiverso_tpu.utils import log
+from multiverso_tpu.utils.async_buffer import prefetch_iterator
+
+PROBE_ROWS = 1024       # embedding rows whose gradient a step returns
+AUX_KEEP = 4            # the last steps whose whole aux stays on the device
+PREFETCH_STEPS = 4      # packed steps the input thread runs ahead
+_SMALL_AUX = ("ce", "balance", "moe", "imbalance")
+
+
+@dataclasses.dataclass
+class LMConfig:
+    # the published config.json's keys
+    hidden_size: int = 64
+    num_hidden_layers: int = 2
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 128
+    moe_intermediate_size: int = 32
+    n_routed_experts: int = 2           # experts HELD HERE
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 2
+    num_attention_heads: int = 4
+    kv_lora_rank: Optional[int] = 16
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 256               # rows HELD HERE
+    scoring_func: str = "softmax"
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    aux_loss_alpha: float = 0.001
+    # this chip's place in the group that shares each layer
+    ep_size: int = 1
+    ep_rank: int = 0
+    vocab_shard: int = 1
+    # the step and the optimizer
+    sequences: int = 2
+    sequence_length: int = 64
+    learning_rate: float = 4.2e-4
+    warmup_steps: int = 1               # linear warm-up; 1 = none
+    beta1: float = 0.9
+    beta2: float = 0.95
+    adam_eps: float = 1e-8
+    init_std: float = 0.006
+    # the embedding rows' own start; None = init_std. With rows as small
+    # as the other tensors the residual is mostly attention's running
+    # mean and a random router sends every token of a layer to the same
+    # experts; with unit rows (nn.Embedding's default) it routes by token
+    embed_init_std: Optional[float] = None
+    seed: int = 0
+    # what is recomputed in blocks (memory only; the numbers are the same)
+    attention_block: int = 512
+    expert_chunk_rows: int = 8192
+    mlp_chunks: int = 1
+    head_chunks: int = 1
+    compute_dtype: str = "bfloat16"     # operands of the matrix products
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "LMConfig":
+        names = {f.name for f in dataclasses.fields(cls)}
+        return cls(**{k: v for k, v in d.items() if k in names})
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts * self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.n_routed_experts * self.ep_rank
+
+    def learning_rate_at(self, step: int) -> float:
+        """The rate of optimizer step ``step`` (from 0): linear warm-up
+        over ``warmup_steps``, then constant."""
+        return self.learning_rate * min(1.0, (step + 1) / self.warmup_steps)
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace \
+            or self.n_routed_experts == 0
+
+    def check(self) -> None:
+        if self.kv_lora_rank is None:
+            raise NotImplementedError(
+                "attention without kv_lora_rank: only latent attention "
+                "is built")
+        if self.q_lora_rank is not None:
+            raise NotImplementedError(
+                "q_lora_rank: only the direct query projection is built")
+        if self.scoring_func != "softmax":
+            raise NotImplementedError(f"scoring_func {self.scoring_func!r}")
+        if self.warmup_steps < 1:
+            raise ValueError(f"warmup_steps {self.warmup_steps}: at least 1")
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} of {self.ep_size}")
+        if self.router_width > 256:
+            raise ValueError("the step returns the chosen experts as "
+                             "uint8: at most 256 router outputs")
+        for n, name in ((self.mlp_chunks, "mlp_chunks"),
+                        (self.head_chunks, "head_chunks")):
+            if self.sequences % n:
+                raise ValueError(f"{name} {n} does not divide "
+                                 f"{self.sequences} sequences")
+
+
+# -- tables and the tensors in them ------------------------------------------
+
+def _attn_columns(c: LMConfig) -> Tuple[int, int, int]:
+    return (c.num_attention_heads * (c.qk_nope_head_dim
+                                     + c.qk_rope_head_dim),
+            c.kv_lora_rank + c.qk_rope_head_dim,
+            c.num_attention_heads * c.v_head_dim)
+
+
+def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
+    """Every table's logical shape, in the order the superstep holds
+    them; the index in this order seeds the table's start values."""
+    D, L = c.hidden_size, c.num_hidden_layers
+    shapes: Dict[str, Tuple[int, ...]] = {
+        "embed": (c.vocab_size, D), "head": (c.vocab_size, D),
+        "norms": (3 * L + 1, D)}
+    for i in range(L):
+        shapes[f"l{i}.attn"] = (D, sum(_attn_columns(c)))
+        shapes[f"l{i}.kv_b"] = (
+            c.kv_lora_rank,
+            c.num_attention_heads * (c.qk_nope_head_dim + c.v_head_dim))
+        if c.is_dense(i):
+            shapes[f"l{i}.mlp"] = (3, D, c.intermediate_size)
+        else:
+            F = c.moe_intermediate_size
+            shapes[f"l{i}.router"] = (D, c.router_width)
+            shapes[f"l{i}.shared"] = (3, D, c.n_shared_experts * F)
+            shapes[f"l{i}.experts"] = (c.n_routed_experts, 3, D, F)
+    return shapes
+
+
+def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
+    """``{table: {role: index}}``: the tensor with that published role
+    is ``table[index]``. Roles: ``embed``, ``head`` [V, D],
+    ``final_norm``; a layer's ``attn_norm``, ``w_q``, ``w_kv_a``,
+    ``kv_norm``, ``w_kv_b``, ``w_o`` [D, H v] (out x in), ``ffn_norm``;
+    ``w_gate`` / ``w_up`` / ``w_down`` [D, F]; ``router``,
+    ``shared_gate`` / ``_up`` / ``_down``, ``exp_gate`` / ``_up`` /
+    ``_down`` [held, D, F]."""
+    q, a, _ = _attn_columns(c)
+    every = slice(None)
+    L = c.num_hidden_layers
+    layout: Dict[str, Dict[str, tuple]] = {
+        "embed": {"embed": (slice(0, c.vocab_size),)},   # less the scratch row
+        "head": {"head": (every,)},
+        "norms": {"final_norm": (3 * L,)}}
+    for i in range(L):
+        layout["norms"].update({
+            f"l{i}.attn_norm": (3 * i,),
+            f"l{i}.kv_norm": (3 * i + 1, slice(0, c.kv_lora_rank)),
+            f"l{i}.ffn_norm": (3 * i + 2,)})
+        layout[f"l{i}.attn"] = {
+            f"l{i}.w_q": (every, slice(0, q)),
+            f"l{i}.w_kv_a": (every, slice(q, q + a)),
+            f"l{i}.w_o": (every, slice(q + a, None))}
+        layout[f"l{i}.kv_b"] = {f"l{i}.w_kv_b": (every,)}
+        if c.is_dense(i):
+            layout[f"l{i}.mlp"] = {f"l{i}.w_{part}": (j,) for j, part in
+                                   enumerate(("gate", "up", "down"))}
+        else:
+            layout[f"l{i}.router"] = {f"l{i}.router": (every,)}
+            layout[f"l{i}.shared"] = {
+                f"l{i}.shared_{part}": (j,) for j, part in
+                enumerate(("gate", "up", "down"))}
+            layout[f"l{i}.experts"] = {
+                f"l{i}.exp_{part}": (every, j) for j, part in
+                enumerate(("gate", "up", "down"))}
+    return layout
+
+
+def named_parameters(c: LMConfig, tables: Dict[str, Any]) -> Dict[str, Any]:
+    """The tensors of the model under their published roles
+    (:func:`table_layout`), cut out of the tables given (numpy or jax
+    arrays; views where the arrays give views)."""
+    return {role: tables[name][index]
+            for name, roles in table_layout(c).items() if name in tables
+            for role, index in roles.items()}
+
+
+def start_std(c: LMConfig, name: str) -> float:
+    """The standard deviation table ``name`` starts from."""
+    if name == "embed" and c.embed_init_std is not None:
+        return c.embed_init_std
+    return c.init_std
+
+
+def start_values(c: LMConfig, index, shape, std):
+    """A table's start (the norm weights apart, which are 1):
+    normal(0, ``std``) from the seed and the table's index in
+    :func:`table_shapes` (traceable, the index and ``std`` too)."""
+    seed = int(c.seed)
+    key = jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF),
+                             (seed >> 31) & 0x7FFFFFFF)
+    return std * jax.random.normal(jax.random.fold_in(key, index),
+                                   tuple(shape), jnp.float32)
+
+
+# -- the phases of a step, each under a program scope --------------------------
+
+@telemetry.scope("lm.embed_gather")
+def _embed_gather(embed, tokens):
+    return jnp.take(embed, tokens, axis=0)
+
+
+@telemetry.scope("lm.embed_scatter")
+def _embed_scatter(embed, tokens, d_x):
+    """The token gradients as a delta of the table's shape: rows
+    scatter-added, word2vec's form."""
+    return jnp.zeros_like(embed).at[tokens.reshape(-1)].add(
+        d_x.reshape(-1, d_x.shape[-1]).astype(embed.dtype))
+
+
+def _swiglu(h, w):
+    """``h`` in the products' dtype; ``w`` [3, D, F] float32: gate, up,
+    and down stored [D, F]."""
+    w = w.astype(h.dtype)
+    gate = jnp.dot(h, w[0], preferred_element_type=jnp.float32)
+    up = jnp.dot(h, w[1], preferred_element_type=jnp.float32)
+    return lax.dot_general((jax.nn.silu(gate) * up).astype(h.dtype), w[2],
+                           (((h.ndim - 1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _over_sequences(fn: Callable, chunks: int, x, *rest):
+    """``fn(x_group, *rest)`` a group of sequences at a time, each group
+    recomputed in the backward pass; the groups' results stacked."""
+    if chunks == 1:
+        return fn(x, *rest)[None]
+    grouped = jax.tree.map(
+        lambda a: a.reshape(chunks, a.shape[0] // chunks, *a.shape[1:]), x)
+    return lax.map(jax.checkpoint(lambda g: fn(g, *rest)), grouped)
+
+
+@telemetry.scope("lm.dense_mlp")
+def _dense_mlp_group(h, w):
+    return _swiglu(h, w)
+
+
+@telemetry.scope("lm.moe.shared")
+def _shared_experts_group(h, w):
+    return _swiglu(h, w)
+
+
+@telemetry.scope("lm.head_loss")
+def _head_loss_group(group, final_norm, head, eps):
+    """Sum of the cross-entropy over a group's predicting tokens;
+    ``head`` arrives in the products' dtype."""
+    x, target, predicts = group
+    h = mla.rms_norm(x, final_norm, eps).astype(head.dtype)
+    logits = lax.dot_general(h, head, (((2,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    nll = jax.nn.logsumexp(logits, -1) \
+        - jnp.take_along_axis(logits, target[..., None], -1)[..., 0]
+    return jnp.sum(nll * predicts)
+
+
+class TransformerLM:
+    """The app: the tables of a decoder and the fused training step.
+
+    ``docs`` is a re-iterable of token-id arrays (one a document); each
+    :meth:`train` call packs it from its start into steps of
+    ``sequences`` x ``sequence_length`` slots on a producer thread."""
+
+    def __init__(self, config: LMConfig,
+                 docs: Optional[Iterable[np.ndarray]] = None, *,
+                 mesh=None, name: str = "lm") -> None:
+        config.check()
+        self.config = c = config
+        self.docs = docs
+        self.mesh = mesh if mesh is not None else core.mesh()
+        if self.mesh.size != 1:
+            raise NotImplementedError(
+                "TransformerLM runs one chip of its group: the exchange "
+                "of tokens over the model axis is not built")
+        self._shape = mla.LatentShape(
+            c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+            c.v_head_dim, c.kv_lora_rank, c.rms_norm_eps, c.compute_dtype)
+        self._rotary = mla.Rotary.from_config(
+            rope_dim=c.qk_rope_head_dim,
+            qk_dim=c.qk_nope_head_dim + c.qk_rope_head_dim,
+            theta=float(c.rope_theta), scaling=c.rope_scaling)
+        option = AddOption(learning_rate=c.learning_rate, momentum=c.beta1,
+                           rho=c.beta2, lam=c.adam_eps)
+        self.tables: Dict[str, Table] = {}
+        with telemetry.span("lm.setup.init_tables"):
+            draw = jax.jit(
+                lambda index, std, ones, shape, pad: jnp.pad(
+                    jnp.ones(shape, jnp.float32) if ones
+                    else start_values(c, index, shape, std), pad),
+                static_argnums=(2, 3, 4))       # one program a shape
+            for index, (tname, shape) in enumerate(
+                    table_shapes(c).items()):
+                # the embedding is a MatrixTable: a scratch row follows
+                # its logical rows
+                pad = ((0, 1 if tname == "embed" else 0),) \
+                    + ((0, 0),) * (len(shape) - 1)
+                kw = dict(updater="adam", mesh=self.mesh,
+                          default_option=dataclasses.replace(option))
+                table = MatrixTable(
+                    *shape, "float32", name=f"{name}.{tname}", **kw) \
+                    if tname == "embed" \
+                    else Table(f"{name}.{tname}", shape, "float32", **kw)
+                # the start is drawn on the device and installed as it
+                # is: 2.5 GB of it never pass the host
+                table.put_raw(draw(index, start_std(c, tname),
+                                   tname == "norms", shape, pad))
+                self.tables[tname] = table
+            jax.block_until_ready([t.param for t in self.tables.values()])
+        self.parameters = sum(int(np.prod(s))
+                              for s in table_shapes(c).values())
+        self.loss_history: List[Tuple[float, float]] = []
+        self.aux_tail: collections.deque = collections.deque(
+            maxlen=AUX_KEEP)
+        self.tokens_trained = 0
+        self._build_superstep()
+
+    # -- the model ---------------------------------------------------------
+
+    def _layer(self, i: int, x, t, norms, doc, pos, real):
+        """One decoder layer on the residual ``x`` [B, S, D] float32,
+        from the layer's tables ``t`` and its three rows of ``norms``;
+        returns ``(new residual, the layer's balance loss)`` and what an
+        expert layer routed (counts, chosen experts, rows computed)."""
+        c = self.config
+        q, a, _ = _attn_columns(c)
+        attn = t["attn"]
+        q_nope, q_pe, k_nope, k_pe, v = mla.project(
+            x, pos, norms[0], attn[:, :q], attn[:, q:q + a],
+            norms[1, :c.kv_lora_rank], t["kv_b"], self._shape,
+            self._rotary)
+        o = mla.attend(q_nope, q_pe, k_nope, k_pe, v, doc,
+                       scale=self._rotary.score_scale,
+                       block=c.attention_block)
+        x = x + mla.output(o, attn[:, q + a:])
+        h = mla.rms_norm(x, norms[2], c.rms_norm_eps)
+        hb = h.astype(c.compute_dtype)
+        if c.is_dense(i):
+            y = _over_sequences(_dense_mlp_group, c.mlp_chunks, hb,
+                                t["mlp"])
+            return (x + y.reshape(x.shape), jnp.zeros(())), None
+        B, S, D = x.shape
+        routing = moe.route(
+            h, t["router"], real, top_k=c.num_experts_per_tok,
+            norm_topk_prob=c.norm_topk_prob,
+            scaling=c.routed_scaling_factor, alpha=c.aux_loss_alpha)
+        plan = moe.plan(routing.top_e, real, first=c.first_expert,
+                        held=c.n_routed_experts,
+                        chunk_rows=c.expert_chunk_rows)
+        row_w = jnp.take(routing.top_s.reshape(-1), plan.row_src)
+        y, rows = moe.routed_experts(h.reshape(B * S, D), t["experts"],
+                                     row_w, plan, c.expert_chunk_rows,
+                                     jnp.dtype(c.compute_dtype))
+        shared = _over_sequences(_shared_experts_group, c.mlp_chunks, hb,
+                                 t["shared"])
+        return (x + shared.reshape(x.shape) + y.reshape(x.shape),
+                routing.balance), (routing.counts,
+                                   routing.top_e.astype(jnp.uint8), rows)
+
+    def _layer_tables(self, tables: Dict[str, Any], i: int):
+        prefix = f"l{i}."
+        return ({k[len(prefix):]: v for k, v in tables.items()
+                 if k.startswith(prefix)},
+                tables["norms"][3 * i:3 * i + 3])
+
+    def _head_loss(self, x, final_norm, head, tokens, doc):
+        """Mean cross-entropy over the tokens that have a successor in
+        their document, a group of sequences at a time."""
+        c = self.config
+        target = jnp.roll(tokens, -1, axis=1)
+        predicts = ((jnp.roll(doc, -1, axis=1) == doc) & (doc > 0)
+                    ).at[:, -1].set(False).astype(jnp.float32)
+        ce_sum = jnp.sum(_over_sequences(
+            _head_loss_group, c.head_chunks, (x, target, predicts),
+            final_norm, head.astype(c.compute_dtype), c.rms_norm_eps))
+        return ce_sum / jnp.maximum(predicts.sum(), 1.0)
+
+    def _sweep(self, tables: Dict[str, Any], batch,
+               consume: Callable[[str, Any], Any]):
+        """One step's loss and gradients, a layer at a time from the
+        last to the first. The forward pass keeps only the residual
+        entering each layer; the backward pass recomputes a layer from
+        it, and hands each table's gradient to ``consume(name, grad)``
+        as soon as the layer is done — before the layer below starts
+        (an optimization barrier holds the order), so that what
+        ``consume`` frees (the optimizer folds a gradient into its table
+        and lets it go) is free while the rest is computed. Returns
+        ``(aux, {table: what consume returned})``."""
+        c = self.config
+        L = c.num_hidden_layers
+        tokens, doc, pos = batch[0], batch[1], batch[2]
+        real = (doc > 0).astype(jnp.float32)
+
+        def layer(i, x, t, n):
+            return self._layer(i, x, t, n, doc, pos, real)
+
+        x = _embed_gather(tables["embed"], tokens)
+        entering = []
+        for i in range(L):
+            entering.append(x)
+            (x, _), _ = layer(i, x, *self._layer_tables(tables, i))
+        norms = tables["norms"]
+        ce, (d_x, d_final, d_head) = jax.value_and_grad(
+            self._head_loss, argnums=(0, 1, 2))(
+                x, norms[3 * L], tables["head"], tokens, doc)
+        out = {"head": consume("head", d_head)}
+        d_norms = jnp.zeros_like(norms).at[3 * L].set(d_final)
+        balance, routed = jnp.zeros(()), []
+        for i in reversed(range(L)):
+            # the barrier keeps the compiler from sharing this forward
+            # pass with the first one (and its memory with it)
+            x, d_x = lax.optimization_barrier((entering.pop(), d_x))
+            (_, b), vjp, r = jax.vjp(partial(layer, i), x,
+                                     *self._layer_tables(tables, i),
+                                     has_aux=True)
+            d_x, d_t, d_n = vjp((d_x, jnp.ones(())))
+            d_norms = d_norms.at[3 * i:3 * i + 3].set(d_n)
+            done = {f"l{i}.{k}": consume(f"l{i}.{k}", g)
+                    for k, g in d_t.items()}
+            d_x, done = lax.optimization_barrier((d_x, done))
+            out.update(done)
+            balance = balance + b
+            if r is not None:
+                routed.insert(0, r)
+        out["norms"] = consume("norms", d_norms)
+        out["embed"] = consume("embed", _embed_scatter(tables["embed"],
+                                                       tokens, d_x))
+        aux = {"ce": ce, "balance": balance}
+        if routed:
+            aux.update(zip(("counts", "chosen", "rows"),
+                           (jnp.stack(a) for a in zip(*routed))))
+        return aux, out
+
+    # -- the fused superstep -------------------------------------------------
+
+    def _build_superstep(self) -> None:
+        self._fused = make_superstep(
+            tuple(self.tables.values()),
+            self._step_body(list(self.tables),
+                            [t.updater.apply for t in self.tables.values()]),
+            name="lm_superstep")
+
+    def _step_body(self, names: List[str], appliers: List[Callable]):
+        """The superstep's body over the tables ``names``: loss,
+        gradients, and each table's updater applied to its gradient."""
+        c = self.config
+        appliers = [telemetry.scope("lm.adam")(a) for a in appliers]
+        probe_rows = min(PROBE_ROWS, c.vocab_size)
+        probe_expert = next((f"l{i}.experts" for i in
+                             range(c.num_hidden_layers)
+                             if not c.is_dense(i)), None)
+
+        def body(params, states, locals_, options, batch):
+            held = {n: (apply, p, s, o) for n, apply, p, s, o in
+                    zip(names, appliers, params, states, options)}
+
+            def fold(name, grad):
+                """The optimizer's step on one table, and what the step
+                reports of its gradient."""
+                apply, p, s, o = held[name]
+                report = {"norm": jnp.sqrt(jnp.sum(jnp.square(grad)))}
+                if name == "embed":
+                    report["probe_embed"] = grad[:probe_rows]
+                elif name == probe_expert:
+                    report["probe_expert"] = grad[0]
+                return apply(p, s, grad, o), report
+
+            aux, done = self._sweep(dict(zip(names, params)), batch, fold)
+            aux["grad_norms"] = jnp.stack([done[n][1]["norm"]
+                                           for n in names])
+            aux["probe_embed"] = done["embed"][1]["probe_embed"]
+            if probe_expert is not None:
+                routed = jnp.sum(lax.dynamic_slice_in_dim(
+                    aux["counts"], c.first_expert, c.n_routed_experts, 1))
+                aux["moe"] = jnp.stack([routed, routed
+                                        - jnp.sum(aux.pop("rows"))])
+                aux["imbalance"] = jnp.max(jax.vmap(
+                    lambda n: moe.expert_load_max_over_mean(
+                        n, first=c.first_expert,
+                        held=c.n_routed_experts))(aux["counts"]))
+                aux["probe_expert"] = done[probe_expert][1]["probe_expert"]
+            return (tuple(done[n][0][0] for n in names),
+                    tuple(done[n][0][1] for n in names), locals_, aux)
+
+        return body
+
+    # -- training ------------------------------------------------------------
+
+    def _batches(self) -> Iterable[Batch]:
+        if self.docs is None:
+            raise ValueError("TransformerLM was given no documents")
+        c = self.config
+        return pack_documents(self.docs, c.sequences, c.sequence_length)
+
+    def _place(self, batch: Batch) -> jax.Array:
+        return core.place(np.stack([batch["tokens"], batch["doc"],
+                                    batch["pos"]]), mesh=self.mesh)
+
+    def train(self, total_steps: Optional[int] = None) -> float:
+        """Train on the documents from their start, ``total_steps``
+        steps or until they fill no further step; returns the last
+        step's loss. Ends on the fence of the updated tables."""
+        steps: List[dict] = []
+        tokens = pads = 0
+        t0 = time.perf_counter()
+        batches = prefetch_iterator(self._batches(), depth=PREFETCH_STEPS,
+                                    name="lm.docs")
+        try:
+            while total_steps is None or len(steps) < total_steps:
+                with telemetry.span("lm.wait_data"):
+                    batch = next(batches, None)
+                if batch is None:
+                    break
+                with telemetry.span("lm.place"):
+                    placed = self._place(batch)
+                with telemetry.span("lm.superstep"):
+                    lr = self.config.learning_rate_at(
+                        self.tables["embed"].default_option.step)
+                    for table in self.tables.values():
+                        table.default_option.learning_rate = lr
+                    _, aux = self._fused((), placed)
+                telemetry.beat()
+                # of every step only the scalars wait for the fence; the
+                # whole aux (probed gradients, chosen experts) of the
+                # last few
+                steps.append({k: aux[k] for k in _SMALL_AUX if k in aux})
+                self.aux_tail.append(aux)
+                n = real_tokens(batch)
+                tokens += n
+                pads += batch["doc"].size - n
+        finally:
+            batches.close()
+        with telemetry.span("lm.fence"):
+            self.tables["embed"].wait()
+            dt = time.perf_counter() - t0
+            small = jax.device_get(steps)
+        self.loss_history += [(float(s["ce"]), float(s["balance"]))
+                              for s in small]
+        self.tokens_trained += tokens
+        telemetry.counter("lm.tokens").inc(tokens)
+        telemetry.counter("lm.pad_tokens").inc(pads)
+        if small and "moe" in small[-1]:
+            telemetry.counter("moe.tokens_routed").inc(
+                int(sum(s["moe"][0] for s in small)))
+            telemetry.counter("moe.tokens_dropped").inc(
+                int(sum(s["moe"][1] for s in small)))
+            telemetry.gauge("moe.expert_load_max_over_mean").set(
+                float(small[-1]["imbalance"]))
+        if not small:
+            return float("nan")
+        final = sum(self.loss_history[-1])
+        log.info("lm train done: %d steps, loss=%.4f, %.0f tokens/s",
+                 len(steps), final, tokens / dt)
+        return final
+
+    def _raw(self) -> Dict[str, jax.Array]:
+        return {n: t.raw() for n, t in self.tables.items()}
+
+    def gradients(self, batch: Batch) -> Tuple[dict, Dict[str, jax.Array]]:
+        """One step's ``(aux, gradient of every table)`` at the tables as
+        they are, nothing updated (an evaluation, compiled on first
+        use; the gradients take a third of the tables' memory)."""
+        if not hasattr(self, "_eval_gradients"):
+            self._eval_gradients = jax.jit(
+                lambda tables, b: self._sweep(tables, b, lambda _, g: g))
+        return self._eval_gradients(self._raw(), self._place(batch))
+
+    def hidden_states(self, batch: Batch) -> jax.Array:
+        """The residual after the last layer, [B, S, D] float32."""
+        if not hasattr(self, "_eval_hidden"):
+            def hidden(tables, b):
+                x = _embed_gather(tables["embed"], b[0])
+                real = (b[1] > 0).astype(jnp.float32)
+                for i in range(self.config.num_hidden_layers):
+                    (x, _), _ = self._layer(
+                        i, x, *self._layer_tables(tables, i), b[1], b[2],
+                        real)
+                return x
+            self._eval_hidden = jax.jit(hidden)
+        return self._eval_hidden(self._raw(), self._place(batch))
+
+    def named_parameters(self) -> Dict[str, jax.Array]:
+        """Live views of the tables under the model's own names."""
+        return named_parameters(self.config, self._raw())

@@ -77,9 +77,11 @@ _HLO_INSTRUCTION = re.compile(
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 # a program scope as :func:`trace.scope` writes it: the path segment
 # ``jit(<app>.<phase>)`` of the op_name (jax's own — ``jit(run)``,
-# ``jit(_take)``, ``while``, ``scatter-add`` — are never dotted words)
+# ``jit(_take)``, ``while``, ``scatter-add`` — are never dotted words),
+# also where differentiation wrapped it (``jvp(jit(lm.mla.attend))``:
+# the forward ops of a phase that ``jax.grad`` traced)
 _SCOPE_SEGMENT = re.compile(
-    r"^jit\(([a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+)\)$")
+    r"^(?:[a-z_]+\()*jit\(([a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+)\)+$")
 
 
 def parse_op_scopes(hlo_text: str) -> Tuple[str, Dict[str, str]]:

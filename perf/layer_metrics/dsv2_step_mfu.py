@@ -1,0 +1,14 @@
+"""Least time for the whole window's work (``perf/dsv2_work.py``
+``step``: forward and backward products and attention scores,
+recomputation not counted, Adam's bytes) over the traced window, in %:
+the share of the chip's peak the whole step reaches."""
+
+from perf import dsv2_work, peaks
+
+
+def read(ctx):
+    if not ctx["work"] or ctx["trace"]["window_s"] <= 0.0:
+        return None
+    least = peaks.least_seconds(dsv2_work.step(ctx["sizes"], ctx["work"]),
+                                ctx["device_kind"], ctx["chips"])
+    return 100.0 * least["seconds"] / ctx["trace"]["window_s"]

@@ -231,6 +231,11 @@ def test_w2v_spans_one_per_phase_per_call(mesh1):
 # -- (d) the compiled text names the ops --------------------------------------
 
 def test_op_scopes_name_the_supersteps_ops(mesh1):
+    # another test's word2vec program of the same ``fn`` (this worker may
+    # have compiled the hierarchical-softmax one) would merge with this
+    # one's, and an instruction name the two scope differently reads
+    # unscoped: read this test's own compile
+    profiling._OP_SCOPES.pop("superstep.w2v_superstep", None)
     w2v = _w2v(mesh1)
     w2v.train(total_steps=4)
     held = profiling.op_scopes()["superstep.w2v_superstep"]
