@@ -28,8 +28,10 @@ Phases: ``w2v`` (WordEmbedding.train: host generator -> prefetch ->
 placement -> fused superstep), ``lda`` (LightLDA doc-blocked sampler —
 the Mosaic-compiled hot loop), ``tables`` (MatrixTable / tiled
 SparseMatrixTable / KVTable get/add/COO-add with duplicate ids, both
-kernel engines against a numpy reference), ``server`` (one wire
-server; numpy bit-for-bit). With >= 4 devices the base phases run on
+kernel engines against a numpy reference), ``attend`` (the latent
+attention kernel, forward and backward at the language-model cell's
+shape on packed documents, against the plain blocked form), ``server``
+(one wire server; numpy bit-for-bit). With >= 4 devices the base phases run on
 ``data=4`` and four more follow: ``w2v@2x2``, ``lda@2x2``,
 ``tables@2x2`` (sharded engines under shard_map) and ``fleet`` (four
 one-chip members answering the single server's requests); otherwise
@@ -84,6 +86,9 @@ FULL = dict(
                 kv_batch=4096, value_dim=8),
     server=dict(array=1 << 16, kv_capacity=1 << 16, kv_batch=1024,
                 value_dim=4),
+    # the language-model cell's attention (perf/configs/dsv2_lite_ep8.json)
+    attend=dict(sequences=8, length=4096, heads=16, nope=128, rope=64,
+                v=128, block=512, doc_median=512, repeats=3),
 )
 TINY = dict(
     w2v=dict(vocab=500, tokens=40_000, dim=16, window=3, negative=3,
@@ -94,6 +99,8 @@ TINY = dict(
                 nnz=64, kv_capacity=2048, kv_batch=48, value_dim=4),
     server=dict(array=1 << 10, kv_capacity=2048, kv_batch=64,
                 value_dim=4),
+    attend=dict(sequences=2, length=64, heads=2, nope=16, rope=8, v=16,
+                block=16, doc_median=12, repeats=1),
 )
 
 
@@ -424,8 +431,106 @@ def phase_tables(cfg: dict, mesh, platform: str) -> dict:
     }
 
 
+def _attend_blocked(q_nope, q_pe, k_nope, k_pe, v, doc, scale, block):
+    """The plain blocked form the attention kernel replaced: a sequence
+    at a time, ``block`` queries against the keys up to the block's end,
+    scores, float32 softmax and weighted values in plain XLA; each block
+    recomputed in the backward pass."""
+    import jax
+    import jax.numpy as jnp
+
+    def one(q_nope, q_pe, k_nope, k_pe, v, doc, first):
+        end = first + block
+        scores = (jnp.einsum("qhd,khd->hqk", q_nope[first:end],
+                             k_nope[:end],
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("qhd,kd->hqk", q_pe[first:end], k_pe[:end],
+                               preferred_element_type=jnp.float32)) * scale
+        allowed = (first + jnp.arange(block)[:, None]
+                   >= jnp.arange(end)[None, :]) \
+            & (doc[first:end, None] == doc[None, :end])
+        prob = jax.nn.softmax(jnp.where(allowed[None], scores, -1e30),
+                              axis=-1).astype(v.dtype)
+        return jnp.einsum("hqk,khd->qhd", prob, v[:end],
+                          preferred_element_type=jnp.float32
+                          ).astype(v.dtype)
+
+    one = jax.checkpoint(one, static_argnums=(6,))
+    S = doc.shape[1]
+    out = jax.lax.map(
+        lambda a: jnp.concatenate([one(*a, f) for f in range(0, S, block)]),
+        (q_nope, q_pe, k_nope, k_pe, v, doc))
+    return out.reshape(out.shape[0], S, -1)
+
+
+def phase_attend(cfg: dict, mesh, platform: str) -> dict:
+    """The latent-attention kernel (Mosaic on the chip), forward and
+    backward, at the language-model cell's shape on packed documents,
+    against the plain blocked form in the same precision."""
+    import jax
+    import jax.numpy as jnp
+    from multiverso_tpu.data.packing import pack_documents
+    from multiverso_tpu.ops import interpret_mode
+    from multiverso_tpu.ops import latent_attention as mla
+
+    B, S, H = cfg["sequences"], cfg["length"], cfg["heads"]
+    rng = np.random.default_rng(11)
+    lengths = np.clip(np.rint(np.exp(rng.normal(
+        np.log(cfg["doc_median"]), 1.0, 64 * B))), 3, S).astype(int)
+    doc = jnp.asarray(next(pack_documents(
+        (np.ones(n, np.int32) for n in lengths), B, S))["doc"])
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    operands = (draw(B, S, H, cfg["nope"]), draw(B, S, H, cfg["rope"]),
+                draw(B, S, H, cfg["nope"]), draw(B, S, cfg["rope"]),
+                draw(B, S, H, cfg["v"]))
+    weight = draw(B, S, H * cfg["v"])
+    scale = float(cfg["nope"] + cfg["rope"]) ** -0.5
+    forms = {
+        "kernel": lambda *a: mla.attend(
+            *a, doc, scale=scale, block=cfg["block"],
+            interpret=interpret_mode(mesh)),
+        "blocked": lambda *a: _attend_blocked(*a, doc, scale,
+                                              cfg["block"])}
+    got, ms = {}, {}
+    for name, form in forms.items():
+        both = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum((form(*a) * weight).astype(jnp.float32)),
+            argnums=(0, 1, 2, 3, 4)))
+        forward = jax.jit(form)
+        for label, fn in (("forward", forward), ("both", both)):
+            out = jax.block_until_ready(fn(*operands))
+            t0 = time.perf_counter()
+            for _ in range(cfg["repeats"]):
+                out = fn(*operands)
+            jax.block_until_ready(out)
+            ms[f"{name}_{label}_ms"] = round(
+                1e3 * (time.perf_counter() - t0) / cfg["repeats"], 3)
+            got[name, label] = out
+    f32 = lambda a: np.asarray(a, np.float32)
+
+    def gap(a, b):
+        return float(np.linalg.norm(f32(a) - f32(b))
+                     / max(np.linalg.norm(f32(b)), 1e-30))
+
+    gaps = {"o": gap(got["kernel", "forward"], got["blocked", "forward"])}
+    for name, a, b in zip(("q_nope", "q_pe", "k_nope", "k_pe", "v"),
+                          got["kernel", "both"][1],
+                          got["blocked", "both"][1]):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        gaps[f"d_{name}"] = gap(a, b)
+    # bfloat16 operands: the two forms round the probabilities at
+    # different points (before and after the division by their sum)
+    assert all(g < 2e-2 for g in gaps.values()), gaps
+    total, computed = (int(n) for n in mla.key_blocks(doc, cfg["block"]))
+    return {"shape": {k: cfg[k] for k in sorted(cfg)},
+            "matches": "the plain blocked form, bfloat16 operands: "
+                       "relative gap of o and of the five gradients < 2e-2",
+            "gaps": {k: round(g, 5) for k, g in gaps.items()},
+            "key_blocks": total, "key_blocks_computed": computed, **ms}
+
+
 CHILD_PHASES = {"w2v": phase_w2v, "lda": phase_lda,
-                "tables": phase_tables}
+                "tables": phase_tables, "attend": phase_attend}
 
 
 def run_child(phase: str, rehearse: bool) -> int:
@@ -748,7 +853,7 @@ class Smoke:
             print("chip_smoke: FAILED — " + "; ".join(self.failed),
                   file=sys.stderr)
             return 1
-        for phase in ("lda", "tables"):
+        for phase in ("lda", "tables", "attend"):
             self.child(phase)
         self.guarded("server", self.server)
         count = int(self.lines[0]["devices"])

@@ -37,9 +37,10 @@ pass keeps only the residual entering each layer; the backward pass
 goes a layer at a time, recomputes the layer, and folds each table's
 gradient into the table (Adam) before the layer below starts, so no more
 than a layer's gradients are alive at once. Inside a layer the attention
-goes a block of queries at a time, the dense and shared feed-forward and
-the output head a group of sequences at a time, the routed experts a
-block of sorted rows at a time.
+is a Pallas kernel over blocks of queries and keys whose scores stay in
+VMEM (forward and backward, :mod:`multiverso_tpu.ops.latent_attention`),
+the dense and shared feed-forward and the output head go a group of
+sequences at a time, the routed experts a block of sorted rows at a time.
 """
 
 from __future__ import annotations
@@ -57,6 +58,7 @@ from jax import lax
 
 from multiverso_tpu import core, telemetry
 from multiverso_tpu.data.packing import Batch, pack_documents, real_tokens
+from multiverso_tpu.ops import interpret_mode
 from multiverso_tpu.ops import latent_attention as mla
 from multiverso_tpu.ops import moe
 from multiverso_tpu.tables import MatrixTable
@@ -69,7 +71,7 @@ from multiverso_tpu.utils.async_buffer import prefetch_iterator
 PROBE_ROWS = 1024       # embedding rows whose gradient a step returns
 AUX_KEEP = 4            # the last steps whose whole aux stays on the device
 PREFETCH_STEPS = 4      # packed steps the input thread runs ahead
-_SMALL_AUX = ("ce", "balance", "moe", "imbalance")
+_SMALL_AUX = ("ce", "balance", "moe", "imbalance", "attend")
 
 
 @dataclasses.dataclass
@@ -116,7 +118,8 @@ class LMConfig:
     # experts; with unit rows (nn.Embedding's default) it routes by token
     embed_init_std: Optional[float] = None
     seed: int = 0
-    # what is recomputed in blocks (memory only; the numbers are the same)
+    # the attention kernels' query (and key) block; then what is
+    # recomputed in blocks (memory only; the numbers are the same)
     attention_block: int = 512
     expert_chunk_rows: int = 8192
     mlp_chunks: int = 1
@@ -343,6 +346,8 @@ class TransformerLM:
             raise NotImplementedError(
                 "TransformerLM runs one chip of its group: the exchange "
                 "of tokens over the model axis is not built")
+        # a CPU mesh (tests) runs the attention kernels interpreted
+        self._interpret = interpret_mode(self.mesh)
         self._shape = mla.LatentShape(
             c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
             c.v_head_dim, c.kv_lora_rank, c.rms_norm_eps, c.compute_dtype)
@@ -401,7 +406,7 @@ class TransformerLM:
             self._rotary)
         o = mla.attend(q_nope, q_pe, k_nope, k_pe, v, doc,
                        scale=self._rotary.score_scale,
-                       block=c.attention_block)
+                       block=c.attention_block, interpret=self._interpret)
         x = x + mla.output(o, attn[:, q + a:])
         h = mla.rms_norm(x, norms[2], c.rms_norm_eps)
         hb = h.astype(c.compute_dtype)
@@ -495,7 +500,9 @@ class TransformerLM:
         out["norms"] = consume("norms", d_norms)
         out["embed"] = consume("embed", _embed_scatter(tables["embed"],
                                                        tokens, d_x))
-        aux = {"ce": ce, "balance": balance}
+        # every layer's attention runs the same block pairs
+        aux = {"ce": ce, "balance": balance,
+               "attend": L * mla.key_blocks(doc, c.attention_block)}
         if routed:
             aux.update(zip(("counts", "chosen", "rows"),
                            (jnp.stack(a) for a in zip(*routed))))
@@ -609,6 +616,10 @@ class TransformerLM:
         self.tokens_trained += tokens
         telemetry.counter("lm.tokens").inc(tokens)
         telemetry.counter("lm.pad_tokens").inc(pads)
+        for i, name in enumerate(("lm.attend.key_blocks",
+                                  "lm.attend.key_blocks_computed")):
+            telemetry.counter(name).inc(int(sum(s["attend"][i]
+                                                for s in small)))
         if small and "moe" in small[-1]:
             telemetry.counter("moe.tokens_routed").inc(
                 int(sum(s["moe"][0] for s in small)))

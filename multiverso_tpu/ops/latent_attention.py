@@ -5,18 +5,43 @@ Queries per head; ONE compressed key-value latent a token
 up-projected per head into the keys' non-rotary part and the values
 (DeepSeek-V2, arXiv:2405.04434 §2.1). :func:`project` makes queries,
 keys and values from the residual; :func:`attend` is the softmax
-attention, causal and inside a document only, over blocks of queries
-against the keys up to the block's end, so that no ``[S, S]`` score
-matrix is ever whole in memory and the blocks above the diagonal are
-never computed; :func:`output` projects back.
+attention, causal and inside a document only; :func:`output` projects
+back.
+
+:func:`attend` is one family of Pallas kernels with a backward pass
+written by hand (``jax.custom_vjp``): Mosaic compiles them on a TPU,
+Pallas's interpreter runs the same kernels anywhere else. A grid step
+is one (sequence, head, query block, key block) and its score tile
+``[block, block]`` float32 (keys down, queries across, so that what is
+kept a query — maximum, sum, log-sum-exp — is a row and a reduction
+over the keys runs down the sublanes). The forward kernel keeps the
+tile, the query block's running maximum and sum and its weighted values
+in VMEM (an online softmax) and writes the output ``[B, S, H * v]``
+and, when a backward pass will follow, one float32 log-sum-exp a
+(sequence, head, query): no score ever reaches HBM. The backward pass is
+ONE kernel, a key block over its query blocks: it recomputes each score
+tile in VMEM from the operands and the log-sum-exp, accumulates the key
+block's two gradients over the query blocks and keeps the queries'
+gradient of the head's whole sequence in VMEM until the head is done;
+so a layer's attention computes its scores three times (forward,
+recomputed forward, backward) and stores them never. A (query block,
+key block) pair in which no query may see a key — every pair above the
+diagonal, and every pair whose blocks' ``doc`` ids do not overlap — is
+neither fetched nor computed (:func:`block_plan`, scalar-prefetched).
+The operands are read where the projections left them (a head is a
+128-lane column block of ``[B, S, H * d]``, the rotary key one block
+for every head); head dims that are no multiple of 128 lanes (the
+rotary 64) are zero-padded first, which changes no score.
 
 Matrix products take operands of ``LatentShape.dtype`` (bfloat16) and
 accumulate in float32; norms, the rotary embedding and the softmax are
-float32.
+float32; the probabilities are cast to the operands' dtype before the
+weighted values, the scores' gradient before its products.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -24,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from multiverso_tpu import telemetry
 
@@ -125,50 +152,352 @@ def project(x, pos, norm_w, w_q, w_kv_a, kv_norm_w, w_kv_b,
     return run(x, pos, norm_w, w_q, w_kv_a, kv_norm_w, w_kv_b)
 
 
-def _attend_block(q_nope, q_pe, k_nope, k_pe, v, doc, first, size, scale):
-    """Queries ``first … first + size - 1`` of ONE sequence against its
-    keys ``0 … first + size - 1``; the arguments are the whole
-    sequence's (``q_*`` [S, H, d], ``k_nope`` / ``v`` [S, H, d], ``k_pe``
-    [S, rope]) and are cut here, so that what the backward pass keeps of
-    a block is the sequence itself and no copy of a slice."""
-    end = first + size
-    q_nope, q_pe, q_doc = q_nope[first:end], q_pe[first:end], doc[first:end]
-    k_nope, k_pe, v, k_doc = k_nope[:end], k_pe[:end], v[:end], doc[:end]
-    scores = (jnp.einsum("qhd,khd->hqk", q_nope, k_nope,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("qhd,kd->hqk", q_pe, k_pe,
-                           preferred_element_type=jnp.float32)) * scale
-    Q, K = q_doc.shape[0], k_doc.shape[0]
-    allowed = (first + jnp.arange(Q)[:, None] >= jnp.arange(K)[None, :]) \
-        & (q_doc[:, None] == k_doc[None, :])
-    scores = jnp.where(allowed[None], scores, -1e30)
-    prob = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("hqk,khd->qhd", prob, v,
-                      preferred_element_type=jnp.float32).astype(v.dtype)
+# -- attend: one Pallas kernel family, differentiated by hand -----------------
+
+LANES = 128
+VMEM_LIMIT = 64 * 2 ** 20   # of a v5e core's 128 MiB; the default is 16
+_MASKED = -1e30
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+class _Static(NamedTuple):
+    scale: float
+    # the query block, ``attend``'s ``block``, and the key block too: a
+    # square score tile (at 512 queries on a v5e a key block of 1,024
+    # ran as fast and one of 256 a fifth slower, PERF.md PR 29)
+    block: int
+    interpret: bool
+
+
+def block_plan(doc, block: int):
+    """Which (sequence, query block, key block) pairs the kernels
+    compute, [B, S / block, S / block] int32 (1 or 0), from the blocks'
+    ranges of ``doc`` ids alone. A pair is skipped — neither fetched nor
+    computed — when no query of it may see a key of it: the key block
+    lies above the diagonal, or the two blocks' id ranges are disjoint.
+    The block on the diagonal is never skipped: a token sees itself.
+    (Telling the pairs that need no mask, or the document mask alone,
+    apart from the rest bought nothing on a v5e, PERF.md PR 29.)"""
+    B, S = doc.shape
+    n = S // block
+    blocks = doc.reshape(B, n, block)
+    lo, hi = blocks.min(-1), blocks.max(-1)
+    overlap = (lo[:, None, :] <= hi[:, :, None]) \
+        & (hi[:, None, :] >= lo[:, :, None])
+    return (jnp.tril(jnp.ones((n, n), bool)) & overlap).astype(jnp.int32)
+
+
+def key_blocks(doc, block: int):
+    """``[block pairs under the diagonal, block pairs the kernels
+    compute]`` of one call of :func:`attend` on ``doc`` [B, S] (a head's;
+    every head and every pass runs the same pairs), int32 [2]."""
+    B, S = doc.shape
+    block = min(block, S)
+    n = S // block
+    return jnp.stack([jnp.asarray(B * n * (n + 1) // 2),
+                      jnp.sum(block_plan(doc, block))]).astype(jnp.int32)
+
+
+def _fetch_plan(plan):
+    """For the kernels' index maps, along the last axis of ``plan``: the
+    block to hold at each grid step — the step's own where it is
+    computed, else the next one that is (so its fetch overlaps the steps
+    skipped before it), else the last one that was (nothing is fetched
+    after it)."""
+    n = plan.shape[-1]
+    at = jnp.arange(n, dtype=jnp.int32)
+    nxt = lax.cummin(jnp.where(plan > 0, at, n), axis=plan.ndim - 1,
+                     reverse=True)
+    prev = lax.cummax(jnp.where(plan > 0, at, -1), axis=plan.ndim - 1)
+    return jnp.where(nxt < n, nxt, prev).astype(jnp.int32)
+
+
+def _scores(a_nope, a_pe, b_nope, b_pe, scale):
+    """``a @ b.T`` over the non-rotary and the rotary depth, float32,
+    scaled: [rows of a, rows of b]."""
+    return (lax.dot_general(a_nope, b_nope, _NT,
+                            preferred_element_type=jnp.float32)
+            + lax.dot_general(a_pe, b_pe, _NT,
+                              preferred_element_type=jnp.float32)) * scale
+
+
+def _mask(s, q0, k0, q_doc, k_doc):
+    """The score tile ``s`` [keys, queries] with ``_MASKED`` where the
+    query may not see the key: it comes before it, or their ``doc`` ids
+    (a row of queries', a column of keys') differ."""
+    ahead = lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+        - lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    return jnp.where((ahead >= k0 - q0) & (q_doc == k_doc), s, _MASKED)
+
+
+def _computed(plan_ref, outer, inner, n):
+    """Whether the plan has this grid step's pair computed."""
+    return plan_ref[(pl.program_id(0) * n + outer) * n + inner] > 0
+
+
+def _forward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
+                    v_ref, qd_ref, kd_ref, o_ref, *rest, scale, block, n):
+    """One (sequence, head, query block, key block) step of the online
+    softmax. The tile is [keys, queries], so the
+    query block's running maximum ``m`` and sum ``l`` are rows and a
+    reduction over the keys runs down the sublanes; the weighted values
+    accumulate transposed, [v, queries]. Tile, ``m``, ``l`` and the
+    accumulator never leave VMEM."""
+    del hold_ref
+    *lse_ref, m_ref, l_ref, acc_ref = rest
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(_computed(plan_ref, i, j, n))
+    def _():
+        s = _mask(_scores(kn_ref[...], kp_ref[...], qn_ref[...],
+                          qp_ref[...], scale),
+                  i * block, j * block, qd_ref[...], kd_ref[...])
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+            v_ref[...], p.astype(v_ref.dtype), _TN,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == n - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).T.astype(o_ref.dtype)
+        if lse_ref:
+            lse_ref[0][...] = m_ref[...] + jnp.log(l_ref[...])
+
+
+def _backward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
+                     v_ref, qd_ref, kd_ref, do_ref, lse_ref, di_ref,
+                     dqn_ref, dqp_ref, dkn_ref, dkp_ref, dv_ref,
+                     dqn_acc, dqp_acc, dkn_acc, dkp_acc, dv_acc, *, scale,
+                     block, n):
+    """One (sequence, head, key block, query block) step of the backward
+    pass. The tile is [keys, queries]: the probabilities are recomputed from the scores and the
+    forward pass's log-sum-exp (a row, like ``di``), the keys' and
+    values' gradients of the key block accumulate over its query blocks,
+    and the queries' gradient of the whole sequence stays in VMEM until
+    the head's last step."""
+    del hold_ref
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((j == 0) & (i == 0))
+    def _():
+        dqn_acc[...] = jnp.zeros_like(dqn_acc)
+        dqp_acc[...] = jnp.zeros_like(dqp_acc)
+
+    @pl.when(i == 0)
+    def _():
+        dkn_acc[...] = jnp.zeros_like(dkn_acc)
+        dkp_acc[...] = jnp.zeros_like(dkp_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_computed(plan_ref, j, i, n))
+    def _():
+        s = _mask(_scores(kn_ref[...], kp_ref[...], qn_ref[...],
+                          qp_ref[...], scale),
+                  i * block, j * block, qd_ref[...], kd_ref[...])
+        p = jnp.exp(s - lse_ref[...])
+        do = do_ref[...]
+        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dprob = lax.dot_general(v_ref[...], do, _NT,
+                                preferred_element_type=jnp.float32)
+        ds = p * (dprob - di_ref[...])
+        ds_t = ds.T.astype(kn_ref.dtype)
+        ds = ds.astype(qn_ref.dtype)
+        dkn_acc[...] += jnp.dot(ds, qn_ref[...],
+                                preferred_element_type=jnp.float32)
+        dkp_acc[...] += jnp.dot(ds, qp_ref[...],
+                                preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(i * block, block), block)
+        dqn_acc[rows, :] += jnp.dot(ds_t, kn_ref[...],
+                                    preferred_element_type=jnp.float32)
+        dqp_acc[rows, :] += jnp.dot(ds_t, kp_ref[...],
+                                    preferred_element_type=jnp.float32)
+
+    @pl.when(i == n - 1)
+    def _():
+        dkn_ref[...] = (dkn_acc[...] * scale).astype(dkn_ref.dtype)
+        dkp_ref[...] = dkp_acc[...] * scale
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((j == n - 1) & (i == n - 1))
+    def _():
+        dqn_ref[...] = (dqn_acc[...] * scale).astype(dqn_ref.dtype)
+        dqp_ref[...] = (dqp_acc[...] * scale).astype(dqp_ref.dtype)
+
+
+def _grid(static: _Static, B, H, S, Dn, Dr, Dv, doc, queries_inner):
+    """Grid, the two scalar-prefetched plans and the block specs the
+    kernels share. The operands stay as the projections left them,
+    [B, S, H * d]: a head's block is the column block ``h``; ``k_pe``
+    [B, S, Dr] is every head's. The tile is [keys, queries], so the queries'
+    ``doc`` ids and statistics are rows ([B, 1, S], [B, H, 1, S]) and
+    the keys' ids a column ([B, S, 1]). The grid is (sequence, head,
+    outer block, inner block): the outer block is held, the inner one
+    follows the fetch plan — key blocks under a query block, or, with
+    ``queries_inner``, query blocks over a key block."""
+    block, n = static.block, S // static.block
+    plan = block_plan(doc, block)
+    if queries_inner:
+        plan = plan.transpose(0, 2, 1)
+
+    def fetched(b, outer, inner, hold):
+        return hold[(b * n + outer) * n + inner]
+
+    if queries_inner:
+        q_at = lambda b, h, j, i, hold, plan: fetched(b, j, i, hold)
+        k_at = lambda b, h, j, i, hold, plan: j
+    else:
+        q_at = lambda b, h, i, j, hold, plan: i
+        k_at = lambda b, h, i, j, hold, plan: fetched(b, i, j, hold)
+
+    def rows(at, d, head=True):
+        """[B, S, H * d]: the rows of block ``at`` of head ``h`` (of the
+        one head there is)."""
+        return pl.BlockSpec((None, block, d), lambda b, h, *a: (
+            b, at(b, h, *a), h if head else 0))
+
+    per_q = functools.partial(rows, q_at)
+    per_k = functools.partial(rows, k_at)
+    q_doc = pl.BlockSpec((None, 1, block),
+                         lambda b, h, *a: (b, 0, q_at(b, h, *a)))
+    q_stat = pl.BlockSpec((None, None, 1, block),
+                          lambda b, h, *a: (b, h, 0, q_at(b, h, *a)))
+    operands = [per_q(Dn), per_q(Dr), per_k(Dn), per_k(Dr, head=False),
+                per_k(Dv), q_doc, per_k(1, head=False)]
+    return ((B, H, n, n), (_fetch_plan(plan).reshape(-1), plan.reshape(-1)),
+            operands, per_q, per_k, q_stat)
+
+
+def _pallas(kernel, static: _Static, grid, plans, in_specs, out_specs,
+            out_shape, scratch, S, inner_only: bool = True):
+    """The call; ``inner_only``: the innermost grid axis alone carries
+    state from step to step (else the two block axes do)."""
+    return functools.partial(pl.pallas_call(
+        functools.partial(kernel, scale=static.scale, block=static.block,
+                          n=S // static.block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel",
+                                 "parallel" if inner_only else "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=static.interpret), *plans)
+
+
+def _sizes(qn, qp, kp, v):
+    B, S, Dr = kp.shape
+    H = qp.shape[2] // Dr
+    return B, H, S, qn.shape[2] // H, Dr, v.shape[2] // H
+
+
+def _forward(qn, qp, kn, kp, v, doc, static: _Static, residuals: bool):
+    """``o`` [B, S, H * Dv] and, with ``residuals``, the log-sum-exp of
+    every (sequence, head, query) [B, H, 1, S] float32."""
+    B, H, S, Dn, Dr, Dv = _sizes(qn, qp, kp, v)
+    block = static.block
+    grid, plans, operands, per_q, _, q_stat = _grid(
+        static, B, H, S, Dn, Dr, Dv, doc, False)
+    out_specs = [per_q(Dv)]
+    out_shape = [jax.ShapeDtypeStruct((B, S, H * Dv), v.dtype)]
+    if residuals:
+        out_specs.append(q_stat)
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
+    out = _pallas(_forward_kernel, static, grid, plans, operands, out_specs,
+                  out_shape, [pltpu.VMEM((1, block), jnp.float32),
+                              pltpu.VMEM((1, block), jnp.float32),
+                              pltpu.VMEM((Dv, block), jnp.float32)], S)(
+        qn, qp, kn, kp, v, doc[:, None, :], doc[:, :, None])
+    return out if residuals else (out[0], None)
+
+
+def _backward(static: _Static, saved, do):
+    """The five operands' gradients from what the forward pass kept (its
+    operands, ``o`` and the log-sum-exp): one kernel that recomputes the
+    score tiles in VMEM."""
+    qn, qp, kn, kp, v, doc, o, lse = saved
+    B, H, S, Dn, Dr, Dv = _sizes(qn, qp, kp, v)
+    block = static.block
+    # rowsum(do * o), what the softmax's gradient subtracts: [B, H, 1, S]
+    di = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                 .reshape(B, S, H, Dv), -1).transpose(0, 2, 1)[:, :, None]
+    f32 = lambda *shape: pltpu.VMEM(shape, jnp.float32)
+    like = lambda a, dtype=None: jax.ShapeDtypeStruct(a.shape,
+                                                      dtype or a.dtype)
+    # a head's whole sequence of query gradients: one block, held
+    whole = lambda d: pl.BlockSpec((None, S, d), lambda b, h, *a: (b, 0, h))
+    grid, plans, operands, per_q, per_k, q_stat = _grid(
+        static, B, H, S, Dn, Dr, Dv, doc, True)
+    dqn, dqp, dkn, dkp, dv = _pallas(
+        _backward_kernel, static, grid, plans,
+        operands + [per_q(Dv), q_stat, q_stat],
+        [whole(Dn), whole(Dr), per_k(Dn), per_k(Dr), per_k(Dv)],
+        [like(qn), like(qp), like(kn), like(qp, jnp.float32), like(v)],
+        [f32(S, Dn), f32(S, Dr), f32(block, Dn), f32(block, Dr),
+         f32(block, Dv)],
+        S, inner_only=False)(
+            qn, qp, kn, kp, v, doc[:, None, :], doc[:, :, None], do, lse, di)
+    # the rotary key is every head's: its gradient is the heads' sum
+    dkp = dkp.reshape(B, S, H, Dr).sum(2).astype(kp.dtype)
+    return dqn, dqp, dkn, dkp, dv, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _attention(qn, qp, kn, kp, v, doc, static):
+    return _forward(qn, qp, kn, kp, v, doc, static, False)[0]
+
+
+def _attention_fwd(qn, qp, kn, kp, v, doc, static):
+    o, lse = _forward(qn, qp, kn, kp, v, doc, static, True)
+    return o, (qn, qp, kn, kp, v, doc, o, lse)
+
+
+_attention.defvjp(_attention_fwd, _backward)
+
+
+def _lanes(x):
+    """``x`` with its last dimension zero-padded to whole lanes (no
+    score, value or gradient changes; nothing happens at 128)."""
+    pad = -x.shape[-1] % LANES
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
 def attend(q_nope, q_pe, k_nope, k_pe, v, doc, *, scale: float,
-           block: int):
+           block: int, interpret: Optional[bool] = None):
     """Softmax attention, causal and inside a document: a token attends
     to the earlier tokens (and itself) whose ``doc`` id equals its own.
-    One sequence at a time, ``block`` queries at a time against the keys
-    up to the block's end; each block is recomputed in the backward
-    pass. Returns [B, S, H * v] in the operands' dtype."""
+    ``block`` queries at a time. Returns [B, S, H * v] in the operands'
+    dtype. ``interpret``: run the kernels in Pallas's interpreter; left
+    out, every backend but a TPU does."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+
     @telemetry.scope("lm.mla.attend")
     def run(q_nope, q_pe, k_nope, k_pe, v, doc):
-        S = doc.shape[1]
-        Q = min(block, S)
-        if S % Q:
+        B, S, H, vd = v.shape
+        size = min(block, S)
+        if S % size:
             raise ValueError(f"sequence {S} is no multiple of the "
-                             f"attention block {Q}")
-        one = jax.checkpoint(_attend_block, static_argnums=(6, 7, 8))
-
-        def sequence(args):
-            return jnp.concatenate([one(*args, a, Q, scale)
-                                    for a in range(0, S, Q)])
-
-        out = lax.map(sequence, (q_nope, q_pe, k_nope, k_pe, v, doc))
-        return out.reshape(out.shape[0], S, -1)
+                             f"attention block {size}")
+        flat = lambda a: _lanes(a).reshape(B, S, -1)
+        o = _attention(flat(q_nope), flat(q_pe), flat(k_nope), _lanes(k_pe),
+                       flat(v), doc,
+                       _Static(float(scale), size, bool(interpret)))
+        return o.reshape(B, S, H, -1)[..., :vd].reshape(B, S, H * vd)
     return run(q_nope, q_pe, k_nope, k_pe, v, doc)
 
 

@@ -261,29 +261,133 @@ def test_changing_one_document_leaves_another_s_states_bit_equal(trained):
     assert not np.array_equal(a[changed], b[changed])
 
 
-def test_blocked_attention_equals_the_unblocked_form():
-    rng = np.random.default_rng(3)
-    B, S, H = 2, 64, 4
-    q_nope, k_nope, v = (jnp.asarray(rng.normal(size=(B, S, H, 16)),
-                                     jnp.float32) for _ in range(3))
-    q_pe = jnp.asarray(rng.normal(size=(B, S, H, 8)), jnp.float32)
-    k_pe = jnp.asarray(rng.normal(size=(B, S, 8)), jnp.float32)
-    doc = jnp.asarray(np.repeat(np.arange(1, 5), 16)[None].repeat(B, 0)
-                      * np.array([[1], [0]]) + np.array([[0], [1]]))
-    whole = mla.attend(q_nope, q_pe, k_nope, k_pe, v, doc, scale=0.2,
-                       block=S)
-    for block in (8, 32):
-        blocked = mla.attend(q_nope, q_pe, k_nope, k_pe, v, doc,
-                             scale=0.2, block=block)
-        np.testing.assert_allclose(blocked, whole, rtol=1e-5, atol=1e-6)
-    # the plain form: one [S, S] score matrix a head, masked
-    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-              + jnp.einsum("bqhd,bkd->bhqk", q_pe, k_pe)) * 0.2
+def plain_attention(q_nope, q_pe, k_nope, k_pe, v, doc, scale):
+    """The oracle: one [S, S] score matrix a head, masked causal and by
+    document, float32 softmax, the operands' dtype in the products."""
+    B, S = doc.shape
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhd,bkd->bhqk", q_pe, k_pe,
+                           preferred_element_type=jnp.float32)) * scale
     t = jnp.arange(S)
     allowed = (t[:, None] >= t[None]) & (doc[:, :, None] == doc[:, None])
     prob = jax.nn.softmax(jnp.where(allowed[:, None], scores, -1e30), -1)
-    plain = jnp.einsum("bhqk,bkhd->bqhd", prob, v).reshape(B, S, -1)
-    np.testing.assert_allclose(whole, plain, rtol=1e-5, atol=1e-6)
+    return jnp.einsum("bhqk,bkhd->bqhd", prob.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32
+                      ).astype(v.dtype).reshape(B, S, -1)
+
+
+def attention_operands(B=2, S=64, H=4, nope=16, rope=8, vd=16, seed=3,
+                       dtype=jnp.float32):
+    """``q_nope, q_pe, k_nope, k_pe, v``: ``k_pe`` is every head's."""
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)
+    return (draw(B, S, H, nope), draw(B, S, H, rope), draw(B, S, H, nope),
+            draw(B, S, rope), draw(B, S, H, vd))
+
+
+def doc_ids(*lengths, S=64):
+    """One sequence's ``doc`` ids: documents of ``lengths`` from id 1,
+    then padding (0)."""
+    ids = np.repeat(np.arange(1, len(lengths) + 1), lengths)
+    return np.concatenate([ids, np.zeros(S - len(ids), np.int64)])
+
+
+# two sequences each: [four documents of 16, one of 64]; a boundary on
+# the edge of a block of 8 / 16 / 32 and one off every edge; a padded
+# tail that starts inside a block, and a sequence that is all padding
+DOCS = {
+    "four_and_one": np.stack([doc_ids(16, 16, 16, 16), doc_ids(64)]),
+    "boundaries_on_and_off_a_block_edge": np.stack(
+        [doc_ids(32, 32), doc_ids(21, 30, 13)]),
+    "padding_slots": np.stack([doc_ids(20, 21, 9), doc_ids()]),
+    "one_document_fills_the_sequence": np.stack([doc_ids(64), doc_ids(64)]),
+}
+
+
+@pytest.mark.parametrize("block", [8, 32, 64])
+def test_blocked_attention_equals_the_unblocked_form(block):
+    """The kernel (interpreted here) at query blocks 8, 32 and S against
+    the plain [S, S] form."""
+    operands = attention_operands()
+    doc = jnp.asarray(DOCS["four_and_one"])
+    got = mla.attend(*operands, doc, scale=0.2, block=block)
+    np.testing.assert_allclose(got, plain_attention(*operands, doc, 0.2),
+                               rtol=1e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("docs", sorted(DOCS))
+def test_attention_and_its_five_gradients_equal_the_plain_forms(docs):
+    """Output and the gradient of every operand, written by hand in the
+    kernel's backward pass, against ``jax.grad`` of the plain form."""
+    operands = attention_operands(seed=5)
+    doc = jnp.asarray(DOCS[docs])
+    weight = attention_operands(seed=6)[0].reshape(2, 64, -1)
+
+    def loss(attention):
+        return lambda *a: jnp.sum(attention(*a) * weight)
+
+    ours = lambda *a: mla.attend(*a, doc, scale=0.3, block=16)
+    plain = lambda *a: plain_attention(*a, doc, 0.3)
+    np.testing.assert_allclose(ours(*operands), plain(*operands),
+                               rtol=1e-5, atol=2e-6)
+    got = jax.grad(loss(ours), argnums=(0, 1, 2, 3, 4))(*operands)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2, 3, 4))(*operands)
+    for name, g, w in zip(("q_nope", "q_pe", "k_nope", "k_pe", "v"),
+                          got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5,
+                                   err_msg=name)
+
+
+def test_attention_at_the_published_head_dims_in_bfloat16():
+    """Score depth 128 + 64 with the rotary key shared by the heads,
+    value depth 128, bfloat16 operands: the kernel against the plain
+    form in the same precision, forward and backward."""
+    operands = attention_operands(B=1, S=64, H=2, nope=128, rope=64,
+                                  vd=128, seed=7, dtype=jnp.bfloat16)
+    doc = jnp.asarray(DOCS["boundaries_on_and_off_a_block_edge"][1:])
+    scale = 192 ** -0.5
+    ours = lambda *a: mla.attend(*a, doc, scale=scale, block=32)
+    plain = lambda *a: plain_attention(*a, doc, scale)
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(f32(ours(*operands)), f32(plain(*operands)),
+                               rtol=2e-2, atol=2e-2)
+    loss = lambda f: lambda *a: jnp.sum(f(*a).astype(jnp.float32))
+    got = jax.grad(loss(ours), argnums=(0, 1, 2, 3, 4))(*operands)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2, 3, 4))(*operands)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == jnp.bfloat16
+        gap = np.linalg.norm(f32(g) - f32(w)) / np.linalg.norm(f32(w))
+        assert gap < 2e-2, gap
+
+
+@pytest.mark.parametrize("docs", sorted(DOCS))
+def test_the_blocks_the_kernel_skips_are_a_count_of_the_doc_ids(docs):
+    """A block pair is computed iff some query of it may see some key —
+    or the two blocks' ranges of ids overlap, where ids are not sorted
+    (a padded tail that starts inside a block)."""
+    doc, block = DOCS[docs], 8
+    n = doc.shape[1] // block
+    t = np.arange(doc.shape[1])
+    allowed = (t[:, None] >= t[None]) & (doc[:, :, None] == doc[:, None])
+    some = allowed.reshape(len(doc), n, block, n, block).any((2, 4))
+    lo = doc.reshape(len(doc), n, block).min(-1)
+    hi = doc.reshape(len(doc), n, block).max(-1)
+    overlap = (lo[:, None] <= hi[:, :, None]) & (hi[:, None] >= lo[:, :, None])
+    under = np.tril(np.ones((n, n), bool))
+    plan = np.asarray(mla.block_plan(jnp.asarray(doc), block)) > 0
+    assert np.array_equal(plan, under & overlap)
+    assert not (some & ~plan).any()         # nothing allowed is skipped
+    # sorted ids: exactly the pairs that hold an allowed (query, key)
+    tidy = (np.diff(doc, axis=1) >= 0).all(1)
+    assert np.array_equal(plan[tidy], some[tidy])
+    total, computed = np.asarray(mla.key_blocks(jnp.asarray(doc), block))
+    assert total == len(doc) * under.sum()
+    assert computed == (under & overlap).sum()
+    if docs == "four_and_one":
+        # 4 documents of 2 blocks: 3 pairs each; one of 8 blocks: 36
+        assert (total, computed) == (72, 12 + 36)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -481,6 +585,16 @@ def test_spans_scopes_and_counters_of_a_training_call(trained):
         - counters["lm.tokens"]
     assert counters["moe.tokens_dropped"] == 0
     assert counters["moe.tokens_routed"] > 0
+    # block pairs of every layer's attention: 4 sequences of 4 blocks
+    c = trained["config"]
+    plans = [np.asarray(mla.block_plan(jnp.asarray(b["doc"]), 16))
+             for b in batches]
+    assert counters["lm.attend.key_blocks"] \
+        == c.num_hidden_layers * len(batches) * 4 * 10
+    assert counters["lm.attend.key_blocks_computed"] \
+        == c.num_hidden_layers * sum(p.sum() for p in plans)
+    assert 0 < counters["lm.attend.key_blocks_computed"] \
+        < counters["lm.attend.key_blocks"]
     assert snap["gauges"]["moe.expert_load_max_over_mean"] >= 1.0
     held = telemetry.op_scopes()["superstep.lm_superstep"]
     assert held["module"] == "jit_run"

@@ -622,7 +622,8 @@ def _kernel_cases():
     """(id, build(mesh) -> (fn, arg shapes+dtypes+specs)) for every
     Pallas kernel `auto` can select on a TPU: the five table kernels
     (flat, masked, sharded), the in-trace functional forms, and the
-    three LDA sampler kernels — at chip_smoke.py's widths."""
+    three LDA sampler kernels and the latent-attention kernels — at
+    chip_smoke.py's widths."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from multiverso_tpu.ops import lda_sampler as ls
@@ -774,6 +775,27 @@ def _kernel_cases():
             [((B, C, 128), i32, P()), w3, sinv,
              ((B,), i32, P()), ((B,), i32, P()),
              ((B,), f32, P()), ((B,), f32, P())]))
+
+    # the latent-attention kernels (forward, and the backward kernel
+    # behind jax.grad) at the language-model cell's shape: 4,096-token
+    # sequences, 16 heads, score depth 128 + 64, value depth 128, blocks
+    # of 512 — the head's whole query gradient has to fit VMEM
+    from multiverso_tpu.ops import latent_attention as mla
+    bf16, S, H = jnp.bfloat16, 4096, 16
+
+    def attention(grad):
+        def attend(q_nope, q_pe, k_nope, k_pe, v, doc):
+            return mla.attend(q_nope, q_pe, k_nope, k_pe, v, doc,
+                              scale=192 ** -0.5, block=512, interpret=False)
+
+        def loss(*a):
+            return jnp.sum(attend(*a).astype(f32))
+        return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if grad else attend,
+                [((2, S, H, 128), bf16, P()), ((2, S, H, 64), bf16, P()),
+                 ((2, S, H, 128), bf16, P()), ((2, S, 64), bf16, P()),
+                 ((2, S, H, 128), bf16, P()), ((2, S), i32, P())])
+    add("latent-attention-forward", lambda m: attention(False))
+    add("latent-attention-backward", lambda m: attention(True))
     return cases
 
 
