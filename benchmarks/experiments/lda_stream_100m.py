@@ -78,8 +78,7 @@ def hbm_mb():
 t0 = time.perf_counter()
 app = LightLDA(tw, td, V, LDAConfig(
     num_topics=K, batch_tokens=2_097_152, steps_per_call=4, seed=1,
-    sampler="tiled", stale_words=True, doc_blocked=True,
-    stream_blocks=True))
+    sampler="tiled", stream_blocks=True))
 setup_secs = time.perf_counter() - t0
 rss_after_init = ram_rss_gb()
 print(f"setup+init: {setup_secs:.0f}s  "

@@ -11,21 +11,19 @@ Protocol (recorded in benchmarks/lda_results.json):
   proposal, 2 MH rounds), one worker. The 16-worker cluster is scored as
   16x this (perfect scaling, zero PS cost — generous to the reference).
 - TPU: the PRODUCTION sampler — the doc-blocked pallas Gibbs kernel
-  (apps/lightlda sampler='tiled', doc_blocked=True, which implies the
-  sweep-stale bf16 word-count mirror): collapsed Gibbs with in-register
+  (apps/lightlda sampler='tiled', with its sweep-stale bf16 word-count
+  mirror): collapsed Gibbs with in-register
   own-token removal, batch-stale doc counts within a 512-token block,
   and word counts stale per sweep — the SAME staleness model the
   reference runs (word rows fetched per slice, updates pushed at block
   end; its alias tables are additionally stale, which ours are not).
   Batch 512k tokens. Steady-state sweep incl. the per-sweep word-master
   rebuild, compile excluded, host-transfer fence. The exact per-run
-  config is recorded in lda_results.json (sampler/stale_words/
-  doc_blocked/block_* fields).
+  config is recorded in lda_results.json (sampler/block_* fields).
 - Quality asymmetry still favors the baseline: every Gibbs variant here
   mixes faster per sweep than the baseline's MH proposals, and the
-  quality ladder (exact gibbs -> tiled -> stale/doc-blocked) is
-  validated by invariant + likelihood-convergence tests
-  (tests/test_lightlda.py).
+  doc-blocked sampler is held to the exact gibbs one by invariant +
+  likelihood-convergence tests (tests/test_lightlda.py).
 
 Run: python benchmarks/measure_lda.py   (rewrites lda_results.json)
 """
@@ -137,8 +135,7 @@ def _tpu_app(sampler: str, steps_per_call: int = 1):
         # steps_per_call=1 was the fastest setting on the 2026-07 v5e
         # host (4 and 10 were ~20-27% slower); not re-measured on the
         # current machine — pass another value as argv[2] to compare
-        steps_per_call=steps_per_call, seed=1, sampler=sampler,
-        stale_words=tiled, doc_blocked=tiled))
+        steps_per_call=steps_per_call, seed=1, sampler=sampler))
 
 
 def measure_tpu(sampler: str = "tiled", timed_sweeps: int = 3,
@@ -176,8 +173,6 @@ def measure_tpu(sampler: str = "tiled", timed_sweeps: int = 3,
             "topics": K_TPU,
             # record the MEASURED configuration, not the defaults
             "batch_tokens": cfg.batch_tokens, "sampler": cfg.sampler,
-            "stale_words": cfg.stale_words,
-            "doc_blocked": cfg.doc_blocked,
             "block_tokens": cfg.block_tokens,
             "block_docs": cfg.block_docs,
             # packing fill scales kernel efficiency — record the
@@ -190,7 +185,7 @@ def measure_tpu(sampler: str = "tiled", timed_sweeps: int = 3,
 def quality_curve(tpu_sweeps: int = 40, cpu_sweeps: int = 12) -> dict:
     """loglik-vs-TRAINING-wallclock, TPU doc_blocked vs CPU MH on the
     matched workload (eval excluded from both clocks). Substantiates
-    'the Gibbs ladder mixes at least as fast per second' with data."""
+    'the Gibbs sampler mixes at least as fast per second' with data."""
     import numpy as np
     cpu = measure_cpu(sweeps=cpu_sweeps, curve=True)
 
@@ -242,9 +237,8 @@ def pinned_cpu() -> dict:
 
 
 if __name__ == "__main__":
-    # reproduce any ladder step (benchmarks/README.md):
-    #   python benchmarks/measure_lda.py [gibbs|mh|tiled]
-    # 'tiled' runs the production config (doc_blocked + stale_words);
+    #   python benchmarks/measure_lda.py [gibbs|tiled]
+    # 'tiled' is the doc-blocked sampler the benchmark's cells run;
     # 'curve' writes the loglik-vs-wallclock comparison instead
     sampler_arg = sys.argv[1] if len(sys.argv) > 1 else "tiled"
     if sampler_arg == "curve":

@@ -87,7 +87,7 @@ def lda_utilization(doc_tokens_per_sec: float, num_topics: int,
                     device_kind: str) -> dict:
     """Roofline fields for the doc-blocked LDA sampler.
 
-    HBM model per token (doc_blocked + stale_words production config):
+    HBM model per token (the doc-blocked sampler, sampler="tiled"):
       w_gather    one bf16 word row [K]                   -> 2*K bytes
       z           int32 read + write                      -> 8
       stream      packed token ~8 B (measured fill)       -> 8

@@ -12,11 +12,10 @@ exists because O(K) per token is unaffordable on a scalar CPU. On TPU the
 economics invert: an O(K) **vectorized collapsed-Gibbs** step — gather the
 token's doc-topic and word-topic count rows, form the K posterior weights
 on the VPU in linear space, sample by inverse-CDF (cumsum + one uniform
-per token) — costs a few microseconds per thousand tokens, is *exact*
-(no proposal bias, no MH rejections), and converges in fewer sweeps than
-MH. The alias tables, proposal splitting, and
-acceptance ratios are CPU machinery with no TPU reason to exist; what is
-preserved is the *model contract*: same collapsed posterior
+per token) — is *exact* (no proposal bias, no MH rejections) and
+converges in fewer sweeps than MH. The alias tables, proposal splitting
+and acceptance ratios are CPU machinery with no TPU reason to exist; what
+is preserved is the *model contract*: same collapsed posterior
 p(z=k | rest) ∝ (N_dk + α)(N_wk + β)/(N_k + Vβ), same count-matrix state
 in the same tables, same streamed-block training shape.
 
@@ -25,33 +24,28 @@ approximation the reference already makes across workers (its workers
 sample against a stale model fetched per slice); here the staleness
 window is one minibatch instead of one model-slice fetch.
 
-Four sampler configurations, a measured performance ladder (one v5e
-chip, benchmarks/README.md has the engineering log; every rung is
-invariant- and convergence-tested):
+Two samplers, chosen by ``LDAConfig.sampler``, both on dp x mp meshes:
 
-1. ``sampler="gibbs"`` — exact vectorized collapsed Gibbs in plain XLA
-   (4.7M doc-tokens/s). Supports model-axis sharding of the tables.
-2. ``sampler="mh"`` — the reference's O(1) alias/z-array MH,
-   vectorized. Measured SLOWER than dense Gibbs on TPU (scalar gathers
-   lose to row gathers); kept as the algorithm-parity mode.
-3. ``sampler="tiled"`` — the pallas kernel (ops.gibbs_sample_tiled):
-   posterior + two-level inverse-CDF draw fused in VMEM over
-   tile-aligned counts (7.5M). ``stale_words=True`` adds the
-   reference's own slice-level staleness — word rows gathered from a
-   bf16 per-sweep mirror, int16 doc counts, int32 master rebuilt from
-   z each sweep (12.6M).
-4. ``doc_blocked=True`` — the production mode (19.6M, ~10x the CPU
-   baseline): whole-document kernel blocks own exclusive slices of a
-   blocked doc-count array, so the doc side (A-row gather + doc-count
-   scatters) happens in VMEM via MXU one-hot matmuls, never touching
-   XLA gather/scatter. Data-parallel across chips via shard_map
-   (per-chip blocks + psum'd summary deltas).
+- ``"gibbs"`` — the exact vectorized collapsed Gibbs in plain XLA over a
+  shuffled token stream. The reference the tests hold the other to, and
+  the only path for a topic count that is not a multiple of 128 or a
+  document longer than ``block_tokens``.
+- ``"tiled"`` — the doc-blocked stale sampler, the one the benchmark's
+  LDA cells run (14.59M doc-tokens/s on one v5e chip: PERF_LEDGER.jsonl,
+  PR 29, ``lda_nytimes_dp1``). The doc-sorted stream is packed into
+  whole-document kernel blocks that own exclusive slices of a blocked
+  int16 doc-count array, so the doc side (row gather + count moves)
+  happens in VMEM by one-hot matmuls inside the Pallas kernel
+  (ops.gibbs_sample_docblock). Word rows follow the reference's own
+  slice-level staleness: gathered from a bf16 mirror refreshed once a
+  sweep, the int32 master rebuilt from z at sweep end. Blocks shard over
+  the data axis via shard_map (summary deltas psum'd). Resident, or with
+  ``stream_blocks`` (and ``local_corpus``) streamed from the host.
 
-Every sampler runs on dp x mp meshes. For the tiled family the int32
-word table stays row-sharded over the model axis — the reference's Meta
-vocab-slicing role — and the per-sweep master rebuild scatters each
-chip's data shard into its vocab slice, psum'd over the data axis, so
-no chip ever materialises the full int32 [V, K]. The stale modes' bf16
+The int32 word table stays row-sharded over the model axis — the
+reference's Meta vocab-slicing role — and the per-sweep master rebuild
+scatters each chip's data shard into its vocab slice, psum'd over the
+data axis, so no chip ever materialises the full int32 [V, K]. The bf16
 mirror is the worker's per-sweep CACHE of that table: the cast
 all-gathers it over the model axis once a sweep, every chip holds it
 whole (2*V*K bytes), and the sweep's word-row gather is a plain local
@@ -61,13 +55,14 @@ each row lives in one shard).
 
 Counts live in:
 - ``SparseMatrixTable [V, K] int32`` — word-topic counts (row-sharded
-  over the mesh model axis like the reference's server shards; the
-  tiled samplers store it tile-aligned),
+  over the mesh model axis like the reference's server shards; ``tiled``
+  stores it tile-aligned),
 - ``ArrayTable [K] int32`` — topic summary,
-- a worker-local doc-topic array (dense ``[D, K]``, or int16 blocked
-  ``[NB, MAXD, C, 128]`` in doc_blocked mode — the reference keeps
+- a worker-local doc-topic array (dense ``[D, K]`` under ``gibbs``, int16
+  blocked ``[NB, MAXD, C, 128]`` under ``tiled`` — the reference keeps
   doc-topic counts worker-local too),
-- ``z [T] int32`` — per-token assignments, device-resident.
+- ``z`` — per-token assignments, device-resident (host-resident when
+  streamed).
 """
 
 from __future__ import annotations
@@ -104,21 +99,17 @@ class LDAConfig:
     checkpoint_prefix: str = ""     # periodic mid-train checkpoints
     checkpoint_interval: int = 0    # store every N sweeps (0 = off;
     # SURVEY §6.4's flag-driven periodic dump trigger)
-    sampler: str = "gibbs"          # "gibbs" (exact O(K)) | "mh" (O(1))
-    #                               | "tiled" (pallas kernel, K%128==0)
-    stale_words: bool = False       # tiled only: word counts gathered
-    # from a bf16 mirror refreshed per sweep (the reference's own model:
-    # word-topic rows fetched per slice, updates pushed at block end);
-    # deletes the per-step word-count scatters, int32 master rebuilt
-    # from z each sweep. Doc counts go int16 (doc len < 32k enforced).
-    doc_blocked: bool = False       # tiled only (implies stale_words):
-    # doc-sorted stream packed into whole-doc kernel blocks that own an
-    # exclusive slice of the blocked doc-topic counts — the doc side
-    # (A-row gather + doc-count scatters) moves INTO the pallas kernel
-    # (VMEM matmuls), the fastest sampler (see benchmarks/README.md)
-    block_tokens: int = 512         # doc_blocked: tokens per kernel block
-    block_docs: int = 16            # doc_blocked: max docs per block
-    stream_blocks: bool = False     # doc_blocked only: OUT-OF-CORE mode —
+    sampler: str = "gibbs"          # "gibbs" (exact O(K), plain XLA) |
+    #                               "tiled" (the doc-blocked stale pallas
+    #                               sampler; K%128==0, docs <= block_tokens)
+    # two keywords kept for callers that predate the two-sampler config
+    # (perf/drivers/lda.py among them): "tiled" implies both and reads
+    # neither; under "gibbs" a true value is refused
+    stale_words: bool = False
+    doc_blocked: bool = False
+    block_tokens: int = 512         # tiled: tokens per kernel block
+    block_docs: int = 16            # tiled: max docs per block
+    stream_blocks: bool = False     # tiled only: OUT-OF-CORE mode —
     # the packed token stream, z assignments, and doc counts stay
     # HOST-resident (the reference streams doc blocks from disk; SURVEY
     # §3.6 DataBlock role). Each superstep call stages one [S, B] slice
@@ -142,8 +133,7 @@ class LDAConfig:
     # comes from greedy packing of the LOCAL shard, so changing the
     # doc-to-process split (or process count) still changes
     # trajectories; only a fixed layout is deterministic.
-    mh_steps: int = 2               # MH: rounds of (word + doc) proposal
-    precision: str = "float32"      # posterior/CDF math dtype; bfloat16
+    precision: str = "float32"      # gibbs posterior/CDF dtype; bfloat16
     # is measured equal-speed at large batches (the op mix is not
     # bandwidth-bound there) and drops topics w/ conditional mass below
     # ~0.2% under bf16 CDF resolution — float32 is the safe default
@@ -241,13 +231,9 @@ class LightLDA:
                 f"token_words must lie in [0, vocab_size={self.V}); got "
                 f"ids from {int(token_words.min())} to "
                 f"{int(token_words.max())}")
-        if c.sampler == "mh" and len(token_docs) \
-                and np.any(np.diff(token_docs) < 0):
-            # doc_start offsets (MH doc proposal) assume a doc-contiguous
-            # stream; an interleaved stream would silently sample the
-            # wrong doc's topics (gibbs is order-agnostic)
-            raise ValueError("token_docs must be doc-contiguous "
-                             "(non-decreasing doc ids) for sampler='mh'")
+        if c.sampler not in ("gibbs", "tiled"):
+            raise ValueError(f"sampler is one of gibbs | tiled, got "
+                             f"{c.sampler!r}")
         if c.precision not in ("float32", "bfloat16"):
             raise ValueError(f"precision must be 'float32' or 'bfloat16', "
                              f"got {c.precision!r}")
@@ -262,16 +248,18 @@ class LightLDA:
         self._sweep_done = 0
         self._resume_sweeps = 0
 
-        tiled = c.sampler == "tiled"
-        if tiled and self.K % 128:
+        # THE sampler decision: the doc-blocked stale sampler, or gibbs
+        self._docblock = c.sampler == "tiled"
+        if self._docblock and self.K % 128:
             raise ValueError(f"sampler='tiled' needs num_topics % 128 "
                              f"== 0, got {self.K}")
-        if (c.stale_words or c.doc_blocked) and not tiled:
+        if (c.stale_words or c.doc_blocked) and not self._docblock:
             raise ValueError(
-                f"stale_words/doc_blocked are sampler='tiled' modes; "
+                f"stale_words/doc_blocked are what sampler='tiled' is; "
                 f"got sampler={c.sampler!r}")
-        if c.stream_blocks and not c.doc_blocked:
-            raise ValueError("stream_blocks requires doc_blocked=True")
+        if c.stream_blocks and not self._docblock:
+            raise ValueError(f"stream_blocks requires sampler='tiled', "
+                             f"got sampler={c.sampler!r}")
         if c.local_corpus and not c.stream_blocks:
             raise ValueError("local_corpus requires stream_blocks=True")
         if c.local_corpus and jax.process_count() > 1:
@@ -292,24 +280,15 @@ class LightLDA:
         # local_corpus=True each process passes and packs ONLY its own
         # doc shard, so host RAM also scales 1/P — the reference's
         # workers-each-read-their-own-DataBlocks model.
-        # tiled samplers support dp x mp meshes: the int32 word-topic
-        # table stays row-sharded over the model axis (each chip holds a
-        # [V/mp] vocab slice — the reference's Meta vocab-slicing role)
-        # and the per-sweep master rebuild scatters each chip's data
-        # shard into its vocab slice, psum'd over the data axis. The
-        # stale modes' bf16 mirror is replicated over the model axis once
-        # a sweep (_build_stale_helpers), so the sweep's word-row gather
-        # is a local read; eval gathers from the sharded master
-        # (_build_word_gather).
         # the pallas kernel needs the Mosaic TPU backend; on a CPU mesh
         # (tests) it runs in interpreter mode
-        self._interpret = tiled and interpret_mode(self.mesh)
+        self._interpret = self._docblock and interpret_mode(self.mesh)
 
         # tables (the reference's server-side state); tiled storage puts
         # one word's topic row in exactly one (8,128) int32 tile
         self.word_topic = SparseMatrixTable(
             self.V, self.K, "int32", updater="default", mesh=self.mesh,
-            name=f"{name}_word_topic", tiled=tiled)
+            name=f"{name}_word_topic", tiled=self._docblock)
         self.summary = ArrayTable(self.K, "int32", updater="default",
                                   mesh=self.mesh, name=f"{name}_summary")
         self._scratch_word = self.word_topic.padded_shape[0] - 1
@@ -319,43 +298,29 @@ class LightLDA:
         # dump_model/store stay exact
         self._wt_view = client.maybe_cached_view(self.word_topic)
 
-        # worker-local doc-topic counts (+1 scratch doc for padded lanes);
-        # placed on the mesh, NOT the default device (platform may differ)
-        self._scratch_doc = self.num_docs
-        self._docblock = tiled and c.doc_blocked
-        # doc_blocked construction IS the stale-words model (no per-step
-        # word scatters; master rebuilt from z per sweep)
-        self._stale = tiled and (c.stale_words or c.doc_blocked)
-        ndk_dtype = np.int32
-        if self._stale:
-            max_len = int(np.bincount(token_docs).max()) \
-                if len(token_docs) else 0
-            if max_len >= 32767:
-                raise ValueError(
-                    f"stale_words stores doc counts int16; a document "
-                    f"has {max_len} tokens (>= 32767)")
-            ndk_dtype = np.int16
         if self._docblock:
-            # blocked layout replaces the dense [D+1, K] doc counts and
-            # the permuted-stream staging entirely
-            self._setup_docblock(token_words, token_docs, ndk_dtype)
+            self._setup_docblock(token_words, token_docs)
             if c.stream_blocks:
                 self._build_docblock_stream_superstep()
                 self._init_streamed_counts()
             else:
                 self._build_docblock_superstep()
-            self._key = core.prng_key(c.seed, mesh=self.mesh)
-            self._calls_done = 0
-            self.ll_history = []
-            self._last_store = ()
-            return
+        else:
+            self._setup_gibbs_stream(token_words, token_docs)
+            self._init_counts()
+            self._build_superstep()
+        self._key = core.prng_key(c.seed, mesh=self.mesh)
+        self._calls_done = 0
+        self.ll_history: list = []
+        self._last_store = ()
 
-        ndk_shape = (self.num_docs + 1, self.K // 128, 128) if tiled \
-            else (self.num_docs + 1, self.K)
-        self._ndk = core.place(np.zeros(ndk_shape, ndk_dtype),
-                               mesh=self.mesh)
+    # -- gibbs stream / state ----------------------------------------------
 
-        # token stream, padded to a whole number of superstep calls
+    def _setup_gibbs_stream(self, token_words, token_docs) -> None:
+        """The ``gibbs`` sampler's staging: the shuffled token stream
+        padded to whole superstep calls and placed once, and random
+        initial assignments."""
+        c = self.config
         B, S = c.batch_tokens, c.steps_per_call
         d_axis = self.mesh.shape[core.DATA_AXIS]
         if B % d_axis:
@@ -367,7 +332,7 @@ class LightLDA:
         self._mask[: self.num_tokens] = True
         tw = np.full(T_pad, self._scratch_word, np.int32)
         tw[: self.num_tokens] = token_words
-        td = np.full(T_pad, self._scratch_doc, np.int32)
+        td = np.full(T_pad, self.num_docs, np.int32)   # +1 scratch doc
         td[: self.num_tokens] = token_docs
         # shuffle the stream: doc-contiguous order would put a whole doc
         # in one batch, zeroing its doc-topic row under the batch-stale
@@ -386,65 +351,20 @@ class LightLDA:
         for call in range(self.calls_per_sweep):
             lo = call * call_tokens
             sl = slice(lo, lo + call_tokens)
-            if tiled:
-                # z positions are contiguous per scan step: pass scalar
-                # offsets and dynamic-slice z (a [B]-index gather/scatter
-                # of z costs ~7-10ms/step, measured — a slice is free)
-                offs = np.arange(lo, lo + call_tokens, B, dtype=np.int32)
-                self._calls.append((
-                    self._place(self._tw[sl].reshape(S, B), spec),
-                    self._place(self._td[sl].reshape(S, B), spec),
-                    self._place(offs, P()),
-                    self._place(self._mask[sl].reshape(S, B)
-                                .astype(np.int32), spec)))
-            else:
-                self._calls.append(tuple(
-                    self._place(a[sl].reshape(S, B), spec) for a in
-                    (self._tw, self._td,
-                     np.arange(T_pad, dtype=np.int32),
-                     self._mask.astype(np.int32))))
-
-        if c.sampler == "mh":
-            # doc structure for the MH doc-proposal (z-array trick): the
-            # stream is doc-contiguous (validated above), so doc d's
-            # tokens live at original positions [doc_start[d],
-            # doc_start[d]+doc_len[d]); inv_perm maps an original
-            # position to its shuffled position (= the z index space).
-            # One scratch-doc entry covers padding. Gibbs never touches
-            # these — don't spend the [T_pad] device memory there.
-            doc_len = np.bincount(token_docs, minlength=self.num_docs) \
-                if len(token_docs) else np.zeros(self.num_docs, np.int64)
-            doc_len = np.append(doc_len, max(T_pad - self.num_tokens, 1))
-            doc_start = np.concatenate([[0], np.cumsum(doc_len)])[:-1]
-            self._doc_len = self._place(doc_len.astype(np.int32), P())
-            self._doc_start = self._place(doc_start.astype(np.int32), P())
-            self._inv_perm = self._place(np.argsort(perm).astype(np.int32),
-                                         P())
-
-        # random initial assignments + count build (one jitted scatter)
+            self._calls.append(tuple(
+                self._place(a[sl].reshape(S, B), spec) for a in
+                (self._tw, self._td, np.arange(T_pad, dtype=np.int32),
+                 self._mask.astype(np.int32))))
+        # random initial assignments (the count build is _init_counts)
         rng = np.random.default_rng(c.seed)
         z0 = rng.integers(0, self.K, T_pad).astype(np.int32)
         self._z = self._place(z0, P())
-        self._init_counts()
-        if tiled:
-            self._build_tiled_superstep()
-        else:
-            self._build_superstep()
-        if c.sampler == "mh":
-            self._build_mh_superstep()
-        elif c.sampler not in ("gibbs", "tiled"):
-            raise ValueError(f"sampler must be 'gibbs', 'mh' or 'tiled', "
-                             f"got {c.sampler!r}")
-        self._key = core.prng_key(c.seed, mesh=self.mesh)
-        self._calls_done = 0
-        self.ll_history: list = []
-        self._last_store = ()
 
     # -- doc-blocked stream / state ---------------------------------------
 
-    def _setup_docblock(self, token_words, token_docs, ndk_dtype) -> None:
+    def _setup_docblock(self, token_words, token_docs) -> None:
         """Pack the doc-sorted stream into whole-doc kernel blocks and
-        build the blocked doc-topic counts (see LDAConfig.doc_blocked)."""
+        build the blocked int16 doc-topic counts."""
         c = self.config
         TB, MAXD = c.block_tokens, c.block_docs
         B, S = c.batch_tokens, c.steps_per_call
@@ -465,6 +385,10 @@ class LightLDA:
             doc_ends = np.append(doc_starts[1:], len(td)) if len(td) \
                 else doc_starts
             lens = doc_ends - doc_starts
+            if len(lens) and lens.max() >= 32767:
+                raise ValueError(
+                    f"the doc-blocked sampler stores doc counts int16; a "
+                    f"document has {lens.max()} tokens (>= 32767)")
             if len(lens) and lens.max() > TB:
                 raise ValueError(f"a document has {lens.max()} tokens > "
                                  f"block_tokens {TB}")
@@ -533,7 +457,7 @@ class LightLDA:
                 self._row_of_doc[doc_ids] = row
             fill = mask_p.sum() / max(nb_alloc * TB, 1)
             self.packing_fill = float(fill)
-            log.info("lda doc_blocked: %d blocks (%d/call, %.0f%% fill)",
+            log.info("lda tiled: %d blocks (%d/call, %.0f%% fill)",
                      nb_alloc, cap, 100 * fill)
 
             # init z — shared by both residency modes so the streamed and
@@ -599,9 +523,9 @@ class LightLDA:
             nwk = jnp.zeros(self.word_topic.storage_shape, jnp.int32)
             nwk = nwk.at[tw_flat, zf // 128, zf % 128].add(m_flat)
             rows = (jnp.arange(nb_pad)[:, None] * MAXD + drel).reshape(-1)
-            ndk = jnp.zeros((nb_pad * MAXD, tiles, 128), ndk_dtype)
+            ndk = jnp.zeros((nb_pad * MAXD, tiles, 128), jnp.int16)
             ndk = ndk.at[rows, zf // 128, zf % 128].add(
-                m_flat.astype(ndk_dtype))
+                m_flat.astype(jnp.int16))
             nk = jnp.zeros(self.summary.padded_shape, jnp.int32)
             nk = nk.at[zf].add(m_flat)
             return nwk, ndk.reshape(nb_pad, MAXD, tiles, 128), nk
@@ -647,33 +571,14 @@ class LightLDA:
                          in_specs=(P(m, None, None), P(d)),
                          out_specs=P(d, None, None), check_vma=False)
 
-    def _wrap_kernel_dp(self, fn):
+    def _wrap_docblock_dp(self, fn):
         """Multi-chip dispatch for the pallas sampler: a Mosaic custom
         call cannot be auto-partitioned by XLA, so on any multi-device
-        mesh each chip runs the kernel on its own token shard via
-        ``shard_map`` (token shards over the data axis, operands
-        replicated over the model axis) and the topic-summary delta is
-        psum'd over ICI."""
-        if self.mesh.devices.size == 1:
-            return fn
-        from jax import shard_map
-        d = core.DATA_AXIS
-        Pb = P(d)
-        Pb3 = P(d, None, None)
-
-        def local(A3, W3, sinv, zi, msk, u1, u2):
-            znew, nkd = fn(A3, W3, sinv, zi, msk, u1, u2)
-            return znew, lax.psum(nkd, d)
-
-        return shard_map(
-            local, mesh=self.mesh,
-            in_specs=(Pb3, Pb3, P(None, None), Pb, Pb, Pb, Pb),
-            out_specs=(Pb, P(None, None)), check_vma=False)
-
-    def _wrap_docblock_dp(self, fn):
-        """Doc-blocked analog of :meth:`_wrap_kernel_dp`: kernel blocks
-        shard over the data axis (each chip exclusively owns its blocks'
-        doc counts — the block layout IS the DP partition)."""
+        mesh each chip runs the kernel on its own kernel blocks via
+        ``shard_map`` (blocks over the data axis — each chip exclusively
+        owns its blocks' doc counts, the block layout IS the DP
+        partition; operands replicated over the model axis) and the
+        topic-summary delta is psum'd over ICI."""
         if self.mesh.devices.size == 1:
             return fn
         from jax import shard_map
@@ -719,9 +624,9 @@ class LightLDA:
                          check_vma=False)
 
     def _build_stale_helpers(self) -> None:
-        """Per-sweep word-count helpers shared by the stale modes: the
-        bf16 gather mirror and the int32 master rebuild from z (z may be
-        the flat stream or the blocked packing — flattened either way).
+        """Per-sweep word-count helpers of the doc-blocked sampler,
+        resident and streamed: the bf16 gather mirror and the int32
+        master rebuild from the blocked z (flattened).
 
         The int32 master stays sharded over the model axis: the rebuild
         scatters each chip's DATA shard of the stream into its own vocab
@@ -818,12 +723,10 @@ class LightLDA:
         return run
 
     def _build_blocked_loglik(self) -> None:
-        """Eval over tile-aligned doc counts, shared by tiled and
-        doc-blocked layouts: ``rows`` index the flattened [*, C, 128]
-        doc-count storage (plain doc ids for the dense layout, packed
-        block rows for doc_blocked). Word rows come through the sharded
-        gather, so eval never materialises the full [V, K] on one chip
-        under model parallelism."""
+        """Eval over the blocked doc counts: ``rows`` index the flattened
+        [*, C, 128] doc-count storage (packed block rows). Word rows come
+        through the sharded gather, so eval never materialises the full
+        [V, K] on one chip under model parallelism."""
         K = self.K
         tiles = K // 128
         run = self._chunked_ll(self._build_word_gather())
@@ -852,7 +755,7 @@ class LightLDA:
         dp = self.mesh.shape[core.DATA_AXIS]
         if nbs % dp:
             raise ValueError(
-                f"doc_blocked: blocks per step {nbs} not divisible by "
+                f"tiled: blocks per step {nbs} not divisible by "
                 f"data-axis size {dp}")
         tiles = K // 128
         interpret = self._interpret
@@ -983,7 +886,7 @@ class LightLDA:
         dp = self.mesh.shape[core.DATA_AXIS]
         if nbs % dp:
             raise ValueError(
-                f"doc_blocked: blocks per step {nbs} not divisible by "
+                f"tiled: blocks per step {nbs} not divisible by "
                 f"data-axis size {dp}")
         tiles = K // 128
         scratch = self._scratch_word
@@ -1294,35 +1197,25 @@ class LightLDA:
     # -- count init --------------------------------------------------------
 
     def _init_counts(self) -> None:
-        tiled = self.config.sampler == "tiled"
-        ndk_dtype = self._ndk.dtype
-
+        """The ``gibbs`` stream's count build (one jitted scatter); the
+        dense doc-topic counts are worker-local, +1 scratch doc for the
+        padded lanes."""
         @jax.jit
         def build(z, tw, td, m):
             nwk = jnp.zeros(self.word_topic.storage_shape, jnp.int32)
-            ndk = jnp.zeros(self._ndk.shape, ndk_dtype)
-            if tiled:
-                nwk = nwk.at[tw, z // 128, z % 128].add(m)
-                ndk = ndk.at[td, z // 128, z % 128].add(
-                    m.astype(ndk_dtype))
-            else:
-                nwk = nwk.at[tw, z].add(m)
-                ndk = ndk.at[td, z].add(m.astype(ndk_dtype))
+            nwk = nwk.at[tw, z].add(m)
+            ndk = jnp.zeros((self.num_docs + 1, self.K), jnp.int32)
+            ndk = ndk.at[td, z].add(m)
             nk = jnp.zeros(self.summary.padded_shape, jnp.int32)
             nk = nk.at[z].add(m)
             return nwk, ndk, nk
 
-        tw_dev = self._place(self._tw, P())
-        m_dev = self._place(self._mask.astype(np.int32), P())
-        nwk, ndk, nk = build(self._z, tw_dev,
-                             self._place(self._td, P()), m_dev)
+        nwk, ndk, nk = build(
+            self._z, self._place(self._tw, P()), self._place(self._td, P()),
+            self._place(self._mask.astype(np.int32), P()))
         self.word_topic.put_raw(nwk)
         self._ndk = ndk
         self.summary.put_raw(nk)
-        if self._stale:
-            # the per-sweep master rebuild scatters over the full stream
-            self._tw_dev = tw_dev
-            self._mask_dev = m_dev
 
     # -- the Gibbs superstep ----------------------------------------------
 
@@ -1385,16 +1278,6 @@ class LightLDA:
                                      name="lda_gibbs")
 
         @jax.jit
-        def build_wcdf(nwk):
-            # stale word-proposal CDF over (N_wk + beta), one row per
-            # padded vocab row; rebuilt once per sweep like the
-            # reference's per-slice alias tables
-            return jnp.cumsum(
-                jnp.maximum(nwk.astype(jnp.float32), 0.0) + beta, axis=1)
-
-        self._build_wcdf = build_wcdf
-
-        @jax.jit
         def loglik(nwk, ndk, nk, ws, ds, mask):
             # operands are the pre-placed [S, B] superstep inputs (mask
             # int32) — flatten here rather than re-uploading the corpus
@@ -1408,219 +1291,6 @@ class LightLDA:
 
         self._loglik = loglik
 
-    def _build_tiled_superstep(self) -> None:
-        """The measured-fastest sampler: tile-aligned counts + the fused
-        pallas posterior/sampler (multiverso_tpu.ops.gibbs_sample_tiled).
-
-        Differences from the exact 'gibbs' body (all within the AD-LDA
-        approximation family the reference itself lives in — see module
-        docstring):
-        - own-token removal is in-register on the numerator counts (no
-          upfront decrement scatters); the summary denominator keeps the
-          own count (+1 in a ~T/K-sized denominator),
-        - counts move by NET scatters (-1 old, +1 new), halving scatter
-          traffic,
-        - the summary delta comes out of the kernel (no [B, K] one-hot
-          reductions in HBM).
-        """
-        c = self.config
-        alpha, beta = self.alpha, self.beta
-        vbeta = self.V * beta
-        K = self.K
-        B = c.batch_tokens
-        tiles = K // 128
-        interpret = self._interpret
-        stale = self._stale
-        from multiverso_tpu.ops import gibbs_sample_tiled
-        sampler_call = self._wrap_kernel_dp(
-            lambda A3, W3, sinv, zi, msk, u1, u2: gibbs_sample_tiled(
-                A3, W3, sinv, zi, msk, u1, u2, alpha=alpha, beta=beta,
-                interpret=interpret))
-
-        def sample_and_update(nk, ndk3, z, W3, w, d, off, msk, key):
-            """Shared step core: sample the slice, move doc/summary
-            counts. Returns (nk, ndk3, z, zi, znew)."""
-            zi = lax.dynamic_slice_in_dim(z, off, B)
-            A3 = jnp.take(ndk3, d, axis=0)              # [B, C, 128]
-            sinv = 1.0 / (nk[:K].astype(jnp.float32).reshape(tiles, 128)
-                          + vbeta)
-            k1, k2 = jax.random.split(key)
-            u1 = jax.random.uniform(k1, (B,))
-            u2 = jax.random.uniform(k2, (B,))
-            znew, nkd = sampler_call(A3, W3, sinv, zi, msk, u1, u2)
-            one = msk.astype(ndk3.dtype)
-            cold, lold = zi // 128, zi % 128
-            cnew, lnew = znew // 128, znew % 128
-            ndk3 = ndk3.at[d, cold, lold].add(-one)
-            ndk3 = ndk3.at[d, cnew, lnew].add(one)
-            nk = nk.at[:K].add(nkd.reshape(-1))
-            z = lax.dynamic_update_slice_in_dim(z, znew, off, 0)
-            return nk, ndk3, z, zi, znew
-
-        if stale:
-            # word rows from the per-sweep bf16 mirror (a local read:
-            # every chip holds it whole); no per-step word-count
-            # scatters (master rebuilt from z at sweep end)
-            self._build_stale_helpers()
-
-            def scan_body(wstale, carry, inp):
-                nk, ndk3, z = carry
-                w, d, off, msk, key = inp
-                W3 = _take_word_rows(wstale, w)
-                nk, ndk3, z, _, _ = sample_and_update(
-                    nk, ndk3, z, W3, w, d, off, msk, key)
-                return (nk, ndk3, z), ()
-
-            def body(params, states, locals_, options, wstale, ws, ds,
-                     offs, msks, key):
-                (nk,) = params
-                ndk3, z = locals_
-                keys = jax.random.split(key, ws.shape[0])
-                (nk, ndk3, z), _ = lax.scan(
-                    lambda cy, inp: scan_body(wstale, cy, inp),
-                    (nk, ndk3, z), (ws, ds, offs, msks, keys))
-                return (nk,), states, (ndk3, z), None
-
-            self._fused = make_superstep((self.summary,), body,
-                                         name="lda_tiled_stale")
-        else:
-            def scan_body(carry, inp):
-                nwk3, nk, ndk3, z = carry
-                w, d, off, msk, key = inp
-                W3 = jnp.take(nwk3, w, axis=0)
-                nk, ndk3, z, zi, znew = sample_and_update(
-                    nk, ndk3, z, W3, w, d, off, msk, key)
-                one = msk
-                nwk3 = nwk3.at[w, zi // 128, zi % 128].add(-one)
-                nwk3 = nwk3.at[w, znew // 128, znew % 128].add(one)
-                return (nwk3, nk, ndk3, z), ()
-
-            def body(params, states, locals_, options, ws, ds, offs,
-                     msks, key):
-                nwk3, nk = params
-                ndk3, z = locals_
-                keys = jax.random.split(key, ws.shape[0])
-                (nwk3, nk, ndk3, z), _ = lax.scan(
-                    scan_body, (nwk3, nk, ndk3, z),
-                    (ws, ds, offs, msks, keys))
-                return (nwk3, nk), states, (ndk3, z), None
-
-            self._fused = make_superstep(
-                (self.word_topic, self.summary), body, name="lda_tiled")
-
-        self._build_blocked_loglik()
-
-    def _build_mh_superstep(self) -> None:
-        """The O(1)-per-token sampler, LightLDA's own sparsity insight
-        vectorized for TPU (no [B, K] tensors anywhere):
-
-        - word proposal: inverse-CDF binary search over the per-sweep
-          stale CDF table — ceil(log2 K) scalar gathers per token,
-        - doc proposal: the z-array trick — sample a random slot of the
-          token's doc and copy its live topic (one gather), alpha-smoothed
-          uniform with the standard mixture probability,
-        - acceptance: full MH ratio with LIVE counts (single-element
-          gathers) against the stale proposal densities.
-        """
-        c = self.config
-        alpha, beta = self.alpha, self.beta
-        vbeta = self.V * beta
-        K = self.K
-        n_search = max(1, (K - 1).bit_length())
-        doc_len, doc_start = self._doc_len, self._doc_start
-        inv_perm = self._inv_perm
-
-        def body(wcdf, nwk_stale, carry, inp):
-            nwk, ndk, nk, z = carry
-            w, d, idx, msk, key = inp
-            zi = jnp.take(z, idx)
-            one = msk
-            nwk = nwk.at[w, zi].add(-one)
-            ndk = ndk.at[d, zi].add(-one)
-            # one-hot reduction, not an element scatter (see gibbs body)
-            oh_old = jax.nn.one_hot(zi, K, dtype=jnp.int32) * one[:, None]
-            nk = nk.at[:K].add(-oh_old.sum(0))
-
-            def p_live(k):
-                # collapsed posterior factor from LIVE counts (own token
-                # removed); clamp transient negatives (AD-LDA)
-                return (jnp.maximum(ndk[d, k].astype(jnp.float32) + alpha,
-                                    1e-12)
-                        * jnp.maximum(nwk[w, k].astype(jnp.float32) + beta,
-                                      1e-12)
-                        / jnp.maximum(nk[k].astype(jnp.float32) + vbeta,
-                                      1e-12))
-
-            def q_word(k):
-                # stale proposal density from the pre-sweep count snapshot
-                # (differencing the f32 CDF instead would cancel
-                # catastrophically for low-count topics of frequent words)
-                return nwk_stale[w, k].astype(jnp.float32) + beta
-
-            cur = zi
-            wtot = wcdf[w, K - 1]
-            dlen = jnp.take(doc_len, d).astype(jnp.float32)
-            dstart = jnp.take(doc_start, d)
-            keys = jax.random.split(key, 5 * c.mh_steps)
-            for r in range(c.mh_steps):
-                k1, k2, k3, k4, k5 = keys[5 * r: 5 * r + 5]
-                # --- word proposal ---
-                target = jax.random.uniform(k1, w.shape) * wtot
-                lo = jnp.zeros_like(cur)
-                hi = jnp.full_like(cur, K)
-                for _ in range(n_search):
-                    mid = (lo + hi) // 2
-                    go = wcdf[w, mid] < target
-                    lo = jnp.where(go, mid + 1, lo)
-                    hi = jnp.where(go, hi, mid)
-                prop = jnp.clip(lo, 0, K - 1)
-                ratio = (p_live(prop) * q_word(cur)
-                         / (p_live(cur) * q_word(prop)))
-                acc = jax.random.uniform(k2, w.shape) < ratio
-                cur = jnp.where(acc, prop, cur)
-                # --- doc proposal (z-array trick) ---
-                pa = (K * alpha) / (dlen + K * alpha)
-                slot = jnp.minimum(
-                    (jax.random.uniform(k3, w.shape) * dlen)
-                    .astype(jnp.int32),
-                    jnp.maximum(dlen.astype(jnp.int32) - 1, 0))
-                zslot = jnp.take(z, jnp.take(inv_perm, dstart + slot))
-                unif = jax.random.randint(k4, w.shape, 0, K)
-                u = jax.random.uniform(k5, w.shape)
-                prop = jnp.where(u < pa, unif, zslot)
-                # z-array density includes the own token (z[idx] still
-                # holds zi): q_d(k) = ndk^- (d,k) + [k==zi] + alpha
-                def q_doc(k):
-                    return (ndk[d, k].astype(jnp.float32)
-                            + (k == zi).astype(jnp.float32) + alpha)
-                ratio = (p_live(prop) * q_doc(cur)
-                         / jnp.maximum(p_live(cur) * q_doc(prop), 1e-20))
-                acc = jax.random.uniform(
-                    jax.random.fold_in(k5, 1), w.shape) < ratio
-                cur = jnp.where(acc, prop, cur)
-
-            znew = jnp.where(msk > 0, cur, zi)
-            nwk = nwk.at[w, znew].add(one)
-            ndk = ndk.at[d, znew].add(one)
-            oh_new = jax.nn.one_hot(znew, K, dtype=jnp.int32) \
-                * one[:, None]
-            nk = nk.at[:K].add(oh_new.sum(0))
-            z = z.at[idx].set(znew)
-            return (nwk, ndk, nk, z), ()
-
-        def fused_body(params, states, locals_, options, wcdf, nwk_stale,
-                       ws, ds, idxs, msks, key):
-            nwk, nk = params
-            ndk, z = locals_
-            keys = jax.random.split(key, ws.shape[0])
-            (nwk, ndk, nk, z), _ = lax.scan(
-                lambda carry, inp: body(wcdf, nwk_stale, carry, inp),
-                (nwk, ndk, nk, z), (ws, ds, idxs, msks, keys))
-            return (nwk, nk), states, (ndk, z), None
-
-        self._fused_mh = make_superstep(
-            (self.word_topic, self.summary), fused_body, name="lda_mh")
-
     def _place(self, arr: np.ndarray, spec) -> jax.Array:
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
@@ -1632,7 +1302,7 @@ class LightLDA:
         cast (``lda.to_stale``), every superstep call (``lda.dispatch``)
         and the master rebuild (``lda.rebuild``) nest inside it."""
         with telemetry.span("lda.sweep"):
-            if self._docblock and self.config.stream_blocks:
+            if self.config.stream_blocks:
                 self._sweep_streamed()
             else:
                 self._sweep_resident()
@@ -1647,39 +1317,20 @@ class LightLDA:
         return wstale
 
     def _sweep_resident(self) -> None:
-        mh = self.config.sampler == "mh"
-        if mh:
-            wcdf = self._build_wcdf(self.word_topic.raw())
-            # pre-sweep snapshot for the stale proposal density (the live
-            # param buffer is donated by the first superstep call)
-            nwk_stale = self.word_topic.raw() + 0
-        if self._stale:
-            wstale = self._refresh_mirror()
+        # the doc-blocked body takes the sweep's mirror before its lanes
+        mirror = (self._refresh_mirror(),) if self._docblock else ()
         for call in self._calls:
             key = jax.random.fold_in(self._key, self._calls_done)
             self._calls_done += 1
             with telemetry.span("lda.dispatch"):
-                if mh:
-                    ws, ds, idxs, msks = call
-                    (self._ndk, self._z), _ = self._fused_mh(
-                        (self._ndk, self._z), wcdf, nwk_stale,
-                        ws, ds, idxs, msks, key)
-                elif self._stale:
-                    (self._ndk, self._z), _ = self._fused(
-                        (self._ndk, self._z), wstale, *call, key)
-                else:
-                    (self._ndk, self._z), _ = self._fused(
-                        (self._ndk, self._z), *call, key)
-        if self._stale:
+                (self._ndk, self._z), _ = self._fused(
+                    (self._ndk, self._z), *mirror, *call, key)
+        if self._docblock:
             # fold the sweep's moves into the int32 master (the
             # reference's block-end Add of accumulated deltas)
             with telemetry.span("lda.rebuild"):
-                if self._docblock:
-                    nwk = self._rebuild(self._z, self._tw_flat,
-                                        self._mask_flat)
-                else:
-                    nwk = self._rebuild(self._z, self._tw_dev,
-                                        self._mask_dev)
+                nwk = self._rebuild(self._z, self._tw_flat,
+                                    self._mask_flat)
                 self.word_topic.put_raw(nwk)
 
     def train(self, num_iterations: Optional[int] = None) -> float:
@@ -1743,7 +1394,7 @@ class LightLDA:
         `Eval` role). Evaluates over the pre-placed device-resident call
         slices — the token stream is static, so no host re-upload."""
         total = 0.0
-        if self._docblock and self.config.stream_blocks:
+        if self.config.stream_blocks:
             for _k, dev in self._stream_calls():
                 total += float(self._loglik_stream(
                     self.word_topic.raw(), self.summary.raw(), dev))
@@ -1768,7 +1419,7 @@ class LightLDA:
         call it in lockstep (an ``if rank == 0:`` guard deadlocks).
         Under ``local_corpus`` there is no sync: the returned counts
         cover THIS process's docs; other processes' rows are zero."""
-        if self._docblock and self.config.stream_blocks:
+        if self.config.stream_blocks:
             self._sync_z_host()
             # host-side scatter over the host-resident z (chunked: the
             # temporaries stay bounded regardless of corpus size)
@@ -1797,7 +1448,7 @@ class LightLDA:
     def assignments(self) -> np.ndarray:
         """int32[num_tokens]: every token's current topic, in the order
         the corpus was handed to the constructor — whatever the sampler
-        mode did to the stream (doc sort and block packing, or the fixed
+        did to the stream (doc sort and block packing, or the fixed
         shuffle). One read of z from the device.
 
         Multi-process ``stream_blocks``: a COLLECTIVE, like
@@ -1888,9 +1539,7 @@ class LightLDA:
                 # z is indexed in the packed block layout; ndk exports
                 # as the dense [D, K] logical counts (the in-memory
                 # loader rebuilds its blocked counts from it)
-                ndk_dtype = np.int16 if self.config.stream_blocks \
-                    else np.dtype(self._ndk.dtype)
-                dense = np.zeros((self.num_docs + 1, self.K), ndk_dtype)
+                dense = np.zeros((self.num_docs + 1, self.K), np.int16)
                 dense[:self.num_docs] = self.doc_topics()
                 if self.config.stream_blocks:
                     self._sync_z_host()
@@ -2039,10 +1688,10 @@ class LightLDA:
                     f"checkpoint block geometry {got} != app {want}: "
                     "z packing must match to resume")
         # T_pad depends on batch_tokens * steps_per_call (and the block
-        # packing for doc_blocked): a geometry mismatch would yield a
+        # packing under tiled): a geometry mismatch would yield a
         # wrong-length z whose out-of-range scatters silently corrupt
         # counts (JAX clamps/drops OOB indices)
-        streamed = self._docblock and self.config.stream_blocks
+        streamed = self.config.stream_blocks
         z_shape = self._z_host.shape if streamed else self._z.shape
         if len(data["z"]) != int(np.prod(z_shape)):
             raise ValueError(
@@ -2120,7 +1769,8 @@ def main(argv=None) -> None:
                             "sparse text model dump (word k:count ...)",
                             overwrite=True)
     configure.define_string("sampler", "gibbs",
-                            "gibbs | mh | tiled (K%128==0; TPU kernel)",
+                            "gibbs | tiled (the doc-blocked pallas "
+                            "sampler; K%128==0)",
                             overwrite=True)
     configure.define_int("checkpoint_interval", 0,
                          "store -output_file every N sweeps (0 = only "

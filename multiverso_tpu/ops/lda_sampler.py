@@ -1,15 +1,19 @@
-"""Fused collapsed-Gibbs posterior+sampler Pallas kernel for LDA.
+"""Fused collapsed-Gibbs posterior+sampler Pallas kernels for LDA: the
+doc-blocked sampler of apps/lightlda.py (``sampler="tiled"``), resident
+(:func:`gibbs_sample_docblock`) and streamed
+(:func:`gibbs_sample_docblock_build`).
 
-Why a kernel (measured on v5e, benchmarks/experiments/lda_tile_probe.py):
-the XLA posterior+sample pipeline costs ~57 ms per 500k-token step beyond
-the count-row gathers — XLA materializes ~6 [B, K]-sized HBM
-intermediates (float posterior, CDF, one-hots, layout copies). This
-kernel keeps everything after the gathers in VMEM: per block of TB
-tokens it forms the collapsed posterior over the [C, 128] topic tile,
-draws by two-level inverse-CDF (chunk totals via a triangular matmul —
-cumsum has no Pallas TPU lowering — then within-chunk lanes), and
-accumulates the topic-summary delta across the sequential grid. Measured
-~15 ms/step for the same work (3.8x).
+Why a kernel: the plain-XLA posterior+sample pipeline materializes
+several [B, K]-sized HBM intermediates beyond the count-row gathers
+(float posterior, CDF, one-hots, layout copies). These kernels keep
+everything after the word-row gather in VMEM: per block of TB tokens of
+WHOLE documents they read (or build) the block's doc-topic counts, form
+the collapsed posterior over the [C, 128] topic tile, draw by two-level
+inverse-CDF (chunk totals via a triangular matmul — cumsum has no Pallas
+TPU lowering — then within-chunk lanes), move the block's doc counts and
+accumulate the topic-summary delta across the sequential grid. Their
+share of a sweep and of their roofline: PERF.md §5
+(``lda_sampler_roofline``).
 
 Semantics (the same approximation stack as the reference's own
 distributed sampler — AD-LDA, see apps/lightlda.py):
@@ -24,9 +28,8 @@ Counts must be tile-aligned: [*, C, 128] with K = C*128, so one logical
 row is one (8,128) int32 tile (4 KB payload per random row access).
 
 Reference: LightLDA's `LightDocSampler` role (SURVEY.md §3.6) — the O(1)
-MH machinery is replaced by an exact O(K) vectorized posterior, which on
-TPU is the faster AND better-mixing design (module docstring of
-apps/lightlda.py).
+MH machinery is replaced by an exact O(K) vectorized posterior (module
+docstring of apps/lightlda.py).
 """
 
 from __future__ import annotations
@@ -45,7 +48,7 @@ LANES = 128
 # need ~17 MiB — just past Mosaic's 16 MiB default scoped-VMEM limit
 # (the bf16/int16 production operands fit under it). v5e has 128 MiB of
 # VMEM per core; 32 MiB covers every dtype the samplers accept at the
-# block sizes _pick_tb / block_tokens produce.
+# block sizes block_tokens produces.
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2 ** 20)
 
 
@@ -88,116 +91,6 @@ def _two_level_draw(probs, kc, u1, u2, c: int):
         (scdf < t2).astype(jnp.int32).sum(1, keepdims=True), LANES - 1)
     return sel_c * LANES + lane
 
-
-def _kernel(A_ref, W_ref, sinv_ref, zi_ref, msk_ref, u1_ref, u2_ref,
-            znew_ref, nkd_ref, *, alpha: float, beta: float, tb: int,
-            c: int):
-    """One grid block: posterior for TB tokens -> znew; nk delta
-    accumulated across the (sequential on TPU) grid into nkd_ref."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        nkd_ref[:] = jnp.zeros_like(nkd_ref)
-
-    # count rows may arrive int32, int16 (doc counts) or bf16 (stale
-    # word-count mirror): cast to f32 FIRST, subtract after -- int counts
-    # here are < 2^24 so the cast is exact
-    A = A_ref[:].astype(jnp.float32)               # [TB, C, 128]
-    W = W_ref[:].astype(jnp.float32)
-    zi = zi_ref[:]                                 # [TB, 1] int32
-    one = msk_ref[:]                               # [TB, 1] int32
-    kc, kk = _lane_iotas(tb, c)
-    self_oh = ((kk == zi[:, :, None]) & (one[:, :, None] > 0))
-    soh = self_oh.astype(jnp.int32)
-    probs = _posterior(A, W, sinv_ref[:], soh.astype(jnp.float32),
-                       alpha, beta)
-    znew = jnp.where(one > 0,
-                     _two_level_draw(probs, kc, u1_ref[:], u2_ref[:], c),
-                     zi)
-    znew_ref[:] = znew
-    new_oh = ((kk == znew[:, :, None]) & (one[:, :, None] > 0))
-    nkd_ref[:] += (new_oh.astype(jnp.int32) - soh).sum(0)
-
-
-def _pick_tb(b: int, c: int) -> int:
-    """Largest multiple-of-8 divisor of b (at most 512) keeping the
-    [TB, C, 128] operand blocks + temporaries inside the VMEM limit
-    :data:`_COMPILER_PARAMS` sets."""
-    cap = max(8, min(512, (10 * 2 ** 20) // (c * LANES * 4 * 5)))
-    tb = 8
-    for cand in range(8, cap + 1, 8):
-        if b % cand == 0:
-            tb = cand
-    if b % tb:
-        raise ValueError(f"batch size {b} must be divisible by 8")
-    return tb
-
-
-@functools.partial(jax.jit, static_argnames=("alpha", "beta", "interpret"))
-def gibbs_sample_tiled(A3: jax.Array, W3: jax.Array, sinv: jax.Array,
-                       zi: jax.Array, msk: jax.Array, u1: jax.Array,
-                       u2: jax.Array, *, alpha: float, beta: float,
-                       interpret: bool = False):
-    """Draw new topics for a batch of tokens.
-
-    Args:
-      A3:   [B, C, 128] int32 — gathered doc-topic count rows (stale).
-      W3:   [B, C, 128] int32 — gathered word-topic count rows (stale).
-      sinv: [C, 128] float32 — 1 / (summary + V*beta).
-      zi:   [B] int32 — current topic assignments.
-      msk:  [B] int32 — 1 for real tokens, 0 for padded lanes.
-      u1, u2: [B] float32 — uniforms (two per token).
-      alpha, beta: LDA priors (static).
-      interpret: run the kernel in interpreter mode (CPU tests).
-
-    Returns:
-      (znew [B] int32, nk_delta [C, 128] int32) — new assignments and the
-      summary-count delta sum(onehot(znew) - onehot(zi)) over real tokens.
-    """
-    b, c, lanes = A3.shape
-    if lanes != LANES:
-        raise ValueError(f"last dim must be {LANES}, got {lanes}")
-    tb = _pick_tb(b, c)
-    kern = functools.partial(_kernel, alpha=float(alpha), beta=float(beta),
-                             tb=tb, c=c)
-    grid_spec = pl.GridSpec(
-        grid=(b // tb,),
-        in_specs=[
-            pl.BlockSpec((tb, c, LANES), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, c, LANES), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((c, LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((c, LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-    )
-    znew2, nkd = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((c, LANES), jnp.int32)],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-    )(A3, W3, sinv, zi[:, None], msk[:, None], u1[:, None], u2[:, None])
-    return znew2[:, 0], nkd
-
-
-# -- doc-blocked variant ---------------------------------------------------
 
 def _docblock_kernel(ndk_ref, W_ref, sinv_ref, zi_ref, drel_ref, msk_ref,
                      u1_ref, u2_ref, ndk_out_ref, znew_ref, nkd_ref, *,

@@ -103,6 +103,27 @@ def test_lightlda_cli(tmp_path):
     assert dump.exists() and dump.stat().st_size > 0
 
 
+def test_lightlda_cli_tiled_is_the_docblocked_sampler(tmp_path):
+    """``-sampler tiled`` reaches the sampler the benchmark's cells
+    measure (it built an intermediate rung before): the state it stores
+    is laid out in whole-document kernel blocks."""
+    from multiverso_tpu.apps import lightlda
+    from multiverso_tpu.data.corpus import synthetic_docs
+    from multiverso_tpu.tables.base import loadz_stream
+    docs = tmp_path / "d.txt"
+    synthetic_docs(str(docs), num_docs=120, vocab_size=150,
+                   avg_doc_len=30, seed=3)
+    out = tmp_path / "lda"
+    lightlda.main([f"-input_file={docs}", "-num_topics=128",
+                   "-num_iterations=2", "-eval_every=10",
+                   "-sampler=tiled", f"-output_file={out}"])
+    manifest, data = loadz_stream(f"{out}.state.npz",
+                                  "multiverso_tpu.lda_state.v1")
+    assert manifest["layout"] == "docblock"
+    assert (manifest["block_tokens"], manifest["block_docs"]) == (512, 16)
+    assert data["ndk"].dtype == np.int16
+
+
 def test_cli_flag_validation():
     """-sync=banana raises; unknown flags pass through as remainder."""
     from multiverso_tpu.utils import configure

@@ -17,6 +17,11 @@ def _clean_tables():
     table_base.reset_tables()
 
 
+# the doc-blocked sampler at this file's corpus: 8 blocks a step
+_TILED = dict(num_topics=128, batch_tokens=2048, steps_per_call=2, seed=1,
+              sampler="tiled", block_tokens=256, block_docs=8)
+
+
 @pytest.fixture(scope="module")
 def docs(tmp_path_factory):
     path = tmp_path_factory.mktemp("lda") / "docs.txt"
@@ -108,76 +113,6 @@ def test_matches_sequential_gibbs_oracle(mesh_dp8, docs):
         f"batch sampler ll {ours:.4f} vs oracle {oracle_ll:.4f}"
 
 
-def test_mh_sampler_converges_near_oracle(mesh_dp8, docs):
-    """The O(1) MH sampler must approach the same likelihood as exact
-    Gibbs (MH mixes somewhat slower per sweep; looser bound)."""
-    tw, td, V = docs
-    app = LightLDA(tw, td, V,
-                   LDAConfig(num_topics=8, batch_tokens=512,
-                             steps_per_call=4, seed=1, sampler="mh"),
-                   mesh=mesh_dp8, name="lda_mh")
-    app.train(num_iterations=15)
-    assert app.ll_history[-1] > app.ll_history[0] + 0.1, \
-        f"MH made no progress: {app.ll_history[0]:.4f} -> " \
-        f"{app.ll_history[-1]:.4f}"
-    # invariants survive the MH update path too
-    nwk = app.word_topics()
-    nk = np.asarray(app.summary.get())
-    assert nwk.sum() == app.num_tokens
-    assert np.array_equal(nk[: app.K], nwk.sum(0))
-    # absolute quality: within 0.3 nats of the exact-Gibbs level (~-4.45
-    # on this corpus after convergence; random init is ~-5.5)
-    assert app.ll_history[-1] > -4.8
-
-
-def test_tiled_sampler_invariants_and_quality(mesh_dp8, docs):
-    """The pallas tiled sampler (interpret mode on CPU) must keep count
-    invariants and reach the exact-Gibbs likelihood level (its AD-LDA
-    approximations — in-register self-removal, net-move scatters — must
-    not change mixing materially)."""
-    tw, td, V = docs
-    app = LightLDA(tw, td, V,
-                   LDAConfig(num_topics=128, batch_tokens=512,
-                             steps_per_call=4, seed=1, sampler="tiled"),
-                   mesh=mesh_dp8, name="lda_tiled")
-    app.train(num_iterations=6)
-    nwk = app.word_topics()
-    nk = np.asarray(app.summary.get())
-    ndk = app.doc_topics()
-    assert nwk.sum() == app.num_tokens
-    assert np.array_equal(nk[: app.K], nwk.sum(0))
-    assert np.array_equal(ndk.sum(1),
-                          np.bincount(td, minlength=app.num_docs))
-    assert (nwk >= 0).all() and (ndk >= 0).all() and (nk >= 0).all()
-    assert app.ll_history[-1] > app.ll_history[0] + 0.1
-    assert np.all(np.isfinite(app.ll_history))
-
-
-def test_tiled_stale_words_invariants_and_quality(mesh_dp8, docs):
-    """stale_words mode (per-sweep bf16 word mirror + int16 doc counts +
-    master rebuild from z) must preserve the count invariants at sweep
-    boundaries and still converge — this is the reference's own staleness
-    model (word rows fetched per slice, updates pushed at block end)."""
-    tw, td, V = docs
-    app = LightLDA(tw, td, V,
-                   LDAConfig(num_topics=128, batch_tokens=512,
-                             steps_per_call=4, seed=1, sampler="tiled",
-                             stale_words=True),
-                   mesh=mesh_dp8, name="lda_stale")
-    app.train(num_iterations=8)
-    nwk = app.word_topics()
-    nk = np.asarray(app.summary.get())
-    ndk = app.doc_topics()
-    assert nwk.sum() == app.num_tokens
-    assert np.array_equal(nk[: app.K], nwk.sum(0))
-    assert np.array_equal(ndk.sum(1),
-                          np.bincount(td, minlength=app.num_docs))
-    assert (nwk >= 0).all() and (ndk >= 0).all() and (nk >= 0).all()
-    assert app.ll_history[-1] > app.ll_history[0] + 0.1
-    # absolute quality: near the exact-Gibbs level on this corpus
-    assert app.ll_history[-1] > -4.9, app.ll_history
-
-
 def test_docblock_sampler_invariants_and_quality(mesh_dp8, docs):
     """doc_blocked: whole-doc kernel blocks own exclusive doc-count
     slices; all invariants must hold at sweep boundaries and mixing must
@@ -217,10 +152,11 @@ def test_docblock_checkpoint_roundtrip(mesh_dp8, docs, tmp_path):
     np.testing.assert_array_equal(app2.doc_topics(), app.doc_topics())
     app2.train(num_iterations=1)
     assert app2.word_topics().sum() == app2.num_tokens
-    # layout mismatch rejected: a stream-layout app can't load this z
+    # layout mismatch rejected: a gibbs app's z is indexed in its own
+    # shuffled stream and can't take this one
     app3 = LightLDA(tw, td, V,
                     LDAConfig(num_topics=128, batch_tokens=512,
-                              steps_per_call=4, seed=3, sampler="tiled"),
+                              steps_per_call=4, seed=3),
                     mesh=mesh_dp8, name="lda_dbc3")
     with pytest.raises(ValueError, match="layout"):
         app3.load(prefix)
@@ -238,12 +174,14 @@ def test_docblock_rejects_oversized_docs(mesh_dp8):
 
 
 def test_stale_words_rejects_giant_docs(mesh_dp8):
+    """Doc counts are int16: a document the block geometry would admit
+    (block_tokens above 32,767) is still refused."""
     tw = np.zeros(40000, np.int32)
     td = np.zeros(40000, np.int32)  # one 40k-token document
     with pytest.raises(ValueError, match="32767"):
         LightLDA(tw, td, 1,
                  LDAConfig(num_topics=128, sampler="tiled",
-                           stale_words=True),
+                           block_tokens=40960, batch_tokens=8 * 40960),
                  mesh=mesh_dp8, name="lda_giant")
 
 
@@ -252,25 +190,6 @@ def test_tiled_requires_lane_aligned_topics(mesh_dp8, docs):
     with pytest.raises(ValueError, match="128"):
         LightLDA(tw, td, V, LDAConfig(num_topics=100, sampler="tiled"),
                  mesh=mesh_dp8, name="lda_tiled_bad")
-
-
-def test_tiled_checkpoint_roundtrip(mesh_dp8, docs, tmp_path):
-    tw, td, V = docs
-    cfg = LDAConfig(num_topics=128, batch_tokens=512, steps_per_call=4,
-                    seed=3, sampler="tiled")
-    app = LightLDA(tw, td, V, cfg, mesh=mesh_dp8, name="lda_tc1")
-    app.train(num_iterations=2)
-    prefix = str(tmp_path / "tiled_ckpt")
-    app.store(prefix)
-    app2 = LightLDA(tw, td, V, cfg, mesh=mesh_dp8, name="lda_tc2")
-    app2.load(prefix)
-    np.testing.assert_array_equal(app2.word_topics(), app.word_topics())
-    np.testing.assert_array_equal(app2.doc_topics(), app.doc_topics())
-    np.testing.assert_array_equal(np.asarray(app2._z), np.asarray(app._z))
-    # resumed training stays consistent
-    app2.train(num_iterations=1)
-    nwk = app2.word_topics()
-    assert nwk.sum() == app2.num_tokens
 
 
 def test_dump_model_sparse_format(mesh_dp8, docs, tmp_path):
@@ -309,19 +228,6 @@ def test_eval_every_cadence(mesh_dp8, docs):
     # evals at sweeps 3, 6 and the final 7th
     assert len(app.ll_history) == 3
     assert np.all(np.isfinite(app.ll_history))
-
-
-def test_mh_interleaved_docs_rejected(mesh_dp8):
-    tw = np.array([0, 1, 2, 3], np.int32)
-    td = np.array([0, 1, 0, 1], np.int32)   # not doc-contiguous
-    with pytest.raises(ValueError, match="contiguous"):
-        LightLDA(tw, td, 4, LDAConfig(num_topics=4, batch_tokens=8,
-                                      steps_per_call=1, sampler="mh"),
-                 mesh=mesh_dp8, name="lda_interleaved")
-    # gibbs is order-agnostic: the same stream must be accepted
-    LightLDA(tw, td, 4, LDAConfig(num_topics=4, batch_tokens=8,
-                                  steps_per_call=1), mesh=mesh_dp8,
-             name="lda_interleaved_gibbs")
 
 
 def test_bad_precision_rejected(mesh_dp8, docs):
@@ -393,14 +299,9 @@ def test_docblock_zero_token_corpus(mesh_dp8):
     lda.sweep()
 
 
-def _run_docblock(mesh, docs, name, batch_tokens=2048):
+def _run_docblock(mesh, docs, name):
     tw, td, V = docs
-    app = LightLDA(tw, td, V,
-                   LDAConfig(num_topics=128, batch_tokens=batch_tokens,
-                             steps_per_call=2, seed=1, sampler="tiled",
-                             doc_blocked=True, block_tokens=256,
-                             block_docs=8),
-                   mesh=mesh, name=name)
+    app = LightLDA(tw, td, V, LDAConfig(**_TILED), mesh=mesh, name=name)
     app.train(num_iterations=3)
     return app
 
@@ -429,42 +330,6 @@ def test_docblock_model_parallel_matches_dp(devices, docs):
     np.testing.assert_allclose(app.ll_history[-1], ref_ll, rtol=1e-5)
     table_base.reset_tables()
     core.shutdown()
-
-
-def test_tiled_stale_model_parallel(mesh8, docs):
-    """sampler='tiled' + stale_words on a 4x2 mesh: invariants hold and
-    mixing reaches the exact-Gibbs band (the int32 word table is
-    vocab-sliced over the model axis, its bf16 mirror whole on every
-    chip)."""
-    tw, td, V = docs
-    app = LightLDA(tw, td, V,
-                   LDAConfig(num_topics=128, batch_tokens=512,
-                             steps_per_call=4, seed=1, sampler="tiled",
-                             stale_words=True),
-                   mesh=mesh8, name="lda_mp_stale")
-    app.train(num_iterations=8)
-    nwk = app.word_topics()
-    nk = np.asarray(app.summary.get())
-    assert nwk.sum() == app.num_tokens
-    assert np.array_equal(nk[: app.K], nwk.sum(0))
-    assert app.ll_history[-1] > app.ll_history[0] + 0.1
-    assert app.ll_history[-1] > -4.9, app.ll_history
-
-
-def test_tiled_exact_model_parallel(mesh8, docs):
-    """Plain tiled (exact per-step word scatters) on a 4x2 mesh rides
-    GSPMD for the sharded-table gathers/scatters."""
-    tw, td, V = docs
-    app = LightLDA(tw, td, V,
-                   LDAConfig(num_topics=128, batch_tokens=512,
-                             steps_per_call=4, seed=1, sampler="tiled"),
-                   mesh=mesh8, name="lda_mp_exact")
-    app.train(num_iterations=4)
-    nwk = app.word_topics()
-    nk = np.asarray(app.summary.get())
-    assert nwk.sum() == app.num_tokens
-    assert np.array_equal(nk[: app.K], nwk.sum(0))
-    assert app.ll_history[-1] > app.ll_history[0] + 0.1
 
 
 def test_docblock_streamed_matches_inmemory(mesh_dp8, docs):
@@ -618,11 +483,94 @@ def test_docblock_streamed_checkpoint_crossmode(mesh_dp8, docs, tmp_path):
 
 
 def test_stream_blocks_requires_docblock(mesh_dp8):
-    with pytest.raises(ValueError, match="doc_blocked"):
+    """Only the doc-blocked sampler streams: refused under gibbs."""
+    with pytest.raises(ValueError, match="stream_blocks requires"):
         LightLDA(np.zeros(8, np.int32), np.zeros(8, np.int32), 4,
-                 LDAConfig(num_topics=128, sampler="tiled",
+                 LDAConfig(num_topics=128, sampler="gibbs",
                            stream_blocks=True),
                  mesh=mesh_dp8, name="lda_sb_bad")
+
+
+@pytest.mark.parametrize("sampler", ["mh", "alias"])
+def test_removed_and_unknown_samplers_are_refused(mesh_dp8, docs, sampler):
+    tw, td, V = docs
+    with pytest.raises(ValueError, match=r"gibbs \| tiled"):
+        LightLDA(tw, td, V, LDAConfig(num_topics=8, batch_tokens=512,
+                                      sampler=sampler),
+                 mesh=mesh_dp8, name="lda_no_such_sampler")
+
+
+@pytest.fixture(scope="module")
+def benchmark_keywords_z(devices, docs):
+    """assignments() after one sweep of the sampler the benchmark's
+    driver builds: sampler="tiled", stale_words=True, doc_blocked=True."""
+    tw, td, V = docs
+    mesh = core.init(devices=devices, data_parallel=8, model_parallel=1)
+    app = LightLDA(tw, td, V, LDAConfig(**_TILED, stale_words=True,
+                                        doc_blocked=True),
+                   mesh=mesh, name="lda_kw_ref")
+    app.sweep()
+    z = app.assignments()
+    table_base.reset_tables()
+    core.shutdown()
+    return z
+
+
+@pytest.mark.parametrize("stale_words", [False, True])
+@pytest.mark.parametrize("doc_blocked", [False, True])
+def test_tiled_is_one_sampler_whatever_the_old_keywords(
+        benchmark_keywords_z, mesh_dp8, docs, stale_words, doc_blocked):
+    """``tiled`` MEANS the doc-blocked stale sampler: the two keywords it
+    used to be spelled with change nothing it builds or draws."""
+    tw, td, V = docs
+    app = LightLDA(tw, td, V,
+                   LDAConfig(**_TILED, stale_words=stale_words,
+                             doc_blocked=doc_blocked),
+                   mesh=mesh_dp8, name="lda_kw")
+    assert app._docblock and app._fused.name == "lda_docblock"
+    assert app._ndk.dtype == np.int16 and app._ndk.ndim == 4
+    app.sweep()
+    np.testing.assert_array_equal(app.assignments(), benchmark_keywords_z)
+
+
+def test_old_keywords_are_refused_under_gibbs(mesh_dp8, docs):
+    tw, td, V = docs
+    for kw in (dict(stale_words=True), dict(doc_blocked=True)):
+        with pytest.raises(ValueError, match="stale_words/doc_blocked"):
+            LightLDA(tw, td, V, LDAConfig(num_topics=8, batch_tokens=512,
+                                          **kw),
+                     mesh=mesh_dp8, name="lda_kw_gibbs")
+
+
+def test_docblock_model_parallel_invariants_and_quality(mesh8, docs):
+    """The doc-blocked sampler on a 4x2 mesh (int32 word table
+    vocab-sliced over the model axis, its bf16 mirror whole on every
+    chip) against the exact ``gibbs`` oracle on the same mesh: count
+    invariants at sweep boundaries, and the oracle's likelihood level
+    (word rows a sweep stale mix about half as fast a sweep: 20 sweeps
+    for the oracle's 12)."""
+    tw, td, V = docs
+    oracle = LightLDA(tw, td, V,
+                      LDAConfig(num_topics=128, batch_tokens=512,
+                                steps_per_call=4, seed=1),
+                      mesh=mesh8, name="lda_mp_oracle")
+    oracle.train(num_iterations=12)
+    table_base.reset_tables()
+    app = LightLDA(tw, td, V, LDAConfig(**_TILED, eval_every=20),
+                   mesh=mesh8, name="lda_mp_db")
+    start = app.loglik()
+    app.train(num_iterations=20)
+    nwk = app.word_topics()
+    nk = np.asarray(app.summary.get())
+    ndk = app.doc_topics()
+    assert nwk.sum() == app.num_tokens
+    assert np.array_equal(nk[: app.K], nwk.sum(0))
+    assert np.array_equal(ndk.sum(1),
+                          np.bincount(td, minlength=app.num_docs))
+    assert (nwk >= 0).all() and (ndk >= 0).all() and (nk >= 0).all()
+    assert app.ll_history[-1] > start + 0.1
+    assert app.ll_history[-1] > oracle.ll_history[-1] - 0.1, \
+        (start, app.ll_history, oracle.ll_history)
 
 
 @pytest.fixture(scope="module")

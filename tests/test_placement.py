@@ -97,23 +97,28 @@ def test_w2v_hs_cbow_no_default_device_leak(offset_mesh):
     _assert_no_strays(before, offset_mesh)
 
 
-@pytest.mark.parametrize("sampler", ["gibbs", "mh"])
+@pytest.mark.parametrize("sampler", ["gibbs", "tiled"])
 def test_lda_no_default_device_leak(offset_mesh, sampler, tmp_path):
+    """Both samplers, resident (the streamed one has its own test
+    below): build, sweep, eval, store / load stay on the mesh."""
     from multiverso_tpu.apps.lightlda import LDAConfig, LightLDA
     rng = np.random.default_rng(0)
     tw = rng.integers(0, 16, 48).astype(np.int32)
     td = np.sort(rng.integers(0, 4, 48)).astype(np.int32)
+    kw = dict(num_topics=4, batch_tokens=8) if sampler == "gibbs" \
+        else dict(num_topics=128, batch_tokens=128, block_tokens=64,
+                  block_docs=8)
     before = _snapshot()
     app = LightLDA(tw, td, 16,
-                   LDAConfig(num_topics=4, batch_tokens=8, steps_per_call=2,
-                             sampler=sampler, seed=0),
+                   LDAConfig(steps_per_call=2, sampler=sampler, seed=0,
+                             **kw),
                    mesh=offset_mesh, name=f"plc_lda_{sampler}")
     app.sweep()
     assert np.isfinite(app.loglik())
-    if sampler == "gibbs":
-        app.store(str(tmp_path / "ck"))
-        app.load(str(tmp_path / "ck"))
-        app.sweep()
+    app.doc_topics()
+    app.store(str(tmp_path / "ck"))
+    app.load(str(tmp_path / "ck"))
+    app.sweep()
     _assert_no_strays(before, offset_mesh)
 
 

@@ -440,12 +440,13 @@ def test_assignments_docblock_in_corpus_order(mesh1, shuffled):
     assert np.array_equal(ndk, lda.doc_topics())
 
 
-@pytest.mark.parametrize("mode", ["gibbs", "tiled", "streamed"])
-def test_assignments_other_layouts_match_the_tables(mesh1, mode):
+@pytest.mark.parametrize("mode", ["gibbs", "dp_mp", "streamed"])
+def test_assignments_other_layouts_match_the_tables(request, mode):
     kw = {"gibbs": dict(sampler="gibbs", doc_blocked=False, num_topics=8),
-          "tiled": dict(doc_blocked=False),
+          "dp_mp": dict(batch_tokens=1024),     # 4 blocks a step over data=4
           "streamed": dict(stream_blocks=True)}[mode]
-    lda, tw, td = _lda(mesh1, name=f"lda_spans_{mode}", **kw)
+    mesh = request.getfixturevalue("mesh8" if mode == "dp_mp" else "mesh1")
+    lda, tw, td = _lda(mesh, name=f"lda_spans_{mode}", **kw)
     lda.sweep()
     z = lda.assignments()
     assert z.shape == (len(tw),)

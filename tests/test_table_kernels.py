@@ -622,7 +622,7 @@ def _kernel_cases():
     """(id, build(mesh) -> (fn, arg shapes+dtypes+specs)) for every
     Pallas kernel `auto` can select on a TPU: the five table kernels
     (flat, masked, sharded), the in-trace functional forms, and the
-    three LDA sampler kernels and the latent-attention kernels — at
+    two LDA sampler kernels and the latent-attention kernels — at
     chip_smoke.py's widths."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -769,12 +769,6 @@ def _kernel_cases():
             functools.partial(ls.gibbs_sample_docblock_build, alpha=0.05,
                               beta=0.01, tb=TB, maxd=MAXD),
             [w3, sinv] + tok))
-        add(f"lda-tiled-{wtag}", lambda m, w3=w3, sinv=sinv: (
-            functools.partial(ls.gibbs_sample_tiled, alpha=0.05,
-                              beta=0.01),
-            [((B, C, 128), i32, P()), w3, sinv,
-             ((B,), i32, P()), ((B,), i32, P()),
-             ((B,), f32, P()), ((B,), f32, P())]))
 
     # the latent-attention kernels (forward, and the backward kernel
     # behind jax.grad) at the language-model cell's shape: 4,096-token
