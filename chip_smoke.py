@@ -32,8 +32,10 @@ kernel engines against a numpy reference), ``attend`` (the latent
 attention kernel, forward and backward at the language-model cell's
 shape on packed documents, against the plain blocked form), ``server``
 (one wire server; numpy bit-for-bit). With >= 4 devices the base phases run on
-``data=4`` and four more follow: ``w2v@2x2``, ``lda@2x2``,
-``tables@2x2`` (sharded engines under shard_map) and ``fleet`` (four
+``data=4`` and five more follow: ``w2v@2x2``, ``lda@1x1`` (one chip of
+the four) and ``lda@2x2``, whose sha256 of the sampler's state after two
+sweeps must equal each other's and ``lda``'s, ``tables@2x2`` (sharded
+engines under shard_map) and ``fleet`` (four
 one-chip members answering the single server's requests); otherwise
 that block reports ``"skipped: N device(s)"``.
 
@@ -133,10 +135,15 @@ class _CompileClock:
 
 
 def _mesh_arg(spec: str):
+    """``"2x2"`` -> a data=2 x model=2 mesh over the first four devices
+    (``lda@1x1`` takes one chip of a four-chip host); no spec -> the
+    runtime's default mesh over all of them."""
     if not spec:
         return {}
+    import jax
     dp, mp = (int(x) for x in spec.split("x"))
-    return dict(data_parallel=dp, model_parallel=mp)
+    return dict(devices=jax.devices()[: dp * mp], data_parallel=dp,
+                model_parallel=mp)
 
 
 def _check_placement(name: str, arr, mesh, platform: str) -> dict:
@@ -208,7 +215,9 @@ def phase_w2v(cfg: dict, mesh, platform: str) -> dict:
 
 
 def phase_lda(cfg: dict, mesh, platform: str) -> dict:
-    from multiverso_tpu import core
+    import hashlib
+
+    from multiverso_tpu import core, telemetry
     from multiverso_tpu.apps.lightlda import LDAConfig, LightLDA
 
     v, t, d = cfg["vocab"], cfg["tokens"], cfg["docs"]
@@ -237,9 +246,23 @@ def phase_lda(cfg: dict, mesh, platform: str) -> dict:
     assert np.isfinite(ll), ll
     wt = app.word_topic
     mp = mesh.shape[core.MODEL_AXIS]
+    chips = telemetry.gauge("lda.sample.chips").value
+    assert chips == mesh.devices.size, \
+        f"{chips} chips sample distinct blocks on a mesh of " \
+        f"{mesh.devices.size}"
+
+    def sha(a):
+        return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()
+
     return {
         "kernel_interpret": app._interpret, "sweeps": 2,
         "tokens": int(app.num_tokens), "loglik": round(float(ll), 4),
+        # what two sweeps left, in the caller's terms: the same bits on
+        # every mesh (Smoke.same_lda_state holds the phases to it)
+        "sha256": {"assignments": sha(app.assignments()),
+                   "word_topic": sha(nwk), "doc_topics": sha(ndk),
+                   "summary": sha(nk)},
+        "sample_chips": int(chips),
         "count_invariants": "word-topic = summary = doc-topic = tokens",
         "packing_fill": round(float(app.packing_fill), 4),
         "tables": {wt.name: _check_placement(wt.name, wt.param, mesh,
@@ -830,6 +853,22 @@ class Smoke:
                    "wall_s": round(time.perf_counter() - t0, 2),
                    "startup_s": round(t_up, 2), "checked": checked})
 
+    def same_lda_state(self) -> None:
+        """Which chip samples a block does not change what it samples:
+        after two sweeps ``assignments()``, the word-topic table,
+        ``doc_topics()`` and the summary of ``lda@2x2`` hash to what
+        ``lda@1x1`` and ``lda`` (the default mesh) read."""
+        shas = {ln["phase"]: ln["checked"]["sha256"] for ln in self.lines
+                if ln.get("phase", "").partition("@")[0] == "lda"}
+        for phase, sha in shas.items():
+            print(f"chip_smoke: {phase} sha256 " + " ".join(
+                f"{k}={v}" for k, v in sha.items()), flush=True)
+        want = shas.get("lda@1x1")
+        differ = [p for p, sha in shas.items() if sha != want]
+        if want is None or "lda@2x2" not in shas or differ:
+            self.failed.append(
+                f"lda state differs between meshes: {differ or shas}")
+
     def guarded(self, name: str, fn, *args) -> None:
         """A serving phase's failure is recorded and printed — and makes
         the run fail — but the launcher's processes are already reaped
@@ -858,8 +897,9 @@ class Smoke:
         self.guarded("server", self.server)
         count = int(self.lines[0]["devices"])
         if count >= 4:
-            for phase in ("w2v@2x2", "lda@2x2", "tables@2x2"):
+            for phase in ("w2v@2x2", "lda@1x1", "lda@2x2", "tables@2x2"):
                 self.child(phase)
+            self.same_lda_state()
             self.guarded("fleet", self.fleet, 4)
         else:
             self.emit({"phase": "four_chips",

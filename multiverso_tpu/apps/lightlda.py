@@ -38,9 +38,12 @@ Two samplers, chosen by ``LDAConfig.sampler``, both on dp x mp meshes:
   happens in VMEM by one-hot matmuls inside the Pallas kernel
   (ops.gibbs_sample_docblock). Word rows follow the reference's own
   slice-level staleness: gathered from a bf16 mirror refreshed once a
-  sweep, the int32 master rebuilt from z at sweep end. Blocks shard over
-  the data axis via shard_map (summary deltas psum'd). Resident, or with
-  ``stream_blocks`` (and ``local_corpus``) streamed from the host.
+  sweep, the int32 master rebuilt from z at sweep end. Resident, a
+  step's blocks — lanes, z, doc counts — are split over EVERY chip of
+  the mesh, data x model, via shard_map (summary deltas psum'd over
+  both axes): no two chips sample the same tokens. With
+  ``stream_blocks`` (and ``local_corpus``) the stream stays on the host
+  and its blocks split over the data axis alone.
 
 The int32 word table stays row-sharded over the model axis — the
 reference's Meta vocab-slicing role — and the per-sweep master rebuild
@@ -207,6 +210,26 @@ def _take_word_rows(table3, w):
     ``jnp.take``'s default mode costs a select over the whole
     [B, C, 128] result."""
     return jnp.take(table3, w, axis=0, mode="clip")
+
+
+def _flat_z(z):
+    """The resident z ``[steps, blocks a step, TB]`` as the flat packed
+    stream, for a scatter's index math — BEHIND a barrier: fused into
+    that math the 3-D reshape takes XLA:TPU minutes to compile and comes
+    out as a real ``reshape`` op (the rebuild at the benchmark's size:
+    78.8 s against 13.8, compiled for a described v5e)."""
+    return lax.optimization_barrier(z.reshape(-1))
+
+
+def _to_host(x: jax.Array) -> np.ndarray:
+    """A device array whole on this host. A process reads the shards of
+    an array it can address; one split over several processes' chips
+    (the resident z and doc counts on a multi-host mesh) is replicated
+    on the devices first, as ``Table.get_jax`` does — COLLECTIVE there."""
+    if not x.is_fully_addressable:
+        x = jax.jit(lambda a: a, out_shardings=NamedSharding(
+            x.sharding.mesh, P()))(x)
+    return np.asarray(x)
 
 
 class LightLDA:
@@ -410,6 +433,7 @@ class LightLDA:
                 cur_tok += ln
             n_blocks = (b + 1) if n_real else 1
             nbs = B // TB                       # blocks per scan step
+            self._split_blocks(nbs)
             per_call = S * nbs
             self._per_call = per_call
             self._tb, self._maxd = TB, MAXD
@@ -488,13 +512,16 @@ class LightLDA:
                     np.nonzero(valid)[0]
                 return
 
-            # per-call staging: [S, B] lanes + per-step block offsets
-            spec = P(None, core.DATA_AXIS)
-            rows_flat = (np.arange(nb_pad)[:, None] * MAXD
-                         + drel_p).astype(np.int32)
+            # per-call staging: [S, B] lanes, a step's blocks split over
+            # every chip of the mesh, + the call's step numbers
+            axes = self._block_axes
+            spec = P(None, axes)
             self._calls = []
-            self._loglik_rows = []   # eval-only gather rows (not a fused
-            #                          operand: the sweep never needs them)
+            self._loglik_rows = []   # eval-only gather rows into the
+            #                          call's own window of doc counts (not
+            #                          a fused operand: no sweep needs them)
+            rows_call = (np.arange(per_call)[:, None] * MAXD) \
+                .astype(np.int32)
             for call in range(n_calls):
                 lo = call * per_call
                 sl = slice(lo, lo + per_call)
@@ -504,31 +531,69 @@ class LightLDA:
                     self._place(drel_p[sl].reshape(shp), spec),
                     self._place(mask_p[sl].reshape(shp).astype(np.int32),
                                 spec),
-                    self._place(np.arange(lo, lo + per_call, nbs,
+                    self._place(np.arange(call * S, (call + 1) * S,
                                           dtype=np.int32), P())))
-                self._loglik_rows.append(
-                    self._place(rows_flat[sl].reshape(shp), spec))
+                self._loglik_rows.append(self._place(
+                    (rows_call + drel_p[sl]).reshape(shp), spec))
 
             # full flat stream for the per-sweep word-count rebuild
             self._tw_flat = self._place(tw_p.reshape(-1), P())
             self._mask_flat = self._place(mask_p.reshape(-1), P())
 
-            self._z = self._place(z0, P())
-            drel_dev = self._place(drel_p, P())
+            # the sampler's state, block-major [steps, blocks a step, ..]
+            # and split like the lanes: a step's window is one index on
+            # the unsharded dimension, and a chip reads and writes only
+            # the blocks it samples. Row-major order is the packed block
+            # order, so a reshape reads what [nb_pad, ..] read
+            n_steps = n_calls * S
+            self._z_sharding = NamedSharding(self.mesh, P(None, axes, None))
+            self._ndk_sharding = NamedSharding(
+                self.mesh, P(None, axes, None, None, None))
+
+            def blocks(a):
+                return jax.device_put(a.reshape(n_steps, nbs, TB),
+                                      self._z_sharding)
+
+            self._z = blocks(z0)
+            drel_dev = blocks(drel_p)
         tiles = self.K // 128
 
-        @jax.jit
+        def block_counts(z, drel, m):
+            # one chip's blocks [steps, its blocks a step, TB], a step at
+            # a time: a block's counts are its tokens' one-hot doc rows
+            # times their one-hot topics — what the kernel does in VMEM.
+            # Exact (sums of at most TB ones in f32), no scatter, and
+            # nothing leaves the chip: a block owns its MAXD rows
+            def step(_, x):
+                zs, ds, ms = x
+                topic = jax.nn.one_hot(zs, self.K, dtype=jnp.bfloat16) \
+                    * ms[..., None].astype(jnp.bfloat16)
+                row = jax.nn.one_hot(ds, MAXD, dtype=jnp.bfloat16)
+                counts = jnp.einsum("btd,btk->bdk", row, topic,
+                                    preferred_element_type=jnp.float32)
+                return None, counts.astype(jnp.int16).reshape(
+                    zs.shape[0], MAXD, tiles, 128)
+
+            return lax.scan(step, None, (z, drel, m))[1]
+
+        from jax import shard_map
+        zspec = self._z_sharding.spec
+        block_counts = shard_map(
+            block_counts, mesh=self.mesh, in_specs=(zspec, zspec, zspec),
+            out_specs=self._ndk_sharding.spec, check_vma=False)
+
+        @partial(jax.jit, out_shardings=(None, self._ndk_sharding, None))
         def build(z, tw_flat, m_flat, drel):
-            zf = z.reshape(-1)
+            zf = _flat_z(z)
+            # the flat mask is whole on every chip: its blocked view is
+            # a local slice
+            msk = lax.with_sharding_constraint(m_flat.reshape(z.shape),
+                                               self._z_sharding)
             nwk = jnp.zeros(self.word_topic.storage_shape, jnp.int32)
             nwk = nwk.at[tw_flat, zf // 128, zf % 128].add(m_flat)
-            rows = (jnp.arange(nb_pad)[:, None] * MAXD + drel).reshape(-1)
-            ndk = jnp.zeros((nb_pad * MAXD, tiles, 128), jnp.int16)
-            ndk = ndk.at[rows, zf // 128, zf % 128].add(
-                m_flat.astype(jnp.int16))
             nk = jnp.zeros(self.summary.padded_shape, jnp.int32)
             nk = nk.at[zf].add(m_flat)
-            return nwk, ndk.reshape(nb_pad, MAXD, tiles, 128), nk
+            return nwk, block_counts(z, drel, msk), nk
 
         # the device's part, fenced so the span holds it (build's own
         # compile included: a bare jit, not in profile.compile.seconds)
@@ -538,6 +603,28 @@ class LightLDA:
         self.word_topic.put_raw(nwk)
         self._ndk = ndk
         self.summary.put_raw(nk)
+
+    def _split_blocks(self, nbs: int) -> None:
+        """Decide which mesh axes split a step's ``nbs`` kernel blocks
+        (``_block_axes``), and set ``lda.sample.chips`` to the number of
+        chips that then sample distinct blocks. Resident: every chip of
+        the mesh, data x model — each chip holds the whole bf16 mirror,
+        so nothing needs two chips to see the same tokens, and the model
+        axis keeps only the int32 master's slices and their rebuild.
+        Streamed (``stream_blocks``): the data axis alone — its host
+        staging, ``_owned_call_offsets`` and the z drain are keyed to the
+        data axis, and the chips of a model group still sample the same
+        blocks there (no benchmark cell runs it: ROADMAP R10)."""
+        self._block_axes = (core.DATA_AXIS,) if self.config.stream_blocks \
+            else (core.DATA_AXIS, core.MODEL_AXIS)
+        chips = int(np.prod([self.mesh.shape[a]
+                             for a in self._block_axes]))
+        if nbs % chips:
+            raise ValueError(
+                f"tiled: blocks per step {nbs} not divisible by the "
+                f"{chips} chips that split them (mesh axes "
+                f"{self._block_axes})")
+        telemetry.gauge("lda.sample.chips").set(chips)
 
     def _build_word_gather(self):
         """``take(nwk3, w)`` from the int32 MASTER, row-sharded over the
@@ -575,25 +662,28 @@ class LightLDA:
         """Multi-chip dispatch for the pallas sampler: a Mosaic custom
         call cannot be auto-partitioned by XLA, so on any multi-device
         mesh each chip runs the kernel on its own kernel blocks via
-        ``shard_map`` (blocks over the data axis — each chip exclusively
-        owns its blocks' doc counts, the block layout IS the DP
-        partition; operands replicated over the model axis) and the
-        topic-summary delta is psum'd over ICI."""
+        ``shard_map``. A step's blocks are split over EVERY chip of the
+        mesh (``_block_axes``: data x model) — each chip exclusively
+        owns its blocks' doc counts and z, the block layout IS the
+        partition — and the topic-summary delta is psum'd over both
+        axes. Only ``sinv`` is replicated. Which chip samples a block
+        does not change what it samples: the uniforms are drawn for the
+        whole step and sliced."""
         if self.mesh.devices.size == 1:
             return fn
         from jax import shard_map
-        d = core.DATA_AXIS
-        Pb = P(d)
+        axes = self._block_axes
+        Pb = P(axes)
 
         def local(ndk_c, W3, sinv, zi, drel, msk, u1, u2):
             ndk_c, znew, nkd = fn(ndk_c, W3, sinv, zi, drel, msk, u1, u2)
-            return ndk_c, znew, lax.psum(nkd, d)
+            return ndk_c, znew, lax.psum(nkd, axes)
 
         return shard_map(
             local, mesh=self.mesh,
-            in_specs=(P(d, None, None, None), P(d, None, None),
+            in_specs=(P(axes, None, None, None), P(axes, None, None),
                       P(None, None), Pb, Pb, Pb, Pb, Pb),
-            out_specs=(P(d, None, None, None), Pb, P(None, None)),
+            out_specs=(P(axes, None, None, None), Pb, P(None, None)),
             check_vma=False)
 
     def _build_vocab_slice_scatter(self):
@@ -628,11 +718,14 @@ class LightLDA:
         resident and streamed: the bf16 gather mirror and the int32
         master rebuild from the blocked z (flattened).
 
-        The int32 master stays sharded over the model axis: the rebuild
-        scatters each chip's DATA shard of the stream into its own vocab
-        slice, psum'd over the data axis — no chip ever holds the int32
-        [V, K]. The mirror is the worker's per-sweep cache of it (the
-        stale-words model): ``to_stale`` casts the chip's slice and
+        The int32 master stays sharded over the model axis — the one job
+        that axis has: the rebuild scatters each chip's DATA shard of
+        the stream into its own vocab slice, psum'd over the data axis —
+        no chip ever holds the int32 [V, K]. (The resident z lies split
+        over data x model by block; the rebuild's ``in_specs`` ask for
+        the data shard, so the compiler moves z once a sweep.) The
+        mirror is the worker's per-sweep cache of it (the stale-words
+        model): ``to_stale`` casts the chip's slice and
         all-gathers it over the model axis, ONCE a sweep, so every chip
         holds the whole bf16 [V, K] and the gather inside the superstep
         (:func:`_take_word_rows`) is a plain local read — no ownership
@@ -650,7 +743,7 @@ class LightLDA:
         if mp == 1:
             @jax.jit
             def rebuild(z, tw, m):
-                zf = z.reshape(-1)
+                zf = _flat_z(z)
                 nwk3 = jnp.zeros(self.word_topic.storage_shape, jnp.int32)
                 return nwk3.at[tw, zf // 128, zf % 128].add(m)
         else:
@@ -658,7 +751,7 @@ class LightLDA:
 
             @jax.jit
             def rebuild(z, tw, m):
-                return sharded(z.reshape(-1), tw, m)
+                return sharded(_flat_z(z), tw, m)
 
         self._to_stale = to_stale
         self._rebuild = rebuild
@@ -724,7 +817,7 @@ class LightLDA:
 
     def _build_blocked_loglik(self) -> None:
         """Eval over the blocked doc counts: ``rows`` index the flattened
-        [*, C, 128] doc-count storage (packed block rows). Word rows come
+        [*, C, 128] doc-count rows of the call's own steps. Word rows come
         through the sharded gather, so eval never materialises the full
         [V, K] on one chip under model parallelism."""
         K = self.K
@@ -732,8 +825,12 @@ class LightLDA:
         run = self._chunked_ll(self._build_word_gather())
 
         @jax.jit
-        def loglik(nwk3, ndk, nk, ws, rows, mask):
-            return run(nwk3, ndk.reshape(-1, tiles, 128),
+        def loglik(nwk3, ndk, nk, ws, rows, mask, steps):
+            # the call's own steps of the doc counts: whatever the
+            # compiler moves between chips to serve the gather is one
+            # call's window, never the corpus's
+            win = lax.dynamic_slice_in_dim(ndk, steps[0], ws.shape[0], 0)
+            return run(nwk3, win.reshape(-1, tiles, 128),
                        nk[:K].astype(jnp.float32), ws.reshape(-1),
                        rows.reshape(-1),
                        mask.reshape(-1).astype(jnp.float32))
@@ -752,11 +849,6 @@ class LightLDA:
         B = c.batch_tokens
         TB = self._tb
         nbs = B // TB
-        dp = self.mesh.shape[core.DATA_AXIS]
-        if nbs % dp:
-            raise ValueError(
-                f"tiled: blocks per step {nbs} not divisible by "
-                f"data-axis size {dp}")
         tiles = K // 128
         interpret = self._interpret
         from multiverso_tpu.ops import gibbs_sample_docblock
@@ -773,22 +865,24 @@ class LightLDA:
         # doc-topic window and the topic totals
         scope = telemetry.scope
 
+        # z and ndk are [steps, blocks a step, ..], split over the chips
+        # on the second dimension: step number ``t`` picks the window
         @scope("lda.carry")
-        def z_window(z, off):
-            return lax.dynamic_slice_in_dim(z, off, nbs).reshape(B)
+        def z_window(z, t):
+            return lax.dynamic_index_in_dim(z, t, 0, False).reshape(B)
 
         @scope("lda.carry")
-        def z_update(z, znew, off):
-            return lax.dynamic_update_slice_in_dim(
-                z, znew.reshape(nbs, TB), off, 0)
+        def z_update(z, znew, t):
+            return lax.dynamic_update_index_in_dim(
+                z, znew.reshape(nbs, TB), t, 0)
 
         @scope("lda.doc_counts")
-        def counts_window(ndk, off):
-            return lax.dynamic_slice_in_dim(ndk, off, nbs)
+        def counts_window(ndk, t):
+            return lax.dynamic_index_in_dim(ndk, t, 0, False)
 
         @scope("lda.doc_counts")
-        def counts_update(ndk, ndk_c, nk, nkd, off):
-            return (lax.dynamic_update_slice_in_dim(ndk, ndk_c, off, 0),
+        def counts_update(ndk, ndk_c, nk, nkd, t):
+            return (lax.dynamic_update_index_in_dim(ndk, ndk_c, t, 0),
                     nk.at[:K].add(nkd.reshape(-1)))
 
         @scope("lda.sample")
@@ -805,13 +899,13 @@ class LightLDA:
 
         def scan_body(wstale, carry, inp):
             nk, ndk, z = carry
-            w, drel, msk, off, key = inp
-            ndk_c = counts_window(ndk, off)
-            zi = z_window(z, off)
+            w, drel, msk, t, key = inp
+            ndk_c = counts_window(ndk, t)
+            zi = z_window(z, t)
             W3 = gather_words(wstale, w.reshape(B))
             ndk_c, znew, nkd = sample(ndk_c, W3, nk, zi, drel, msk, key)
-            ndk, nk = counts_update(ndk, ndk_c, nk, nkd, off)
-            z = z_update(z, znew, off)
+            ndk, nk = counts_update(ndk, ndk_c, nk, nkd, t)
+            z = z_update(z, znew, t)
             return (nk, ndk, z), ()
 
         self._db_scan_body = scan_body
@@ -821,17 +915,18 @@ class LightLDA:
         scan_body = self._db_scan_body
 
         def body(params, states, locals_, options, wstale, ws, drels,
-                 msks, offs, key):
+                 msks, steps, key):
             (nk,) = params
             ndk, z = locals_
             keys = jax.random.split(key, ws.shape[0])
             (nk, ndk, z), _ = lax.scan(
                 lambda cy, inp: scan_body(wstale, cy, inp),
-                (nk, ndk, z), (ws, drels, msks, offs, keys))
+                (nk, ndk, z), (ws, drels, msks, steps, keys))
             return (nk,), states, (ndk, z), None
 
-        self._fused = make_superstep((self.summary,), body,
-                                     name="lda_docblock")
+        self._fused = make_superstep(
+            (self.summary,), body, name="lda_docblock",
+            local_shardings=(self._ndk_sharding, self._z_sharding))
 
         self._build_blocked_loglik()
 
@@ -859,11 +954,15 @@ class LightLDA:
 
     def _wrap_docblock_build_dp(self, fn):
         """shard_map dispatch for the count-building kernel (no blocked
-        count array: z is the only sampler state)."""
+        count array: z is the only sampler state). The streamed path's
+        blocks are split over the DATA axis alone, operands replicated
+        over the model axis — its host staging and z drain are keyed to
+        the data axis (:meth:`_split_blocks`); the resident path splits
+        over data x model (:meth:`_wrap_docblock_dp`)."""
         if self.mesh.devices.size == 1:
             return fn
         from jax import shard_map
-        d = core.DATA_AXIS
+        d = self._block_axes
         Pb = P(d)
 
         def local(W3, sinv, zi, drel, msk, u1, u2):
@@ -883,11 +982,6 @@ class LightLDA:
         K = self.K
         S, B, TB = c.steps_per_call, c.batch_tokens, self._tb
         nbs, MAXD = B // TB, self._maxd
-        dp = self.mesh.shape[core.DATA_AXIS]
-        if nbs % dp:
-            raise ValueError(
-                f"tiled: blocks per step {nbs} not divisible by "
-                f"data-axis size {dp}")
         tiles = K // 128
         scratch = self._scratch_word
         interpret = self._interpret
@@ -1401,8 +1495,8 @@ class LightLDA:
             return total / max(self.num_tokens, 1)
         for i, call in enumerate(self._calls):
             if self._docblock:
-                ws, _drels, msks, _offs = call
-                args = (ws, self._loglik_rows[i], msks)
+                ws, _drels, msks, steps = call
+                args = (ws, self._loglik_rows[i], msks, steps)
             else:
                 ws, ds, _idxs, msks = call
                 args = (ws, ds, msks)
@@ -1435,12 +1529,12 @@ class LightLDA:
                 np.add.at(out, (docs[valid], z[valid]), 1)
             return out
         if self._docblock:
-            blocked = np.asarray(self._ndk)
+            blocked = _to_host(self._ndk).reshape(
+                self._nb_pad * self._maxd, self.K)
             out = np.zeros((self.num_docs, self.K), np.int32)
             valid = self._blk_of_doc >= 0
-            out[valid] = blocked[self._blk_of_doc[valid],
-                                 self._row_of_doc[valid]].reshape(
-                int(valid.sum()), self.K)
+            out[valid] = blocked[self._blk_of_doc[valid] * self._maxd
+                                 + self._row_of_doc[valid]]
             return out
         return np.asarray(self._ndk[: self.num_docs]).reshape(
             self.num_docs, self.K)
@@ -1468,7 +1562,7 @@ class LightLDA:
             z = self._z_host[real]
         else:
             real = np.asarray(self._mask_flat).astype(bool)
-            z = np.asarray(self._z).reshape(-1)[real]
+            z = _to_host(self._z).reshape(-1)[real]
         # the packer keeps the doc-sorted order, so the real lanes in
         # packed order ARE the sorted stream
         if self._doc_order is None:
@@ -1545,7 +1639,7 @@ class LightLDA:
                     self._sync_z_host()
                     z = self._z_host.reshape(-1)
                 else:
-                    z = np.asarray(self._z).reshape(-1)
+                    z = _to_host(self._z).reshape(-1)
             layout = "docblock"
         else:
             dense = np.asarray(self._ndk).reshape(self.num_docs + 1,
@@ -1706,13 +1800,14 @@ class LightLDA:
             self._z_synced = True    # checkpoint z is globally complete
             self._calls_done = int(manifest.get("calls_done", 0))
             return
-        self._z = self._place(
-            np.asarray(data["z"]).reshape(self._z.shape), P())
+        # restore INTO the live arrays' own shardings (what the fused
+        # superstep's donation aliasing was compiled against). The stored
+        # z is the packed block order flat and the counts are dense
+        # [D, K]: neither knows how the blocks lie over the chips
+        self._z = jax.device_put(
+            np.asarray(data["z"]).reshape(self._z.shape),
+            self._z.sharding)
         dense = np.asarray(data["ndk"])
-        # restore INTO the live array's own sharding (the init-time
-        # build jit's output layout) — the fused superstep's donation
-        # aliasing was compiled against it, and a replicated P() here
-        # hits an XLA aliased-size mismatch on model-parallel meshes
         ndk_sharding = self._ndk.sharding
         if self._docblock:
             blocked = np.zeros(self._ndk.shape,

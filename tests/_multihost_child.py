@@ -206,7 +206,10 @@ def main() -> None:
         assert np.isfinite(ll), ll
         nwk = lda.word_topics()
         assert nwk.sum() == lda.num_tokens, (nwk.sum(), lda.num_tokens)
-        z_ref = np.asarray(lda._z)
+        # the resident z is split over every process's chips: read it
+        # whole through the app's own (collective) host read
+        from multiverso_tpu.apps.lightlda import _to_host
+        z_ref = _to_host(lda._z)
 
     # OUT-OF-CORE streamed mode across all processes: process-local
     # staging (each host device_puts only its addressable lanes) and
@@ -225,7 +228,8 @@ def main() -> None:
     assert nwk_s.sum() == lda_s.num_tokens
     assert np.isfinite(lda_s.loglik())
     if full:
-        np.testing.assert_array_equal(lda_s._z_host, z_ref)
+        np.testing.assert_array_equal(
+            lda_s._z_host, z_ref.reshape(lda_s._z_host.shape))
         np.testing.assert_array_equal(nwk_s, nwk)
         np.testing.assert_array_equal(lda_s.doc_topics(),
                                       lda.doc_topics())

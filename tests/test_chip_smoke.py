@@ -77,9 +77,11 @@ class _FakeSmoke:
     test's choice; everything about exit codes and the result line is
     the real code."""
 
-    def __init__(self, smoke, fail, devices=1):
+    def __init__(self, smoke, fail, devices=1, lda_state=None):
         self.fail = set(fail)
         self.devices = devices
+        # phase -> what its sampler state "hashed to" (default: alike)
+        self.lda_state = lda_state or {}
         outer = self
 
         class Fake(smoke.Smoke):
@@ -92,6 +94,9 @@ class _FakeSmoke:
                         "devices": outer.devices, "compile_s": 1.0}
                 if self.rehearse:       # the real child tags its line
                     line["rehearsal"] = smoke.REHEARSAL_TAG
+                if phase.partition("@")[0] == "lda":
+                    line["checked"] = {"sha256": {
+                        "assignments": outer.lda_state.get(phase, "a1")}}
                 self.lines.append(line)
                 print(json.dumps(line))
 
@@ -129,6 +134,21 @@ def test_four_chip_phases_fail_the_run_too(smoke, capsys):
         assert not any("device" in doc for doc in _json_lines(out.out))
 
 
+@pytest.mark.parametrize("odd", ["lda", "lda@1x1", "lda@2x2"])
+def test_lda_state_that_differs_between_meshes_fails_the_run(smoke, capsys,
+                                                             odd):
+    """Every ``lda*`` phase passed on its own, and one mesh's sampler
+    state hashes unlike the others': which chip samples a block changed
+    what it samples."""
+    rc = _FakeSmoke(smoke, [], devices=4,
+                    lda_state={odd: "b2"}).cls(rehearse=False).run()
+    out = capsys.readouterr()
+    assert rc != 0 and "lda state differs between meshes" in out.err
+    assert not any("device" in doc for doc in _json_lines(out.out))
+    for phase in ("lda", "lda@1x1", "lda@2x2"):
+        assert f"chip_smoke: {phase} sha256 assignments=" in out.out
+
+
 def test_all_phases_passing_prints_the_result_line_last(smoke, capsys):
     rc = _FakeSmoke(smoke, []).cls(rehearse=False).run()
     lines = capsys.readouterr().out.strip().splitlines()
@@ -142,8 +162,8 @@ def test_all_phases_passing_prints_the_result_line_last(smoke, capsys):
     docs = _json_lines(capsys.readouterr().out)
     assert rc == 0 and docs[-1]["device"]["count"] == 4
     assert [d["phase"] for d in docs if "phase" in d] == [
-        "w2v", "lda", "tables", "attend", "server", "w2v@2x2", "lda@2x2",
-        "tables@2x2", "fleet"]
+        "w2v", "lda", "tables", "attend", "server", "w2v@2x2", "lda@1x1",
+        "lda@2x2", "tables@2x2", "fleet"]
 
 
 def test_a_real_child_that_raises_is_a_failed_phase(smoke):

@@ -5,7 +5,7 @@ batch-parallel TPU sampler must mix like sequential Gibbs)."""
 import numpy as np
 import pytest
 
-from multiverso_tpu import core
+from multiverso_tpu import core, telemetry
 from multiverso_tpu.apps.lightlda import LDAConfig, LightLDA, load_docs
 from multiverso_tpu.data.corpus import synthetic_docs
 from multiverso_tpu.tables import base as table_base
@@ -299,35 +299,139 @@ def test_docblock_zero_token_corpus(mesh_dp8):
     lda.sweep()
 
 
-def _run_docblock(mesh, docs, name):
+def _sub_mesh(devices, dp, mp):
+    return core.init(devices=devices[:dp * mp], data_parallel=dp,
+                     model_parallel=mp)
+
+
+def _docblock_state(app):
+    """What a sweep leaves, in the caller's terms: every token's topic,
+    the word-topic table, the doc-topic counts, the topic summary."""
+    return (app.assignments(), app.word_topics(), app.doc_topics(),
+            np.asarray(app.summary.get()))
+
+
+@pytest.fixture(scope="module")
+def docblock_two_sweeps(devices, docs):
+    """Two sweeps of the STREAMED doc-blocked sampler on one device: the
+    other residency (host z, the count-building kernel, no shard_map) of
+    the same draws — the state every resident mesh must land on."""
     tw, td, V = docs
-    app = LightLDA(tw, td, V, LDAConfig(**_TILED), mesh=mesh, name=name)
-    app.train(num_iterations=3)
-    return app
+    mesh = _sub_mesh(devices, 1, 1)
+    app = LightLDA(tw, td, V, LDAConfig(**_TILED, stream_blocks=True),
+                   mesh=mesh, name="lda_mesh_ref")
+    app.train(num_iterations=2)
+    state = _docblock_state(app) + (app.ll_history[-1],)
+    table_base.reset_tables()
+    core.shutdown()
+    return state
 
 
-def test_docblock_model_parallel_matches_dp(devices, docs):
-    """The model-axis sharding (vocab-sliced int32 word table, its bf16
-    mirror all-gathered over the model axis once a sweep and read
-    locally) must be EXACTLY the dp-only computation: the replicated
-    mirror holds the same rows, the rebuild psum is integer and eval's
-    partial-gather rows each live in one shard, so z and all counts are
-    bit-identical between a pure-DP mesh and a dp x mp mesh."""
-    from multiverso_tpu import core
-    mesh_dp = core.init(devices=devices, data_parallel=8, model_parallel=1)
-    ref = _run_docblock(mesh_dp, docs, "lda_mp_ref")
-    ref_w, ref_d = ref.word_topics(), ref.doc_topics()
-    ref_nk = np.asarray(ref.summary.get())
-    ref_ll = ref.ll_history[-1]
+@pytest.mark.parametrize("dp,mp", [(8, 1), (4, 2), (2, 4), (1, 8), (2, 2),
+                                   (1, 2), (1, 1)])
+def test_docblock_model_parallel_matches_dp(devices, docs,
+                                            docblock_two_sweeps, dp, mp):
+    """A step's blocks are split over every chip of the mesh, data x
+    model, and which chip samples a block must not change what it
+    samples (the uniforms and the start are made for the whole step and
+    sliced; the summary delta's psum and the rebuild's are integer; the
+    replicated bf16 mirror holds the same rows): z and all counts are
+    BIT-IDENTICAL on every mesh shape the eight devices allow, and equal
+    to the streamed path's on one device."""
+    tw, td, V = docs
+    ref_z, ref_w, ref_d, ref_nk, ref_ll = docblock_two_sweeps
+    mesh = _sub_mesh(devices, dp, mp)
+    app = LightLDA(tw, td, V, LDAConfig(**_TILED), mesh=mesh,
+                   name=f"lda_mesh_{dp}x{mp}")
+    # the counter of the mechanism: every chip samples its own blocks
+    assert telemetry.gauge("lda.sample.chips").value == dp * mp
+    (shard,) = {s.data.shape for s in app._ndk.addressable_shards}
+    assert shard[1] * dp * mp == app._ndk.shape[1] == 2048 // 256
+    app.train(num_iterations=2)
+    z, w, d, nk = _docblock_state(app)
+    np.testing.assert_array_equal(z, ref_z)
+    np.testing.assert_array_equal(w, ref_w)
+    np.testing.assert_array_equal(d, ref_d)
+    np.testing.assert_array_equal(nk, ref_nk)
+    np.testing.assert_allclose(app.ll_history[-1], ref_ll, rtol=1e-5)
     table_base.reset_tables()
     core.shutdown()
 
-    mesh_mp = core.init(devices=devices, data_parallel=4, model_parallel=2)
-    app = _run_docblock(mesh_mp, docs, "lda_mp_test")
-    np.testing.assert_array_equal(app.word_topics(), ref_w)
-    np.testing.assert_array_equal(app.doc_topics(), ref_d)
-    np.testing.assert_array_equal(np.asarray(app.summary.get()), ref_nk)
-    np.testing.assert_allclose(app.ll_history[-1], ref_ll, rtol=1e-5)
+
+def test_blocks_a_step_must_divide_by_the_chips_that_split_them(devices,
+                                                                docs):
+    """4 blocks a step on a 4x2 mesh: the resident sweep needs them to
+    divide by data x model and refuses; the streamed one splits over
+    data alone and takes them."""
+    tw, td, V = docs
+    small = dict(_TILED, batch_tokens=1024)         # 4 blocks a step
+    mesh = _sub_mesh(devices, 4, 2)
+    with pytest.raises(ValueError, match="4 not divisible by the 8 chips"):
+        LightLDA(tw, td, V, LDAConfig(**small), mesh=mesh,
+                 name="lda_split_bad")
+    app = LightLDA(tw, td, V, LDAConfig(**small, stream_blocks=True),
+                   mesh=mesh, name="lda_split_stream")
+    assert telemetry.gauge("lda.sample.chips").value == 4
+    assert app._block_axes == (core.DATA_AXIS,)
+    table_base.reset_tables()
+    core.shutdown()
+
+
+@pytest.mark.parametrize("dp,mp", [(4, 2), (2, 2)])
+def test_docblock_checkpoint_roundtrip_across_meshes(devices, docs,
+                                                     tmp_path, dp, mp):
+    """The stored sampler state knows nothing of how the blocks lie over
+    the chips (z flat in packed block order, doc counts dense [D, K]): a
+    state stored on a dp x mp mesh resumes on it, and on another shape,
+    to the same bits as the run that never stopped."""
+    tw, td, V = docs
+    cfg = LDAConfig(**dict(_TILED, seed=3))
+    mesh = _sub_mesh(devices, dp, mp)
+    app = LightLDA(tw, td, V, cfg, mesh=mesh, name="lda_ckm1")
+    app.train(num_iterations=2)
+    prefix = str(tmp_path / "ckm")
+    app.store(prefix)
+    app.train(num_iterations=1)
+    want = _docblock_state(app)
+    table_base.reset_tables()
+    core.shutdown()
+    for shape in ((dp, mp), (8, 1)):
+        mesh = _sub_mesh(devices, *shape)
+        app2 = LightLDA(tw, td, V, cfg, mesh=mesh, name="lda_ckm2")
+        app2.load(prefix)
+        assert app2._z.sharding.spec[1] == (core.DATA_AXIS,
+                                            core.MODEL_AXIS)
+        app2.train(num_iterations=1)
+        for got, ref in zip(_docblock_state(app2), want):
+            np.testing.assert_array_equal(got, ref)
+        table_base.reset_tables()
+        core.shutdown()
+
+
+def test_a_state_stored_by_the_parent_layout_resumes(devices, docs):
+    """``tests/data/lda_pr30/`` was written by PR 30's tree — z
+    ``[nb_pad, TB]`` and the doc counts ``[nb_pad, MAXD, C, 128]`` whole
+    on every chip of a dp8 mesh — after two sweeps at this file's corpus
+    (``LDAConfig(**_TILED, seed=3)``), with every token's topic after
+    that tree's third sweep beside it. What is stored is z flat in
+    packed block order and the counts dense [D, K]: the block-major,
+    data x model layout loads it unchanged and sweeps on to the same
+    bits."""
+    import os
+    tw, td, V = docs
+    prefix = os.path.join(os.path.dirname(__file__), "data", "lda_pr30",
+                          "pr30_dp8")
+    mesh = _sub_mesh(devices, 4, 2)
+    app = LightLDA(tw, td, V, LDAConfig(**dict(_TILED, seed=3)),
+                   mesh=mesh, name="lda_from_pr30")
+    app.load(prefix)
+    assert app._z.ndim == 3 and app._ndk.ndim == 5
+    assert app.word_topics().sum() == app.num_tokens
+    np.testing.assert_array_equal(
+        app.doc_topics().sum(1), np.bincount(td, minlength=app.num_docs))
+    app.train(num_iterations=1)
+    np.testing.assert_array_equal(
+        app.assignments(), np.load(prefix + ".next_sweep_z.npy"))
     table_base.reset_tables()
     core.shutdown()
 
@@ -346,13 +450,15 @@ def test_docblock_streamed_matches_inmemory(mesh_dp8, docs):
     ref.train(num_iterations=3)
     ref_w, ref_d = ref.word_topics(), ref.doc_topics()
     ref_nk = np.asarray(ref.summary.get())
-    ref_z = np.asarray(ref._z)
+    ref_z = np.asarray(ref._z)      # [steps, blocks a step, TB]
     table_base.reset_tables()
 
     app = LightLDA(tw, td, V, LDAConfig(**kw, stream_blocks=True),
                    mesh=mesh_dp8, name="db_stream")
     app.train(num_iterations=3)
-    np.testing.assert_array_equal(app._z_host, ref_z)
+    # the same packed block order, row-major, under either residency
+    np.testing.assert_array_equal(app._z_host,
+                                  ref_z.reshape(app._z_host.shape))
     np.testing.assert_array_equal(app.word_topics(), ref_w)
     np.testing.assert_array_equal(app.doc_topics(), ref_d)
     np.testing.assert_array_equal(np.asarray(app.summary.get()), ref_nk)
@@ -469,7 +575,8 @@ def test_docblock_streamed_checkpoint_crossmode(mesh_dp8, docs, tmp_path):
     mem = LightLDA(tw, td, V, LDAConfig(**kw), mesh=mesh_dp8,
                    name="dbs_ck2")
     mem.load(prefix)
-    np.testing.assert_array_equal(np.asarray(mem._z), z_after)
+    np.testing.assert_array_equal(
+        np.asarray(mem._z).reshape(z_after.shape), z_after)
     mem.train(num_iterations=1)
     ref_w = mem.word_topics()
     table_base.reset_tables()
@@ -528,7 +635,8 @@ def test_tiled_is_one_sampler_whatever_the_old_keywords(
                              doc_blocked=doc_blocked),
                    mesh=mesh_dp8, name="lda_kw")
     assert app._docblock and app._fused.name == "lda_docblock"
-    assert app._ndk.dtype == np.int16 and app._ndk.ndim == 4
+    # blocked int16 doc counts [steps, blocks a step, MAXD, C, 128]
+    assert app._ndk.dtype == np.int16 and app._ndk.ndim == 5
     app.sweep()
     np.testing.assert_array_equal(app.assignments(), benchmark_keywords_z)
 
@@ -603,47 +711,51 @@ def test_dp_mp_eval_compiles_for_a_v5e_2x2(mesh_v5e_2x2):
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = mesh_v5e_2x2
     K, V, tiles, B = 1024, 50_000, 8, 307_200      # chip_smoke's widths
-    vpad = V + 2
+    vpad, nbs, steps = V + 2, 600, 5
     app = types.SimpleNamespace(
         mesh=mesh, K=K, V=V, alpha=50.0 / K, beta=0.01,
         word_topic=types.SimpleNamespace(storage_shape=(vpad, tiles, 128)))
-    app._eval_chunk = lambda n: LightLDA._eval_chunk(app, n)
-    run = LightLDA._chunked_ll(app, LightLDA._build_word_gather(app))
-
-    def loglik(nwk3, ndk, nk, ws, rows, mask):
-        return run(nwk3, ndk.reshape(-1, tiles, 128),
-                   nk[:K].astype(jnp.float32), ws.reshape(-1),
-                   rows.reshape(-1), mask.reshape(-1).astype(jnp.float32))
+    for method in ("_eval_chunk", "_chunked_ll", "_build_word_gather"):
+        setattr(app, method,
+                types.MethodType(getattr(LightLDA, method), app))
+    LightLDA._build_blocked_loglik(app)
 
     def sds(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    lanes = sds((1, B), jnp.int32, P(None, core.DATA_AXIS))
-    jax.jit(loglik).trace(
+    # the operands as _setup_docblock places them: lanes and the doc
+    # counts' blocks split over data x model, the counts [steps, blocks
+    # a step, ..] of which the eval reads one call's steps
+    axes = (core.DATA_AXIS, core.MODEL_AXIS)
+    lanes = sds((1, B), jnp.int32, P(None, axes))
+    text = app._loglik.trace(
         sds((vpad, tiles, 128), jnp.int32, P(core.MODEL_AXIS, None, None)),
-        sds((3000, 16, tiles, 128), jnp.int16),
+        sds((steps, nbs, 16, tiles, 128), jnp.int16,
+            P(None, axes, None, None, None)),
         sds((K,), jnp.int32, P(core.MODEL_AXIS)),
-        lanes, lanes, lanes).lower().compile()
+        lanes, lanes, lanes, sds((1,), jnp.int32)).lower().compile() \
+        .as_text()
+    # nothing larger than one call's window of doc counts is moved
+    assert f"s16[{steps}," not in "".join(
+        ln for ln in text.splitlines() if " all-gather" in ln)
 
 
-def test_docblock_superstep_for_a_v5e_2x2_reduces_no_word_rows(mesh_v5e_2x2):
-    """The doc-blocked sweep compiled ahead of time for a described v5e
-    2x2 at chip_smoke's widths: ``to_stale`` casts and all-gathers the
-    mirror over the model axis (bf16, once), and the superstep gathers
-    from it locally — no all-reduce over gathered ``bf16[.., 8, 128]``
-    rows, no select under ``lda.gather_words``. Before, every step
-    psum'd its masked partial rows over the model axis (2 KB a lane)."""
-    import re
+@pytest.fixture(scope="module")
+def superstep_v5e_2x2(mesh_v5e_2x2):
+    """(the app's stand-in, ``lda.sample.chips`` as building it left the
+    gauge, compiled text of the resident doc-blocked superstep) at
+    chip_smoke's widths, compiled ONCE ahead of time for the described
+    v5e 2x2, the operands sharded as ``_setup_docblock`` places them."""
     import types
 
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = mesh_v5e_2x2
+    mesh, steps = mesh_v5e_2x2, 4
     K, V, tiles, B, TB, MAXD = 1024, 50_000, 8, 307_200, 512, 16
-    vpad, nb = V + 2, 4 * (B // TB)
+    vpad, nbs = V + 2, B // TB
     app = types.SimpleNamespace(
         mesh=mesh, K=K, V=V, alpha=50.0 / K, beta=0.01, _tb=TB,
         _interpret=False, _account_mirror=lambda: None,  # no chip to ask
@@ -652,37 +764,63 @@ def test_docblock_superstep_for_a_v5e_2x2_reduces_no_word_rows(mesh_v5e_2x2):
                          doc_blocked=True),
         word_topic=types.SimpleNamespace(storage_shape=(vpad, tiles, 128)))
     for method in ("_wrap_docblock_dp", "_build_stale_helpers",
-                   "_build_vocab_slice_scatter"):
+                   "_build_vocab_slice_scatter", "_split_blocks"):
         setattr(app, method,
                 types.MethodType(getattr(LightLDA, method), app))
+    app._split_blocks(nbs)
+    chips = telemetry.gauge("lda.sample.chips").value
     LightLDA._build_docblock_kernel(app)
+    axes = app._block_axes
 
     def sds(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    stale = app._to_stale.trace(sds(
-        (vpad, tiles, 128), jnp.int32,
-        P(core.MODEL_AXIS, None, None))).lower().compile()
+    def run(nk, ndk, z, wstale, ws, drels, msks, ts, key):
+        keys = jax.random.split(key, ws.shape[0])
+        (nk, ndk, z), _ = lax.scan(
+            lambda cy, inp: app._db_scan_body(wstale, cy, inp),
+            (nk, ndk, z), (ws, drels, msks, ts, keys))
+        return nk, ndk, z
+
+    lanes = sds((1, B), jnp.int32, P(None, axes))
+    state = (sds((K,), jnp.int32),
+             sds((steps, nbs, MAXD, tiles, 128), jnp.int16,
+                 P(None, axes, None, None, None)),
+             sds((steps, nbs, TB), jnp.int32, P(None, axes, None)))
+    text = jax.jit(
+        run, donate_argnums=(0, 1, 2),
+        out_shardings=tuple(x.sharding for x in state)).trace(
+        *state, sds((vpad, tiles, 128), jnp.bfloat16),
+        lanes, lanes, lanes, sds((1,), jnp.int32),
+        sds((2,), jnp.uint32)).lower().compile().as_text()
+    return app, chips, text
+
+
+def test_docblock_superstep_for_a_v5e_2x2_reduces_no_word_rows(
+        mesh_v5e_2x2, superstep_v5e_2x2):
+    """The doc-blocked sweep compiled ahead of time for a described v5e
+    2x2 at chip_smoke's widths: ``to_stale`` casts and all-gathers the
+    mirror over the model axis (bf16, once), and the superstep gathers
+    from it locally — no all-reduce over gathered ``bf16[.., 8, 128]``
+    rows, no select under ``lda.gather_words``. Before, every step
+    psum'd its masked partial rows over the model axis (2 KB a lane)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = mesh_v5e_2x2
+    app, _, text = superstep_v5e_2x2
+    vpad = 50_002
+    stale = app._to_stale.trace(jax.ShapeDtypeStruct(
+        (vpad, 8, 128), jnp.int32, sharding=NamedSharding(
+            mesh, P(core.MODEL_AXIS, None, None)))).lower().compile()
     gathers = [ln for ln in stale.as_text().splitlines()
                if " all-gather(" in ln or " all-gather-start(" in ln]
     assert len(gathers) == 1 and f"bf16[{vpad},8,128]" in gathers[0], gathers
     (mirror_sharding,) = jax.tree.leaves(stale.output_shardings)
     assert mirror_sharding.is_fully_replicated
-
-    def run(nk, ndk, z, wstale, ws, drels, msks, offs, key):
-        keys = jax.random.split(key, ws.shape[0])
-        (nk, ndk, z), _ = lax.scan(
-            lambda cy, inp: app._db_scan_body(wstale, cy, inp),
-            (nk, ndk, z), (ws, drels, msks, offs, keys))
-        return nk, ndk, z
-
-    lanes = sds((1, B), jnp.int32, P(None, core.DATA_AXIS))
-    text = jax.jit(run, donate_argnums=(0, 1, 2)).trace(
-        sds((K,), jnp.int32), sds((nb, MAXD, tiles, 128), jnp.int16),
-        sds((nb, TB), jnp.int32), sds((vpad, tiles, 128), jnp.bfloat16),
-        lanes, lanes, lanes, sds((1,), jnp.int32),
-        sds((2,), jnp.uint32)).lower().compile().as_text()
     assert "tpu_custom_call" in text        # the Mosaic kernel is in it
     assert not re.search(r"= bf16\[[0-9,]*8,128\]\S* all-reduce", text)
     scoped = [ln for ln in text.splitlines()
@@ -691,6 +829,31 @@ def test_docblock_superstep_for_a_v5e_2x2_reduces_no_word_rows(mesh_v5e_2x2):
     assert not [ln for ln in scoped
                 if " select(" in ln or " all-reduce" in ln
                 or " all-gather" in ln]
+
+
+def test_docblock_superstep_for_a_v5e_2x2_gives_each_chip_a_quarter(
+        superstep_v5e_2x2):
+    """The same compiled superstep, read for the partition: the sampler
+    kernel of one chip sees ``nbs / (dp * mp)`` = 150 of the step's 600
+    blocks (300 while the blocks were split over data alone), gathers
+    150 x 512 word rows for them, and no window of z or of the doc
+    counts crosses chips — the only collective left in the step is the
+    all-reduce of the 1,024 topic-summary deltas."""
+    import re
+    app, chips, text = superstep_v5e_2x2
+    assert app._block_axes == (core.DATA_AXIS, core.MODEL_AXIS)
+    assert chips == 4
+    kernel = [ln for ln in text.splitlines() if " custom-call(" in ln
+              and "tpu_custom_call" in ln]
+    assert kernel and all("s16[150,16,8,128]" in ln for ln in kernel), kernel
+    gather = [ln for ln in text.splitlines()
+              if "jit(lda.gather_words)" in ln and " gather(" in ln]
+    assert gather and all("bf16[76800,8,128]" in ln for ln in gather), gather
+    assert not re.search(r" all-gather(-start)?\(", text), \
+        [ln for ln in text.splitlines() if " all-gather" in ln]
+    reduces = [ln for ln in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", ln)]
+    assert reduces and all("s32[8,128]" in ln for ln in reduces), reduces
 
 
 @pytest.mark.parametrize("bad", [-1, "V"])

@@ -106,8 +106,8 @@ def test_lda_no_default_device_leak(offset_mesh, sampler, tmp_path):
     tw = rng.integers(0, 16, 48).astype(np.int32)
     td = np.sort(rng.integers(0, 4, 48)).astype(np.int32)
     kw = dict(num_topics=4, batch_tokens=8) if sampler == "gibbs" \
-        else dict(num_topics=128, batch_tokens=128, block_tokens=64,
-                  block_docs=8)
+        else dict(num_topics=128, batch_tokens=256, block_tokens=64,
+                  block_docs=8)     # 4 blocks a step: one a chip of 2x2
     before = _snapshot()
     app = LightLDA(tw, td, 16,
                    LDAConfig(steps_per_call=2, sampler=sampler, seed=0,

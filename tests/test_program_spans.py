@@ -347,7 +347,7 @@ def _superstep_args(lda):
 
 def test_no_collective_under_gather_words_on_a_dp_mp_mesh(mesh8):
     from multiverso_tpu import core
-    lda, _, _ = _lda(mesh8, batch_tokens=1024)
+    lda, _, _ = _lda(mesh8, batch_tokens=2048)     # a block a chip
     args = _superstep_args(lda)
     mirror = args[4]
     # the mirror is the chip's whole per-sweep cache; the table it
@@ -360,7 +360,7 @@ def test_no_collective_under_gather_words_on_a_dp_mp_mesh(mesh8):
     assert [ln for ln in scoped if " gather(" in ln]
     assert not [ln for ln in scoped if _COLLECTIVE.search(ln)]
     # the pattern does see this mesh's collectives: the summary delta's
-    # psum over the data axis sits under lda.sample
+    # psum over data x model sits under lda.sample
     assert [ln for ln in lines if _COLLECTIVE.search(ln)
             and "jit(lda.sample)" in ln]
 
@@ -397,7 +397,7 @@ def test_mirror_replications_counted_once_a_sweep(shape, request):
         lda, _, _ = _lda(request.getfixturevalue("mesh1"))
     else:
         lda, _, _ = _lda(request.getfixturevalue("mesh8"),
-                         batch_tokens=1024)
+                         batch_tokens=2048)
     snap = metrics.snapshot()
     assert snap["gauges"]["lda.mirror.bytes_per_chip"] == \
         2 * np.prod(lda.word_topic.storage_shape)
@@ -443,7 +443,7 @@ def test_assignments_docblock_in_corpus_order(mesh1, shuffled):
 @pytest.mark.parametrize("mode", ["gibbs", "dp_mp", "streamed"])
 def test_assignments_other_layouts_match_the_tables(request, mode):
     kw = {"gibbs": dict(sampler="gibbs", doc_blocked=False, num_topics=8),
-          "dp_mp": dict(batch_tokens=1024),     # 4 blocks a step over data=4
+          "dp_mp": dict(batch_tokens=2048),  # 8 blocks a step, 4x2 chips
           "streamed": dict(stream_blocks=True)}[mode]
     mesh = request.getfixturevalue("mesh8" if mode == "dp_mp" else "mesh1")
     lda, tw, td = _lda(mesh, name=f"lda_spans_{mode}", **kw)
