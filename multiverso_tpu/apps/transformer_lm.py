@@ -8,12 +8,17 @@ embedding is a ``MatrixTable`` read by row gather and written by row
 scatter-add of the token gradients, word2vec's forms.
 
 The model is built from the keys of a published ``config.json``
-(:class:`LMConfig`): which attention a layer has (``kv_lora_rank`` set:
-multi-head latent attention, :mod:`multiverso_tpu.ops.latent_attention`)
-and which feed-forward block (a dense SwiGLU for the first
+(:class:`LMConfig`): which mixer a layer has (``layer_types`` names it a
+layer: ``linear_attention``, the gated delta rule of
+:mod:`multiverso_tpu.ops.gated_delta`, or ``full_attention``, softmax
+attention with per-head keys; else ``kv_lora_rank`` set: multi-head
+latent attention, :mod:`multiverso_tpu.ops.latent_attention`), which
+feed-forward block (a dense SwiGLU for the first
 ``first_k_dense_replace`` layers, then shared experts beside routed
-ones, :mod:`multiverso_tpu.ops.moe`) are read from it, as is every
-width. ``ep_size`` / ``ep_rank`` say that this chip is one of a group
+ones, :mod:`multiverso_tpu.ops.moe`; dense throughout where the config
+has no experts) and where the block's norms stand (``model_type``:
+before mixer and feed-forward, or — ``olmo_hybrid`` — on their outputs)
+are read from it, as is every width. ``ep_size`` / ``ep_rank`` say that this chip is one of a group
 that shares each layer: ``n_routed_experts`` is then the number of
 experts HELD HERE (the router keeps ``n_routed_experts * ep_size``
 outputs), ``vocab_size`` the rows of the vocabulary held here
@@ -21,18 +26,25 @@ outputs), ``vocab_size`` the rows of the vocabulary held here
 chip runs without the group's exchange; nothing stands in for it.
 
 Tables (the count stays in the tens): ``embed`` [V, D] (MatrixTable),
-``head`` [V, D], ``norms`` [3 L + 1, D]; a layer: ``l{i}.attn``
-[D, H (nope + rope) | rank + rope | H v] (``w_q | w_kv_a | w_o``, the
-last stored out x in), ``l{i}.kv_b`` [rank, H (nope + v)]; a dense
-layer ``l{i}.mlp`` [3, D, F] (gate, up, down stored [D, F]); an expert
-layer ``l{i}.router`` [D, E], ``l{i}.shared`` [3, D, shared F],
-``l{i}.experts`` [held, 3, D, F] — the leading dimension is the expert,
-which is what a table shards over the model axis.
-:func:`table_layout` says where every tensor of a published role lies.
+``head`` [V, D], ``norms`` [rows, D] (3 or 4 rows a layer,
+:func:`norm_offsets`, and the final norm's); a latent-attention layer:
+``l{i}.attn`` [D, H (nope + rope) | rank + rope | H v] (``w_q | w_kv_a |
+w_o``, the last stored out x in), ``l{i}.kv_b`` [rank, H (nope + v)]; a
+linear-attention layer: ``l{i}.gdn_in`` [D, q | k | v | gate | a | b],
+``l{i}.gdn_conv`` [taps, q | k | v], ``l{i}.gdn_decay`` [2, H]
+(``a_log``, ``dt_bias``), ``l{i}.gdn_out`` [D, H v] (out x in); a
+full-attention layer: ``l{i}.attn`` [D, q | k | v | o] (``w_o`` stored
+out x in); a dense layer ``l{i}.mlp`` [3, D, F] (gate, up, down stored
+[D, F]); an expert layer ``l{i}.router`` [D, E], ``l{i}.shared``
+[3, D, shared F], ``l{i}.experts`` [held, 3, D, F] — the leading
+dimension is the expert, which is what a table shards over the model
+axis. :func:`table_layout` says where every tensor of a published role
+lies.
 
 Precision: tables, gradients and Adam's moments float32; matrix products
 on bfloat16 operands with float32 accumulation; norms, rotary, router
-logits and softmax, attention softmax and the loss float32. The forward
+logits and softmax, attention softmax, the recurrence's decay, gates
+and state between chunks, and the loss float32. The forward
 pass keeps only the residual entering each layer; the backward pass
 goes a layer at a time, recomputes the layer, and folds each table's
 gradient into the table (Adam) before the layer below starts, so no more
@@ -58,6 +70,7 @@ from jax import lax
 
 from multiverso_tpu import core, telemetry
 from multiverso_tpu.data.packing import Batch, pack_documents, real_tokens
+from multiverso_tpu.ops import gated_delta as gdn
 from multiverso_tpu.ops import interpret_mode
 from multiverso_tpu.ops import latent_attention as mla
 from multiverso_tpu.ops import moe
@@ -71,7 +84,11 @@ from multiverso_tpu.utils.async_buffer import prefetch_iterator
 PROBE_ROWS = 1024       # embedding rows whose gradient a step returns
 AUX_KEEP = 4            # the last steps whose whole aux stays on the device
 PREFETCH_STEPS = 4      # packed steps the input thread runs ahead
-_SMALL_AUX = ("ce", "balance", "moe", "imbalance", "attend")
+_SMALL_AUX = ("ce", "balance", "moe", "imbalance", "attend", "gdn")
+LATENT, LINEAR, FULL = "latent", "linear_attention", "full_attention"
+# model_type -> the block's norms stand on the outputs of mixer and
+# feed-forward (the Olmo 2 / 3 family's reordered norm), not before them
+_POST_NORM = {"deepseek_v2": False, "olmo_hybrid": True}
 
 
 @dataclasses.dataclass
@@ -99,6 +116,22 @@ class LMConfig:
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
     aux_loss_alpha: float = 0.001
+    model_type: str = "deepseek_v2"
+    # a layer's mixer by name (its first num_hidden_layers entries);
+    # None: latent attention throughout
+    layer_types: Optional[List[str]] = None
+    num_key_value_heads: Optional[int] = None
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+    rope_parameters: Optional[dict] = None
+    # published keys that change the model and are built one way only
+    attention_bias: bool = False
+    hidden_act: str = "silu"
+    tie_word_embeddings: bool = False
     # this chip's place in the group that shares each layer
     ep_size: int = 1
     ep_rank: int = 0
@@ -106,6 +139,8 @@ class LMConfig:
     # the step and the optimizer
     sequences: int = 2
     sequence_length: int = 64
+    # sequences the packer holds open (None: 4 x sequences)
+    open_sequences: Optional[int] = None
     learning_rate: float = 4.2e-4
     warmup_steps: int = 1               # linear warm-up; 1 = none
     beta1: float = 0.9
@@ -124,12 +159,19 @@ class LMConfig:
     expert_chunk_rows: int = 8192
     mlp_chunks: int = 1
     head_chunks: int = 1
+    gdn_chunk: int = 64         # tokens the recurrence solves at once
     compute_dtype: str = "bfloat16"     # operands of the matrix products
 
     @classmethod
     def from_dict(cls, d: dict) -> "LMConfig":
+        """From a published config's keys (and the program's): a key
+        the config lacks is a part the model lacks — no experts without
+        ``n_routed_experts``, no latent attention without
+        ``kv_lora_rank`` — and not this class's small default."""
         names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
+        absent = {"n_routed_experts": 0, "kv_lora_rank": None}
+        return cls(**{**{k: v for k, v in absent.items() if k not in d},
+                      **{k: v for k, v in d.items() if k in names}})
 
     @property
     def router_width(self) -> int:
@@ -148,14 +190,45 @@ class LMConfig:
         return layer < self.first_k_dense_replace \
             or self.n_routed_experts == 0
 
+    def mixer(self, layer: int) -> str:
+        """``LATENT``, ``LINEAR`` or ``FULL``."""
+        return LATENT if self.layer_types is None \
+            else self.layer_types[layer]
+
+    def layers_of(self, kind: str) -> int:
+        return sum(self.mixer(i) == kind
+                   for i in range(self.num_hidden_layers))
+
+    @property
+    def post_norm(self) -> bool:
+        return _POST_NORM[self.model_type]
+
+    @property
+    def head_dim(self) -> int:
+        """Of ``full_attention``: the hidden size over the heads."""
+        return self.hidden_size // self.num_attention_heads
+
     def check(self) -> None:
-        if self.kv_lora_rank is None:
+        if self.model_type not in _POST_NORM:
             raise NotImplementedError(
-                "attention without kv_lora_rank: only latent attention "
-                "is built")
+                f"model_type {self.model_type!r}: built are "
+                f"{sorted(_POST_NORM)}")
+        if self.attention_bias:
+            raise NotImplementedError("attention_bias: no projection "
+                                      "has a bias here")
+        if self.hidden_act != "silu":
+            raise NotImplementedError(f"hidden_act {self.hidden_act!r}: "
+                                      "only silu (SwiGLU) is built")
+        if self.tie_word_embeddings:
+            raise NotImplementedError("tie_word_embeddings: embedding "
+                                      "and head are two tables")
         if self.q_lora_rank is not None:
             raise NotImplementedError(
                 "q_lora_rank: only the direct query projection is built")
+        if self.layer_types is None:
+            self._check_latent()
+        else:
+            self._check_layer_types()
         if self.scoring_func != "softmax":
             raise NotImplementedError(f"scoring_func {self.scoring_func!r}")
         if self.warmup_steps < 1:
@@ -171,6 +244,62 @@ class LMConfig:
                 raise ValueError(f"{name} {n} does not divide "
                                  f"{self.sequences} sequences")
 
+    def _check_latent(self) -> None:
+        if self.kv_lora_rank is None:
+            raise NotImplementedError(
+                "attention without kv_lora_rank and without layer_types: "
+                "latent attention is what a config that names no mixer "
+                "gets")
+        if self.post_norm:
+            raise NotImplementedError(
+                f"latent attention in a {self.model_type} block")
+
+    def _check_layer_types(self) -> None:
+        kinds = self.layer_types[:self.num_hidden_layers]
+        if len(kinds) < self.num_hidden_layers:
+            raise ValueError(f"layer_types names {len(kinds)} of "
+                             f"{self.num_hidden_layers} layers")
+        for kind in kinds:
+            if kind not in (LINEAR, FULL):
+                raise NotImplementedError(
+                    f"layer_types entry {kind!r}: built are "
+                    f"{LINEAR!r} and {FULL!r}")
+        if not self.post_norm:
+            raise NotImplementedError(
+                f"layer_types in a {self.model_type} block: the mixers "
+                "it names are built with the norms on their outputs")
+        if FULL in kinds:
+            heads = self.num_attention_heads
+            if self.num_key_value_heads not in (None, heads):
+                raise NotImplementedError(
+                    f"num_key_value_heads {self.num_key_value_heads} of "
+                    f"{heads} heads: grouped key-value heads are not "
+                    "built")
+            if self.hidden_size % heads:
+                raise ValueError(f"{heads} heads do not divide hidden "
+                                 f"size {self.hidden_size}")
+            if (self.rope_parameters or {}).get("rope_theta") is not None:
+                raise NotImplementedError(
+                    "rope_parameters.rope_theta: full attention is built "
+                    "without a rotary embedding")
+        if LINEAR in kinds:
+            if self.linear_num_value_heads != self.linear_num_key_heads:
+                raise NotImplementedError(
+                    "linear_num_value_heads other than "
+                    "linear_num_key_heads: value heads that share a key "
+                    "head are not built")
+            if min(self.linear_num_key_heads, self.linear_key_head_dim,
+                   self.linear_value_head_dim,
+                   self.linear_conv_kernel_dim) < 1:
+                raise ValueError("linear_attention wants its linear_* "
+                                 "sizes")
+            if self.linear_value_head_dim > self.hidden_size:
+                raise ValueError("linear_value_head_dim over hidden_size")
+            if self.sequence_length % min(self.gdn_chunk,
+                                          self.sequence_length):
+                raise ValueError(f"gdn_chunk {self.gdn_chunk} does not "
+                                 f"divide {self.sequence_length}")
+
 
 # -- tables and the tensors in them ------------------------------------------
 
@@ -181,18 +310,48 @@ def _attn_columns(c: LMConfig) -> Tuple[int, int, int]:
             c.num_attention_heads * c.v_head_dim)
 
 
+def gdn_shape(c: LMConfig) -> gdn.GatedDeltaShape:
+    return gdn.GatedDeltaShape(
+        c.linear_num_key_heads, c.linear_key_head_dim,
+        c.linear_value_head_dim, c.linear_conv_kernel_dim,
+        bool(c.linear_allow_neg_eigval), c.rms_norm_eps, c.gdn_chunk,
+        c.compute_dtype)
+
+
+def norm_offsets(c: LMConfig) -> List[int]:
+    """Row of ``norms`` at which each layer's rows start, and (last) the
+    final norm's: a latent layer has 3 (attention, latent, feed-forward),
+    a linear-attention layer 3 (mixer, feed-forward, the gated output
+    norm), a full-attention layer 4 (mixer, feed-forward, q, k)."""
+    rows = [0]
+    for i in range(c.num_hidden_layers):
+        rows.append(rows[-1] + (4 if c.mixer(i) == FULL else 3))
+    return rows
+
+
 def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
     """Every table's logical shape, in the order the superstep holds
     them; the index in this order seeds the table's start values."""
     D, L = c.hidden_size, c.num_hidden_layers
     shapes: Dict[str, Tuple[int, ...]] = {
         "embed": (c.vocab_size, D), "head": (c.vocab_size, D),
-        "norms": (3 * L + 1, D)}
+        "norms": (norm_offsets(c)[L] + 1, D)}
     for i in range(L):
-        shapes[f"l{i}.attn"] = (D, sum(_attn_columns(c)))
-        shapes[f"l{i}.kv_b"] = (
-            c.kv_lora_rank,
-            c.num_attention_heads * (c.qk_nope_head_dim + c.v_head_dim))
+        kind = c.mixer(i)
+        if kind == LATENT:
+            shapes[f"l{i}.attn"] = (D, sum(_attn_columns(c)))
+            shapes[f"l{i}.kv_b"] = (
+                c.kv_lora_rank,
+                c.num_attention_heads * (c.qk_nope_head_dim
+                                         + c.v_head_dim))
+        elif kind == LINEAR:
+            g = gdn_shape(c)
+            shapes[f"l{i}.gdn_in"] = (D, g.in_width)
+            shapes[f"l{i}.gdn_conv"] = (g.taps, g.conv_width)
+            shapes[f"l{i}.gdn_decay"] = (2, g.heads)
+            shapes[f"l{i}.gdn_out"] = (D, g.heads * g.dv)
+        else:
+            shapes[f"l{i}.attn"] = (D, 4 * D)
         if c.is_dense(i):
             shapes[f"l{i}.mlp"] = (3, D, c.intermediate_size)
         else:
@@ -206,28 +365,57 @@ def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
 def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
     """``{table: {role: index}}``: the tensor with that published role
     is ``table[index]``. Roles: ``embed``, ``head`` [V, D],
-    ``final_norm``; a layer's ``attn_norm``, ``w_q``, ``w_kv_a``,
+    ``final_norm``; a latent layer's ``attn_norm``, ``w_q``, ``w_kv_a``,
     ``kv_norm``, ``w_kv_b``, ``w_o`` [D, H v] (out x in), ``ffn_norm``;
-    ``w_gate`` / ``w_up`` / ``w_down`` [D, F]; ``router``,
+    a linear- or full-attention layer's ``mixer_norm``, ``ffn_norm``,
+    ``w_q``, ``w_k``, ``w_v``, ``w_o`` (out x in), and the first's
+    ``w_g``, ``w_a``, ``w_b``, ``conv`` [taps, q | k | v], ``a_log``,
+    ``dt_bias``, ``o_norm`` [v head dim], the second's ``q_norm``,
+    ``k_norm``; ``w_gate`` / ``w_up`` / ``w_down`` [D, F]; ``router``,
     ``shared_gate`` / ``_up`` / ``_down``, ``exp_gate`` / ``_up`` /
     ``_down`` [held, D, F]."""
-    q, a, _ = _attn_columns(c)
     every = slice(None)
-    L = c.num_hidden_layers
+    L, D = c.num_hidden_layers, c.hidden_size
+    rows = norm_offsets(c)
     layout: Dict[str, Dict[str, tuple]] = {
         "embed": {"embed": (slice(0, c.vocab_size),)},   # less the scratch row
         "head": {"head": (every,)},
-        "norms": {"final_norm": (3 * L,)}}
+        "norms": {"final_norm": (rows[L],)}}
+
+    def columns(names, widths):
+        ends = np.cumsum(widths)
+        return {f"l{i}.{n}": (every, slice(int(e - w), int(e)))
+                for n, w, e in zip(names, widths, ends)}
+
     for i in range(L):
-        layout["norms"].update({
-            f"l{i}.attn_norm": (3 * i,),
-            f"l{i}.kv_norm": (3 * i + 1, slice(0, c.kv_lora_rank)),
-            f"l{i}.ffn_norm": (3 * i + 2,)})
-        layout[f"l{i}.attn"] = {
-            f"l{i}.w_q": (every, slice(0, q)),
-            f"l{i}.w_kv_a": (every, slice(q, q + a)),
-            f"l{i}.w_o": (every, slice(q + a, None))}
-        layout[f"l{i}.kv_b"] = {f"l{i}.w_kv_b": (every,)}
+        kind, r = c.mixer(i), rows[i]
+        if kind == LATENT:
+            layout["norms"].update({
+                f"l{i}.attn_norm": (r,),
+                f"l{i}.kv_norm": (r + 1, slice(0, c.kv_lora_rank)),
+                f"l{i}.ffn_norm": (r + 2,)})
+            layout[f"l{i}.attn"] = columns(("w_q", "w_kv_a", "w_o"),
+                                           _attn_columns(c))
+            layout[f"l{i}.kv_b"] = {f"l{i}.w_kv_b": (every,)}
+        elif kind == LINEAR:
+            g = gdn_shape(c)
+            layout["norms"].update({
+                f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,),
+                f"l{i}.o_norm": (r + 2, slice(0, g.dv))})
+            layout[f"l{i}.gdn_in"] = columns(
+                ("w_q", "w_k", "w_v", "w_g", "w_a", "w_b"),
+                (g.heads * g.dk, g.heads * g.dk, g.heads * g.dv,
+                 g.heads * g.dv, g.heads, g.heads))
+            layout[f"l{i}.gdn_conv"] = {f"l{i}.conv": (every,)}
+            layout[f"l{i}.gdn_decay"] = {f"l{i}.a_log": (0,),
+                                         f"l{i}.dt_bias": (1,)}
+            layout[f"l{i}.gdn_out"] = {f"l{i}.w_o": (every,)}
+        else:
+            layout["norms"].update({
+                f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,),
+                f"l{i}.q_norm": (r + 2,), f"l{i}.k_norm": (r + 3,)})
+            layout[f"l{i}.attn"] = columns(("w_q", "w_k", "w_v", "w_o"),
+                                           (D,) * 4)
         if c.is_dense(i):
             layout[f"l{i}.mlp"] = {f"l{i}.w_{part}": (j,) for j, part in
                                    enumerate(("gate", "up", "down"))}
@@ -258,15 +446,36 @@ def start_std(c: LMConfig, name: str) -> float:
     return c.init_std
 
 
+def _table_key(c: LMConfig, index):
+    """The key a table's start is drawn from: the seed's, folded with
+    the table's index in :func:`table_shapes` (traceable)."""
+    seed = int(c.seed)
+    key = jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF),
+                             (seed >> 31) & 0x7FFFFFFF)
+    return jax.random.fold_in(key, index)
+
+
 def start_values(c: LMConfig, index, shape, std):
     """A table's start (the norm weights apart, which are 1):
     normal(0, ``std``) from the seed and the table's index in
     :func:`table_shapes` (traceable, the index and ``std`` too)."""
-    seed = int(c.seed)
-    key = jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF),
-                             (seed >> 31) & 0x7FFFFFFF)
-    return std * jax.random.normal(jax.random.fold_in(key, index),
-                                   tuple(shape), jnp.float32)
+    return std * jax.random.normal(_table_key(c, index), tuple(shape),
+                                   jnp.float32)
+
+
+def decay_start(c: LMConfig, index, heads: int):
+    """A linear-attention layer's ``[a_log; dt_bias]`` [2, H] as the
+    public layer starts them: ``a_log = log U(0, 16)`` (the draw kept
+    off 0), ``dt_bias`` the inverse softplus of ``exp(U(log 0.001,
+    log 0.1))``, from the seed and the table's index."""
+    key = _table_key(c, index)
+    a = jax.random.uniform(jax.random.fold_in(key, 0), (heads,),
+                           jnp.float32, 0.0, 16.0)
+    dt = jnp.exp(jax.random.uniform(
+        jax.random.fold_in(key, 1), (heads,), jnp.float32,
+        float(np.log(0.001)), float(np.log(0.1))))
+    return jnp.stack([jnp.log(jnp.maximum(a, 1e-4)),
+                      dt + jnp.log(-jnp.expm1(-dt))])
 
 
 # -- the phases of a step, each under a program scope --------------------------
@@ -315,6 +524,13 @@ def _shared_experts_group(h, w):
     return _swiglu(h, w)
 
 
+@telemetry.scope("lm.block_norm")
+def _output_norm(y, weight, eps):
+    """A block's norm on the output of its mixer or feed-forward (the
+    reordered norm); ``eps`` arrives as an array."""
+    return mla.rms_norm(y, weight, eps)
+
+
 @telemetry.scope("lm.head_loss")
 def _head_loss_group(group, final_norm, head, eps):
     """Sum of the cross-entropy over a group's predicting tokens;
@@ -348,20 +564,27 @@ class TransformerLM:
                 "of tokens over the model axis is not built")
         # a CPU mesh (tests) runs the attention kernels interpreted
         self._interpret = interpret_mode(self.mesh)
-        self._shape = mla.LatentShape(
-            c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
-            c.v_head_dim, c.kv_lora_rank, c.rms_norm_eps, c.compute_dtype)
-        self._rotary = mla.Rotary.from_config(
-            rope_dim=c.qk_rope_head_dim,
-            qk_dim=c.qk_nope_head_dim + c.qk_rope_head_dim,
-            theta=float(c.rope_theta), scaling=c.rope_scaling)
+        if c.layer_types is None:
+            self._shape = mla.LatentShape(
+                c.num_attention_heads, c.qk_nope_head_dim,
+                c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank,
+                c.rms_norm_eps, c.compute_dtype)
+            self._rotary = mla.Rotary.from_config(
+                rope_dim=c.qk_rope_head_dim,
+                qk_dim=c.qk_nope_head_dim + c.qk_rope_head_dim,
+                theta=float(c.rope_theta), scaling=c.rope_scaling)
+        else:
+            self._gdn = gdn_shape(c)
+        self._norm_rows = norm_offsets(c)
         option = AddOption(learning_rate=c.learning_rate, momentum=c.beta1,
                            rho=c.beta2, lam=c.adam_eps)
         self.tables: Dict[str, Table] = {}
         with telemetry.span("lm.setup.init_tables"):
             draw = jax.jit(
-                lambda index, std, ones, shape, pad: jnp.pad(
-                    jnp.ones(shape, jnp.float32) if ones
+                lambda index, std, start, shape, pad: jnp.pad(
+                    jnp.ones(shape, jnp.float32) if start == "ones"
+                    else decay_start(c, index, shape[1])
+                    if start == "decay"
                     else start_values(c, index, shape, std), pad),
                 static_argnums=(2, 3, 4))       # one program a shape
             for index, (tname, shape) in enumerate(
@@ -378,8 +601,10 @@ class TransformerLM:
                     else Table(f"{name}.{tname}", shape, "float32", **kw)
                 # the start is drawn on the device and installed as it
                 # is: 2.5 GB of it never pass the host
-                table.put_raw(draw(index, start_std(c, tname),
-                                   tname == "norms", shape, pad))
+                start = "ones" if tname == "norms" else "decay" \
+                    if tname.endswith(".gdn_decay") else "normal"
+                table.put_raw(draw(index, start_std(c, tname), start,
+                                   shape, pad))
                 self.tables[tname] = table
             jax.block_until_ready([t.param for t in self.tables.values()])
         self.parameters = sum(int(np.prod(s))
@@ -392,11 +617,9 @@ class TransformerLM:
 
     # -- the model ---------------------------------------------------------
 
-    def _layer(self, i: int, x, t, norms, doc, pos, real):
-        """One decoder layer on the residual ``x`` [B, S, D] float32,
-        from the layer's tables ``t`` and its three rows of ``norms``;
-        returns ``(new residual, the layer's balance loss)`` and what an
-        expert layer routed (counts, chosen experts, rows computed)."""
+    def _latent(self, x, t, norms, doc, pos):
+        """Latent attention's addition to the residual (its own norm
+        first, inside ``project``)."""
         c = self.config
         q, a, _ = _attn_columns(c)
         attn = t["attn"]
@@ -407,7 +630,53 @@ class TransformerLM:
         o = mla.attend(q_nope, q_pe, k_nope, k_pe, v, doc,
                        scale=self._rotary.score_scale,
                        block=c.attention_block, interpret=self._interpret)
-        x = x + mla.output(o, attn[:, q + a:])
+        return mla.output(o, attn[:, q + a:])
+
+    def _gated_delta(self, x, doc, t_in, conv, decay, t_out, o_norm):
+        """The gated delta rule's addition to the residual, before the
+        block's norm."""
+        g = self._gdn
+        qkv, gate, log_decay, beta = gdn.project(x, t_in, decay[0],
+                                                 decay[1], g)
+        o = gdn.recur(gdn.short_conv(qkv, conv, doc), log_decay, beta, doc,
+                      g)
+        return gdn.gate_out(o, gate, o_norm, t_out, g)
+
+    def _attention(self, x, doc, attn, q_norm, k_norm):
+        """Softmax attention with per-head keys, QK-norm and no rotary
+        part, before the block's norm."""
+        c = self.config
+        D = c.hidden_size
+        q, k, v = mla.project_heads(
+            x, attn[:, :D], attn[:, D:2 * D], attn[:, 2 * D:3 * D], q_norm,
+            k_norm, c.num_attention_heads, c.rms_norm_eps, c.compute_dtype)
+        o = mla.attend_heads(q, k, v, doc, scale=c.head_dim ** -0.5,
+                             block=c.attention_block,
+                             interpret=self._interpret)
+        return mla.output_heads(o, attn[:, 3 * D:])
+
+    def _layer(self, i: int, x, t, norms, doc, pos, real):
+        """One decoder layer on the residual ``x`` [B, S, D] float32,
+        from the layer's tables ``t`` and its rows of ``norms``;
+        returns ``(new residual, the layer's balance loss)`` and what an
+        expert layer routed (counts, chosen experts, rows computed)."""
+        c = self.config
+        kind, eps = c.mixer(i), jnp.float32(c.rms_norm_eps)
+        if kind == LATENT:
+            x = x + self._latent(x, t, norms, doc, pos)
+        else:
+            if kind == LINEAR:
+                y = self._gated_delta(
+                    x, doc, t["gdn_in"], t["gdn_conv"], t["gdn_decay"],
+                    t["gdn_out"], norms[2, :c.linear_value_head_dim])
+            else:
+                y = self._attention(x, doc, t["attn"], norms[2], norms[3])
+            x = x + _output_norm(y, norms[0], eps)
+        if c.post_norm:
+            y = _over_sequences(_dense_mlp_group, c.mlp_chunks,
+                                x.astype(c.compute_dtype), t["mlp"])
+            return (x + _output_norm(y.reshape(x.shape), norms[1], eps),
+                    jnp.zeros(())), None
         h = mla.rms_norm(x, norms[2], c.rms_norm_eps)
         hb = h.astype(c.compute_dtype)
         if c.is_dense(i):
@@ -434,9 +703,10 @@ class TransformerLM:
 
     def _layer_tables(self, tables: Dict[str, Any], i: int):
         prefix = f"l{i}."
+        rows = self._norm_rows
         return ({k[len(prefix):]: v for k, v in tables.items()
                  if k.startswith(prefix)},
-                tables["norms"][3 * i:3 * i + 3])
+                tables["norms"][rows[i]:rows[i + 1]])
 
     def _head_loss(self, x, final_norm, head, tokens, doc):
         """Mean cross-entropy over the tokens that have a successor in
@@ -474,12 +744,12 @@ class TransformerLM:
         for i in range(L):
             entering.append(x)
             (x, _), _ = layer(i, x, *self._layer_tables(tables, i))
-        norms = tables["norms"]
+        norms, rows = tables["norms"], self._norm_rows
         ce, (d_x, d_final, d_head) = jax.value_and_grad(
             self._head_loss, argnums=(0, 1, 2))(
-                x, norms[3 * L], tables["head"], tokens, doc)
+                x, norms[rows[L]], tables["head"], tokens, doc)
         out = {"head": consume("head", d_head)}
-        d_norms = jnp.zeros_like(norms).at[3 * L].set(d_final)
+        d_norms = jnp.zeros_like(norms).at[rows[L]].set(d_final)
         balance, routed = jnp.zeros(()), []
         for i in reversed(range(L)):
             # the barrier keeps the compiler from sharing this forward
@@ -489,7 +759,7 @@ class TransformerLM:
                                      *self._layer_tables(tables, i),
                                      has_aux=True)
             d_x, d_t, d_n = vjp((d_x, jnp.ones(())))
-            d_norms = d_norms.at[3 * i:3 * i + 3].set(d_n)
+            d_norms = d_norms.at[rows[i]:rows[i + 1]].set(d_n)
             done = {f"l{i}.{k}": consume(f"l{i}.{k}", g)
                     for k, g in d_t.items()}
             d_x, done = lax.optimization_barrier((d_x, done))
@@ -500,9 +770,19 @@ class TransformerLM:
         out["norms"] = consume("norms", d_norms)
         out["embed"] = consume("embed", _embed_scatter(tables["embed"],
                                                        tokens, d_x))
-        # every layer's attention runs the same block pairs
-        aux = {"ce": ce, "balance": balance,
-               "attend": L * mla.key_blocks(doc, c.attention_block)}
+        aux = {"ce": ce, "balance": balance}
+        attending = L - c.layers_of(LINEAR)
+        if attending:
+            # every attending layer's kernels run the same block pairs
+            aux["attend"] = attending * mla.key_blocks(doc,
+                                                       c.attention_block)
+        if attending < L:
+            # (sequence, linear layer, chunk) triples computed, and the
+            # restarts of their states: one a document and layer
+            B, S = doc.shape
+            aux["gdn"] = (L - attending) * jnp.stack([
+                jnp.asarray(B * (S // min(c.gdn_chunk, S)), jnp.int32),
+                gdn.doc_starts(doc)])
         if routed:
             aux.update(zip(("counts", "chosen", "rows"),
                            (jnp.stack(a) for a in zip(*routed))))
@@ -526,6 +806,11 @@ class TransformerLM:
         probe_expert = next((f"l{i}.experts" for i in
                              range(c.num_hidden_layers)
                              if not c.is_dense(i)), None)
+        # the first recurrence's key projection, entry by entry
+        probe_gdn = next((f"l{i}.gdn_in" for i in
+                          range(c.num_hidden_layers)
+                          if c.mixer(i) == LINEAR), None)
+        gdn_keys = c.linear_num_key_heads * c.linear_key_head_dim
 
         def body(params, states, locals_, options, batch):
             held = {n: (apply, p, s, o) for n, apply, p, s, o in
@@ -540,12 +825,16 @@ class TransformerLM:
                     report["probe_embed"] = grad[:probe_rows]
                 elif name == probe_expert:
                     report["probe_expert"] = grad[0]
+                elif name == probe_gdn:
+                    report["probe_gdn_k"] = grad[:, gdn_keys:2 * gdn_keys]
                 return apply(p, s, grad, o), report
 
             aux, done = self._sweep(dict(zip(names, params)), batch, fold)
             aux["grad_norms"] = jnp.stack([done[n][1]["norm"]
                                            for n in names])
             aux["probe_embed"] = done["embed"][1]["probe_embed"]
+            if probe_gdn is not None:
+                aux["probe_gdn_k"] = done[probe_gdn][1]["probe_gdn_k"]
             if probe_expert is not None:
                 routed = jnp.sum(lax.dynamic_slice_in_dim(
                     aux["counts"], c.first_expert, c.n_routed_experts, 1))
@@ -567,7 +856,8 @@ class TransformerLM:
         if self.docs is None:
             raise ValueError("TransformerLM was given no documents")
         c = self.config
-        return pack_documents(self.docs, c.sequences, c.sequence_length)
+        return pack_documents(self.docs, c.sequences, c.sequence_length,
+                              open_sequences=c.open_sequences)
 
     def _place(self, batch: Batch) -> jax.Array:
         return core.place(np.stack([batch["tokens"], batch["doc"],
@@ -616,10 +906,13 @@ class TransformerLM:
         self.tokens_trained += tokens
         telemetry.counter("lm.tokens").inc(tokens)
         telemetry.counter("lm.pad_tokens").inc(pads)
-        for i, name in enumerate(("lm.attend.key_blocks",
-                                  "lm.attend.key_blocks_computed")):
-            telemetry.counter(name).inc(int(sum(s["attend"][i]
-                                                for s in small)))
+        for key, names in (("attend", ("lm.attend.key_blocks",
+                                       "lm.attend.key_blocks_computed")),
+                           ("gdn", ("lm.gdn.chunks", "lm.gdn.doc_starts"))):
+            if small and key in small[-1]:
+                for i, name in enumerate(names):
+                    telemetry.counter(name).inc(
+                        int(sum(s[key][i] for s in small)))
         if small and "moe" in small[-1]:
             telemetry.counter("moe.tokens_routed").inc(
                 int(sum(s["moe"][0] for s in small)))
@@ -659,6 +952,18 @@ class TransformerLM:
                 return x
             self._eval_hidden = jax.jit(hidden)
         return self._eval_hidden(self._raw(), self._place(batch))
+
+    def logits(self, batch: Batch) -> jax.Array:
+        """The logits of the vocabulary rows held here, [B, S, V]
+        float32: the final norm and the head on :meth:`hidden_states`."""
+        c = self.config
+        tables = self._raw()
+        h = mla.rms_norm(self.hidden_states(batch),
+                         tables["norms"][self._norm_rows[-1]],
+                         c.rms_norm_eps).astype(c.compute_dtype)
+        return lax.dot_general(
+            h, tables["head"].astype(c.compute_dtype),
+            (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
     def named_parameters(self) -> Dict[str, jax.Array]:
         """Live views of the tables under the model's own names."""

@@ -33,6 +33,11 @@ The operands are read where the projections left them (a head is a
 for every head); head dims that are no multiple of 128 lanes (the
 rotary 64) are zero-padded first, which changes no score.
 
+The same kernels run plain multi-head attention — per-head keys of one
+depth, no rotary part (:func:`attend_heads`, with :func:`project_heads`
+and :func:`output_heads` around it): the variant is static, the rotary
+operands, their products, gradients and scratch are simply not there.
+
 Matrix products take operands of ``LatentShape.dtype`` (bfloat16) and
 accumulate in float32; norms, the rotary embedding and the softmax are
 float32; the probabilities are cast to the operands' dtype before the
@@ -168,6 +173,9 @@ class _Static(NamedTuple):
     # ran as fast and one of 256 a fifth slower, PERF.md PR 29)
     block: int
     interpret: bool
+    # of the variant without rotary operands, which has no shared key
+    # to count its heads by
+    heads: int = 0
 
 
 def block_plan(doc, block: int):
@@ -214,12 +222,20 @@ def _fetch_plan(plan):
 
 
 def _scores(a_nope, a_pe, b_nope, b_pe, scale):
-    """``a @ b.T`` over the non-rotary and the rotary depth, float32,
-    scaled: [rows of a, rows of b]."""
-    return (lax.dot_general(a_nope, b_nope, _NT,
-                            preferred_element_type=jnp.float32)
-            + lax.dot_general(a_pe, b_pe, _NT,
-                              preferred_element_type=jnp.float32)) * scale
+    """``a @ b.T`` over the non-rotary and the rotary depth (where there
+    is one), float32, scaled: [rows of a, rows of b]."""
+    s = lax.dot_general(a_nope, b_nope, _NT,
+                        preferred_element_type=jnp.float32)
+    if a_pe is not None:
+        s = s + lax.dot_general(a_pe, b_pe, _NT,
+                                preferred_element_type=jnp.float32)
+    return s * scale
+
+
+def _read(ref):
+    return None if ref is None else ref[...]
+
+
 
 
 def _mask(s, q0, k0, q_doc, k_doc):
@@ -256,8 +272,8 @@ def _forward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
 
     @pl.when(_computed(plan_ref, i, j, n))
     def _():
-        s = _mask(_scores(kn_ref[...], kp_ref[...], qn_ref[...],
-                          qp_ref[...], scale),
+        s = _mask(_scores(kn_ref[...], _read(kp_ref), qn_ref[...],
+                          _read(qp_ref), scale),
                   i * block, j * block, qd_ref[...], kd_ref[...])
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
@@ -276,6 +292,14 @@ def _forward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
             lse_ref[0][...] = m_ref[...] + jnp.log(l_ref[...])
 
 
+def _forward_kernel_plain(hold_ref, plan_ref, qn_ref, kn_ref, *rest,
+                          **static):
+    """The forward kernel of the variant whose calls carry no rotary
+    operand: ``None`` in the place of the two rotary refs."""
+    _forward_kernel(hold_ref, plan_ref, qn_ref, None, kn_ref, None, *rest,
+                    **static)
+
+
 def _backward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
                      v_ref, qd_ref, kd_ref, do_ref, lse_ref, di_ref,
                      dqn_ref, dqp_ref, dkn_ref, dkp_ref, dv_ref,
@@ -290,21 +314,25 @@ def _backward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
     del hold_ref
     j, i = pl.program_id(2), pl.program_id(3)
 
+    rotary = qp_ref is not None
+
     @pl.when((j == 0) & (i == 0))
     def _():
         dqn_acc[...] = jnp.zeros_like(dqn_acc)
-        dqp_acc[...] = jnp.zeros_like(dqp_acc)
+        if rotary:
+            dqp_acc[...] = jnp.zeros_like(dqp_acc)
 
     @pl.when(i == 0)
     def _():
         dkn_acc[...] = jnp.zeros_like(dkn_acc)
-        dkp_acc[...] = jnp.zeros_like(dkp_acc)
+        if rotary:
+            dkp_acc[...] = jnp.zeros_like(dkp_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     @pl.when(_computed(plan_ref, j, i, n))
     def _():
-        s = _mask(_scores(kn_ref[...], kp_ref[...], qn_ref[...],
-                          qp_ref[...], scale),
+        s = _mask(_scores(kn_ref[...], _read(kp_ref), qn_ref[...],
+                          _read(qp_ref), scale),
                   i * block, j * block, qd_ref[...], kd_ref[...])
         p = jnp.exp(s - lse_ref[...])
         do = do_ref[...]
@@ -317,31 +345,47 @@ def _backward_kernel(hold_ref, plan_ref, qn_ref, qp_ref, kn_ref, kp_ref,
         ds = ds.astype(qn_ref.dtype)
         dkn_acc[...] += jnp.dot(ds, qn_ref[...],
                                 preferred_element_type=jnp.float32)
-        dkp_acc[...] += jnp.dot(ds, qp_ref[...],
-                                preferred_element_type=jnp.float32)
+        if rotary:
+            dkp_acc[...] += jnp.dot(ds, qp_ref[...],
+                                    preferred_element_type=jnp.float32)
         rows = pl.ds(pl.multiple_of(i * block, block), block)
         dqn_acc[rows, :] += jnp.dot(ds_t, kn_ref[...],
                                     preferred_element_type=jnp.float32)
-        dqp_acc[rows, :] += jnp.dot(ds_t, kp_ref[...],
-                                    preferred_element_type=jnp.float32)
+        if rotary:
+            dqp_acc[rows, :] += jnp.dot(ds_t, kp_ref[...],
+                                        preferred_element_type=jnp.float32)
 
     @pl.when(i == n - 1)
     def _():
         dkn_ref[...] = (dkn_acc[...] * scale).astype(dkn_ref.dtype)
-        dkp_ref[...] = dkp_acc[...] * scale
+        if rotary:
+            dkp_ref[...] = dkp_acc[...] * scale
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
     @pl.when((j == n - 1) & (i == n - 1))
     def _():
         dqn_ref[...] = (dqn_acc[...] * scale).astype(dqn_ref.dtype)
-        dqp_ref[...] = (dqp_acc[...] * scale).astype(dqp_ref.dtype)
+        if rotary:
+            dqp_ref[...] = (dqp_acc[...] * scale).astype(dqp_ref.dtype)
+
+
+def _backward_kernel_plain(hold_ref, plan_ref, qn_ref, kn_ref, v_ref, qd_ref,
+                           kd_ref, do_ref, lse_ref, di_ref, dqn_ref,
+                           dkn_ref, dv_ref, dqn_acc, dkn_acc, dv_acc,
+                           **static):
+    """The backward kernel without rotary operands, their two gradients
+    and their scratch."""
+    _backward_kernel(hold_ref, plan_ref, qn_ref, None, kn_ref, None, v_ref,
+                     qd_ref, kd_ref, do_ref, lse_ref, di_ref, dqn_ref, None,
+                     dkn_ref, None, dv_ref, dqn_acc, None, dkn_acc, None,
+                     dv_acc, **static)
 
 
 def _grid(static: _Static, B, H, S, Dn, Dr, Dv, doc, queries_inner):
     """Grid, the two scalar-prefetched plans and the block specs the
     kernels share. The operands stay as the projections left them,
     [B, S, H * d]: a head's block is the column block ``h``; ``k_pe``
-    [B, S, Dr] is every head's. The tile is [keys, queries], so the queries'
+    [B, S, Dr] is every head's (``Dr`` 0: no rotary operands). The tile is [keys, queries], so the queries'
     ``doc`` ids and statistics are rows ([B, 1, S], [B, H, 1, S]) and
     the keys' ids a column ([B, S, 1]). The grid is (sequence, head,
     outer block, inner block): the outer block is held, the inner one
@@ -376,6 +420,8 @@ def _grid(static: _Static, B, H, S, Dn, Dr, Dv, doc, queries_inner):
                           lambda b, h, *a: (b, h, 0, q_at(b, h, *a)))
     operands = [per_q(Dn), per_q(Dr), per_k(Dn), per_k(Dr, head=False),
                 per_k(Dv), q_doc, per_k(1, head=False)]
+    if not Dr:
+        del operands[3], operands[1]
     return ((B, H, n, n), (_fetch_plan(plan).reshape(-1), plan.reshape(-1)),
             operands, per_q, per_k, q_stat)
 
@@ -399,29 +445,35 @@ def _pallas(kernel, static: _Static, grid, plans, in_specs, out_specs,
         interpret=static.interpret), *plans)
 
 
-def _sizes(qn, qp, kp, v):
-    B, S, Dr = kp.shape
-    H = qp.shape[2] // Dr
+def _sizes(qn, qp, kp, v, static: _Static):
+    if kp is None:
+        (B, S, _), H, Dr = v.shape, static.heads, 0
+    else:
+        B, S, Dr = kp.shape
+        H = qp.shape[2] // Dr
     return B, H, S, qn.shape[2] // H, Dr, v.shape[2] // H
 
 
 def _forward(qn, qp, kn, kp, v, doc, static: _Static, residuals: bool):
     """``o`` [B, S, H * Dv] and, with ``residuals``, the log-sum-exp of
     every (sequence, head, query) [B, H, 1, S] float32."""
-    B, H, S, Dn, Dr, Dv = _sizes(qn, qp, kp, v)
+    B, H, S, Dn, Dr, Dv = _sizes(qn, qp, kp, v, static)
     block = static.block
     grid, plans, operands, per_q, _, q_stat = _grid(
         static, B, H, S, Dn, Dr, Dv, doc, False)
+    kernel = _forward_kernel if Dr else _forward_kernel_plain
+    rotary = (qp, kp) if Dr else ()
     out_specs = [per_q(Dv)]
     out_shape = [jax.ShapeDtypeStruct((B, S, H * Dv), v.dtype)]
     if residuals:
         out_specs.append(q_stat)
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
-    out = _pallas(_forward_kernel, static, grid, plans, operands, out_specs,
+    out = _pallas(kernel, static, grid, plans, operands, out_specs,
                   out_shape, [pltpu.VMEM((1, block), jnp.float32),
                               pltpu.VMEM((1, block), jnp.float32),
                               pltpu.VMEM((Dv, block), jnp.float32)], S)(
-        qn, qp, kn, kp, v, doc[:, None, :], doc[:, :, None])
+        qn, *rotary[:1], kn, *rotary[1:], v, doc[:, None, :],
+        doc[:, :, None])
     return out if residuals else (out[0], None)
 
 
@@ -430,7 +482,7 @@ def _backward(static: _Static, saved, do):
     operands, ``o`` and the log-sum-exp): one kernel that recomputes the
     score tiles in VMEM."""
     qn, qp, kn, kp, v, doc, o, lse = saved
-    B, H, S, Dn, Dr, Dv = _sizes(qn, qp, kp, v)
+    B, H, S, Dn, Dr, Dv = _sizes(qn, qp, kp, v, static)
     block = static.block
     # rowsum(do * o), what the softmax's gradient subtracts: [B, H, 1, S]
     di = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
@@ -442,6 +494,16 @@ def _backward(static: _Static, saved, do):
     whole = lambda d: pl.BlockSpec((None, S, d), lambda b, h, *a: (b, 0, h))
     grid, plans, operands, per_q, per_k, q_stat = _grid(
         static, B, H, S, Dn, Dr, Dv, doc, True)
+    if not Dr:
+        dqn, dkn, dv = _pallas(
+            _backward_kernel_plain, static, grid, plans,
+            operands + [per_q(Dv), q_stat, q_stat],
+            [whole(Dn), per_k(Dn), per_k(Dv)],
+            [like(qn), like(kn), like(v)],
+            [f32(S, Dn), f32(block, Dn), f32(block, Dv)],
+            S, inner_only=False)(
+                qn, kn, v, doc[:, None, :], doc[:, :, None], do, lse, di)
+        return dqn, None, dkn, None, dv, None
     dqn, dqp, dkn, dkp, dv = _pallas(
         _backward_kernel, static, grid, plans,
         operands + [per_q(Dv), q_stat, q_stat],
@@ -489,20 +551,66 @@ def attend(q_nope, q_pe, k_nope, k_pe, v, doc, *, scale: float,
     @telemetry.scope("lm.mla.attend")
     def run(q_nope, q_pe, k_nope, k_pe, v, doc):
         B, S, H, vd = v.shape
-        size = min(block, S)
-        if S % size:
-            raise ValueError(f"sequence {S} is no multiple of the "
-                             f"attention block {size}")
         flat = lambda a: _lanes(a).reshape(B, S, -1)
         o = _attention(flat(q_nope), flat(q_pe), flat(k_nope), _lanes(k_pe),
                        flat(v), doc,
-                       _Static(float(scale), size, bool(interpret)))
+                       _Static(float(scale), _block(block, S),
+                               bool(interpret)))
         return o.reshape(B, S, H, -1)[..., :vd].reshape(B, S, H * vd)
     return run(q_nope, q_pe, k_nope, k_pe, v, doc)
 
 
-@telemetry.scope("lm.mla.project")
-def output(o, w_o):
+def _block(block: int, S: int) -> int:
+    size = min(block, S)
+    if S % size:
+        raise ValueError(f"sequence {S} is no multiple of the "
+                         f"attention block {size}")
+    return size
+
+
+def _project_out(o, w_o):
     """``o`` [B, S, H * v] times ``w_o`` [D, H * v] transposed."""
     return lax.dot_general(o, w_o.astype(o.dtype), (((2,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
+
+
+output = telemetry.scope("lm.mla.project")(_project_out)
+
+
+# -- plain multi-head attention: per-head keys, no rotary part ----------------
+
+def project_heads(x, w_q, w_k, w_v, q_norm_w, k_norm_w, heads: int,
+                  eps: float, dtype):
+    """From the residual ``x`` [B, S, D] float32: ``q``, ``k``, ``v``
+    [B, S, H, d] in ``dtype``; ``q`` and ``k`` through an RMS norm over
+    all heads' dims (QK-norm) first. No positions: nothing is rotated."""
+    @telemetry.scope("lm.attn.project")
+    def run(x, w_q, w_k, w_v, q_norm_w, k_norm_w):
+        B, S, _ = x.shape
+        heads_of = lambda a: a.astype(dtype).reshape(B, S, heads, -1)
+        return (heads_of(rms_norm(_dot(x, w_q, dtype), q_norm_w, eps)),
+                heads_of(rms_norm(_dot(x, w_k, dtype), k_norm_w, eps)),
+                heads_of(_dot(x, w_v, dtype)))
+    return run(x, w_q, w_k, w_v, q_norm_w, k_norm_w)
+
+
+def attend_heads(q, k, v, doc, *, scale: float, block: int,
+                 interpret: Optional[bool] = None):
+    """:func:`attend` for keys of one depth a head and no rotary part:
+    ``q``, ``k`` [B, S, H, d], ``v`` [B, S, H, v]; the same kernels
+    without the rotary operands. Returns [B, S, H * v]."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+
+    @telemetry.scope("lm.attn.attend")
+    def run(q, k, v, doc):
+        B, S, H, vd = v.shape
+        flat = lambda a: _lanes(a).reshape(B, S, -1)
+        o = _attention(flat(q), None, flat(k), None, flat(v), doc,
+                       _Static(float(scale), _block(block, S),
+                               bool(interpret), H))
+        return o.reshape(B, S, H, -1)[..., :vd].reshape(B, S, H * vd)
+    return run(q, k, v, doc)
+
+
+output_heads = telemetry.scope("lm.attn.project")(_project_out)

@@ -102,7 +102,11 @@ def trained(mesh):
              zip(app.tables.items(), table_shapes(c).values())}
     batches = list(pack_documents(docs, c.sequences, c.sequence_length))
     first = app.gradients(batches[0])
+    # the registry is the process's: what THIS call counted is a growth
+    before = telemetry.snapshot()["counters"]
     app.train(total_steps=3)
+    counted = {k: v - before.get(k, 0)
+               for k, v in telemetry.snapshot()["counters"].items()}
     p = {k: jnp.asarray(v) for k, v in
          named_parameters(c, start_tables(c)).items()}
     m, v = ref.zeros_like(p), ref.zeros_like(p)
@@ -117,7 +121,7 @@ def trained(mesh):
                                 lr=c.learning_rate * (s + 1) / 4,
                                 b1=c.beta1, b2=c.beta2, eps=c.adam_eps)
     return {"config": c, "app": app, "start": start, "first": first,
-            "batches": batches, "steps": steps,
+            "batches": batches, "steps": steps, "counters": counted,
             "final": {k: np.asarray(x) for k, x in p.items()}}
 
 
@@ -578,7 +582,7 @@ def test_spans_scopes_and_counters_of_a_training_call(trained):
     for name in ("lm.wait_data", "lm.place", "lm.superstep", "lm.fence",
                  "lm.setup.init_tables", "lm.docs.produce"):
         assert f"span.seconds{{name={name}}}" in spans, name
-    counters = snap["counters"]
+    counters = trained["counters"]
     batches = trained["batches"][:3]
     assert counters["lm.tokens"] == sum(real_tokens(b) for b in batches)
     assert counters["lm.pad_tokens"] == sum(b["doc"].size for b in batches) \
@@ -612,5 +616,27 @@ def test_what_is_not_built_says_so(mesh):
         tiny(kv_lora_rank=None).check()
     with pytest.raises(ValueError, match="ep_rank"):
         tiny(ep_rank=4).check()
+    # published keys that change the model and are built one way only:
+    # from_dict would drop them and train another model under the name
+    for key, value, says in (
+            ("attention_bias", True, "attention_bias"),
+            ("hidden_act", "gelu", "hidden_act"),
+            ("tie_word_embeddings", True, "tie_word_embeddings"),
+            ("model_type", "llama", "model_type"),
+            ("layer_types", ["full_attention", "sliding_attention"] * 14,
+             "layer_types entry"),
+            ("model_type", "olmo_hybrid", "latent attention in a")):
+        published = dict(PUBLISHED, **{key: value})
+        if key == "layer_types":
+            published["model_type"] = "olmo_hybrid"
+        with pytest.raises(NotImplementedError, match=says):
+            LMConfig.from_dict(published).check()
+    LMConfig.from_dict(PUBLISHED).check()
+    # a config that names no experts and no latent rank has neither
+    bare = {k: v for k, v in PUBLISHED.items()
+            if k not in ("n_routed_experts", "kv_lora_rank")}
+    assert LMConfig.from_dict(bare).n_routed_experts == 0
+    with pytest.raises(NotImplementedError, match="names no mixer"):
+        LMConfig.from_dict(bare).check()
     with pytest.raises(ValueError, match="no documents"):
         TransformerLM(tiny(num_hidden_layers=1), mesh=mesh).train(1)

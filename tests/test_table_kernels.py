@@ -790,6 +790,34 @@ def _kernel_cases():
                  ((2, S, H, 128), bf16, P()), ((2, S), i32, P())])
     add("latent-attention-forward", lambda m: attention(False))
     add("latent-attention-backward", lambda m: attention(True))
+
+    # the same kernels without rotary operands at the hybrid cell's
+    # shape: 30 heads with keys and values of depth 128
+    def plain_attention(grad):
+        def attend(q, k, v, doc):
+            return mla.attend_heads(q, k, v, doc, scale=128 ** -0.5,
+                                    block=512, interpret=False)
+
+        def loss(*a):
+            return jnp.sum(attend(*a).astype(f32))
+        return (jax.grad(loss, argnums=(0, 1, 2)) if grad else attend,
+                [((1, S, 30, 128), bf16, P())] * 3 + [((1, S), i32, P())])
+    add("plain-attention-forward", lambda m: plain_attention(False))
+    add("plain-attention-backward", lambda m: plain_attention(True))
+
+    # the chunked gated delta rule (blocked XLA: the triangular solve,
+    # the scan over chunks and its transpose) at the published widths:
+    # 30 heads of 96 x 192, chunks of 64
+    from multiverso_tpu.ops import gated_delta as gdn
+    shape = gdn.GatedDeltaShape(30, 96, 192, 4, True, 1e-6, 64, "bfloat16")
+
+    def recurrence(qkv, g, beta, doc):
+        return jax.grad(lambda *a: jnp.sum(gdn.recur(*a, doc, shape)),
+                        argnums=(0, 1, 2))(qkv, g, beta)
+    add("gated-delta-recurrence-backward", lambda m: (
+        recurrence, [((1, S, shape.conv_width), f32, P()),
+                     ((1, S, 30), f32, P()), ((1, S, 30), f32, P()),
+                     ((1, S), i32, P())]))
     return cases
 
 
