@@ -63,7 +63,7 @@ def run_variant(name: str, batch: int, steps: int) -> dict:
 
     def dispatch(i, placed):
         key = jax.random.fold_in(app._key, i)
-        _, loss = app._fused((), placed, key, lrs)
+        _, (loss, _) = app._fused((), placed, key, lrs)
         return loss
 
     wl = None

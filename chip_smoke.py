@@ -30,7 +30,9 @@ the Mosaic-compiled hot loop), ``tables`` (MatrixTable / tiled
 SparseMatrixTable / KVTable get/add/COO-add with duplicate ids, both
 kernel engines against a numpy reference), ``attend`` (the latent
 attention kernel, forward and backward at the language-model cell's
-shape on packed documents, against the plain blocked form), ``server``
+shape on packed documents, against the plain blocked form),
+``w2v_scatter`` (the distinct-row writer against XLA's scatter-add: one
+step of the word2vec cell's output side), ``server``
 (one wire server; numpy bit-for-bit). With >= 4 devices the base phases run on
 ``data=4`` and five more follow: ``w2v@2x2``, ``lda@1x1`` (one chip of
 the four) and ``lda@2x2``, whose sha256 of the sampler's state after two
@@ -91,6 +93,9 @@ FULL = dict(
     # the language-model cell's attention (perf/configs/dsv2_lite_ep8.json)
     attend=dict(sequences=8, length=4096, heads=16, nope=128, rope=64,
                 v=128, block=512, doc_median=512, repeats=3),
+    # one step of the word2vec cell's output side
+    # (perf/configs/w2v_gnews300.json): 4,096 pairs x (1 + 5) rows
+    w2v_scatter=dict(vocab=3_000_000, dim=300, lanes=24_576, zipf=1.05),
 )
 TINY = dict(
     w2v=dict(vocab=500, tokens=40_000, dim=16, window=3, negative=3,
@@ -103,6 +108,7 @@ TINY = dict(
                 value_dim=4),
     attend=dict(sequences=2, length=64, heads=2, nope=16, rope=8, v=16,
                 block=16, doc_median=12, repeats=1),
+    w2v_scatter=dict(vocab=3000, dim=20, lanes=600, zipf=1.3),
 )
 
 
@@ -552,8 +558,48 @@ def phase_attend(cfg: dict, mesh, platform: str) -> dict:
             "key_blocks": total, "key_blocks_computed": computed, **ms}
 
 
+def phase_w2v_scatter(cfg: dict, mesh, platform: str) -> dict:
+    """The distinct-row writer (Mosaic on the chip) against XLA's
+    ``.at[].add``: one step's lanes, ids by a zipf law, into a table held
+    as the trainer holds it on one device."""
+    import jax
+    import jax.numpy as jnp
+    from multiverso_tpu.ops import distinct_rows, interpret_mode
+
+    rng = np.random.default_rng(13)
+    rows, cols = distinct_rows.aligned_shape(cfg["vocab"] + 1, cfg["dim"])
+    ids_host = np.minimum(rng.zipf(cfg["zipf"], cfg["lanes"]) - 1,
+                          cfg["vocab"] - 1).astype(np.int32)
+    ids = jnp.asarray(ids_host)
+    upd = jnp.asarray(rng.normal(size=(cfg["lanes"], cols)) * 1e-2,
+                      jnp.float32)
+    table = jax.random.normal(jax.random.PRNGKey(13), (rows, cols),
+                              jnp.float32)
+    want = jax.jit(lambda t: t.at[ids].add(upd))(table)
+    got, distinct = jax.jit(lambda t: distinct_rows.add_rows(
+        t, ids, lambda lanes: jnp.take(upd, lanes, axis=0),
+        interpret=interpret_mode(mesh)), donate_argnums=0)(table)
+    # the writer sorts and writes MAX_LANES lanes a kernel call
+    step = distinct_rows.MAX_LANES
+    written = sum(len(np.unique(ids_host[lo:lo + step]))
+                  for lo in range(0, len(ids_host), step))
+    assert int(distinct) == written, (int(distinct), written)
+    # a row's duplicates are added in lane order, as the scatter adds
+    # them: every row, touched or not, comes out with the same bits
+    differing = int(jax.jit(lambda a, b: jnp.any(a != b, axis=1).sum())(
+        got, want))
+    assert differing == 0, differing
+    return {"shape": {k: cfg[k] for k in sorted(cfg)},
+            "table": [rows, cols],
+            "distinct_rows": len(np.unique(ids_host)),
+            "distinct_groups": len(np.unique(ids_host // 8)),
+            "rows_written": written,
+            "matches": ".at[].add in float32, bit for bit in every row"}
+
+
 CHILD_PHASES = {"w2v": phase_w2v, "lda": phase_lda,
-                "tables": phase_tables, "attend": phase_attend}
+                "tables": phase_tables, "attend": phase_attend,
+                "w2v_scatter": phase_w2v_scatter}
 
 
 def run_child(phase: str, rehearse: bool) -> int:
@@ -892,7 +938,7 @@ class Smoke:
             print("chip_smoke: FAILED — " + "; ".join(self.failed),
                   file=sys.stderr)
             return 1
-        for phase in ("lda", "tables", "attend"):
+        for phase in ("lda", "tables", "attend", "w2v_scatter"):
             self.child(phase)
         self.guarded("server", self.server)
         count = int(self.lines[0]["devices"])

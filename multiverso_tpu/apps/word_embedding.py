@@ -17,7 +17,11 @@ TPU design (the whole point — nothing here is a translation):
   trains S*B pairs.
 - The reference's Trainer-thread Hogwild + per-block aggregation becomes
   the batched scatter-add: duplicate rows within a minibatch accumulate
-  additively (`.at[].add`), exactly the reference's Aggregator semantics.
+  additively, exactly the reference's Aggregator semantics. The tables
+  are held in whole (8, 128) tiles, row by row. On one device the step's
+  lanes are sorted by row and each distinct 8-row group of a table is
+  read, added to and written once (`ops/distinct_rows.py`); sharded
+  tables go through XLA's `.at[].add`.
 - Negative sampling runs **on device**: by default a precomputed unigram
   table (the reference word2vec's own ``InitUnigramTable`` — one uniform
   + ONE gather per draw), or the exact Vose alias method
@@ -49,6 +53,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from multiverso_tpu import client, core, telemetry
 from multiverso_tpu.data.corpus import Corpus
+from multiverso_tpu.ops import distinct_rows, interpret_mode
 from multiverso_tpu.tables import MatrixTable, make_superstep
 from multiverso_tpu.utils import log
 
@@ -160,19 +165,6 @@ def table_sample(key, table: jax.Array, shape):
     return jnp.take(table, idx, axis=0)
 
 
-# the phases of the fused body that both objectives share, each under a
-# program scope: the compiled ops carry the name (profiling.op_scopes)
-@telemetry.scope("w2v.gather_out")
-def _gather_out(w_out, ids):
-    return jnp.take(w_out, ids, axis=0)                       # [B, n, D]
-
-
-@telemetry.scope("w2v.scatter_out")
-def _scatter_out(w_out, ids, grad_u):
-    return w_out.at[ids.reshape(-1)].add(
-        -grad_u.reshape(-1, grad_u.shape[-1]).astype(w_out.dtype))
-
-
 class WordEmbedding:
     """The app: two MatrixTables + the fused scan superstep."""
 
@@ -187,18 +179,33 @@ class WordEmbedding:
         if c.subsample is not None:
             corpus.set_subsample(c.subsample)
         v, d = corpus.vocab_size, c.embedding_dim
+        # the distinct-row writer is one Pallas call, which GSPMD cannot
+        # split: it runs where both tables lie whole on one device (and
+        # hold 32-bit words, what its (8, 128) row groups are made of);
+        # sharded tables keep XLA's scatter (_scatter_rows, the one fork)
+        self._whole = (self.mesh.devices.size == 1
+                       and jnp.dtype(c.dtype).itemsize == 4)
         with telemetry.span("w2v.setup.init_tables"):
-            rng = np.random.default_rng(c.seed)
-            # reference init: input embeddings ~ U(-0.5/dim, 0.5/dim),
-            # output 0
-            w_in_init = rng.uniform(-0.5 / d, 0.5 / d,
-                                    (v, d)).astype(c.dtype)
-            self.w_in = MatrixTable(v, d, c.dtype, init_value=w_in_init,
+            def drawn(shape, dtype, sharding):
+                # reference init: input embeddings ~ U(-0.5/dim, 0.5/dim),
+                # drawn into the table's padded shape (one 3.6 GB copy
+                # less on the host)
+                rng = np.random.default_rng(c.seed)
+                init = np.zeros(shape, dtype)
+                init[:v, :d] = rng.uniform(-0.5 / d, 0.5 / d, (v, d))
+                return jax.device_put(init, sharding)
+            # on every mesh the tables are held in whole (8, 128) tiles,
+            # row by row: what the writer's DMAs need, and what spares
+            # every call a transposed copy of both (PERF.md §6, PR 33);
+            # rows past v and columns past d stay zero. The output
+            # table's zeros are made on the device
+            self.w_in = MatrixTable(v, d, c.dtype, init_value=drawn,
                                     updater="default", mesh=self.mesh,
-                                    name=f"{name}_in")
-            self.w_out = MatrixTable(v, d, c.dtype, init_value=0,
+                                    name=f"{name}_in", tile_aligned=True)
+            self.w_out = MatrixTable(v, d, c.dtype,
+                                     init_value=core.sharded_zeros,
                                      updater="default", mesh=self.mesh,
-                                     name=f"{name}_out")
+                                     name=f"{name}_out", tile_aligned=True)
         self._scratch = self.w_in.padded_shape[0] - 1  # masked-lane row
         # MVTPU_STALENESS: embeddings() (logging/eval — nearest,
         # similarity, analogy; never fed back into training) serves from
@@ -246,6 +253,7 @@ class WordEmbedding:
         self._sched_plan = 0        # set by load(): original planned
         # call count (0 = fresh run; train() re-plans per call as today)
         self._train_plan = 0        # last train()'s effective plan
+        self._scattered: list = []  # per call, until train()'s fence
         self._last_store = ()       # (prefix, step) of the last store
         self.loss_history: list = []
         self._local_chunks = None   # local_data: [(device, b0, b1), ...]
@@ -295,7 +303,8 @@ class WordEmbedding:
 
     def _pos_neg_step(self, w_out, v, tgt, key, lr):
         """Shared NS inner math: v [B,D] input vectors vs target ids [B].
-        Returns (w_out', grad wrt v [B,D], mean loss)."""
+        Returns (w_out', the scatter's [lanes, rows written], grad wrt
+        v [B,D], mean loss)."""
         c = self.config
 
         @telemetry.scope("w2v.negatives")
@@ -321,13 +330,64 @@ class WordEmbedding:
                         axis=1))
             g = (sig - labels) * lr                           # [B, 1+K]
             grad_v = jnp.einsum("bk,bkd->bd", g, u)
-            grad_u = g[:, :, None] * v[:, None, :]            # [B,1+K,D]
-            return loss, grad_v, grad_u
+            return loss, grad_v, g
 
         ids = target_ids(tgt, key)                            # [B, 1+K]
-        u = _gather_out(w_out, ids)                           # [B, 1+K, D]
-        loss, grad_v, grad_u = math(v, u, lr)
-        return _scatter_out(w_out, ids, grad_u), grad_v, loss
+        u = self._gather_out(w_out, ids)                      # [B, 1+K, D]
+        loss, grad_v, g = math(v, u, lr)
+        w_out, rows = self._scatter_out(w_out, ids, g, v)
+        return w_out, rows, grad_v, loss
+
+    # the phases of the fused body that both objectives share, each under
+    # a program scope: the compiled ops carry the name
+    # (profiling.op_scopes)
+    def _gather_out(self, w_out, ids):
+        @telemetry.scope("w2v.gather_out")
+        def gather(w_out, ids):
+            return self._take(w_out, ids)                     # [B, n, D]
+        return gather(w_out, ids)
+
+    def _take(self, table, ids):
+        """Rows of a table without the padding columns it is held with:
+        the step's arithmetic is the reference's, D wide."""
+        return jnp.take(table, ids, axis=0)[..., :self.config.embedding_dim]
+
+    def _scatter_rows(self, table, ids, coef, rows):
+        """``table[ids[b, k]] -= coef[b, k] * rows[b]``, duplicates
+        summed: both scatters of a step are this outer product, widened
+        by the table's padding columns. Where the table lies whole on
+        one device the lanes go sorted through the distinct-row writer,
+        each lane's update row formed from ``coef`` and ``rows`` in the
+        order it asks for ([B, n, D] is never built and permuted); where
+        it is sharded, through XLA's scatter. Returns the table and the
+        step's [lanes, rows written]: a row once a run of its lanes
+        under the writer, once a lane under the scatter."""
+        flat_ids, n = ids.reshape(-1), ids.shape[1]
+        lanes = flat_ids.shape[0]
+        pad = ((0, 0), (0, table.shape[1] - rows.shape[1]))
+        if self._whole:
+            flat = coef.reshape(-1)
+            table, written = distinct_rows.add_rows(
+                table, flat_ids,
+                lambda order: jnp.pad(-jnp.take(flat, order)[:, None]
+                                      * jnp.take(rows, order // n, axis=0),
+                                      pad),
+                interpret=interpret_mode(self.mesh))
+        else:
+            # the outer product as the scatter's own operand, as wide as
+            # the table (a scatter into its first D columns alone lowers
+            # to a loop of slice updates: 40x slower, PERF.md §6)
+            update = -(coef[:, :, None] * jnp.pad(rows, pad)[:, None, :])
+            table = table.at[flat_ids].add(
+                update.reshape(lanes, -1).astype(table.dtype))
+            written = jnp.int32(lanes)
+        return table, jnp.stack([jnp.int32(lanes), written])
+
+    def _scatter_out(self, w_out, ids, g, v):
+        """``w_out[ids[b, k]] -= g[b, k] * v[b]``: ``grad_u``
+        scatter-added."""
+        return telemetry.scope("w2v.scatter_out")(self._scatter_rows)(
+            w_out, ids, g, v)
 
     def _hs_step(self, w_out, v, tgt, lr):
         """Hierarchical-softmax inner math along the Huffman path."""
@@ -346,12 +406,12 @@ class WordEmbedding:
             ) / jnp.maximum(jnp.sum(msk), 1.0)
             g = (sig - code) * msk * lr                       # [B, L]
             grad_v = jnp.einsum("bl,bld->bd", g, u)
-            grad_u = g[:, :, None] * v[:, None, :]
-            return loss, grad_v, grad_u
+            return loss, grad_v, g
 
-        u = _gather_out(w_out, pts)                           # [B, L, D]
-        loss, grad_v, grad_u = math(v, u, lr)
-        return _scatter_out(w_out, pts, grad_u), grad_v, loss
+        u = self._gather_out(w_out, pts)                      # [B, L, D]
+        loss, grad_v, g = math(v, u, lr)
+        w_out, rows = self._scatter_out(w_out, pts, g, v)
+        return w_out, rows, grad_v, loss
 
     def _build_superstep(self) -> None:
         c = self.config
@@ -360,34 +420,36 @@ class WordEmbedding:
         @telemetry.scope("w2v.gather_in")
         def gather_in(w_in, src):
             if not cbow:
-                return jnp.take(w_in, src, axis=0), None, None   # [B, D]
+                return self._take(w_in, src), None, None         # [B, D]
             # src [B, 2w] context ids (scratch row = padding)
             ctx_mask = (src != self._scratch).astype(w_in.dtype)
             n_ctx = jnp.maximum(ctx_mask.sum(axis=1, keepdims=True), 1.0)
-            vecs = jnp.take(w_in, src, axis=0)                # [B, 2w, D]
+            vecs = self._take(w_in, src)                      # [B, 2w, D]
             return (jnp.einsum("bwd,bw->bd", vecs, ctx_mask) / n_ctx,
                     ctx_mask, n_ctx)
 
         @telemetry.scope("w2v.scatter_in")
         def scatter_in(w_in, src, grad_v, ctx_mask, n_ctx):
             if not cbow:
-                return w_in.at[src].add(-grad_v.astype(w_in.dtype))
-            # spread the input-side gradient over the context words
-            gctx = (grad_v / n_ctx)[:, None, :] * ctx_mask[:, :, None]
-            return w_in.at[src.reshape(-1)].add(
-                -gctx.reshape(-1, gctx.shape[-1]).astype(w_in.dtype))
+                # a centre's pairs arrive together, so the writer's sort
+                # finds its runs nearly made; any order is right
+                return self._scatter_rows(
+                    w_in, src[:, None], jnp.ones_like(grad_v[:, :1]), grad_v)
+            # the input-side gradient spread over the context words
+            return self._scatter_rows(w_in, src, ctx_mask, grad_v / n_ctx)
 
         def scan_body(carry, inp):
-            w_in, w_out = carry
+            w_in, w_out, rows = carry
             src, tgt, key, lr = inp
             v, ctx_mask, n_ctx = gather_in(w_in, src)
             if c.objective == "ns":
-                w_out, grad_v, loss = self._pos_neg_step(
+                w_out, rows_out, grad_v, loss = self._pos_neg_step(
                     w_out, v, tgt, key, lr)
             else:
-                w_out, grad_v, loss = self._hs_step(w_out, v, tgt, lr)
-            w_in = scatter_in(w_in, src, grad_v, ctx_mask, n_ctx)
-            return (w_in, w_out), loss
+                w_out, rows_out, grad_v, loss = self._hs_step(
+                    w_out, v, tgt, lr)
+            w_in, rows_in = scatter_in(w_in, src, grad_v, ctx_mask, n_ctx)
+            return (w_in, w_out, rows + jnp.stack([rows_in, rows_out])), loss
 
         def body(params, states, locals_, options, pairs, key, lrs):
             # pairs [S, B, ctx+1]: context ids + target in ONE operand
@@ -398,9 +460,11 @@ class WordEmbedding:
             srcs = pairs[..., :-1] if cbow else pairs[..., 0]
             tgts = pairs[..., -1]
             keys = jax.random.split(key, pairs.shape[0])
-            params, losses = lax.scan(
-                scan_body, params, (srcs, tgts, keys, lrs))
-            return params, states, locals_, losses.mean()
+            # rows: the call's [in | out] x [lanes, rows written]
+            (w_in, w_out, rows), losses = lax.scan(
+                scan_body, (*params, jnp.zeros((2, 2), jnp.int32)),
+                (srcs, tgts, keys, lrs))
+            return (w_in, w_out), states, locals_, (losses.mean(), rows)
 
         # the supported fused-update path: donation, out-shardings, and
         # step/generation counting live in the table layer
@@ -518,6 +582,7 @@ class WordEmbedding:
         S = c.steps_per_call
         buf: list = []              # one call's (src, tgt) batches
         losses, call_no = [], 0
+        self._scattered = []        # _dispatch: what the scatters met
         t0 = time.perf_counter()
         # host pair generation overlaps device compute (the reference's
         # ParameterLoader/ASyncBuffer pipelining role, SURVEY.md §4.5);
@@ -586,6 +651,15 @@ class WordEmbedding:
             self.loss_history = [float(l) for l in
                                  np.asarray(jnp.stack(losses))] \
                 if losses else []
+            # what the two scatters met, summed on the device over each
+            # call's steps: lanes offered, and rows written for them
+            if self._scattered:
+                met = np.sum(jax.device_get(self._scattered), axis=0)
+                for table, (lanes, written) in zip(("in", "out"), met):
+                    telemetry.counter("w2v.scatter.rows",
+                                      table=table).inc(int(lanes))
+                    telemetry.counter("w2v.scatter.rows_written",
+                                      table=table).inc(int(written))
         # count the work actually dispatched: with total_steps (or a
         # short corpus) the full-corpus token count would overstate
         # throughput by corpus_batches/steps_run
@@ -627,12 +701,15 @@ class WordEmbedding:
         # the host side of the fused dispatch; the step record links to
         # the span (its ``parent``), whose ``dur_s`` is the one timing
         with telemetry.span("w2v.superstep"):
-            _, loss = self._fused((), pd, key,
-                                  core.place(lrs, mesh=self.mesh))
+            _, (loss, rows) = self._fused((), pd, key,
+                                          core.place(lrs, mesh=self.mesh))
             telemetry.step_timeline("w2v", call_no,
                                     pairs=s * c.batch_size)
         telemetry.beat()    # flight recorder: one heartbeat per dispatch
         self._step_no += s
+        # the call's [in | out] x [lanes, rows written], left on the
+        # device until train()'s fence
+        self._scattered.append(rows)
         return loss
 
     # -- embeddings out / eval --------------------------------------------

@@ -258,7 +258,8 @@ class Table:
         lead = self.logical_shape[0] if self.logical_shape else 1
         lead_mult = shards * dp if self.shard_update else shards
         padded_lead = self._pad_lead(lead, lead_mult)
-        self.padded_shape = (padded_lead,) + self.logical_shape[1:]
+        self.padded_shape = (padded_lead,) + self._pad_trailing(
+            self.logical_shape[1:])
         # physical layout of the param array; subclasses may re-tile it
         # (storage_shape != padded_shape) while keeping the 2-D logical
         # contract — checkpoints always serialize the PADDED shape
@@ -270,9 +271,17 @@ class Table:
             if self.shard_update else self.spec
         self.state_sharding = NamedSharding(self.mesh, state_spec)
 
-        init = np.full(self.padded_shape, init_value, dtype=self.dtype) \
-            if np.isscalar(init_value) else self._pad(np.asarray(init_value))
-        self.param = jax.device_put(init, self.sharding)
+        if callable(init_value):
+            # made where it will live, in the padded shape: gigabytes are
+            # neither built twice on the host nor, where they are zeros
+            # (``core.sharded_zeros``), sent at all
+            self.param = init_value(self.padded_shape, self.dtype,
+                                    self.sharding)
+        else:
+            init = np.full(self.padded_shape, init_value, dtype=self.dtype) \
+                if np.isscalar(init_value) \
+                else self._pad(np.asarray(init_value))
+            self.param = jax.device_put(init, self.sharding)
         # state leaves are zeros_like(param) shaped -> param sharding,
         # refined over the data axis under shard_update
         self.state = jax.tree.map(
@@ -337,6 +346,13 @@ class Table:
 
     def _pad_lead(self, lead: int, shards: int) -> int:
         return -(-lead // shards) * shards
+
+    def _pad_trailing(self, trailing: Tuple[int, ...]) -> Tuple[int, ...]:
+        """The padded shape's further dimensions; a subclass may widen
+        them (MatrixTable ``tile_aligned``). Get, Add, Store and Load
+        pad and slice every dimension between the logical and the padded
+        shape, so a checkpoint loads across tables padded differently."""
+        return trailing
 
     def _pad(self, arr: np.ndarray) -> np.ndarray:
         if arr.shape == self.padded_shape:

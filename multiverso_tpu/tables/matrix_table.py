@@ -19,6 +19,13 @@ TPU design:
 - row-count-dependent shapes are bucketed to powers of two and padded, so
   the jit cache stays small; padded lanes scatter into a reserved scratch
   row that lives beyond the logical row range.
+- ``tile_aligned=True`` holds the table in whole (8, 128) tiles: each
+  shard's rows padded to a multiple of 8, the columns to a multiple of
+  128, the padding zero. The device then stores it row by row (a
+  [N, 300] float32 array is held column-major, and every program that
+  gathers its rows first copies it whole), and a kernel may address its
+  8-row groups by DMA (``ops/distinct_rows.py``). The logical shape, and
+  so Get, Add and a checkpoint's portability, stay [num_rows, num_cols].
 """
 
 from __future__ import annotations
@@ -59,10 +66,12 @@ class MatrixTable(Table):
                  *, init_value: Any = 0, updater: Optional[str] = None,
                  mesh: Optional[Mesh] = None, name: str = "matrix_table",
                  default_option: Optional[AddOption] = None,
-                 shard_update: bool = False) -> None:
+                 shard_update: bool = False,
+                 tile_aligned: bool = False) -> None:
         if num_rows <= 0 or num_cols <= 0:
             raise ValueError(f"MatrixTable dims must be positive, got "
                              f"{num_rows}x{num_cols}")
+        self._tile_aligned = tile_aligned   # read by the padding hooks
         super().__init__(name, (num_rows, num_cols), dtype, updater=updater,
                          mesh=mesh, init_value=init_value,
                          default_option=default_option,
@@ -81,7 +90,14 @@ class MatrixTable(Table):
 
     # base class hook: reserve at least one padding row for scatter scratch
     def _pad_lead(self, lead: int, shards: int) -> int:
+        if self._tile_aligned:
+            shards *= tk.SUBLANES
         return -(-(lead + 1) // shards) * shards
+
+    def _pad_trailing(self, trailing):
+        if not self._tile_aligned:
+            return trailing
+        return (-(-trailing[0] // tk.LANES) * tk.LANES,)
 
     @property
     def num_rows(self) -> int:
@@ -155,7 +171,7 @@ class MatrixTable(Table):
         if self.dtype.itemsize != 4:
             return {}
         name = f"table.{op}.{self.name}.pallas"
-        kw = dict(num_cols=self.num_cols, tiles=tiles,
+        kw = dict(num_cols=self.padded_shape[1], tiles=tiles,
                   interpret=tk.interpret_mode(self.mesh))
         return dict(
             pallas=lambda: profiled_jit(build(**kw), name=name, **jit_kw),
@@ -185,7 +201,7 @@ class MatrixTable(Table):
         mask[:n] = True
         if deltas is None:
             return out_ids, mask, n
-        out_d = np.zeros((b, self.num_cols), dtype=deltas.dtype)
+        out_d = np.zeros((b, deltas.shape[1]), dtype=deltas.dtype)
         out_d[:n] = deltas
         return out_ids, mask, n, out_d
 
@@ -230,12 +246,15 @@ class MatrixTable(Table):
 
     def _gather_dispatch(self, ids: np.ndarray):
         """One gather dispatch in whichever operand layout the selected
-        engine wants; returns the device rows future (first n real)."""
+        engine wants; returns the device rows future (first n real,
+        without a tile-aligned table's padding columns)."""
         if self._gather_rows.layout == "sharded":
             sl_ids, _valid, inv, n = self._pad_ids_sharded(ids)
-            return self._gather_rows(self.param, sl_ids, inv)[:n]
-        padded, _, n = self._pad_ids(ids)
-        return self._gather_rows(self.param, padded)[:n]
+            rows = self._gather_rows(self.param, sl_ids, inv)
+        else:
+            padded, _, n = self._pad_ids(ids)
+            rows = self._gather_rows(self.param, padded)
+        return rows[:n, :self.num_cols]
 
     def get_rows(self, row_ids) -> np.ndarray:
         """Fetch a list of rows (``MatrixWorkerTable::Get(row_ids, ...)``)."""
@@ -273,6 +292,9 @@ class MatrixTable(Table):
         self._record_op("add", deltas.size,
                         deltas.size * self.dtype.itemsize)
         _health.observe_update(self, deltas)
+        if self.padded_shape[1] != self.num_cols:     # tile_aligned
+            deltas = np.pad(deltas, ((0, 0), (
+                0, self.padded_shape[1] - self.num_cols)))
         if self.updater.name in ("default", "sgd"):
             if self.updater.name == "sgd":
                 # stateless: scatter-add of -lr*delta, duplicate-safe

@@ -257,8 +257,10 @@ def test_op_scopes_name_the_supersteps_ops(mesh1):
     # a cached executable cannot come back without them
     assert "w2v.scatter_out" in lowered.as_text()
     text = lowered.compile().as_text()
+    # on one device the scatter is the distinct-row writer: its sort of
+    # the step's lanes is an op of the scope as the scatter was
     scatters = [ln for ln in text.splitlines()
-                if " scatter(" in ln and "w2v.scatter_out" in ln]
+                if " sort(" in ln and "w2v.scatter_out" in ln]
     assert scatters
     name = scatters[0].split(" = ")[0].replace("ROOT", "").strip(" %")
     assert held["scopes"][name] == "w2v.scatter_out"

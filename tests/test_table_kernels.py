@@ -818,6 +818,20 @@ def _kernel_cases():
         recurrence, [((1, S, shape.conv_width), f32, P()),
                      ((1, S, 30), f32, P()), ((1, S, 30), f32, P()),
                      ((1, S), i32, P())]))
+
+    # the distinct-row writer at the word2vec cell's shape: 4,096 pairs
+    # x (1 + 5) rows a step into 3M x 300 held as [3,000,008, 384], the
+    # update rows formed from g and v in sorted order as the step does
+    from multiverso_tpu.ops import distinct_rows
+
+    def write_rows(table, ids, g, v):
+        return distinct_rows.add_rows(
+            table, ids, lambda lanes: -jnp.take(g, lanes)[:, None]
+            * jnp.take(v, lanes // 6, axis=0), interpret=False)
+    add("distinct-rows-add", lambda m: (
+        write_rows, [(distinct_rows.aligned_shape(3_000_001, 300), f32, P()),
+                     ((24_576,), i32, P()), ((24_576,), f32, P()),
+                     ((4096, 384), f32, P())]))
     return cases
 
 
