@@ -2,49 +2,66 @@
 
 Every parameter lives in a :class:`~multiverso_tpu.tables.base.Table`
 with updater ``adam``; one :class:`FusedSuperstep` a step computes the
-loss and its gradients and folds each gradient into its table through
-``table.updater.apply`` — the same pure function ``Table.add`` uses. The
+loss and its gradients and folds each table's delta into the table
+through ``table.updater.apply`` — the same pure function ``Table.add``
+uses. The
 embedding is a ``MatrixTable`` read by row gather and written by row
 scatter-add of the token gradients, word2vec's forms.
 
 The model is built from the keys of a published ``config.json``
-(:class:`LMConfig`): which mixer a layer has (``layer_types`` names it a
-layer: ``linear_attention``, the gated delta rule of
-:mod:`multiverso_tpu.ops.gated_delta`, or ``full_attention``, softmax
-attention with per-head keys; else ``kv_lora_rank`` set: multi-head
-latent attention, :mod:`multiverso_tpu.ops.latent_attention`), which
-feed-forward block (a dense SwiGLU for the first
-``first_k_dense_replace`` layers, then shared experts beside routed
-ones, :mod:`multiverso_tpu.ops.moe`; dense throughout where the config
-has no experts) and where the block's norms stand (``model_type``:
-before mixer and feed-forward, or — ``olmo_hybrid`` — on their outputs)
-are read from it, as is every width. ``ep_size`` / ``ep_rank`` say that this chip is one of a group
-that shares each layer: ``n_routed_experts`` is then the number of
-experts HELD HERE (the router keeps ``n_routed_experts * ep_size``
-outputs), ``vocab_size`` the rows of the vocabulary held here
-(``vocab_shard`` shards: ids, logits and loss are over the slice). The
-chip runs without the group's exchange; nothing stands in for it.
+(:class:`LMConfig`, which reads them under their published names):
+which mixer a layer has (``layer_types`` names it a layer:
+``linear_attention``, the gated delta rule of
+:mod:`multiverso_tpu.ops.gated_delta`; ``conv``, the gated short
+convolution of :mod:`multiverso_tpu.ops.short_conv`; or
+``full_attention``, softmax attention with per-head keys; else
+``kv_lora_rank`` set: multi-head latent attention,
+:mod:`multiverso_tpu.ops.latent_attention`), which feed-forward block (a
+dense SwiGLU for the first ``first_k_dense_replace`` /
+``num_dense_layers`` layers, then routed experts,
+:mod:`multiverso_tpu.ops.moe`, beside shared ones where the config has
+them; dense throughout where it has no experts) and what ``model_type``
+says of the block (:class:`_Block`): where its norms stand — before
+mixer and feed-forward, or, ``olmo_hybrid``, on their outputs —, whether
+full attention's QK-norm is over all heads' dims with nothing rotated
+or, ``lfm2_moe``, over a head's with a rotary embedding behind it and
+grouped key-value heads, and whether the router scores by a softmax or a
+sigmoid; as is every width. ``tie_word_embeddings``: no ``head`` table,
+``embed`` is gathered from AND multiplied by and takes ONE optimizer
+step on the sum of its two gradients. ``use_expert_bias``: an expert
+layer's choice is steered by a bias that is a table of its own, stepped
+by the routing's counts and no gradient. ``ep_size`` / ``ep_rank`` say
+that this chip is one of a group that shares each layer:
+``n_routed_experts`` (``num_experts``) is then the number of experts HELD
+HERE (the router keeps ``n_routed_experts * ep_size`` outputs),
+``vocab_size`` the rows of the vocabulary held here (``vocab_shard``
+shards: ids, logits and loss are over the slice). The chip runs without
+the group's exchange; nothing stands in for it.
 
 Tables (the count stays in the tens): ``embed`` [V, D] (MatrixTable),
-``head`` [V, D], ``norms`` [rows, D] (3 or 4 rows a layer,
-:func:`norm_offsets`, and the final norm's); a latent-attention layer:
-``l{i}.attn`` [D, H (nope + rope) | rank + rope | H v] (``w_q | w_kv_a |
-w_o``, the last stored out x in), ``l{i}.kv_b`` [rank, H (nope + v)]; a
-linear-attention layer: ``l{i}.gdn_in`` [D, q | k | v | gate | a | b],
-``l{i}.gdn_conv`` [taps, q | k | v], ``l{i}.gdn_decay`` [2, H]
+``head`` [V, D] (untied models), ``norms`` [rows, D] (2 to 4 rows a
+layer, :func:`norm_offsets`, and the final norm's); a latent-attention
+layer: ``l{i}.attn`` [D, H (nope + rope) | rank + rope | H v] (``w_q |
+w_kv_a | w_o``, the last stored out x in), ``l{i}.kv_b`` [rank, H (nope
++ v)]; a linear-attention layer: ``l{i}.gdn_in`` [D, q | k | v | gate |
+a | b], ``l{i}.gdn_conv`` [taps, q | k | v], ``l{i}.gdn_decay`` [2, H]
 (``a_log``, ``dt_bias``), ``l{i}.gdn_out`` [D, H v] (out x in); a
-full-attention layer: ``l{i}.attn`` [D, q | k | v | o] (``w_o`` stored
-out x in); a dense layer ``l{i}.mlp`` [3, D, F] (gate, up, down stored
-[D, F]); an expert layer ``l{i}.router`` [D, E], ``l{i}.shared``
-[3, D, shared F], ``l{i}.experts`` [held, 3, D, F] — the leading
-dimension is the expert, which is what a table shards over the model
-axis. :func:`table_layout` says where every tensor of a published role
-lies.
+short-convolution layer: ``l{i}.conv_in`` [D, B | C | X],
+``l{i}.conv_taps`` [taps, D], ``l{i}.conv_out`` [D, D] (in x out); a
+full-attention layer: ``l{i}.attn`` [D, q | k | v | o] (``k`` and ``v``
+as wide as the key-value heads; ``w_o`` stored out x in); a dense layer
+``l{i}.mlp`` [3, D, F] (gate, up, down stored [D, F]); an expert layer
+``l{i}.router`` [D, E], ``l{i}.expert_bias`` [E] (updater ``sgd``: its
+delta is no derivative), ``l{i}.shared`` [3, D, shared F],
+``l{i}.experts`` [held, 3, D, F] — the leading dimension is the expert,
+which is what a table shards over the model axis. :func:`table_layout`
+says where every tensor of a published role lies.
 
 Precision: tables, gradients and Adam's moments float32; matrix products
 on bfloat16 operands with float32 accumulation; norms, rotary, router
-logits and softmax, attention softmax, the recurrence's decay, gates
-and state between chunks, and the loss float32. The forward
+logits and scores, the selection bias, attention softmax, the
+recurrence's decay, gates and state between chunks, the short
+convolution's gates and taps, and the loss float32. The forward
 pass keeps only the residual entering each layer; the backward pass
 goes a layer at a time, recomputes the layer, and folds each table's
 gradient into the table (Adam) before the layer below starts, so no more
@@ -61,7 +78,8 @@ import collections
 import dataclasses
 import time
 from functools import partial
-from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, NamedTuple,
+                    Optional, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +92,7 @@ from multiverso_tpu.ops import gated_delta as gdn
 from multiverso_tpu.ops import interpret_mode
 from multiverso_tpu.ops import latent_attention as mla
 from multiverso_tpu.ops import moe
+from multiverso_tpu.ops import short_conv as sconv
 from multiverso_tpu.tables import MatrixTable
 from multiverso_tpu.tables.base import Table
 from multiverso_tpu.tables.superstep import make_superstep
@@ -84,11 +103,32 @@ from multiverso_tpu.utils.async_buffer import prefetch_iterator
 PROBE_ROWS = 1024       # embedding rows whose gradient a step returns
 AUX_KEEP = 4            # the last steps whose whole aux stays on the device
 PREFETCH_STEPS = 4      # packed steps the input thread runs ahead
-_SMALL_AUX = ("ce", "balance", "moe", "imbalance", "attend", "gdn")
-LATENT, LINEAR, FULL = "latent", "linear_attention", "full_attention"
-# model_type -> the block's norms stand on the outputs of mixer and
-# feed-forward (the Olmo 2 / 3 family's reordered norm), not before them
-_POST_NORM = {"deepseek_v2": False, "olmo_hybrid": True}
+_SMALL_AUX = ("ce", "balance", "moe", "imbalance", "attend", "gdn", "conv",
+              "bias")
+LATENT, LINEAR, FULL, CONV = ("latent", "linear_attention",
+                              "full_attention", "conv")
+
+
+class _Block(NamedTuple):
+    """What a ``model_type`` says of its decoder block."""
+    # the norms stand on the outputs of mixer and feed-forward (the
+    # Olmo 2 / 3 family's reordered norm), not before them
+    post_norm: bool
+    # full attention's QK-norm is over ONE head's dims (one weight
+    # vector a projection) and a rotary embedding follows it; else it is
+    # over all heads' dims and nothing is rotated
+    head_norm: bool
+    score: str                  # the router's: "softmax" or "sigmoid"
+    mixers: Tuple[str, ...]     # what its layer_types may name
+
+
+_BLOCKS = {"deepseek_v2": _Block(False, False, "softmax", ()),
+           "olmo_hybrid": _Block(True, False, "softmax", (LINEAR, FULL)),
+           "lfm2_moe": _Block(False, True, "sigmoid", (CONV, FULL))}
+# published names of keys this class holds under an older model's
+_PUBLISHED_NAMES = {"num_experts": "n_routed_experts",
+                    "num_dense_layers": "first_k_dense_replace",
+                    "norm_eps": "rms_norm_eps"}
 
 
 @dataclasses.dataclass
@@ -112,7 +152,7 @@ class LMConfig:
     rope_scaling: Optional[dict] = None
     rms_norm_eps: float = 1e-6
     vocab_size: int = 256               # rows HELD HERE
-    scoring_func: str = "softmax"
+    scoring_func: Optional[str] = None  # None: the model_type's own
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
     aux_loss_alpha: float = 0.001
@@ -128,6 +168,12 @@ class LMConfig:
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False
     rope_parameters: Optional[dict] = None
+    conv_L_cache: int = 3               # taps of the short convolution
+    conv_bias: bool = False
+    # a selection bias an expert layer: a table of its own, stepped by
+    # the routing's counts at expert_bias_rate (arXiv:2408.15664)
+    use_expert_bias: bool = False
+    expert_bias_rate: float = 1e-3
     # published keys that change the model and are built one way only
     attention_bias: bool = False
     hidden_act: str = "silu"
@@ -162,14 +208,22 @@ class LMConfig:
     gdn_chunk: int = 64         # tokens the recurrence solves at once
     compute_dtype: str = "bfloat16"     # operands of the matrix products
 
+    def __post_init__(self) -> None:
+        if self.scoring_func is None and self.model_type in _BLOCKS:
+            self.scoring_func = self.block.score
+
     @classmethod
     def from_dict(cls, d: dict) -> "LMConfig":
         """From a published config's keys (and the program's): a key
         the config lacks is a part the model lacks — no experts without
-        ``n_routed_experts``, no latent attention without
-        ``kv_lora_rank`` — and not this class's small default."""
+        ``n_routed_experts`` (published also as ``num_experts``), no
+        shared ones without ``n_shared_experts``, no balance loss without
+        ``aux_loss_alpha``, no latent attention without ``kv_lora_rank``
+        — and not this class's small default."""
         names = {f.name for f in dataclasses.fields(cls)}
-        absent = {"n_routed_experts": 0, "kv_lora_rank": None}
+        d = {_PUBLISHED_NAMES.get(k, k): v for k, v in d.items()}
+        absent = {"n_routed_experts": 0, "kv_lora_rank": None,
+                  "n_shared_experts": 0, "aux_loss_alpha": 0.0}
         return cls(**{**{k: v for k, v in absent.items() if k not in d},
                       **{k: v for k, v in d.items() if k in names}})
 
@@ -200,28 +254,49 @@ class LMConfig:
                    for i in range(self.num_hidden_layers))
 
     @property
+    def block(self) -> _Block:
+        return _BLOCKS[self.model_type]
+
+    @property
     def post_norm(self) -> bool:
-        return _POST_NORM[self.model_type]
+        return self.block.post_norm
 
     @property
     def head_dim(self) -> int:
         """Of ``full_attention``: the hidden size over the heads."""
         return self.hidden_size // self.num_attention_heads
 
+    @property
+    def kv_heads(self) -> int:
+        """Of ``full_attention``: key-value heads (a query head's group
+        shares one)."""
+        return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def attention_theta(self) -> Optional[float]:
+        """``full_attention``'s rotary base; ``None``: nothing is
+        rotated. ``rope_parameters.rope_theta`` where the config has
+        that group, else — behind a QK-norm a head — ``rope_theta``."""
+        if self.rope_parameters is not None:
+            return self.rope_parameters.get("rope_theta")
+        return self.rope_theta if self.block.head_norm else None
+
+    @property
+    def expert_layers(self) -> int:
+        return sum(not self.is_dense(i)
+                   for i in range(self.num_hidden_layers))
+
     def check(self) -> None:
-        if self.model_type not in _POST_NORM:
+        if self.model_type not in _BLOCKS:
             raise NotImplementedError(
                 f"model_type {self.model_type!r}: built are "
-                f"{sorted(_POST_NORM)}")
+                f"{sorted(_BLOCKS)}")
         if self.attention_bias:
             raise NotImplementedError("attention_bias: no projection "
                                       "has a bias here")
         if self.hidden_act != "silu":
             raise NotImplementedError(f"hidden_act {self.hidden_act!r}: "
                                       "only silu (SwiGLU) is built")
-        if self.tie_word_embeddings:
-            raise NotImplementedError("tie_word_embeddings: embedding "
-                                      "and head are two tables")
         if self.q_lora_rank is not None:
             raise NotImplementedError(
                 "q_lora_rank: only the direct query projection is built")
@@ -229,8 +304,15 @@ class LMConfig:
             self._check_latent()
         else:
             self._check_layer_types()
-        if self.scoring_func != "softmax":
-            raise NotImplementedError(f"scoring_func {self.scoring_func!r}")
+        if self.scoring_func != self.block.score:
+            raise NotImplementedError(
+                f"scoring_func {self.scoring_func!r} in a {self.model_type} "
+                f"block: its router's scores are a {self.block.score}")
+        if self.n_routed_experts and self.num_experts_per_tok \
+                > self.router_width:
+            raise ValueError(f"num_experts_per_tok "
+                             f"{self.num_experts_per_tok} of "
+                             f"{self.router_width} router outputs")
         if self.warmup_steps < 1:
             raise ValueError(f"warmup_steps {self.warmup_steps}: at least 1")
         if not 0 <= self.ep_rank < self.ep_size:
@@ -250,38 +332,68 @@ class LMConfig:
                 "attention without kv_lora_rank and without layer_types: "
                 "latent attention is what a config that names no mixer "
                 "gets")
-        if self.post_norm:
+        if self.block.mixers:
             raise NotImplementedError(
                 f"latent attention in a {self.model_type} block")
+
+    def _check_full_attention(self) -> None:
+        heads, kv = self.num_attention_heads, self.kv_heads
+        if self.hidden_size % heads:
+            raise ValueError(f"{heads} heads do not divide hidden size "
+                             f"{self.hidden_size}")
+        if heads % kv:
+            raise ValueError(f"num_key_value_heads {kv} do not divide "
+                             f"{heads} heads")
+        if self.block.head_norm:
+            if self.attention_theta is None or self.rope_scaling:
+                raise NotImplementedError(
+                    "rope_theta / rope_scaling: behind a QK-norm a head, "
+                    "full attention is built with a plain rotary "
+                    "embedding")
+            if self.head_dim % 2:
+                raise ValueError(f"a rotary embedding over a head of "
+                                 f"{self.head_dim} dims")
+            return
+        if kv != heads:
+            raise NotImplementedError(
+                f"num_key_value_heads {kv} of {heads} heads: behind a "
+                "QK-norm over all heads' dims, grouped key-value heads "
+                "are not built")
+        if self.attention_theta is not None:
+            raise NotImplementedError(
+                "rope_parameters.rope_theta: behind a QK-norm over all "
+                "heads' dims, full attention is built without a rotary "
+                "embedding")
 
     def _check_layer_types(self) -> None:
         kinds = self.layer_types[:self.num_hidden_layers]
         if len(kinds) < self.num_hidden_layers:
             raise ValueError(f"layer_types names {len(kinds)} of "
                              f"{self.num_hidden_layers} layers")
-        for kind in kinds:
-            if kind not in (LINEAR, FULL):
-                raise NotImplementedError(
-                    f"layer_types entry {kind!r}: built are "
-                    f"{LINEAR!r} and {FULL!r}")
-        if not self.post_norm:
+        mixers = self.block.mixers
+        if not mixers:
             raise NotImplementedError(
-                f"layer_types in a {self.model_type} block: the mixers "
-                "it names are built with the norms on their outputs")
+                f"layer_types in a {self.model_type} block: its mixer is "
+                "latent attention throughout")
+        for kind in kinds:
+            if kind not in mixers:
+                raise NotImplementedError(
+                    f"layer_types entry {kind!r}: a {self.model_type} "
+                    f"block builds {' and '.join(map(repr, mixers))}")
+        if self.kv_lora_rank is not None:
+            raise NotImplementedError(
+                "kv_lora_rank beside layer_types: a config names its "
+                "mixers one way")
         if FULL in kinds:
-            heads = self.num_attention_heads
-            if self.num_key_value_heads not in (None, heads):
+            self._check_full_attention()
+        if CONV in kinds:
+            if self.conv_bias:
                 raise NotImplementedError(
-                    f"num_key_value_heads {self.num_key_value_heads} of "
-                    f"{heads} heads: grouped key-value heads are not "
-                    "built")
-            if self.hidden_size % heads:
-                raise ValueError(f"{heads} heads do not divide hidden "
-                                 f"size {self.hidden_size}")
-            if (self.rope_parameters or {}).get("rope_theta") is not None:
-                raise NotImplementedError(
-                    "rope_parameters.rope_theta: full attention is built "
-                    "without a rotary embedding")
+                    "conv_bias: the short convolution is built without "
+                    "a bias")
+            if self.conv_L_cache < 1:
+                raise ValueError(f"conv_L_cache {self.conv_L_cache}: at "
+                                 "least one tap")
         if LINEAR in kinds:
             if self.linear_num_value_heads != self.linear_num_key_heads:
                 raise NotImplementedError(
@@ -322,20 +434,29 @@ def norm_offsets(c: LMConfig) -> List[int]:
     """Row of ``norms`` at which each layer's rows start, and (last) the
     final norm's: a latent layer has 3 (attention, latent, feed-forward),
     a linear-attention layer 3 (mixer, feed-forward, the gated output
-    norm), a full-attention layer 4 (mixer, feed-forward, q, k)."""
+    norm), a full-attention layer 4 (mixer, feed-forward, q, k), a
+    short-convolution layer 2 (mixer, feed-forward)."""
+    per = {FULL: 4, CONV: 2}
     rows = [0]
     for i in range(c.num_hidden_layers):
-        rows.append(rows[-1] + (4 if c.mixer(i) == FULL else 3))
+        rows.append(rows[-1] + per.get(c.mixer(i), 3))
     return rows
+
+
+def _full_columns(c: LMConfig) -> Tuple[int, int, int, int]:
+    """``w_q | w_k | w_v | w_o`` of a full-attention layer's table."""
+    D, kv = c.hidden_size, c.kv_heads * c.head_dim
+    return D, kv, kv, D
 
 
 def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
     """Every table's logical shape, in the order the superstep holds
     them; the index in this order seeds the table's start values."""
     D, L = c.hidden_size, c.num_hidden_layers
-    shapes: Dict[str, Tuple[int, ...]] = {
-        "embed": (c.vocab_size, D), "head": (c.vocab_size, D),
-        "norms": (norm_offsets(c)[L] + 1, D)}
+    shapes: Dict[str, Tuple[int, ...]] = {"embed": (c.vocab_size, D)}
+    if not c.tie_word_embeddings:
+        shapes["head"] = (c.vocab_size, D)
+    shapes["norms"] = (norm_offsets(c)[L] + 1, D)
     for i in range(L):
         kind = c.mixer(i)
         if kind == LATENT:
@@ -350,16 +471,29 @@ def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
             shapes[f"l{i}.gdn_conv"] = (g.taps, g.conv_width)
             shapes[f"l{i}.gdn_decay"] = (2, g.heads)
             shapes[f"l{i}.gdn_out"] = (D, g.heads * g.dv)
+        elif kind == CONV:
+            shapes[f"l{i}.conv_in"] = (D, 3 * D)
+            shapes[f"l{i}.conv_taps"] = (c.conv_L_cache, D)
+            shapes[f"l{i}.conv_out"] = (D, D)
         else:
-            shapes[f"l{i}.attn"] = (D, 4 * D)
+            shapes[f"l{i}.attn"] = (D, sum(_full_columns(c)))
         if c.is_dense(i):
             shapes[f"l{i}.mlp"] = (3, D, c.intermediate_size)
         else:
             F = c.moe_intermediate_size
             shapes[f"l{i}.router"] = (D, c.router_width)
-            shapes[f"l{i}.shared"] = (3, D, c.n_shared_experts * F)
+            if c.use_expert_bias:
+                shapes[f"l{i}.expert_bias"] = (c.router_width,)
+            if c.n_shared_experts:
+                shapes[f"l{i}.shared"] = (3, D, c.n_shared_experts * F)
             shapes[f"l{i}.experts"] = (c.n_routed_experts, 3, D, F)
     return shapes
+
+
+def is_bias(name: str) -> bool:
+    """Whether table ``name`` is an expert layer's selection bias: the
+    one kind whose updater is ``sgd`` and whose delta is no gradient."""
+    return name.endswith(".expert_bias")
 
 
 def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
@@ -371,16 +505,22 @@ def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
     ``w_q``, ``w_k``, ``w_v``, ``w_o`` (out x in), and the first's
     ``w_g``, ``w_a``, ``w_b``, ``conv`` [taps, q | k | v], ``a_log``,
     ``dt_bias``, ``o_norm`` [v head dim], the second's ``q_norm``,
-    ``k_norm``; ``w_gate`` / ``w_up`` / ``w_down`` [D, F]; ``router``,
-    ``shared_gate`` / ``_up`` / ``_down``, ``exp_gate`` / ``_up`` /
-    ``_down`` [held, D, F]."""
+    ``k_norm`` (a head's dims wide where the QK-norm is a head's); a
+    short-convolution layer's ``mixer_norm``, ``ffn_norm``, ``conv_in``
+    [D, B | C | X], ``conv_taps`` [taps, D], ``conv_out`` [D, D] (in x
+    out); ``w_gate`` / ``w_up`` / ``w_down`` [D, F]; ``router``,
+    ``expert_bias`` [E], ``shared_gate`` / ``_up`` / ``_down``,
+    ``exp_gate`` / ``_up`` / ``_down`` [held, D, F]. A tied model has no
+    ``head``: its head is ``embed``."""
     every = slice(None)
-    L, D = c.num_hidden_layers, c.hidden_size
+    L = c.num_hidden_layers
     rows = norm_offsets(c)
     layout: Dict[str, Dict[str, tuple]] = {
-        "embed": {"embed": (slice(0, c.vocab_size),)},   # less the scratch row
-        "head": {"head": (every,)},
-        "norms": {"final_norm": (rows[L],)}}
+        "embed": {"embed": (slice(0, c.vocab_size),)}}   # less the scratch row
+    if not c.tie_word_embeddings:
+        layout["head"] = {"head": (every,)}
+    layout["norms"] = {"final_norm": (rows[L],)}
+    qk_norm = (slice(0, c.head_dim),) if c.block.head_norm else ()
 
     def columns(names, widths):
         ends = np.cumsum(widths)
@@ -410,20 +550,30 @@ def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
             layout[f"l{i}.gdn_decay"] = {f"l{i}.a_log": (0,),
                                          f"l{i}.dt_bias": (1,)}
             layout[f"l{i}.gdn_out"] = {f"l{i}.w_o": (every,)}
+        elif kind == CONV:
+            layout["norms"].update({
+                f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,)})
+            for part in ("conv_in", "conv_taps", "conv_out"):
+                layout[f"l{i}.{part}"] = {f"l{i}.{part}": (every,)}
         else:
             layout["norms"].update({
                 f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,),
-                f"l{i}.q_norm": (r + 2,), f"l{i}.k_norm": (r + 3,)})
+                f"l{i}.q_norm": (r + 2, *qk_norm),
+                f"l{i}.k_norm": (r + 3, *qk_norm)})
             layout[f"l{i}.attn"] = columns(("w_q", "w_k", "w_v", "w_o"),
-                                           (D,) * 4)
+                                           _full_columns(c))
         if c.is_dense(i):
             layout[f"l{i}.mlp"] = {f"l{i}.w_{part}": (j,) for j, part in
                                    enumerate(("gate", "up", "down"))}
         else:
             layout[f"l{i}.router"] = {f"l{i}.router": (every,)}
-            layout[f"l{i}.shared"] = {
-                f"l{i}.shared_{part}": (j,) for j, part in
-                enumerate(("gate", "up", "down"))}
+            if c.use_expert_bias:
+                layout[f"l{i}.expert_bias"] = {
+                    f"l{i}.expert_bias": (every,)}
+            if c.n_shared_experts:
+                layout[f"l{i}.shared"] = {
+                    f"l{i}.shared_{part}": (j,) for j, part in
+                    enumerate(("gate", "up", "down"))}
             layout[f"l{i}.experts"] = {
                 f"l{i}.exp_{part}": (every, j) for j, part in
                 enumerate(("gate", "up", "down"))}
@@ -486,10 +636,15 @@ def _embed_gather(embed, tokens):
 
 
 @telemetry.scope("lm.embed_scatter")
-def _embed_scatter(embed, tokens, d_x):
+def _embed_scatter(embed, tokens, d_x, d_head=None):
     """The token gradients as a delta of the table's shape: rows
-    scatter-added, word2vec's form."""
-    return jnp.zeros_like(embed).at[tokens.reshape(-1)].add(
+    scatter-added, word2vec's form — onto zeros, or, for a tied table,
+    onto the head's dense gradient ``d_head`` (the table's logical
+    rows; a scratch row follows them): its ONE delta."""
+    base = jnp.zeros_like(embed) if d_head is None else jnp.pad(
+        d_head.astype(embed.dtype),
+        ((0, embed.shape[0] - d_head.shape[0]), (0, 0)))
+    return base.at[tokens.reshape(-1)].add(
         d_x.reshape(-1, d_x.shape[-1]).astype(embed.dtype))
 
 
@@ -522,6 +677,13 @@ def _dense_mlp_group(h, w):
 @telemetry.scope("lm.moe.shared")
 def _shared_experts_group(h, w):
     return _swiglu(h, w)
+
+
+@telemetry.scope("lm.block_norm")
+def _input_norm(x, weight, eps):
+    """A pre-norm block's norm on the residual its ``layer_types`` mixer
+    reads; ``eps`` arrives as an array."""
+    return mla.rms_norm(x, weight, eps)
 
 
 @telemetry.scope("lm.block_norm")
@@ -573,7 +735,7 @@ class TransformerLM:
                 rope_dim=c.qk_rope_head_dim,
                 qk_dim=c.qk_nope_head_dim + c.qk_rope_head_dim,
                 theta=float(c.rope_theta), scaling=c.rope_scaling)
-        else:
+        elif c.layers_of(LINEAR):
             self._gdn = gdn_shape(c)
         self._norm_rows = norm_offsets(c)
         option = AddOption(learning_rate=c.learning_rate, momentum=c.beta1,
@@ -582,7 +744,8 @@ class TransformerLM:
         with telemetry.span("lm.setup.init_tables"):
             draw = jax.jit(
                 lambda index, std, start, shape, pad: jnp.pad(
-                    jnp.ones(shape, jnp.float32) if start == "ones"
+                    jnp.full(shape, float(start == "ones"), jnp.float32)
+                    if start in ("ones", "zeros")
                     else decay_start(c, index, shape[1])
                     if start == "decay"
                     else start_values(c, index, shape, std), pad),
@@ -593,8 +756,14 @@ class TransformerLM:
                 # its logical rows
                 pad = ((0, 1 if tname == "embed" else 0),) \
                     + ((0, 0),) * (len(shape) - 1)
-                kw = dict(updater="adam", mesh=self.mesh,
-                          default_option=dataclasses.replace(option))
+                # a selection bias is state stepped by a rule that is no
+                # gradient: plain sgd on the routing's delta, at its own
+                # rate
+                updater, own = ("sgd", AddOption(
+                    learning_rate=c.expert_bias_rate)) if is_bias(tname) \
+                    else ("adam", dataclasses.replace(option))
+                kw = dict(updater=updater, mesh=self.mesh,
+                          default_option=own)
                 table = MatrixTable(
                     *shape, "float32", name=f"{name}.{tname}", **kw) \
                     if tname == "embed" \
@@ -602,7 +771,8 @@ class TransformerLM:
                 # the start is drawn on the device and installed as it
                 # is: 2.5 GB of it never pass the host
                 start = "ones" if tname == "norms" else "decay" \
-                    if tname.endswith(".gdn_decay") else "normal"
+                    if tname.endswith(".gdn_decay") else "zeros" \
+                    if is_bias(tname) else "normal"
                 table.put_raw(draw(index, start_std(c, tname), start,
                                    shape, pad))
                 self.tables[tname] = table
@@ -642,18 +812,37 @@ class TransformerLM:
                       g)
         return gdn.gate_out(o, gate, o_norm, t_out, g)
 
-    def _attention(self, x, doc, attn, q_norm, k_norm):
-        """Softmax attention with per-head keys, QK-norm and no rotary
-        part, before the block's norm."""
+    def _attention(self, x, doc, pos, attn, q_norm, k_norm):
+        """Softmax attention with per-head keys, on ``x`` as the block
+        hands it over: QK-norm over all heads' dims and no rotary part,
+        or (``head_norm``) QK-norm a head, a rotary embedding and
+        grouped key-value heads."""
         c = self.config
-        D = c.hidden_size
-        q, k, v = mla.project_heads(
-            x, attn[:, :D], attn[:, D:2 * D], attn[:, 2 * D:3 * D], q_norm,
-            k_norm, c.num_attention_heads, c.rms_norm_eps, c.compute_dtype)
+        q_end, k_end, v_end, _ = np.cumsum(_full_columns(c))
+        w_q, w_k, w_v = (attn[:, :q_end], attn[:, q_end:k_end],
+                         attn[:, k_end:v_end])
+        if c.block.head_norm:
+            d = c.head_dim
+            q, k, v = mla.project_grouped(
+                x, pos, w_q, w_k, w_v, q_norm[:d], k_norm[:d],
+                c.num_attention_heads, c.kv_heads, c.rms_norm_eps,
+                c.attention_theta, c.compute_dtype)
+        else:
+            q, k, v = mla.project_heads(
+                x, w_q, w_k, w_v, q_norm, k_norm, c.num_attention_heads,
+                c.rms_norm_eps, c.compute_dtype)
         o = mla.attend_heads(q, k, v, doc, scale=c.head_dim ** -0.5,
                              block=c.attention_block,
                              interpret=self._interpret)
-        return mla.output_heads(o, attn[:, 3 * D:])
+        return mla.output_heads(o, attn[:, v_end:])
+
+    def _short_conv(self, u, doc, w_in, taps, w_out):
+        """The gated short convolution's addition to the residual, from
+        the block's normed input."""
+        dtype = self.config.compute_dtype
+        return sconv.project_out(
+            sconv.mix(sconv.project_in(u, w_in, dtype), taps, doc), w_out,
+            dtype)
 
     def _layer(self, i: int, x, t, norms, doc, pos, real):
         """One decoder layer on the residual ``x`` [B, S, D] float32,
@@ -664,20 +853,32 @@ class TransformerLM:
         kind, eps = c.mixer(i), jnp.float32(c.rms_norm_eps)
         if kind == LATENT:
             x = x + self._latent(x, t, norms, doc, pos)
-        else:
+        elif c.post_norm:
             if kind == LINEAR:
                 y = self._gated_delta(
                     x, doc, t["gdn_in"], t["gdn_conv"], t["gdn_decay"],
                     t["gdn_out"], norms[2, :c.linear_value_head_dim])
             else:
-                y = self._attention(x, doc, t["attn"], norms[2], norms[3])
+                y = self._attention(x, doc, pos, t["attn"], norms[2],
+                                    norms[3])
             x = x + _output_norm(y, norms[0], eps)
+        else:
+            u = _input_norm(x, norms[0], eps)
+            if kind == CONV:
+                x = x + self._short_conv(u, doc, t["conv_in"],
+                                         t["conv_taps"], t["conv_out"])
+            else:
+                x = x + self._attention(u, doc, pos, t["attn"], norms[2],
+                                        norms[3])
         if c.post_norm:
             y = _over_sequences(_dense_mlp_group, c.mlp_chunks,
                                 x.astype(c.compute_dtype), t["mlp"])
             return (x + _output_norm(y.reshape(x.shape), norms[1], eps),
                     jnp.zeros(())), None
-        h = mla.rms_norm(x, norms[2], c.rms_norm_eps)
+        # the feed-forward's norm: a latent layer's third row, else the
+        # second
+        h = mla.rms_norm(x, norms[2 if kind == LATENT else 1],
+                         c.rms_norm_eps)
         hb = h.astype(c.compute_dtype)
         if c.is_dense(i):
             y = _over_sequences(_dense_mlp_group, c.mlp_chunks, hb,
@@ -687,7 +888,8 @@ class TransformerLM:
         routing = moe.route(
             h, t["router"], real, top_k=c.num_experts_per_tok,
             norm_topk_prob=c.norm_topk_prob,
-            scaling=c.routed_scaling_factor, alpha=c.aux_loss_alpha)
+            scaling=c.routed_scaling_factor, alpha=c.aux_loss_alpha,
+            score=c.scoring_func, bias=t.get("expert_bias"))
         plan = moe.plan(routing.top_e, real, first=c.first_expert,
                         held=c.n_routed_experts,
                         chunk_rows=c.expert_chunk_rows)
@@ -695,11 +897,11 @@ class TransformerLM:
         y, rows = moe.routed_experts(h.reshape(B * S, D), t["experts"],
                                      row_w, plan, c.expert_chunk_rows,
                                      jnp.dtype(c.compute_dtype))
-        shared = _over_sequences(_shared_experts_group, c.mlp_chunks, hb,
-                                 t["shared"])
-        return (x + shared.reshape(x.shape) + y.reshape(x.shape),
-                routing.balance), (routing.counts,
-                                   routing.top_e.astype(jnp.uint8), rows)
+        if c.n_shared_experts:
+            x = x + _over_sequences(_shared_experts_group, c.mlp_chunks, hb,
+                                    t["shared"]).reshape(x.shape)
+        return (x + y.reshape(x.shape), routing.balance), (
+            routing.counts, routing.top_e.astype(jnp.uint8), rows)
 
     def _layer_tables(self, tables: Dict[str, Any], i: int):
         prefix = f"l{i}."
@@ -729,8 +931,12 @@ class TransformerLM:
         as soon as the layer is done — before the layer below starts
         (an optimization barrier holds the order), so that what
         ``consume`` frees (the optimizer folds a gradient into its table
-        and lets it go) is free while the rest is computed. Returns
-        ``(aux, {table: what consume returned})``."""
+        and lets it go) is free while the rest is computed. Two deltas
+        are no gradient of their own table alone: a selection bias gets
+        what the layer's routing asks of it (:func:`moe.bias_delta`),
+        and a tied ``embed`` ONE delta, the head's dense gradient — held
+        until the sweep's end — with the tokens' rows scatter-added onto
+        it. Returns ``(aux, {table: what consume returned})``."""
         c = self.config
         L = c.num_hidden_layers
         tokens, doc, pos = batch[0], batch[1], batch[2]
@@ -745,10 +951,13 @@ class TransformerLM:
             entering.append(x)
             (x, _), _ = layer(i, x, *self._layer_tables(tables, i))
         norms, rows = tables["norms"], self._norm_rows
+        tied = c.tie_word_embeddings
         ce, (d_x, d_final, d_head) = jax.value_and_grad(
             self._head_loss, argnums=(0, 1, 2))(
-                x, norms[rows[L]], tables["head"], tokens, doc)
-        out = {"head": consume("head", d_head)}
+                x, norms[rows[L]],
+                tables["embed"][:c.vocab_size] if tied else tables["head"],
+                tokens, doc)
+        out = {} if tied else {"head": consume("head", d_head)}
         d_norms = jnp.zeros_like(norms).at[rows[L]].set(d_final)
         balance, routed = jnp.zeros(()), []
         for i in reversed(range(L)):
@@ -760,6 +969,8 @@ class TransformerLM:
                                      has_aux=True)
             d_x, d_t, d_n = vjp((d_x, jnp.ones(())))
             d_norms = d_norms.at[rows[i]:rows[i + 1]].set(d_n)
+            if "expert_bias" in d_t:
+                d_t["expert_bias"] = moe.bias_delta(r[0])
             done = {f"l{i}.{k}": consume(f"l{i}.{k}", g)
                     for k, g in d_t.items()}
             d_x, done = lax.optimization_barrier((d_x, done))
@@ -768,21 +979,24 @@ class TransformerLM:
             if r is not None:
                 routed.insert(0, r)
         out["norms"] = consume("norms", d_norms)
-        out["embed"] = consume("embed", _embed_scatter(tables["embed"],
-                                                       tokens, d_x))
+        out["embed"] = consume("embed", _embed_scatter(
+            tables["embed"], tokens, d_x, d_head if tied else None))
         aux = {"ce": ce, "balance": balance}
-        attending = L - c.layers_of(LINEAR)
+        attending = L - c.layers_of(LINEAR) - c.layers_of(CONV)
         if attending:
             # every attending layer's kernels run the same block pairs
             aux["attend"] = attending * mla.key_blocks(doc,
                                                        c.attention_block)
-        if attending < L:
+        if c.layers_of(LINEAR):
             # (sequence, linear layer, chunk) triples computed, and the
             # restarts of their states: one a document and layer
             B, S = doc.shape
-            aux["gdn"] = (L - attending) * jnp.stack([
+            aux["gdn"] = c.layers_of(LINEAR) * jnp.stack([
                 jnp.asarray(B * (S // min(c.gdn_chunk, S)), jnp.int32),
                 gdn.doc_starts(doc)])
+        if c.layers_of(CONV):
+            # documents whose first tokens read zeroed taps, a layer
+            aux["conv"] = c.layers_of(CONV) * gdn.doc_starts(doc)
         if routed:
             aux.update(zip(("counts", "chosen", "rows"),
                            (jnp.stack(a) for a in zip(*routed))))
@@ -811,6 +1025,12 @@ class TransformerLM:
                           range(c.num_hidden_layers)
                           if c.mixer(i) == LINEAR), None)
         gdn_keys = c.linear_num_key_heads * c.linear_key_head_dim
+        # the first short convolution behind experts (else the first)
+        convs = [i for i in range(c.num_hidden_layers)
+                 if c.mixer(i) == CONV]
+        probe_conv = next((f"l{i}.conv_in" for i in sorted(
+            convs, key=c.is_dense)), None)
+        biases = [n for n in names if is_bias(n)]
 
         def body(params, states, locals_, options, batch):
             held = {n: (apply, p, s, o) for n, apply, p, s, o in
@@ -827,6 +1047,8 @@ class TransformerLM:
                     report["probe_expert"] = grad[0]
                 elif name == probe_gdn:
                     report["probe_gdn_k"] = grad[:, gdn_keys:2 * gdn_keys]
+                elif name == probe_conv:
+                    report["probe_conv_in"] = grad
                 return apply(p, s, grad, o), report
 
             aux, done = self._sweep(dict(zip(names, params)), batch, fold)
@@ -835,6 +1057,11 @@ class TransformerLM:
             aux["probe_embed"] = done["embed"][1]["probe_embed"]
             if probe_gdn is not None:
                 aux["probe_gdn_k"] = done[probe_gdn][1]["probe_gdn_k"]
+            if probe_conv is not None:
+                aux["probe_conv_in"] = done[probe_conv][1]["probe_conv_in"]
+            if biases:      # the largest |bias| the step leaves
+                aux["bias"] = jnp.max(jnp.abs(jnp.stack(
+                    [done[n][0][0] for n in biases])))
             if probe_expert is not None:
                 routed = jnp.sum(lax.dynamic_slice_in_dim(
                     aux["counts"], c.first_expert, c.n_routed_experts, 1))
@@ -883,8 +1110,9 @@ class TransformerLM:
                 with telemetry.span("lm.superstep"):
                     lr = self.config.learning_rate_at(
                         self.tables["embed"].default_option.step)
-                    for table in self.tables.values():
-                        table.default_option.learning_rate = lr
+                    for tname, table in self.tables.items():
+                        if not is_bias(tname):  # a bias keeps its own rate
+                            table.default_option.learning_rate = lr
                     _, aux = self._fused((), placed)
                 telemetry.beat()
                 # of every step only the scalars wait for the fence; the
@@ -908,11 +1136,17 @@ class TransformerLM:
         telemetry.counter("lm.pad_tokens").inc(pads)
         for key, names in (("attend", ("lm.attend.key_blocks",
                                        "lm.attend.key_blocks_computed")),
-                           ("gdn", ("lm.gdn.chunks", "lm.gdn.doc_starts"))):
+                           ("gdn", ("lm.gdn.chunks", "lm.gdn.doc_starts")),
+                           ("conv", ("lm.conv.doc_starts",))):
             if small and key in small[-1]:
                 for i, name in enumerate(names):
-                    telemetry.counter(name).inc(
-                        int(sum(s[key][i] for s in small)))
+                    telemetry.counter(name).inc(int(sum(
+                        np.atleast_1d(s[key])[i] for s in small)))
+        if small and "bias" in small[-1]:
+            telemetry.counter("moe.bias_steps").inc(
+                len(small) * self.config.expert_layers)
+            telemetry.gauge("moe.expert_bias_max_abs").set(
+                float(small[-1]["bias"]))
         if small and "moe" in small[-1]:
             telemetry.counter("moe.tokens_routed").inc(
                 int(sum(s["moe"][0] for s in small)))
@@ -961,8 +1195,10 @@ class TransformerLM:
         h = mla.rms_norm(self.hidden_states(batch),
                          tables["norms"][self._norm_rows[-1]],
                          c.rms_norm_eps).astype(c.compute_dtype)
+        head = tables["embed"][:c.vocab_size] if c.tie_word_embeddings \
+            else tables["head"]
         return lax.dot_general(
-            h, tables["head"].astype(c.compute_dtype),
+            h, head.astype(c.compute_dtype),
             (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
     def named_parameters(self) -> Dict[str, jax.Array]:

@@ -126,21 +126,26 @@ def project(x, w_in, a_log, dt_bias, shape: GatedDeltaShape):
     return run(x, w_in, a_log, dt_bias)
 
 
-@telemetry.scope("lm.gdn.conv")
-def short_conv(qkv, taps, doc):
-    """Each channel of ``qkv`` [B, S, W] through its own causal filter
+def causal_taps(x, taps, doc):
+    """Each channel of ``x`` [B, S, W] through its own causal filter
     ``taps`` [K, W] (tap ``K - 1`` weighs the token itself, tap ``j`` the
     token ``K - 1 - j`` back; no bias), a tap that would reach into an
-    earlier document — or before the sequence — reading 0; then
-    ``silu``."""
+    earlier document — or before the sequence — reading 0."""
     K = taps.shape[0]
     seg = segments(doc)
-    y = qkv * taps[K - 1]
+    y = x * taps[K - 1]
     for back in range(1, K):
-        shifted = jnp.pad(qkv, ((0, 0), (back, 0), (0, 0)))[:, :-back]
+        shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :-back]
         same = jnp.pad(seg, ((0, 0), (back, 0)))[:, :-back] == seg
         y = y + jnp.where(same[..., None], shifted, 0.0) * taps[K - 1 - back]
-    return jax.nn.silu(y)
+    return y
+
+
+@telemetry.scope("lm.gdn.conv")
+def short_conv(qkv, taps, doc):
+    """``q | k | v`` [B, S, W] through :func:`causal_taps`, then
+    ``silu``."""
+    return jax.nn.silu(causal_taps(qkv, taps, doc))
 
 
 def _unit(x):

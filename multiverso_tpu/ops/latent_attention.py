@@ -37,6 +37,16 @@ The same kernels run plain multi-head attention — per-head keys of one
 depth, no rotary part (:func:`attend_heads`, with :func:`project_heads`
 and :func:`output_heads` around it): the variant is static, the rotary
 operands, their products, gradients and scratch are simply not there.
+With fewer key-value heads than query heads (grouped-query attention,
+:func:`project_grouped` before it: QK-norm a head, then a rotary
+embedding over the whole head) query head ``h`` fetches the key and
+value blocks of head ``h // group``; the backward kernel writes the
+keys' and values' gradients one a QUERY head in float32 and the group's
+are summed outside it — a key block's accumulators live through one
+head's query blocks, and the queries' gradient of a head's whole
+sequence is held across the head's key blocks, so a sum over the group
+inside the kernel would need both held at once for every head of the
+group; the sum outside costs one pass over ``group`` x the keys.
 
 Matrix products take operands of ``LatentShape.dtype`` (bfloat16) and
 accumulate in float32; norms, the rotary embedding and the softmax are
@@ -176,6 +186,8 @@ class _Static(NamedTuple):
     # of the variant without rotary operands, which has no shared key
     # to count its heads by
     heads: int = 0
+    # query heads that share a key-value head (that variant's alone)
+    group: int = 1
 
 
 def block_plan(doc, block: int):
@@ -391,7 +403,7 @@ def _grid(static: _Static, B, H, S, Dn, Dr, Dv, doc, queries_inner):
     outer block, inner block): the outer block is held, the inner one
     follows the fetch plan — key blocks under a query block, or, with
     ``queries_inner``, query blocks over a key block."""
-    block, n = static.block, S // static.block
+    block, n, group = static.block, S // static.block, static.group
     plan = block_plan(doc, block)
     if queries_inner:
         plan = plan.transpose(0, 2, 1)
@@ -406,20 +418,25 @@ def _grid(static: _Static, B, H, S, Dn, Dr, Dv, doc, queries_inner):
         q_at = lambda b, h, i, j, hold, plan: i
         k_at = lambda b, h, i, j, hold, plan: fetched(b, i, j, hold)
 
-    def rows(at, d, head=True):
+    def rows(at, d, head=True, shared=False):
         """[B, S, H * d]: the rows of block ``at`` of head ``h`` (of the
-        one head there is)."""
+        one head there is; ``shared``: of the head ``h``'s group
+        shares)."""
+        if shared and group > 1:
+            return pl.BlockSpec((None, block, d), lambda b, h, *a: (
+                b, at(b, h, *a), h // group))
         return pl.BlockSpec((None, block, d), lambda b, h, *a: (
             b, at(b, h, *a), h if head else 0))
 
     per_q = functools.partial(rows, q_at)
     per_k = functools.partial(rows, k_at)
+    held_k = functools.partial(rows, k_at, shared=True)
     q_doc = pl.BlockSpec((None, 1, block),
                          lambda b, h, *a: (b, 0, q_at(b, h, *a)))
     q_stat = pl.BlockSpec((None, None, 1, block),
                           lambda b, h, *a: (b, h, 0, q_at(b, h, *a)))
-    operands = [per_q(Dn), per_q(Dr), per_k(Dn), per_k(Dr, head=False),
-                per_k(Dv), q_doc, per_k(1, head=False)]
+    operands = [per_q(Dn), per_q(Dr), held_k(Dn), per_k(Dr, head=False),
+                held_k(Dv), q_doc, per_k(1, head=False)]
     if not Dr:
         del operands[3], operands[1]
     return ((B, H, n, n), (_fetch_plan(plan).reshape(-1), plan.reshape(-1)),
@@ -451,7 +468,7 @@ def _sizes(qn, qp, kp, v, static: _Static):
     else:
         B, S, Dr = kp.shape
         H = qp.shape[2] // Dr
-    return B, H, S, qn.shape[2] // H, Dr, v.shape[2] // H
+    return B, H, S, qn.shape[2] // H, Dr, v.shape[2] * static.group // H
 
 
 def _forward(qn, qp, kn, kp, v, doc, static: _Static, residuals: bool):
@@ -495,14 +512,23 @@ def _backward(static: _Static, saved, do):
     grid, plans, operands, per_q, per_k, q_stat = _grid(
         static, B, H, S, Dn, Dr, Dv, doc, True)
     if not Dr:
+        group = static.group
+        # grouped: a key block's two gradients one a QUERY head, float32
+        per_head = (lambda a: like(a)) if group == 1 else (
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape[:2] + (a.shape[2] * group,), jnp.float32))
         dqn, dkn, dv = _pallas(
             _backward_kernel_plain, static, grid, plans,
             operands + [per_q(Dv), q_stat, q_stat],
             [whole(Dn), per_k(Dn), per_k(Dv)],
-            [like(qn), like(kn), like(v)],
+            [like(qn), per_head(kn), per_head(v)],
             [f32(S, Dn), f32(block, Dn), f32(block, Dv)],
             S, inner_only=False)(
                 qn, kn, v, doc[:, None, :], doc[:, :, None], do, lse, di)
+        if group > 1:       # a key-value head's is its query heads' sum
+            dkn, dv = (d.reshape(B, S, H // group, group, -1).sum(3)
+                       .reshape(a.shape).astype(a.dtype)
+                       for d, a in ((dkn, kn), (dv, v)))
         return dqn, None, dkn, None, dv, None
     dqn, dqp, dkn, dkp, dv = _pallas(
         _backward_kernel, static, grid, plans,
@@ -594,21 +620,52 @@ def project_heads(x, w_q, w_k, w_v, q_norm_w, k_norm_w, heads: int,
     return run(x, w_q, w_k, w_v, q_norm_w, k_norm_w)
 
 
+def project_grouped(x, pos, w_q, w_k, w_v, q_norm_w, k_norm_w, heads: int,
+                    kv_heads: int, eps: float, theta: float, dtype):
+    """From the block's normed input ``x`` [B, S, D] float32 and the
+    positions inside the documents: ``q`` [B, S, H, d], ``k``, ``v``
+    [B, S, G, d] in ``dtype``. ``q`` and ``k`` go through an RMS norm
+    over ONE head's ``d`` dims (one weight vector of ``d`` for every
+    head), then a rotary embedding over all ``d`` dims, half-split pairs
+    ``(i, i + d / 2)``, frequencies ``theta ** (-2 i / d)``."""
+    @telemetry.scope("lm.attn.project")
+    def run(x, pos, w_q, w_k, w_v, q_norm_w, k_norm_w):
+        B, S, _ = x.shape
+        d = w_q.shape[1] // heads
+        ang = pos[..., None].astype(jnp.float32) * jnp.asarray(
+            Rotary.from_config(rope_dim=d, qk_dim=d, theta=float(theta),
+                               scaling=None).inv_freq)
+        cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+
+        def normed(w, norm_w, n):
+            y = rms_norm(_dot(x, w, dtype).reshape(B, S, n, d), norm_w, eps)
+            return _rotate(y, cos, sin).astype(dtype)
+
+        return (normed(w_q, q_norm_w, heads), normed(w_k, k_norm_w, kv_heads),
+                _dot(x, w_v, dtype).astype(dtype).reshape(B, S, kv_heads, d))
+    return run(x, pos, w_q, w_k, w_v, q_norm_w, k_norm_w)
+
+
 def attend_heads(q, k, v, doc, *, scale: float, block: int,
                  interpret: Optional[bool] = None):
     """:func:`attend` for keys of one depth a head and no rotary part:
-    ``q``, ``k`` [B, S, H, d], ``v`` [B, S, H, v]; the same kernels
-    without the rotary operands. Returns [B, S, H * v]."""
+    ``q`` [B, S, H, d], ``k`` [B, S, G, d], ``v`` [B, S, G, v] with
+    ``G`` = ``H``, or a divisor of it: query head ``h`` reads key-value
+    head ``h // (H / G)``; the same kernels without the rotary operands.
+    Returns [B, S, H * v]."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     @telemetry.scope("lm.attn.attend")
     def run(q, k, v, doc):
-        B, S, H, vd = v.shape
+        (B, S, H, _), vd = q.shape, v.shape[-1]
+        if H % v.shape[2]:
+            raise ValueError(f"{v.shape[2]} key-value heads do not divide "
+                             f"{H} query heads")
         flat = lambda a: _lanes(a).reshape(B, S, -1)
         o = _attention(flat(q), None, flat(k), None, flat(v), doc,
                        _Static(float(scale), _block(block, S),
-                               bool(interpret), H))
+                               bool(interpret), H, H // v.shape[2]))
         return o.reshape(B, S, H, -1)[..., :vd].reshape(B, S, H * vd)
     return run(q, k, v, doc)
 

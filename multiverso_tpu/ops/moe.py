@@ -48,36 +48,66 @@ class Plan(NamedTuple):
     # rows from offsets[-1] on belong to no expert held here
 
 
+# a router's score function, and what its published code adds under
+# the sum that normalises the chosen scores
+_SCORES = {"softmax": (partial(jax.nn.softmax, axis=-1), 1e-20),
+           "sigmoid": (jax.nn.sigmoid, 1e-6)}
+
+
 def route(h, w_router, real, *, top_k: int, norm_topk_prob: bool,
-          scaling: float, alpha: float) -> Routing:
-    """Softmax scores over all experts in float32 (the logits at full
-    float32 precision: a near-tie decided by bfloat16 inputs would send
-    the token elsewhere), the greedy top-k as they are, exact counts,
-    and the per-sequence balance loss ``alpha * sum_e f_e P_e`` with
-    ``f_e = E / (k n) * (real tokens of the sequence that chose e)`` and
-    ``P_e`` the mean score of ``e`` over the sequence's real tokens."""
+          scaling: float, alpha: float, score: str = "softmax",
+          bias=None) -> Routing:
+    """Scores over all experts in float32 — ``score``: ``softmax`` over
+    them or a ``sigmoid`` each — (the logits at full float32 precision:
+    a near-tie decided by bfloat16 inputs would send the token
+    elsewhere), the greedy top-k, exact counts, and the per-sequence
+    balance loss ``alpha * sum_e f_e P_e`` with ``f_e = E / (k n) *
+    (real tokens of the sequence that chose e)`` and ``P_e`` the mean
+    score of ``e`` over the sequence's real tokens (0 where ``alpha`` is
+    0). With a ``bias`` [E] (auxiliary-loss-free balancing,
+    arXiv:2408.15664) the experts chosen are the top-k of ``score +
+    bias``, their weights the scores alone: the bias steers the choice,
+    takes no gradient and scales nothing."""
+    squash, under = _SCORES[score]
+
     @telemetry.scope("lm.moe.route")
-    def run(h, w_router, real):
+    def run(h, w_router, real, bias):
         B, S, _ = h.shape
         E = w_router.shape[1]
         logits = jnp.einsum("bsd,de->bse", h, w_router,
                             precision=lax.Precision.HIGHEST)
-        s = jax.nn.softmax(logits, axis=-1)
-        top_s, top_e = lax.top_k(s, top_k)
+        s = squash(logits)
+        if bias is None:
+            top_s, top_e = lax.top_k(s, top_k)
+        else:
+            top_e = lax.top_k(s + lax.stop_gradient(bias), top_k)[1]
+            top_s = jnp.take_along_axis(s, top_e, -1)
         if norm_topk_prob:
-            top_s = top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-20)
+            top_s = top_s / (jnp.sum(top_s, -1, keepdims=True) + under)
         top_s = top_s * scaling
         chose = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), 2) \
             * real[..., None]                                  # [B, S, E]
         per_seq = chose.sum(1)                                 # [B, E]
-        n = jnp.maximum(real.sum(1), 1.0)[:, None]
-        f = per_seq * (E / (top_k * n))
-        P = (s * real[..., None]).sum(1) / n
-        balance = alpha * jnp.mean(jnp.sum(f * P, -1))
+        balance = jnp.zeros(())
+        if alpha:
+            n = jnp.maximum(real.sum(1), 1.0)[:, None]
+            f = per_seq * (E / (top_k * n))
+            P = (s * real[..., None]).sum(1) / n
+            balance = alpha * jnp.mean(jnp.sum(f * P, -1))
         counts = per_seq.sum(0).astype(jnp.int32)
         return Routing(top_s.reshape(B * S, top_k),
                        top_e.reshape(B * S, top_k), counts, balance)
-    return run(h, w_router, real)
+    return run(h, w_router, real, bias)
+
+
+def bias_delta(counts):
+    """What a step's routing asks of an expert layer's selection bias:
+    ``sign(c_e - mean c)`` [E] float32 from the real tokens ``counts``
+    [E] that chose each expert — a table's delta that is no derivative;
+    updater ``sgd`` at rate gamma makes it ``b_e += gamma sign(mean c -
+    c_e)``."""
+    c = counts.astype(jnp.float32)
+    return jnp.sign(c - jnp.mean(c))
 
 
 
@@ -185,7 +215,11 @@ def routed_experts(h, w, row_w, plan_, chunk_rows, dtype=jnp.bfloat16):
     block from ``h``. It is written out because jax refuses reverse mode
     through a loop whose trip count is traced (``ragged_dot`` itself
     differentiates); a static count would be ``T k / chunk_rows`` blocks
-    (24 at the cell's sizes) where the mean routing fills 3."""
+    (24 at the cell's sizes) where the mean routing fills 3. (``dtype``
+    float32 on a v5e, libtpu 0.0.34: a block of 8,192 float32 rows came
+    out of ``ragged_dot`` wrong — 0.95 of the layer's value — where
+    2,048 agree with the plain form to 2e-7, and bfloat16 blocks of
+    8,192 to rounding; PERF.md §7, PR 34.)"""
     return _routed_forward(h, w, row_w, plan_, chunk_rows, dtype)
 
 

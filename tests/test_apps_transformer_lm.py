@@ -621,7 +621,8 @@ def test_what_is_not_built_says_so(mesh):
     for key, value, says in (
             ("attention_bias", True, "attention_bias"),
             ("hidden_act", "gelu", "hidden_act"),
-            ("tie_word_embeddings", True, "tie_word_embeddings"),
+            ("scoring_func", "sigmoid", "scoring_func"),
+            ("model_type", "lfm2_moe", "latent attention in a"),
             ("model_type", "llama", "model_type"),
             ("layer_types", ["full_attention", "sliding_attention"] * 14,
              "layer_types entry"),

@@ -553,8 +553,9 @@ def test_the_packer_s_pool_is_the_configuration_s(mesh):
 @pytest.mark.parametrize("change, error, says", [
     (dict(attention_bias=True), NotImplementedError, "attention_bias"),
     (dict(hidden_act="gelu"), NotImplementedError, "hidden_act"),
-    (dict(tie_word_embeddings=True), NotImplementedError,
-     "tie_word_embeddings"),
+    (dict(kv_lora_rank=16), NotImplementedError, "kv_lora_rank"),
+    (dict(layer_types=[LINEAR, "conv", LINEAR, FULL]), NotImplementedError,
+     "layer_types entry"),
     (dict(model_type="olmo3"), NotImplementedError, "model_type"),
     (dict(layer_types=[LINEAR, "sliding_attention", LINEAR, FULL]),
      NotImplementedError, "layer_types entry"),

@@ -805,6 +805,22 @@ def _kernel_cases():
     add("plain-attention-forward", lambda m: plain_attention(False))
     add("plain-attention-backward", lambda m: plain_attention(True))
 
+    # ... and grouped: 32 query heads on 8 key-value heads of depth 64
+    # (padded to whole lanes), the third language-model cell's shape; a
+    # key block's gradients come out one a QUERY head in float32
+    def grouped_attention(grad):
+        def attend(q, k, v, doc):
+            return mla.attend_heads(q, k, v, doc, scale=64 ** -0.5,
+                                    block=512, interpret=False)
+
+        def loss(*a):
+            return jnp.sum(attend(*a).astype(f32))
+        return (jax.grad(loss, argnums=(0, 1, 2)) if grad else attend,
+                [((4, S, 32, 64), bf16, P())] + [((4, S, 8, 64), bf16, P())]
+                * 2 + [((4, S), i32, P())])
+    add("grouped-attention-forward", lambda m: grouped_attention(False))
+    add("grouped-attention-backward", lambda m: grouped_attention(True))
+
     # the chunked gated delta rule (blocked XLA: the triangular solve,
     # the scan over chunks and its transpose) at the published widths:
     # 30 heads of 96 x 192, chunks of 64
