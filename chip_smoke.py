@@ -93,6 +93,11 @@ FULL = dict(
     # the language-model cell's attention (perf/configs/dsv2_lite_ep8.json)
     attend=dict(sequences=8, length=4096, heads=16, nope=128, rope=64,
                 v=128, block=512, doc_median=512, repeats=3),
+    # one linear-attention layer's recurrence of the hybrid's cell
+    # (perf/configs/olmo_hybrid_7b_vp8.json): 96 and 192 are no multiple
+    # of 128 lanes
+    gdn_recur=dict(sequences=1, length=4096, heads=30, dk=96, dv=192,
+                   chunk=64, doc_median=512, repeats=3),
     # one step of the word2vec cell's output side
     # (perf/configs/w2v_gnews300.json): 4,096 pairs x (1 + 5) rows
     w2v_scatter=dict(vocab=3_000_000, dim=300, lanes=24_576, zipf=1.05),
@@ -108,6 +113,8 @@ TINY = dict(
                 value_dim=4),
     attend=dict(sequences=2, length=64, heads=2, nope=16, rope=8, v=16,
                 block=16, doc_median=12, repeats=1),
+    gdn_recur=dict(sequences=2, length=64, heads=3, dk=8, dv=16, chunk=16,
+                   doc_median=12, repeats=1),
     w2v_scatter=dict(vocab=3000, dim=20, lanes=600, zipf=1.3),
 )
 
@@ -460,6 +467,37 @@ def phase_tables(cfg: dict, mesh, platform: str) -> dict:
     }
 
 
+def _packed_doc(rng, sequences, length, median):
+    """``doc`` [sequences, length] of packed documents, lengths lognormal
+    around ``median``."""
+    import jax.numpy as jnp
+    from multiverso_tpu.data.packing import pack_documents
+
+    lengths = np.clip(np.rint(np.exp(rng.normal(
+        np.log(median), 1.0, 64 * sequences))), 3, length).astype(int)
+    return jnp.asarray(next(pack_documents(
+        (np.ones(n, np.int32) for n in lengths), sequences, length))["doc"])
+
+
+def _gap(a, b) -> float:
+    f32 = lambda x: np.asarray(x, np.float32)
+    return float(np.linalg.norm(f32(a) - f32(b))
+                 / max(np.linalg.norm(f32(b)), 1e-30))
+
+
+def _timed(fn, operands, repeats):
+    """``fn``'s result and its milliseconds a call, after a first call
+    that compiles."""
+    import jax
+
+    out = jax.block_until_ready(fn(*operands))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        out = fn(*operands)
+    jax.block_until_ready(out)
+    return out, round(1e3 * (time.perf_counter() - t0) / repeats, 3)
+
+
 def _attend_blocked(q_nope, q_pe, k_nope, k_pe, v, doc, scale, block):
     """The plain blocked form the attention kernel replaced: a sequence
     at a time, ``block`` queries against the keys up to the block's end,
@@ -498,16 +536,12 @@ def phase_attend(cfg: dict, mesh, platform: str) -> dict:
     against the plain blocked form in the same precision."""
     import jax
     import jax.numpy as jnp
-    from multiverso_tpu.data.packing import pack_documents
     from multiverso_tpu.ops import interpret_mode
     from multiverso_tpu.ops import latent_attention as mla
 
     B, S, H = cfg["sequences"], cfg["length"], cfg["heads"]
     rng = np.random.default_rng(11)
-    lengths = np.clip(np.rint(np.exp(rng.normal(
-        np.log(cfg["doc_median"]), 1.0, 64 * B))), 3, S).astype(int)
-    doc = jnp.asarray(next(pack_documents(
-        (np.ones(n, np.int32) for n in lengths), B, S))["doc"])
+    doc = _packed_doc(rng, B, S, cfg["doc_median"])
     draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
     operands = (draw(B, S, H, cfg["nope"]), draw(B, S, H, cfg["rope"]),
                 draw(B, S, H, cfg["nope"]), draw(B, S, cfg["rope"]),
@@ -527,26 +561,14 @@ def phase_attend(cfg: dict, mesh, platform: str) -> dict:
             argnums=(0, 1, 2, 3, 4)))
         forward = jax.jit(form)
         for label, fn in (("forward", forward), ("both", both)):
-            out = jax.block_until_ready(fn(*operands))
-            t0 = time.perf_counter()
-            for _ in range(cfg["repeats"]):
-                out = fn(*operands)
-            jax.block_until_ready(out)
-            ms[f"{name}_{label}_ms"] = round(
-                1e3 * (time.perf_counter() - t0) / cfg["repeats"], 3)
-            got[name, label] = out
-    f32 = lambda a: np.asarray(a, np.float32)
-
-    def gap(a, b):
-        return float(np.linalg.norm(f32(a) - f32(b))
-                     / max(np.linalg.norm(f32(b)), 1e-30))
-
-    gaps = {"o": gap(got["kernel", "forward"], got["blocked", "forward"])}
+            got[name, label], ms[f"{name}_{label}_ms"] = _timed(
+                fn, operands, cfg["repeats"])
+    gaps = {"o": _gap(got["kernel", "forward"], got["blocked", "forward"])}
     for name, a, b in zip(("q_nope", "q_pe", "k_nope", "k_pe", "v"),
                           got["kernel", "both"][1],
                           got["blocked", "both"][1]):
         assert a.shape == b.shape and a.dtype == b.dtype, name
-        gaps[f"d_{name}"] = gap(a, b)
+        gaps[f"d_{name}"] = _gap(a, b)
     # bfloat16 operands: the two forms round the probabilities at
     # different points (before and after the division by their sum)
     assert all(g < 2e-2 for g in gaps.values()), gaps
@@ -556,6 +578,88 @@ def phase_attend(cfg: dict, mesh, platform: str) -> dict:
                        "relative gap of o and of the five gradients < 2e-2",
             "gaps": {k: round(g, 5) for k, g in gaps.items()},
             "key_blocks": total, "key_blocks_computed": computed, **ms}
+
+
+def _recur_scan(qkv, g, beta, doc, shape):
+    """The plain form the recurrence's kernels replaced: the chunk-local
+    terms as ``recur`` makes them, then a ``lax.scan`` over the chunks
+    with the state as its carry; the backward pass is the scan's own."""
+    import jax
+    import jax.numpy as jnp
+    from multiverso_tpu.ops import gated_delta as gdn
+
+    w, u, attn, q_in, k_out, keep = gdn.chunk_terms(qkv, g, beta, doc, shape)
+    dot = lambda eq, a, b: jnp.einsum(eq, a, b,
+                                      preferred_element_type=jnp.float32)
+
+    def chunk(state, xs):
+        w, u, attn, q_in, k_out, keep = xs
+        held = state.astype(w.dtype)
+        newb = (u - dot("hck,hkv->hcv", w, held)).astype(w.dtype)
+        o = dot("hck,hkv->hcv", q_in, held) + dot("hij,hjv->hiv", attn, newb)
+        return keep * state + dot("hck,hcv->hkv", k_out, newb), o
+
+    o = jax.lax.scan(chunk, jnp.zeros((w.shape[1], shape.dk, shape.dv),
+                                      jnp.float32),
+                     (w, u, attn, q_in, k_out, keep))[1]
+    return gdn.from_chunks(o, qkv.shape[0])
+
+
+def phase_gdn_recur(cfg: dict, mesh, platform: str) -> dict:
+    """The gated delta rule's recurrence (``ops/gated_delta.py``
+    ``recur``: the state from chunk to chunk in two Mosaic kernels on
+    the chip), forward and backward at the hybrid cell's widths on
+    packed documents, against the plain scan over chunks in the same
+    precision and in float32."""
+    import jax
+    import jax.numpy as jnp
+    from multiverso_tpu.ops import gated_delta as gdn
+    from multiverso_tpu.ops import interpret_mode
+
+    B, S, H = cfg["sequences"], cfg["length"], cfg["heads"]
+    shape = gdn.GatedDeltaShape(H, cfg["dk"], cfg["dv"], 4, True, 1e-6,
+                                cfg["chunk"], "bfloat16")
+    rng = np.random.default_rng(17)
+    doc = _packed_doc(rng, B, S, cfg["doc_median"])
+    operands = (
+        jnp.asarray(rng.normal(size=(B, S, shape.conv_width)), jnp.float32),
+        -jnp.asarray(rng.uniform(1e-3, 0.5, size=(B, S, H)), jnp.float32),
+        jnp.asarray(rng.uniform(0.1, 1.9, size=(B, S, H)), jnp.float32))
+    weight = jnp.asarray(rng.normal(size=(B, S, H, cfg["dv"])), jnp.float32)
+
+    def float32(*a):
+        with jax.default_matmul_precision("highest"):
+            return _recur_scan(*a, doc, shape._replace(dtype="float32"))
+
+    forms = {
+        "kernel": lambda *a: gdn.recur(*a, doc, shape,
+                                       interpret=interpret_mode(mesh)),
+        "scan": lambda *a: _recur_scan(*a, doc, shape),
+        "float32": float32}
+    got, ms = {}, {}
+    for name, form in forms.items():
+        both = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(form(*a) * weight), argnums=(0, 1, 2)))
+        for label, fn in (("forward", jax.jit(form)), ("both", both)):
+            got[name, label], ms[f"{name}_{label}_ms"] = _timed(
+                fn, operands, cfg["repeats"])
+    gaps = {}
+    for other in ("scan", "float32"):
+        gaps[f"o_{other}"] = _gap(got["kernel", "forward"],
+                                  got[other, "forward"])
+        for name, a, b in zip(("qkv", "g", "beta"), got["kernel", "both"][1],
+                              got[other, "both"][1]):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            gaps[f"d_{name}_{other}"] = _gap(a, b)
+    assert all(g < 2e-2 for g in gaps.values()), gaps
+    return {"shape": {k: cfg[k] for k in sorted(cfg)},
+            "heads_a_grid_step": gdn._head_block(
+                B * H, min(cfg["chunk"], S), cfg["dk"], cfg["dv"]),
+            "documents": int(gdn.doc_starts(doc)),
+            "matches": "the plain scan over chunks with bfloat16 operands "
+                       "and with float32 ones at highest: relative gap of "
+                       "o and of the three gradients < 2e-2",
+            "gaps": {k: round(g, 5) for k, g in gaps.items()}, **ms}
 
 
 def phase_w2v_scatter(cfg: dict, mesh, platform: str) -> dict:
@@ -599,6 +703,7 @@ def phase_w2v_scatter(cfg: dict, mesh, platform: str) -> dict:
 
 CHILD_PHASES = {"w2v": phase_w2v, "lda": phase_lda,
                 "tables": phase_tables, "attend": phase_attend,
+                "gdn_recur": phase_gdn_recur,
                 "w2v_scatter": phase_w2v_scatter}
 
 
@@ -938,7 +1043,8 @@ class Smoke:
             print("chip_smoke: FAILED — " + "; ".join(self.failed),
                   file=sys.stderr)
             return 1
-        for phase in ("lda", "tables", "attend", "w2v_scatter"):
+        for phase in ("lda", "tables", "attend", "gdn_recur",
+                      "w2v_scatter"):
             self.child(phase)
         self.guarded("server", self.server)
         count = int(self.lines[0]["devices"])

@@ -724,7 +724,7 @@ class TransformerLM:
             raise NotImplementedError(
                 "TransformerLM runs one chip of its group: the exchange "
                 "of tokens over the model axis is not built")
-        # a CPU mesh (tests) runs the attention kernels interpreted
+        # a CPU mesh (tests) runs the Pallas kernels interpreted
         self._interpret = interpret_mode(self.mesh)
         if c.layer_types is None:
             self._shape = mla.LatentShape(
@@ -809,7 +809,7 @@ class TransformerLM:
         qkv, gate, log_decay, beta = gdn.project(x, t_in, decay[0],
                                                  decay[1], g)
         o = gdn.recur(gdn.short_conv(qkv, conv, doc), log_decay, beta, doc,
-                      g)
+                      g, interpret=self._interpret)
         return gdn.gate_out(o, gate, o_norm, t_out, g)
 
     def _attention(self, x, doc, pos, attn, q_norm, k_norm):

@@ -162,7 +162,8 @@ def test_all_phases_passing_prints_the_result_line_last(smoke, capsys):
     docs = _json_lines(capsys.readouterr().out)
     assert rc == 0 and docs[-1]["device"]["count"] == 4
     assert [d["phase"] for d in docs if "phase" in d] == [
-        "w2v", "lda", "tables", "attend", "w2v_scatter", "server",
+        "w2v", "lda", "tables", "attend", "gdn_recur", "w2v_scatter",
+        "server",
         "w2v@2x2", "lda@1x1", "lda@2x2", "tables@2x2", "fleet"]
 
 

@@ -103,13 +103,14 @@ class TestWatchdog:
         for the next stall (two dumps, not a dump storm)."""
         with wd.watchdog(0.15, name="t.rearm",
                          dump_dir=str(tmp_path / "dumps")) as w:
-            assert _wait_for(lambda: w.stalls == 1)
+            # the count moves before the dump is written: wait for both
+            assert _wait_for(lambda: w.stalls == 1 and w.last_dump_path)
             first = w.last_dump_path
             time.sleep(0.3)              # tripped: no second dump yet
             assert w.stalls == 1
             w.beat()                     # recover -> re-arm
-            assert _wait_for(lambda: w.stalls == 2)
-            assert w.last_dump_path != first
+            assert _wait_for(lambda: w.stalls == 2
+                             and w.last_dump_path != first)
         assert len(os.listdir(str(tmp_path / "dumps"))) == 2
 
     def test_kill_action_terminates_after_dump(self, tmp_path):

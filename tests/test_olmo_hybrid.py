@@ -9,6 +9,7 @@ vocabulary's share, the published parameter counts, and the spans,
 scopes and counters of a training call."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -185,6 +186,105 @@ def test_a_large_decay_neither_overflows_nor_leaks():
     grads = jax.grad(lambda *a: jnp.sum(gdn.recur(*a, doc, shape)),
                      (0, 1, 2))(qkv, g, beta)
     assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+
+
+def scan_over_chunks(w, u, attn, q_in, k_out, keep):
+    """The plain form ``gdn.across_chunks``'s kernels replaced: a
+    ``lax.scan`` over the chunks with the state as its carry, in the
+    kernels' order and precisions; its gradients are the scan's own."""
+    dtype = w.dtype
+    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+
+    def chunk(state, xs):
+        w, u, attn, q_in, k_out, keep = xs
+        held = state.astype(dtype)
+        newb = (u - dot("hck,hkv->hcv", w, held)).astype(dtype)
+        o = dot("hck,hkv->hcv", q_in, held) \
+            + dot("hij,hjv->hiv", attn, newb)
+        return keep * state + dot("hck,hcv->hkv", k_out, newb), o
+
+    start = jnp.zeros((w.shape[1], w.shape[3], u.shape[3]), jnp.float32)
+    return jax.lax.scan(chunk, start, (w, u, attn, q_in, k_out, keep))[1]
+
+
+TERMS = ("w", "u", "attn", "q_in", "k_out", "keep")
+
+
+def assert_the_kernels_equal_the_scan(terms, seed=5):
+    """Output and the cotangent of each of the six operands."""
+    got, got_vjp = jax.vjp(
+        lambda *a: gdn.across_chunks(*a, True), *terms)
+    want, want_vjp = jax.vjp(scan_over_chunks, *terms)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    weight = jnp.asarray(np.random.default_rng(seed).normal(
+        size=want.shape), jnp.float32)
+    for name, g, w in zip(TERMS, got_vjp(weight), want_vjp(weight)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+@pytest.mark.parametrize("docs", sorted(DOCS))
+def test_the_kernels_across_chunks_equal_the_scan_they_replaced(docs,
+                                                                chunk):
+    """The forward and the backward kernel (interpreted here) against
+    ``jax.vjp`` of the plain scan, on the six operands ``recur`` makes
+    of every document layout."""
+    shape = gdn.GatedDeltaShape(3, 8, 16, 4, True, 1e-6, chunk, "float32")
+    assert_the_kernels_equal_the_scan(gdn.chunk_terms(
+        *recurrence_operands(), jnp.asarray(DOCS[docs], jnp.int32), shape))
+
+
+def test_a_head_count_the_kernels_block_does_not_divide(monkeypatch):
+    """A grid step takes the most heads that fit its budget AND divide
+    the head count; with ten heads (two sequences of five) two a step,
+    one block holds the last head of one sequence beside the first of
+    the next."""
+    published = (64, 96, 192)       # chunk, d_k, d_v: 22 heads fit
+    assert gdn._head_block(30, *published) == 15
+    assert gdn._head_block(62, *published) == 2
+    assert gdn._head_block(7, *published) == 7
+    assert gdn._head_block(23, *published) == 1
+    H, dk, dv, C = 5, 8, 16, 16
+    monkeypatch.setattr(gdn, "_head_block", lambda *a: 2)
+    shape = gdn.GatedDeltaShape(H, dk, dv, 4, True, 1e-6, C, "float32")
+    assert_the_kernels_equal_the_scan(gdn.chunk_terms(
+        *recurrence_operands(H=H, dk=dk, dv=dv),
+        jnp.asarray(DOCS["many_documents"], jnp.int32), shape))
+
+
+def test_the_recurrence_at_the_published_widths_in_bfloat16():
+    """30 heads of 96 x 192, chunks of 64, bfloat16 operands: output and
+    the three gradients of ``recur`` against the plain scan over
+    float32 operands."""
+    H, dk, dv, S = 30, 96, 192, 256
+    shape = gdn.GatedDeltaShape(H, dk, dv, 4, True, 1e-6, 64, "bfloat16")
+    doc = jnp.asarray(np.tile(DOCS["a_boundary_inside_a_chunk"][1:], 4)
+                      + 3 * np.repeat(np.arange(4), 64), jnp.int32)
+    operands = recurrence_operands(B=1, S=S, H=H, dk=dk, dv=dv, seed=7)
+
+    def plain(*a):
+        return gdn.from_chunks(scan_over_chunks(*gdn.chunk_terms(
+            *a, doc, shape._replace(dtype="float32"))), 1)
+
+    ours = lambda *a: gdn.recur(*a, doc, shape)
+    weight = jnp.asarray(np.random.default_rng(8).normal(
+        size=(1, S, H, dv)), jnp.float32)
+
+    def output_and_gradients(f):
+        def loss(*a):
+            o = f(*a)
+            return jnp.sum(o * weight), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True))(*operands)
+        return (o,) + grads
+
+    for name, g, w in zip(("o", "qkv", "g", "beta"),
+                          output_and_gradients(ours),
+                          output_and_gradients(plain)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert gap(g, w) < 2e-2, name
 
 
 @pytest.mark.parametrize("docs", sorted(DOCS))

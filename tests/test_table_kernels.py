@@ -821,19 +821,26 @@ def _kernel_cases():
     add("grouped-attention-forward", lambda m: grouped_attention(False))
     add("grouped-attention-backward", lambda m: grouped_attention(True))
 
-    # the chunked gated delta rule (blocked XLA: the triangular solve,
-    # the scan over chunks and its transpose) at the published widths:
-    # 30 heads of 96 x 192, chunks of 64
+    # the chunked gated delta rule at the published widths — 30 heads of
+    # 96 x 192 (no multiple of 128 lanes), chunks of 64: the chunk-local
+    # terms and the triangular solve are blocked XLA, the state crosses
+    # the chunks in two Mosaic kernels (forward; backward under jax.grad)
     from multiverso_tpu.ops import gated_delta as gdn
     shape = gdn.GatedDeltaShape(30, 96, 192, 4, True, 1e-6, 64, "bfloat16")
 
-    def recurrence(qkv, g, beta, doc):
-        return jax.grad(lambda *a: jnp.sum(gdn.recur(*a, doc, shape)),
-                        argnums=(0, 1, 2))(qkv, g, beta)
-    add("gated-delta-recurrence-backward", lambda m: (
-        recurrence, [((1, S, shape.conv_width), f32, P()),
-                     ((1, S, 30), f32, P()), ((1, S, 30), f32, P()),
-                     ((1, S), i32, P())]))
+    def recurrence(grad):
+        def recur(qkv, g, beta, doc):
+            return gdn.recur(qkv, g, beta, doc, shape, interpret=False)
+
+        def grads(qkv, g, beta, doc):
+            return jax.grad(lambda *a: jnp.sum(recur(*a, doc)),
+                            argnums=(0, 1, 2))(qkv, g, beta)
+        return (grads if grad else recur,
+                [((1, S, shape.conv_width), f32, P()),
+                 ((1, S, 30), f32, P()), ((1, S, 30), f32, P()),
+                 ((1, S), i32, P())])
+    add("gated-delta-recurrence-forward", lambda m: recurrence(False))
+    add("gated-delta-recurrence-backward", lambda m: recurrence(True))
 
     # the distinct-row writer at the word2vec cell's shape: 4,096 pairs
     # x (1 + 5) rows a step into 3M x 300 held as [3,000,008, 384], the
