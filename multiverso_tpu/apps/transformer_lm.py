@@ -877,8 +877,7 @@ class TransformerLM:
                     jnp.zeros(())), None
         # the feed-forward's norm: a latent layer's third row, else the
         # second
-        h = mla.rms_norm(x, norms[2 if kind == LATENT else 1],
-                         c.rms_norm_eps)
+        h = _input_norm(x, norms[2 if kind == LATENT else 1], eps)
         hb = h.astype(c.compute_dtype)
         if c.is_dense(i):
             y = _over_sequences(_dense_mlp_group, c.mlp_chunks, hb,
@@ -893,7 +892,7 @@ class TransformerLM:
         plan = moe.plan(routing.top_e, real, first=c.first_expert,
                         held=c.n_routed_experts,
                         chunk_rows=c.expert_chunk_rows)
-        row_w = jnp.take(routing.top_s.reshape(-1), plan.row_src)
+        row_w = moe.row_weights(routing.top_s, plan.row_src)
         y, rows = moe.routed_experts(h.reshape(B * S, D), t["experts"],
                                      row_w, plan, c.expert_chunk_rows,
                                      jnp.dtype(c.compute_dtype))
@@ -943,7 +942,12 @@ class TransformerLM:
         real = (doc > 0).astype(jnp.float32)
 
         def layer(i, x, t, n):
-            return self._layer(i, x, t, n, doc, pos, real)
+            # what a block does between its phases (residual adds, casts,
+            # regrouping by sequences, the cotangents' sums where the
+            # residual forks) is named too; a phase's ops keep the
+            # phase's name, the innermost scope
+            return telemetry.scope("lm.residual")(partial(self._layer, i))(
+                x, t, n, doc, pos, real)
 
         x = _embed_gather(tables["embed"], tokens)
         entering = []

@@ -131,6 +131,13 @@ def plan(top_e, real, *, first: int, held: int, chunk_rows: int) -> Plan:
     return run(top_e, real)
 
 
+@telemetry.scope("lm.moe.permute")
+def row_weights(top_s, row_src):
+    """The weight of each sorted row: ``top_s`` [T, k] read in the plan's
+    order (``Plan.row_src``)."""
+    return jnp.take(top_s.reshape(-1), row_src)
+
+
 def _gmm(x, w, sizes, transpose_rhs=False):
     """Grouped product of the rows of ``x`` [m, a] with ``w`` [g, a, b]
     (or [g, b, a] contracted over its last axis), float32
@@ -185,8 +192,8 @@ def _experts_forward(x, wb, sizes):
 
 
 @telemetry.scope("lm.moe.experts")
-def _experts_backward(x, wb, sizes, g, u, a, d_out):
-    d_out = d_out.astype(x.dtype)
+def _experts_backward(x, wb, sizes, g, u, a, rw, d_rows):
+    d_out = (rw * d_rows).astype(x.dtype)
     d_a = _gmm(d_out, wb[:, 2], sizes)
     sig = jax.nn.sigmoid(g)
     d_g = (d_a * u * sig * (1.0 + g * (1.0 - sig))).astype(x.dtype)
@@ -195,6 +202,37 @@ def _experts_backward(x, wb, sizes, g, u, a, d_out):
         + _gmm(d_u, wb[:, 1], sizes, transpose_rhs=True)
     return d_x, (_tgmm(x, d_g, sizes), _tgmm(x, d_u, sizes),
                  _tgmm(d_out, a, sizes))
+
+
+@telemetry.scope("lm.moe.experts")
+def _held_rows(valid, rows, weight=None):
+    """``rows`` (times their ``weight``) where an expert held here owns
+    them, zero elsewhere: on the chip the select is fused with the
+    product that made the rows."""
+    return jnp.where(valid, rows if weight is None else weight * rows, 0.0)
+
+
+def _cast_held(w, dtype):
+    """The held experts' weights in the products' dtype, once a pass."""
+    @telemetry.scope("lm.moe.accumulate")
+    def run(w):
+        return w.astype(dtype)
+    return run(w)
+
+
+@telemetry.scope("lm.moe.accumulate")
+def _accumulate(d_w, d_rw, new_w, out, d_rows, r0):
+    """A block's gradients added to what the blocks before it left: the
+    three weight accumulators (read and written whole, every block) and
+    the block's rows of the row weights' gradient."""
+    return (tuple(acc + new for acc, new in zip(d_w, new_w)),
+            lax.dynamic_update_slice(d_rw, jnp.sum(out * d_rows, -1),
+                                     (r0,)))
+
+
+@telemetry.scope("lm.moe.accumulate")
+def _stack_gradients(d_w):
+    return jnp.stack(d_w, axis=1)
 
 
 def _chunks(plan_, chunk_rows):
@@ -219,19 +257,24 @@ def routed_experts(h, w, row_w, plan_, chunk_rows, dtype=jnp.bfloat16):
     float32 on a v5e, libtpu 0.0.34: a block of 8,192 float32 rows came
     out of ``ragged_dot`` wrong — 0.95 of the layer's value — where
     2,048 agree with the plain form to 2e-7, and bfloat16 blocks of
-    8,192 to rounding; PERF.md §7, PR 34.)"""
+    8,192 to rounding; PERF.md §7, PR 34.)
+
+    Every op of the two loops carries a program scope: gathers and
+    scatter-adds ``lm.moe.permute``, the products with the valid-row
+    selects around them ``lm.moe.experts``, the cast of the held weights
+    and the gradients' accumulators ``lm.moe.accumulate``."""
     return _routed_forward(h, w, row_w, plan_, chunk_rows, dtype)
 
 
 def _routed_forward(h, w, row_w, plan_, chunk_rows, dtype):
-    wb = w.astype(dtype)
+    wb = _cast_held(w, dtype)
 
     def body(c, carry):
         y, done = carry
         tok, rw, valid, sizes = _block(c, chunk_rows, plan_, row_w)
         x = _gather_rows(h, tok).astype(dtype)
         out = _experts_forward(x, wb, sizes)[3]
-        return (_scatter_rows(y, tok, jnp.where(valid, rw * out, 0.0)),
+        return (_scatter_rows(y, tok, _held_rows(valid, out, rw)),
                 done + jnp.sum(sizes))
 
     return lax.fori_loop(0, _chunks(plan_, chunk_rows), body,
@@ -247,27 +290,25 @@ def _routed_fwd(h, w, row_w, plan_, chunk_rows, dtype):
 def _routed_bwd(chunk_rows, dtype, saved, cotangent):
     h, w, row_w, plan_ = saved
     d_y, _ = cotangent
-    wb = w.astype(dtype)
+    wb = _cast_held(w, dtype)
 
     def body(c, carry):
         d_h, d_w, d_rw = carry
         tok, rw, valid, sizes = _block(c, chunk_rows, plan_, row_w)
         x = _gather_rows(h, tok).astype(dtype)
         g, u, a, out = _experts_forward(x, wb, sizes)
-        d_rows = jnp.where(valid, _gather_rows(d_y, tok), 0.0)
-        d_rw = lax.dynamic_update_slice(
-            d_rw, jnp.sum(jnp.where(valid, out, 0.0) * d_rows, -1),
-            (c * chunk_rows,))
-        d_x, d_wc = _experts_backward(x, wb, sizes, g, u, a, rw * d_rows)
-        return (_scatter_rows(d_h, tok, jnp.where(valid, d_x, 0.0)),
-                tuple(acc + new for acc, new in zip(d_w, d_wc)), d_rw)
+        d_rows = _held_rows(valid, _gather_rows(d_y, tok))
+        d_x, d_wc = _experts_backward(x, wb, sizes, g, u, a, rw, d_rows)
+        d_w, d_rw = _accumulate(d_w, d_rw, d_wc, _held_rows(valid, out),
+                                d_rows, c * chunk_rows)
+        return _scatter_rows(d_h, tok, _held_rows(valid, d_x)), d_w, d_rw
 
     one = jnp.zeros(w.shape[:1] + w.shape[2:], jnp.float32)
     d_h, d_w, d_rw = lax.fori_loop(
         0, _chunks(plan_, chunk_rows), body,
         (jnp.zeros(h.shape, jnp.float32), (one, one, one),
          jnp.zeros(row_w.shape, jnp.float32)))
-    return d_h, jnp.stack(d_w, axis=1), d_rw, None
+    return d_h, _stack_gradients(d_w), d_rw, None
 
 
 routed_experts.defvjp(_routed_fwd, _routed_bwd)

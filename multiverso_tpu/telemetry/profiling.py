@@ -11,7 +11,8 @@ looks identical from outside to one stuck in a collective.
   (so a watchdog dump's trace tail shows "in compile" vs "in step"):
 
   - ``profile.lower.seconds{fn=...}`` / ``profile.compile.seconds{...}``
-    histograms + last-value gauges,
+    histograms, and the last compile as the gauge
+    ``profile.compile.last_s{fn=...}``,
   - ``profile.compiles{fn=...}`` counter (signature-cache misses —
     retrace storms show up as a climbing counter),
   - ``profile.calls{fn=...}`` counter (every dispatch through the
@@ -26,7 +27,10 @@ looks identical from outside to one stuck in a collective.
     (:func:`multiverso_tpu.telemetry.trace.scope`) in their ``op_name``;
     :func:`op_scopes` returns the instruction-level map, which is what
     lets a device trace's ``jit_run/fusion.62`` be read as
-    ``w2v.scatter_out``.
+    ``w2v.scatter_out``. A fusion whose own ``op_name`` names no scope
+    takes its body's, and one the compiler made itself its operands'
+    (:func:`infer_op_scopes`); the map's ``inferred`` says which names
+    were found that way, and the gauge counts them with the rest.
 
   The compiled executable is cached per signature — avals AND input
   shardings, because an AOT executable accepts exactly the shardings
@@ -59,21 +63,29 @@ import os
 import re
 import sys
 import time
-from typing import Any, Callable, Dict, Iterator, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterator, List, NamedTuple, Optional,
+                    Tuple)
 
 from multiverso_tpu.telemetry import metrics as _metrics
 from multiverso_tpu.telemetry import trace as _trace
 
 
 UNSCOPED = "unscoped"
-# fn -> {"module": <HLO module name>, "scopes": {instruction: scope}};
-# process-wide like the registry, and outliving the wrappers: a
-# benchmark reads it after the program's tables are freed
+# fn -> {"module": <HLO module name>, "scopes": {instruction: scope},
+# "inferred": {instruction: scopes its body holds}}; process-wide like
+# the registry, and outliving the wrappers: a benchmark reads it after
+# the program's tables are freed
 _OP_SCOPES: Dict[str, dict] = {}
 _HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+# a computation opens with ``[ENTRY ]%name (parameters) -> shape {``
+_HLO_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{$")
 # one instruction per line: ``[ROOT ]%name = shape opcode(...), ...``
-_HLO_INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ([^\n]*)$", re.M)
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+# the opcode is the first word in front of a parenthesis (a shape's
+# ``T(8,128)`` follows a colon, a tuple shape opens the line)
+_HLO_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 # a program scope as :func:`trace.scope` writes it: the path segment
 # ``jit(<app>.<phase>)`` of the op_name (jax's own — ``jit(run)``,
@@ -82,32 +94,143 @@ _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 # the forward ops of a phase that ``jax.grad`` traced)
 _SCOPE_SEGMENT = re.compile(
     r"^(?:[a-z_]+\()*jit\(([a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+)\)+$")
+# a body's instructions that say nothing of the phase it belongs to
+_GLUE = frozenset(("parameter", "constant", "bitcast", "tuple",
+                   "get-tuple-element"))
+# ... and those that decide it, where they agree
+_PRODUCTS = frozenset(("convolution", "dot", "custom-call"))
+
+
+class _Instruction(NamedTuple):
+    name: str
+    opcode: str
+    scope: str          # its own op_name's, else UNSCOPED
+    named: bool         # it carries an op_name at all
+    calls: str          # a fusion's body, else ""
+    operands: Tuple[str, ...]       # a fusion's, else ()
+
+
+def _read_instruction(name: str, rest: str) -> _Instruction:
+    op_name = _HLO_OP_NAME.search(rest)
+    segments = op_name.group(1).split("/") if op_name else ()
+    found = filter(None, map(_SCOPE_SEGMENT.match, reversed(segments)))
+    scope = next((m.group(1) for m in found), UNSCOPED)
+    opcode = _HLO_OPCODE.search(" " + rest)
+    opcode = opcode.group(1) if opcode else ""
+    calls, operands = "", ()
+    if opcode == "fusion":
+        found = _HLO_CALLS.search(rest)
+        calls = found.group(1) if found else ""
+        head = rest.split(", kind=", 1)[0]
+        operands = tuple(_HLO_OPERAND.findall(
+            head[head.index("fusion(") + 6:]))
+    return _Instruction(name, opcode, scope, op_name is not None, calls,
+                        operands)
+
+
+def _read_computations(hlo_text: str) -> Dict[str, List[_Instruction]]:
+    """The module's instructions by computation, in the text's order."""
+    computations: Dict[str, List[_Instruction]] = {}
+    current = computations.setdefault("", [])
+    for line in hlo_text.splitlines():
+        found = _HLO_INSTRUCTION.match(line)
+        if found:
+            current.append(_read_instruction(*found.groups()))
+            continue
+        found = _HLO_COMPUTATION.match(line)
+        if found:
+            current = computations.setdefault(found.group(1), [])
+    return computations
+
+
+def infer_op_scopes(hlo_text: str
+                    ) -> Tuple[str, Dict[str, str], Dict[str, int]]:
+    """``(module name, {instruction: scope}, {instruction: n})`` of one
+    compiled module's text, by three rules in this order:
+
+    1. an instruction whose own ``op_name`` names a program scope keeps
+       it (the innermost one);
+    2. a ``fusion`` left unscoped takes its body's: the scope of the
+       body's products and kernels (``convolution``, ``dot``,
+       ``custom-call``) where those that carry one agree, else the scope
+       most of the body's scoped instructions carry (parameters,
+       constants, bitcasts, tuples and their elements not counted; a
+       fusion inside the body counts as what these rules make of it); a
+       tie stays :data:`UNSCOPED`;
+    3. a ``fusion`` the COMPILER made — no ``op_name`` of its own and a
+       body that names nothing: what ``ragged_dot`` is expanded into, a
+       masked convolution summed over the groups — takes the one scope
+       its operands carry, where those that carry one agree.
+
+    Everything else without a scope of its own is :data:`UNSCOPED`. The
+    third value holds every instruction that rule 2 or 3 named, with the
+    number of distinct scopes among its body's instructions: 0 = named
+    by its operands, 1 = the body agrees, 2 or more = the fusion crosses
+    a scope's edge and nobody splits its seconds."""
+    found = _HLO_MODULE.search(hlo_text)
+    module = found.group(1) if found else ""
+    computations = _read_computations(hlo_text)
+    bodies: Dict[str, Tuple[str, int]] = {}
+
+    def body_scope(name: str) -> Tuple[str, int]:
+        if name not in bodies:
+            bodies[name] = (UNSCOPED, 0)       # a cycle names nothing
+            votes: Dict[str, int] = {}
+            products = set()
+            for ins in computations.get(name, ()):
+                scope = ins.scope
+                if scope == UNSCOPED and ins.calls:
+                    scope = body_scope(ins.calls)[0]
+                if scope == UNSCOPED or ins.opcode in _GLUE:
+                    continue
+                votes[scope] = votes.get(scope, 0) + 1
+                if ins.opcode in _PRODUCTS:
+                    products.add(scope)
+            most = sorted(votes.values())[-2:]
+            if len(products) == 1:
+                bodies[name] = (products.pop(), len(votes))
+            elif votes and (len(most) == 1 or most[0] < most[1]):
+                bodies[name] = (max(votes, key=votes.get), len(votes))
+            else:
+                bodies[name] = (UNSCOPED, len(votes))
+        return bodies[name]
+
+    scopes: Dict[str, str] = {}
+    inferred: Dict[str, int] = {}
+    for instructions in computations.values():
+        for ins in instructions:
+            scope = ins.scope
+            if scope == UNSCOPED and ins.calls:
+                scope, held = body_scope(ins.calls)
+                if not held and not ins.named:
+                    given = {scopes.get(o, UNSCOPED)
+                             for o in ins.operands} - {UNSCOPED}
+                    if len(given) == 1:
+                        scope = given.pop()
+                if scope != UNSCOPED:
+                    inferred[ins.name] = held
+            scopes[ins.name] = scope
+    return module, scopes, inferred
 
 
 def parse_op_scopes(hlo_text: str) -> Tuple[str, Dict[str, str]]:
     """``(module name, {instruction name: scope})`` of one compiled
-    module's text: the innermost program scope in the instruction's
-    ``op_name``, :data:`UNSCOPED` where it has none. A fusion carries
-    the metadata XLA left on it, its root's."""
-    found = _HLO_MODULE.search(hlo_text)
-    module = found.group(1) if found else ""
-    scopes: Dict[str, str] = {}
-    for name, rest in _HLO_INSTRUCTION.findall(hlo_text):
-        op_name = _HLO_OP_NAME.search(rest)
-        segments = op_name.group(1).split("/") if op_name else ()
-        found = filter(None, map(_SCOPE_SEGMENT.match, reversed(segments)))
-        scopes[name] = next((m.group(1) for m in found), UNSCOPED)
-    return module, scopes
+    module's text: :func:`infer_op_scopes` without its account of which
+    names were inferred. A fusion's name is its own scope where its
+    ``op_name`` has one, else its body's, else — the compiler's own
+    fusions — its operands'; :data:`UNSCOPED` where none names it."""
+    return infer_op_scopes(hlo_text)[:2]
 
 
 def _record_scopes(fn: str, compiled: Any) -> None:
     """Keep ``fn``'s instruction -> scope map (one text dump a compile).
     Two programs of one ``fn`` (two signatures) merge; an instruction
-    name they scope differently is unscoped."""
+    name they scope differently is unscoped, and not inferred."""
     with _trace.span("profile.op_scopes"):
-        module, scopes = parse_op_scopes(compiled.as_text())
-    held = _OP_SCOPES.setdefault(fn, {"module": module, "scopes": {}})
-    merge_op_scopes(held["scopes"], scopes)
+        module, scopes, inferred = infer_op_scopes(compiled.as_text())
+    held = _OP_SCOPES.setdefault(
+        fn, {"module": module, "scopes": {}, "inferred": {}})
+    merge_op_scopes(held["scopes"], scopes, held["inferred"], inferred)
     if not _names_something(held["scopes"]):
         return
     counts: Dict[str, int] = {}
@@ -122,22 +245,37 @@ def _names_something(scopes: Dict[str, str]) -> bool:
     return any(scope != UNSCOPED for scope in scopes.values())
 
 
-def merge_op_scopes(into: Dict[str, str], more: Dict[str, str]) -> None:
+def merge_op_scopes(into: Dict[str, str], more: Dict[str, str],
+                    into_inferred: Optional[Dict[str, int]] = None,
+                    more_inferred: Optional[Dict[str, int]] = None
+                    ) -> None:
     """Fold ``more`` into ``into``; a name the two scope differently
     becomes :data:`UNSCOPED` (two programs may share a module name, and
-    with it their instructions' names)."""
+    with it their instructions' names) and leaves ``into_inferred``,
+    which otherwise keeps the larger count of the two."""
+    into_inferred = {} if into_inferred is None else into_inferred
     for name, scope in more.items():
         if into.setdefault(name, scope) != scope:
             into[name] = UNSCOPED
+            into_inferred.pop(name, None)
+    for name, n in (more_inferred or {}).items():
+        if into[name] != UNSCOPED:
+            into_inferred[name] = max(n, into_inferred.get(name, n))
 
 
 def op_scopes() -> Dict[str, dict]:
-    """``{fn: {"module": name, "scopes": {instruction: scope}}}`` for
-    every program :func:`profiled_jit` compiled in this process whose
-    compiled text names at least one program scope. ``module`` is the
-    name a device trace files the ops under (``jit_run``)."""
+    """``{fn: {"module": name, "scopes": {instruction: scope},
+    "inferred": {instruction: n}}}`` for every program
+    :func:`profiled_jit` compiled in this process whose compiled text
+    names at least one program scope. ``module`` is the name a device
+    trace files the ops under (``jit_run``); ``inferred`` holds the
+    instructions whose scope is not their own ``op_name``'s
+    (:func:`infer_op_scopes`: 0 = a compiler-made fusion named by its
+    operands, 1 = a fusion named by a body that agrees, 2 or more = by
+    a body that holds that many scopes)."""
     return {fn: {"module": held["module"],
-                 "scopes": dict(held["scopes"])}
+                 "scopes": dict(held["scopes"]),
+                 "inferred": dict(held["inferred"])}
             for fn, held in _OP_SCOPES.items()
             if _names_something(held["scopes"])}
 
@@ -191,7 +329,6 @@ class _ProfiledJit:
             .observe(lower_s)
         reg.histogram("profile.compile.seconds", fn=self.name) \
             .observe(compile_s)
-        reg.gauge("profile.lower.last_s", fn=self.name).set(lower_s)
         reg.gauge("profile.compile.last_s", fn=self.name).set(compile_s)
         self._record_cost(reg, compiled)
         _record_scopes(self.name, compiled)

@@ -609,6 +609,19 @@ def test_spans_scopes_and_counters_of_a_training_call(trained):
             "lm.head_loss", "lm.adam"} <= named
 
 
+def test_the_step_names_what_lies_between_its_phases(trained):
+    """The expert loops' accumulators and cast, the feed-forward's norm
+    and a block's glue carry scopes of their own (PR 36)."""
+    held = telemetry.op_scopes()["superstep.lm_superstep"]
+    named = set(held["scopes"].values())
+    assert {"lm.moe.accumulate", "lm.block_norm", "lm.residual"} <= named
+    # the map says which names are not an op's own: fusions named by
+    # their body (1 or more scopes in it) or by their operands (0)
+    assert set(held) == {"module", "scopes", "inferred"}
+    for name, n in held["inferred"].items():
+        assert held["scopes"][name] != "unscoped" and n >= 0
+
+
 def test_what_is_not_built_says_so(mesh):
     with pytest.raises(NotImplementedError, match="q_lora_rank"):
         tiny(q_lora_rank=8).check()

@@ -29,6 +29,7 @@ from multiverso_tpu.data.packing import pack_documents         # noqa: E402
 from multiverso_tpu.ops import latent_attention as mla         # noqa: E402
 from multiverso_tpu.ops import moe                             # noqa: E402
 from multiverso_tpu.ops import short_conv as sconv             # noqa: E402
+from multiverso_tpu.telemetry import profiling                 # noqa: E402
 from perf.reference import lfm2 as ref                         # noqa: E402
 
 # the catalog's config of LFM2-8B-A1B (model-configs guide, row 34)
@@ -395,6 +396,10 @@ def reference_steps(c, batches, steps=3):
 def trained(mesh):
     """A float32 trainer after three steps, beside the reference's three
     steps from the same start on the same packed batches."""
+    # another module's steps of the same ``fn`` (the first model's, with
+    # shared experts) would merge into this module's map of scopes when
+    # a worker ran them first: read this module's own compiles
+    profiling._OP_SCOPES.pop("superstep.lm_superstep", None)
     c = tiny()
     docs = documents(c)
     app = TransformerLM(c, docs, mesh=mesh)
@@ -784,6 +789,19 @@ def test_spans_scopes_and_counters_of_a_training_call(trained):
             "lm.moe.permute", "lm.moe.experts", "lm.head_loss",
             "lm.adam"} <= named
     assert "lm.moe.shared" not in named
+
+
+def test_the_step_names_what_lies_between_its_phases(trained):
+    """The expert loops' accumulators and cast, the feed-forward's norm
+    and a block's glue carry scopes of their own (PR 36)."""
+    held = telemetry.op_scopes()["superstep.lm_superstep"]
+    named = set(held["scopes"].values())
+    assert {"lm.moe.accumulate", "lm.block_norm", "lm.residual"} <= named
+    # the map says which names are not an op's own: fusions named by
+    # their body (1 or more scopes in it) or by their operands (0)
+    assert set(held) == {"module", "scopes", "inferred"}
+    for name, n in held["inferred"].items():
+        assert held["scopes"][name] != "unscoped" and n >= 0
 
 
 @pytest.mark.parametrize("change, error, says", [

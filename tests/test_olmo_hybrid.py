@@ -634,6 +634,19 @@ def test_spans_scopes_and_counters_of_a_training_call(trained):
             "lm.head_loss", "lm.adam"} <= named
 
 
+def test_the_step_names_what_lies_between_its_phases(trained):
+    """A block's glue carries a scope of its own beside the reordered
+    norms' (PR 36); this model has no expert layer."""
+    held = telemetry.op_scopes()["superstep.lm_superstep"]
+    named = set(held["scopes"].values())
+    assert {"lm.block_norm", "lm.residual"} <= named
+    # the map says which names are not an op's own: fusions named by
+    # their body (1 or more scopes in it) or by their operands (0)
+    assert set(held) == {"module", "scopes", "inferred"}
+    for name, n in held["inferred"].items():
+        assert held["scopes"][name] != "unscoped" and n >= 0
+
+
 def test_the_packer_s_pool_is_the_configuration_s(mesh):
     """``open_sequences`` reaches ``pack_documents``; left out, the pool
     is 4 x sequences as before."""

@@ -304,6 +304,148 @@ ENTRY %main.3 (a: f32[8]) -> f32[8] {
     assert into == {"x": "a.b", "y": "unscoped", "z": "c.d"}
 
 
+def _op(name, scope=None, opcode="add", operands="%p", tail=""):
+    """One line of compiled text: ``scope`` a program scope, ``""`` an
+    ``op_name`` that names none, ``None`` no metadata at all."""
+    meta = "" if scope is None else ', metadata={op_name="jit(run)/%s%s"}' \
+        % (f"jit({scope})/" if scope else "", opcode)
+    return f"  %{name} = f32[8]{{0}} {opcode}({operands}){tail}{meta}"
+
+
+def _module(bodies, entry):
+    """A module's text from its fused computations and its entry's
+    instructions (``fusion`` lines call the bodies by name)."""
+    text = ["HloModule jit_run, is_scheduled=true", ""]
+    for name, lines in bodies.items():
+        text += [f"%{name} (p: f32[8]) -> f32[8] {{",
+                 "  %p = f32[8]{0} parameter(0)", *lines, "}", ""]
+    text += ["ENTRY %main.9 (a: f32[8]) -> f32[8] {",
+             '  %a = f32[8]{0} parameter(0), metadata={op_name="params[0]"}',
+             *entry, "}", ""]
+    return "\n".join(text)
+
+
+def _fusion(name, body, scope=None, operands="%a"):
+    return _op(name, scope, "fusion", operands,
+               f", kind=kLoop, calls=%{body}")
+
+
+_DOT = dict(opcode="dot", operands="%p, %p")
+FUSION_CASES = {
+    # case: (bodies, entry, scopes expected of the entry's fusions, inferred)
+    "a body with one scope names its fusion":
+        ({"b": [_op("m.1", "lm.x.y"), _op("n.1", "lm.x.y"), _op("o.1")]},
+         [_fusion("fusion.1", "b", "")],
+         {"fusion.1": "lm.x.y"}, {"fusion.1": 1}),
+    "its own scope wins over the body's":
+        ({"b": [_op("m.1", "lm.x.y")]},
+         [_fusion("fusion.1", "b", "lm.own.z")],
+         {"fusion.1": "lm.own.z"}, {}),
+    "products decide over the count":
+        ({"b": [_op("m.1", "lm.moe.permute"), _op("m.2", "lm.moe.permute"),
+                _op("d.1", "lm.moe.experts", **_DOT),
+                _op("c.1", None, "custom-call")]},
+         [_fusion("fusion.1", "b")],
+         {"fusion.1": "lm.moe.experts"}, {"fusion.1": 2}),
+    "products that disagree leave it to the count":
+        ({"b": [_op("d.1", "lm.a.b", **_DOT), _op("d.2", "lm.c.d", **_DOT),
+                _op("m.1", "lm.c.d")]},
+         [_fusion("fusion.1", "b")],
+         {"fusion.1": "lm.c.d"}, {"fusion.1": 2}),
+    "the count decides where there is no product":
+        ({"b": [_op("m.1", "lm.a.b"), _op("m.2", "lm.c.d"),
+                _op("m.3", "lm.c.d"),
+                _op("g.1", "lm.a.b", "bitcast"),
+                _op("g.2", "lm.a.b", "get-tuple-element")]},
+         [_fusion("fusion.1", "b", "")],
+         {"fusion.1": "lm.c.d"}, {"fusion.1": 2}),
+    "a tie stays unscoped":
+        ({"b": [_op("m.1", "lm.a.b"), _op("m.2", "lm.c.d")]},
+         [_fusion("fusion.1", "b")],
+         {"fusion.1": "unscoped"}, {}),
+    "a body that names nothing stays unscoped":
+        ({"b": [_op("m.1", ""), _op("m.2")]},
+         [_op("x.1", "lm.a.b", operands="%a"),
+          _fusion("fusion.1", "b", "", "%x.1")],
+         {"fusion.1": "unscoped"}, {}),
+    "a fusion inside the body counts as what it was made":
+        ({"inner": [_op("m.1", "lm.a.b")],
+          "b": [_fusion("fusion.7", "inner", operands="%p"), _op("m.2", "")]},
+         [_fusion("fusion.1", "b")],
+         {"fusion.1": "lm.a.b", "fusion.7": "lm.a.b"},
+         {"fusion.1": 1, "fusion.7": 1}),
+    "the compiler's own fusion takes its operands' scope":
+        ({"b": [_op("conv.1", None, "convolution", "%p, %p"),
+                _op("sel.1", None, "select")]},
+         [_op("x.1", "lm.moe.experts", operands="%a"),
+          _op("dep.1", None, "add-dependency", "%a"),
+          _fusion("fusion.1", "b", None,
+                  "%x.1, %dep.1, /*index=2*/%a"),
+          _fusion("fusion.2", "b", None, "%fusion.1")],
+         {"fusion.1": "lm.moe.experts", "fusion.2": "lm.moe.experts"},
+         {"fusion.1": 0, "fusion.2": 0}),
+    "operands that disagree name nothing":
+        ({"b": [_op("conv.1", None, "convolution", "%p, %p")]},
+         [_op("x.1", "lm.a.b", operands="%a"),
+          _op("x.2", "lm.c.d", operands="%a"),
+          _fusion("fusion.1", "b", None, "%x.1, %x.2")],
+         {"fusion.1": "unscoped"}, {}),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSION_CASES))
+def test_a_fusion_without_a_scope_takes_its_bodys(case):
+    bodies, entry, want, want_inferred = FUSION_CASES[case]
+    text = _module(bodies, entry)
+    module, scopes, inferred = profiling.infer_op_scopes(text)
+    assert module == "jit_run"
+    assert {name: scopes[name] for name in want} == want
+    assert inferred == want_inferred
+    assert (module, scopes) == profiling.parse_op_scopes(text)
+    # glue and what nothing names read as they did
+    assert scopes["p"] == scopes["a"] == "unscoped"
+
+
+def test_two_programs_that_disagree_are_unscoped_and_not_inferred():
+    body = {"b": [_op("m.1", "lm.a.b")]}
+    _, one, one_inferred = profiling.infer_op_scopes(_module(
+        body, [_fusion("fusion.1", "b"), _fusion("fusion.2", "b")]))
+    _, two, two_inferred = profiling.infer_op_scopes(_module(
+        {"b": [_op("m.1", "lm.c.d")], "c": [_op("m.3", "lm.a.b"),
+                                            _op("m.4", "lm.e.f", **_DOT)]},
+        [_fusion("fusion.1", "b"), _fusion("fusion.2", "c")]))
+    assert one_inferred == {"fusion.1": 1, "fusion.2": 1}
+    assert two_inferred == {"fusion.1": 1, "fusion.2": 2}
+    profiling.merge_op_scopes(one, two, one_inferred, two_inferred)
+    assert one["fusion.1"] == "unscoped" and one["m.1"] == "unscoped"
+    assert one["fusion.2"] == "unscoped"
+    assert one_inferred == {}
+    # programs that agree keep the name, and the larger count
+    _, three, three_inferred = profiling.infer_op_scopes(_module(
+        body, [_fusion("fusion.1", "b")]))
+    _, four, four_inferred = profiling.infer_op_scopes(_module(
+        {"b": [_op("m.1", "lm.a.b"), _op("m.2", "lm.a.b"),
+               _op("m.5", "lm.c.d")]}, [_fusion("fusion.1", "b")]))
+    profiling.merge_op_scopes(three, four, three_inferred, four_inferred)
+    assert three["fusion.1"] == "lm.a.b"
+    assert three_inferred == {"fusion.1": 2}
+
+
+def test_op_scopes_say_which_names_were_inferred():
+    @telemetry.scope("t.inner.math")
+    def inner(x):
+        return jax.numpy.tanh(x) * 2.0
+
+    f = profiling.profiled_jit(lambda x: inner(x).sum(), name="t.inferred")
+    f(np.ones(8, np.float32))
+    held = profiling.op_scopes()["t.inferred"]
+    assert set(held) == {"module", "scopes", "inferred"}
+    assert "t.inner.math" in held["scopes"].values()
+    for name, n in held["inferred"].items():
+        assert held["scopes"][name] != "unscoped" and n >= 0
+    profiling._OP_SCOPES.pop("t.inferred", None)
+
+
 # -- (e) the names the benchmark's accepted readers match ----------------------
 
 def test_module_and_kernel_names_the_readers_lean_on(mesh1):
