@@ -333,7 +333,7 @@ class LightLDA:
             self._init_counts()
             self._build_superstep()
         self._key = core.prng_key(c.seed, mesh=self.mesh)
-        self._calls_done = 0
+        self._count_calls_from(0)
         self.ll_history: list = []
         self._last_store = ()
 
@@ -915,18 +915,20 @@ class LightLDA:
         scan_body = self._db_scan_body
 
         def body(params, states, locals_, options, wstale, ws, drels,
-                 msks, steps, key):
+                 msks, steps, base_key):
             (nk,) = params
-            ndk, z = locals_
-            keys = jax.random.split(key, ws.shape[0])
+            ndk, z, calls = locals_
+            keys = jax.random.split(jax.random.fold_in(base_key, calls),
+                                    ws.shape[0])
             (nk, ndk, z), _ = lax.scan(
                 lambda cy, inp: scan_body(wstale, cy, inp),
                 (nk, ndk, z), (ws, drels, msks, steps, keys))
-            return (nk,), states, (ndk, z), None
+            return (nk,), states, (ndk, z, calls + 1), None
 
         self._fused = make_superstep(
             (self.summary,), body, name="lda_docblock",
-            local_shardings=(self._ndk_sharding, self._z_sharding))
+            local_shardings=(self._ndk_sharding, self._z_sharding,
+                             NamedSharding(self.mesh, P())))
 
         self._build_blocked_loglik()
 
@@ -1020,9 +1022,10 @@ class LightLDA:
             nk = nk.at[:K].add(nkd.reshape(-1))
             return (nk, z), ()
 
-        def body(params, states, locals_, options, wstale, stacked, key):
+        def body(params, states, locals_, options, wstale, stacked,
+                 base_key):
             (nk,) = params
-            (acc,) = locals_   # fresh word-count accumulator: over one
+            acc, calls = locals_   # fresh word-count accumulator: over one
             # sweep the per-call +/- master deltas TELESCOPE to
             # counts(z_end) (the subtracted counts(z_start) equal the
             # old master exactly), so one add-only scatter pass per call
@@ -1031,7 +1034,7 @@ class LightLDA:
             tw, drel, z_in, msk, _rows = unpack(stacked)
             z = z_in.reshape(S * nbs, TB)
             offs = jnp.arange(S, dtype=jnp.int32) * nbs
-            keys = jax.random.split(key, S)
+            keys = jax.random.split(jax.random.fold_in(base_key, calls), S)
             (nk, z), _ = lax.scan(
                 lambda cy, inp: scan_body(wstale, cy, inp),
                 (nk, z), (tw, drel, msk, offs, keys))
@@ -1045,11 +1048,12 @@ class LightLDA:
             # back lanes it does not own
             z_out = lax.with_sharding_constraint(
                 z_out, NamedSharding(self.mesh, P(None, core.DATA_AXIS)))
-            return (nk,), states, (acc,), z_out
+            return (nk,), states, (acc, calls + 1), z_out
 
         self._fused_stream = make_superstep(
             (self.summary,), body,
-            local_shardings=(self.word_topic.sharding,),
+            local_shardings=(self.word_topic.sharding,
+                             NamedSharding(self.mesh, P())),
             name="lda_docblock_stream")
 
         # streamed eval: stage (tw, drel, z), rebuild the call's doc
@@ -1271,11 +1275,10 @@ class LightLDA:
                 self._z_host[bidx.reshape(-1)] = data.reshape(-1, TB)
 
         for k, dev in self._stream_calls():
-            key = jax.random.fold_in(self._key, self._calls_done)
-            self._calls_done += 1
             with telemetry.span("lda.dispatch"):
-                (acc,), z_out = self._fused_stream((acc,), wstale, dev,
-                                                   key)
+                (acc, self._calls_dev), z_out = self._fused_stream(
+                    (acc, self._calls_dev), wstale, dev, self._key)
+            self._calls_done += 1
             try:
                 z_out.copy_to_host_async()
             except AttributeError:
@@ -1358,16 +1361,18 @@ class LightLDA:
             z = z.at[idx].set(znew)
             return (nwk, ndk, nk, z), ()
 
-        def body(params, states, locals_, options, ws, ds, idxs, msks, key):
+        def body(params, states, locals_, options, ws, ds, idxs, msks,
+                 base_key):
             nwk, nk = params
-            ndk, z = locals_
-            keys = jax.random.split(key, ws.shape[0])
+            ndk, z, calls = locals_
+            keys = jax.random.split(jax.random.fold_in(base_key, calls),
+                                    ws.shape[0])
             (nwk, ndk, nk, z), _ = lax.scan(
                 scan_body, (nwk, ndk, nk, z), (ws, ds, idxs, msks, keys))
-            return (nwk, nk), states, (ndk, z), None
+            return (nwk, nk), states, (ndk, z, calls + 1), None
 
         # supported fused path: tables = (word_topic, summary); app-local
-        # carry = (doc-topic counts, z assignments)
+        # carry = (doc-topic counts, z assignments, the call counter)
         self._fused = make_superstep((self.word_topic, self.summary), body,
                                      name="lda_gibbs")
 
@@ -1387,6 +1392,14 @@ class LightLDA:
 
     def _place(self, arr: np.ndarray, spec) -> jax.Array:
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
+
+    def _count_calls_from(self, n: int) -> None:
+        """Set the host's count of superstep calls (what a checkpoint
+        records) and seed the supersteps' device copy from it: call i
+        folds ``i`` into the base key inside its program and hands on
+        i + 1, so no key is made on the host per call."""
+        self._calls_done = int(n)
+        self._calls_dev = self._place(np.asarray(n, np.int32), P())
 
     # -- training ----------------------------------------------------------
 
@@ -1414,11 +1427,11 @@ class LightLDA:
         # the doc-blocked body takes the sweep's mirror before its lanes
         mirror = (self._refresh_mirror(),) if self._docblock else ()
         for call in self._calls:
-            key = jax.random.fold_in(self._key, self._calls_done)
-            self._calls_done += 1
             with telemetry.span("lda.dispatch"):
-                (self._ndk, self._z), _ = self._fused(
-                    (self._ndk, self._z), *mirror, *call, key)
+                (self._ndk, self._z, self._calls_dev), _ = self._fused(
+                    (self._ndk, self._z, self._calls_dev), *mirror, *call,
+                    self._key)
+            self._calls_done += 1
         if self._docblock:
             # fold the sweep's moves into the int32 master (the
             # reference's block-end Add of accumulated deltas)
@@ -1447,7 +1460,8 @@ class LightLDA:
             # divergence rollback (MVTPU_HEALTH_ACTION=rollback):
             # restore_run_state moved the sweep cursor back to the last
             # clean generation — replay from there (sweep keys derive
-            # from _calls_done, which the restore also rewound)
+            # from the call counter, which the restore re-seeds from the
+            # rewound _calls_done)
             if telemetry.health.maybe_rollback(self) is not None:
                 it = min(self._resume_sweeps, iters)
                 self._resume_sweeps = 0
@@ -1798,7 +1812,7 @@ class LightLDA:
             self._z_host = np.asarray(data["z"]).reshape(z_shape) \
                 .astype(np.int32)
             self._z_synced = True    # checkpoint z is globally complete
-            self._calls_done = int(manifest.get("calls_done", 0))
+            self._count_calls_from(manifest.get("calls_done", 0))
             return
         # restore INTO the live arrays' own shardings (what the fused
         # superstep's donation aliasing was compiled against). The stored
@@ -1826,7 +1840,7 @@ class LightLDA:
                 ndk_sharding)
         # resume the RNG sequence where the checkpoint left off; replaying
         # consumed fold_in keys would correlate sweeps across the resume
-        self._calls_done = int(manifest.get("calls_done", 0))
+        self._count_calls_from(manifest.get("calls_done", 0))
 
     # -- fault tolerance (ft.checkpoint contract) --------------------------
 

@@ -150,8 +150,14 @@ def test_docblock_checkpoint_roundtrip(mesh_dp8, docs, tmp_path):
     app2.load(prefix)
     np.testing.assert_array_equal(app2.word_topics(), app.word_topics())
     np.testing.assert_array_equal(app2.doc_topics(), app.doc_topics())
+    # resumed in mid-run: the device call counter is seeded from the
+    # stored calls_done, so the next sweep draws what the run that never
+    # stopped draws
+    assert int(app2._calls_dev) == app2._calls_done == app._calls_done
+    app.train(num_iterations=1)
     app2.train(num_iterations=1)
     assert app2.word_topics().sum() == app2.num_tokens
+    np.testing.assert_array_equal(app2.assignments(), app.assignments())
     # layout mismatch rejected: a gibbs app's z is indexed in its own
     # shuffled stream and can't take this one
     app3 = LightLDA(tw, td, V,
@@ -570,6 +576,10 @@ def test_docblock_streamed_checkpoint_crossmode(mesh_dp8, docs, tmp_path):
     prefix = str(tmp_path / "dbs_ckpt")
     app.store(prefix)
     z_after = app._z_host.copy()
+    # the run that never stops: its next sweep is what either resume
+    # must draw (the device call counter re-seeded from calls_done)
+    app.train(num_iterations=1)
+    z_next = app._z_host.copy()
     table_base.reset_tables()
 
     mem = LightLDA(tw, td, V, LDAConfig(**kw), mesh=mesh_dp8,
@@ -579,6 +589,8 @@ def test_docblock_streamed_checkpoint_crossmode(mesh_dp8, docs, tmp_path):
         np.asarray(mem._z).reshape(z_after.shape), z_after)
     mem.train(num_iterations=1)
     ref_w = mem.word_topics()
+    np.testing.assert_array_equal(
+        np.asarray(mem._z).reshape(z_next.shape), z_next)
     table_base.reset_tables()
 
     # and back into a streamed app: one more sweep must match in-memory
@@ -587,6 +599,75 @@ def test_docblock_streamed_checkpoint_crossmode(mesh_dp8, docs, tmp_path):
     st.load(prefix)
     st.train(num_iterations=1)
     np.testing.assert_array_equal(st.word_topics(), ref_w)
+    np.testing.assert_array_equal(st._z_host, z_next)
+    assert int(st._calls_dev) == st._calls_done
+
+
+# -- the call's key, folded in on the device --------------------------------
+
+@pytest.mark.parametrize("streamed", [False, True])
+def test_device_call_counter_draws_the_host_folded_keys(
+        mesh_dp8, docs, monkeypatch, streamed):
+    """Call i's key is ``fold_in(key, i)`` folded INSIDE the superstep
+    from the int32 counter it carries: two sweeps give z, the doc
+    counts, the word table and the summary bit for bit as the same run
+    whose keys are folded on the host from a Python int (the parent's
+    way, here by handing the program the folded key and making its own
+    fold the identity)."""
+    import jax
+    tw, td, V = docs
+    cfg = LDAConfig(**_TILED, stream_blocks=streamed)
+    app = LightLDA(tw, td, V, cfg, mesh=mesh_dp8, name="lda_dev_keys")
+    app.train(num_iterations=2)
+    want = _docblock_state(app)
+    table_base.reset_tables()
+
+    fold_in = jax.random.fold_in
+    monkeypatch.setattr(jax.random, "fold_in", lambda key, data: key)
+    ref = LightLDA(tw, td, V, cfg, mesh=mesh_dp8, name="lda_host_keys")
+    attr = "_fused_stream" if streamed else "_fused"
+    fused = getattr(ref, attr)
+
+    def host_folded(locals_, *inputs):
+        key = fold_in(inputs[-1], ref._calls_done)
+        return fused(locals_, *inputs[:-1], key)
+
+    setattr(ref, attr, host_folded)
+    ref.train(num_iterations=2)
+    for got, exp in zip(_docblock_state(ref), want):
+        np.testing.assert_array_equal(got, exp)
+
+
+@pytest.mark.parametrize("sampler", ["tiled", "tiled_streamed", "gibbs"])
+def test_a_sweep_folds_no_key_on_the_host(mesh_dp8, docs, monkeypatch,
+                                          sampler):
+    """No ``fold_in`` runs outside a trace during ``sweep()`` (each such
+    call was two programs launched per superstep call), and the device
+    counter the supersteps carry equals the host's ``_calls_done``
+    after every sweep."""
+    import jax
+    tw, td, V = docs
+    if sampler == "gibbs":
+        cfg = LDAConfig(num_topics=8, batch_tokens=512, steps_per_call=4,
+                        seed=1)
+    else:
+        cfg = LDAConfig(**_TILED,
+                        stream_blocks=sampler == "tiled_streamed")
+    app = LightLDA(tw, td, V, cfg, mesh=mesh_dp8, name="lda_no_host_key")
+    fold_in = jax.random.fold_in
+    on_host = []
+
+    def counted(key, data):
+        if not isinstance(data, jax.core.Tracer):
+            on_host.append(data)
+        return fold_in(key, data)
+
+    monkeypatch.setattr(jax.random, "fold_in", counted)
+    for k in range(1, 4):
+        app.sweep()
+        assert app._calls_done == k * app.calls_per_sweep
+        assert int(app._calls_dev) == app._calls_done
+    assert on_host == []
 
 
 def test_stream_blocks_requires_docblock(mesh_dp8):
@@ -776,20 +857,22 @@ def superstep_v5e_2x2(mesh_v5e_2x2):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    def run(nk, ndk, z, wstale, ws, drels, msks, ts, key):
-        keys = jax.random.split(key, ws.shape[0])
+    def run(nk, ndk, z, calls, wstale, ws, drels, msks, ts, base_key):
+        keys = jax.random.split(jax.random.fold_in(base_key, calls),
+                                ws.shape[0])
         (nk, ndk, z), _ = lax.scan(
             lambda cy, inp: app._db_scan_body(wstale, cy, inp),
             (nk, ndk, z), (ws, drels, msks, ts, keys))
-        return nk, ndk, z
+        return nk, ndk, z, calls + 1
 
     lanes = sds((1, B), jnp.int32, P(None, axes))
     state = (sds((K,), jnp.int32),
              sds((steps, nbs, MAXD, tiles, 128), jnp.int16,
                  P(None, axes, None, None, None)),
-             sds((steps, nbs, TB), jnp.int32, P(None, axes, None)))
+             sds((steps, nbs, TB), jnp.int32, P(None, axes, None)),
+             sds((), jnp.int32))
     text = jax.jit(
-        run, donate_argnums=(0, 1, 2),
+        run, donate_argnums=(0, 1, 2, 3),
         out_shardings=tuple(x.sharding for x in state)).trace(
         *state, sds((vpad, tiles, 128), jnp.bfloat16),
         lanes, lanes, lanes, sds((1,), jnp.int32),

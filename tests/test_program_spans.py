@@ -485,7 +485,8 @@ def _superstep_args(lda):
     call (``lower`` donates nothing)."""
     wstale = lda._to_stale(lda.word_topic.raw())
     return ((lda.summary.param,), (lda.summary.state,),
-            (lda._ndk, lda._z), (lda.summary._resolve_option(None),),
+            (lda._ndk, lda._z, lda._calls_dev),
+            (lda.summary._resolve_option(None),),
             wstale, *lda._calls[0], lda._key)
 
 
