@@ -46,9 +46,11 @@ Two samplers, chosen by ``LDAConfig.sampler``, both on dp x mp meshes:
   and its blocks split over the data axis alone.
 
 The int32 word table stays row-sharded over the model axis — the
-reference's Meta vocab-slicing role — and the per-sweep master rebuild
-scatters each chip's data shard into its vocab slice, psum'd over the
-data axis, so no chip ever materialises the full int32 [V, K]. The bf16
+reference's Meta vocab-slicing role. The resident per-sweep master
+rebuild scatters each chip's own blocks into a whole-vocabulary table
+for the length of one program, reduce-scatters it over the model axis
+and psums the slice over the data axis; between sweeps no chip holds
+the full int32 [V, K]. The bf16
 mirror is the worker's per-sweep CACHE of that table: the cast
 all-gathers it over the model axis once a sweep, every chip holds it
 whole (2*V*K bytes), and the sweep's word-row gather is a plain local
@@ -689,9 +691,11 @@ class LightLDA:
     def _build_vocab_slice_scatter(self):
         """shard_map'd count scatter for a model-sharded word table:
         each chip scatters its DATA shard's in-range tokens into its
-        vocab slice, psum over the data axis. Shared by the per-sweep
-        rebuild and the streamed master accumulator (one copy of the
-        slice math). Returns f(z_flat, tw, msk) -> [V/mp, C, 128]."""
+        vocab slice, psum over the data axis. The streamed master
+        accumulator's, whose lanes lie over the data axis alone (the
+        resident rebuild scatters each chip's own blocks:
+        :meth:`_build_blocked_rebuild`). Returns f(z_flat, tw, msk) ->
+        [V/mp, C, 128]."""
         from jax import shard_map
         d, maxis = core.DATA_AXIS, core.MODEL_AXIS
         mp = self.mesh.shape[maxis]
@@ -714,47 +718,88 @@ class LightLDA:
                          check_vma=False)
 
     def _build_stale_helpers(self) -> None:
-        """Per-sweep word-count helpers of the doc-blocked sampler,
-        resident and streamed: the bf16 gather mirror and the int32
-        master rebuild from the blocked z (flattened).
+        """Per-sweep word-count helpers of the doc-blocked sampler: the
+        bf16 gather mirror (resident and streamed) and the resident
+        sweep's int32 master rebuild from the blocked z (the streamed
+        sweep folds its moves in call by call instead:
+        :meth:`_build_master_accumulate`).
 
-        The int32 master stays sharded over the model axis — the one job
-        that axis has: the rebuild scatters each chip's DATA shard of
-        the stream into its own vocab slice, psum'd over the data axis —
-        no chip ever holds the int32 [V, K]. (The resident z lies split
-        over data x model by block; the rebuild's ``in_specs`` ask for
-        the data shard, so the compiler moves z once a sweep.) The
-        mirror is the worker's per-sweep cache of it (the stale-words
-        model): ``to_stale`` casts the chip's slice and
-        all-gathers it over the model axis, ONCE a sweep, so every chip
-        holds the whole bf16 [V, K] and the gather inside the superstep
+        The int32 master stays sharded over the model axis — no chip
+        ever holds it whole between sweeps (the rebuild's own local
+        table aside: :meth:`_build_blocked_rebuild`). The mirror is the
+        worker's per-sweep cache of it (the stale-words model):
+        ``to_stale`` casts the chip's slice and all-gathers it over the
+        model axis, ONCE a sweep, so every chip holds the whole bf16
+        [V, K] and the gather inside the superstep
         (:func:`_take_word_rows`) is a plain local read — no ownership
-        mask, no collective. A
-        chip's word-table bytes are 4*V*K/mp + 2*V*K; a model whose
-        whole mirror cannot fit is refused here (it wants an owned-row
-        exchange, PERF.md §7, not this path)."""
-        mp = self.mesh.shape[core.MODEL_AXIS]
+        mask, no collective. A chip's word-table bytes are 4*V*K/mp +
+        2*V*K; a model whose whole mirror cannot fit is refused here (it
+        wants an owned-row exchange, PERF.md §7, not this path)."""
         self._account_mirror()
 
         @partial(jax.jit, out_shardings=NamedSharding(self.mesh, P()))
         def to_stale(nwk3):
             return nwk3.astype(jnp.bfloat16)
 
-        if mp == 1:
+        self._to_stale = to_stale
+        if not self.config.stream_blocks:
+            self._rebuild = self._build_blocked_rebuild()
+
+    def _build_blocked_rebuild(self):
+        """``rebuild(z, tw, m)``: the int32 master from the resident z
+        ``[steps, blocks a step, TB]`` and the flat packed stream, which
+        is whole on every chip. Sets ``lda.rebuild.lanes_per_chip``, the
+        lanes one chip's scatter takes.
+
+        On a mesh of several chips, data-only meshes too, the rebuild
+        works in z's own layout: each chip scatters the lanes of the
+        blocks it samples, a (dp * mp)-th of the stream, into a zeroed
+        WHOLE-vocabulary table; a reduce-scatter over the model axis
+        leaves it the vocab slice ``P(model)`` puts there (XLA:TPU
+        compiles an int32 one as an all-reduce and a local slice), and a
+        psum over the data axis sums the rest. The stream's blocked view
+        is a local slice of the whole flat one, so nothing crosses chips
+        but the two reductions; a scatter of the flat stream left to the
+        partitioner all-gathers z and gives every chip every lane. The
+        counts are integer sums: the master is bit for bit what any mesh
+        gives."""
+        shape = self.word_topic.storage_shape
+        telemetry.gauge("lda.rebuild.lanes_per_chip").set(
+            self._nb_pad * self._tb // self.mesh.devices.size)
+        if self.mesh.devices.size == 1:
             @jax.jit
             def rebuild(z, tw, m):
                 zf = _flat_z(z)
-                nwk3 = jnp.zeros(self.word_topic.storage_shape, jnp.int32)
+                nwk3 = jnp.zeros(shape, jnp.int32)
                 return nwk3.at[tw, zf // 128, zf % 128].add(m)
-        else:
-            sharded = self._build_vocab_slice_scatter()
 
-            @jax.jit
-            def rebuild(z, tw, m):
-                return sharded(_flat_z(z), tw, m)
+            return rebuild
 
-        self._to_stale = to_stale
-        self._rebuild = rebuild
+        from jax import shard_map
+        d, maxis = core.DATA_AXIS, core.MODEL_AXIS
+        zs = self._z_sharding
+
+        def local(zb, twb, mb):
+            zf = _flat_z(zb)
+            nwk3 = jnp.zeros(shape, jnp.int32)
+            nwk3 = nwk3.at[twb.reshape(-1), zf // 128, zf % 128].add(
+                mb.reshape(-1))
+            nwk3 = lax.psum_scatter(nwk3, maxis, scatter_dimension=0,
+                                    tiled=True)
+            return lax.psum(nwk3, d)
+
+        sharded = shard_map(local, mesh=self.mesh,
+                            in_specs=(zs.spec,) * 3,
+                            out_specs=P(maxis, None, None),
+                            check_vma=False)
+
+        @jax.jit
+        def rebuild(z, tw, m):
+            return sharded(z, *(
+                lax.with_sharding_constraint(a.reshape(z.shape), zs)
+                for a in (tw, m)))
+
+        return rebuild
 
     def _account_mirror(self) -> None:
         """Set ``lda.mirror.bytes_per_chip`` and refuse a mirror that
@@ -940,9 +985,15 @@ class LightLDA:
         scatters IN PLACE (no full-table temporary per call — measured
         ~0.2s/sweep of HBM traffic at V=50k, K=1024). Under model
         parallelism each chip scatters its data shard's in-range tokens
-        into a vocab-slice delta, psum'd over the data axis (the
-        per-sweep-rebuild pattern)."""
+        into a vocab-slice delta, psum'd over the data axis
+        (:meth:`_build_vocab_slice_scatter`). Sets
+        ``lda.rebuild.lanes_per_chip`` to a sweep's lanes one chip
+        scatters: its data shard's, or every lane on ``mp == 1``, where
+        the partitioner gathers the call's."""
         mp = self.mesh.shape[core.MODEL_AXIS]
+        lanes = self._nb_pad * self._tb
+        telemetry.gauge("lda.rebuild.lanes_per_chip").set(
+            lanes if mp == 1 else lanes // self.mesh.shape[core.DATA_AXIS])
         if mp == 1:
             def accumulate(acc, z, tw, msk):
                 return acc.at[tw, z // 128, z % 128].add(msk)

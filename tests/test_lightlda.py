@@ -349,8 +349,11 @@ def test_docblock_model_parallel_matches_dp(devices, docs,
     mesh = _sub_mesh(devices, dp, mp)
     app = LightLDA(tw, td, V, LDAConfig(**_TILED), mesh=mesh,
                    name=f"lda_mesh_{dp}x{mp}")
-    # the counter of the mechanism: every chip samples its own blocks
+    # the counter of the mechanism: every chip samples its own blocks,
+    # and the rebuild scatters that chip's own lanes
     assert telemetry.gauge("lda.sample.chips").value == dp * mp
+    assert telemetry.gauge("lda.rebuild.lanes_per_chip").value * dp * mp \
+        == app._tw_flat.shape[0]
     (shard,) = {s.data.shape for s in app._ndk.addressable_shards}
     assert shard[1] * dp * mp == app._ndk.shape[1] == 2048 // 256
     app.train(num_iterations=2)
@@ -360,6 +363,39 @@ def test_docblock_model_parallel_matches_dp(devices, docs,
     np.testing.assert_array_equal(d, ref_d)
     np.testing.assert_array_equal(nk, ref_nk)
     np.testing.assert_allclose(app.ll_history[-1], ref_ll, rtol=1e-5)
+    table_base.reset_tables()
+    core.shutdown()
+
+
+@pytest.mark.parametrize("dp,mp", [(2, 2), (1, 2), (4, 2), (2, 4)])
+def test_resident_rebuild_counts_exactly(devices, docs, dp, mp):
+    """The resident rebuild alone, where each chip scatters the lanes of
+    its own blocks into a whole-vocabulary table and the model axis
+    reduce-scatters it: the master equals the host's ``np.add.at`` of
+    (word, z // 128, z % 128) weighted by the mask, bit for bit — with a
+    token of the last real word, two tiles of topics, and z drawn over
+    every lane, the padded ones (mask 0, the scratch word) too."""
+    import jax
+    tw, td, V = docs
+    tw = tw.copy()
+    tw[-1] = V - 1
+    mesh = _sub_mesh(devices, dp, mp)
+    app = LightLDA(tw, td, V, LDAConfig(**dict(_TILED, num_topics=256)),
+                   mesh=mesh, name=f"lda_rebuild_{dp}x{mp}")
+    w, m = np.asarray(app._tw_flat), np.asarray(app._mask_flat)
+    assert telemetry.gauge("lda.rebuild.lanes_per_chip").value \
+        == w.size // (dp * mp)
+    assert (m == 0).any() and (w[m == 1] == V - 1).any()
+    z = np.random.default_rng(10 * dp + mp).integers(
+        0, 256, w.size).astype(np.int32)
+    want = np.zeros(app.word_topic.storage_shape, np.int32)
+    np.add.at(want, (w, z // 128, z % 128), m)
+    got = app._rebuild(jax.device_put(z.reshape(app._z.shape),
+                                      app._z_sharding),
+                       app._tw_flat, app._mask_flat)
+    # sliced as the table holds it: put_raw takes it as it is
+    assert got.sharding.is_equivalent_to(app.word_topic.sharding, 3)
+    np.testing.assert_array_equal(np.asarray(got), want)
     table_base.reset_tables()
     core.shutdown()
 
@@ -845,11 +881,13 @@ def superstep_v5e_2x2(mesh_v5e_2x2):
                          doc_blocked=True),
         word_topic=types.SimpleNamespace(storage_shape=(vpad, tiles, 128)))
     for method in ("_wrap_docblock_dp", "_build_stale_helpers",
-                   "_build_vocab_slice_scatter", "_split_blocks"):
+                   "_build_blocked_rebuild", "_split_blocks"):
         setattr(app, method,
                 types.MethodType(getattr(LightLDA, method), app))
     app._split_blocks(nbs)
     chips = telemetry.gauge("lda.sample.chips").value
+    app._nb_pad = steps * nbs
+    app._z_sharding = NamedSharding(mesh, P(None, app._block_axes, None))
     LightLDA._build_docblock_kernel(app)
     axes = app._block_axes
 
@@ -937,6 +975,61 @@ def test_docblock_superstep_for_a_v5e_2x2_gives_each_chip_a_quarter(
     reduces = [ln for ln in text.splitlines()
                if re.search(r" all-reduce(-start)?\(", ln)]
     assert reduces and all("s32[8,128]" in ln for ln in reduces), reduces
+
+
+def test_docblock_rebuild_for_a_v5e_2x2_scatters_a_quarter_of_the_lanes(
+        superstep_v5e_2x2):
+    """The resident rebuild compiled ahead of time for the described v5e
+    2x2 at chip_smoke's widths (4 steps of 600 blocks of 512: 1,228,800
+    lanes), z split as the superstep leaves it: each chip sorts and
+    scatters the 307,200 lanes of its own blocks into the whole
+    vocabulary, with no all-gather (before, the compiler gathered z to
+    every chip, and each scattered its data shard's 614,400 lanes into
+    its vocab slice). Then one reduction over the model axis leaves the
+    chip its slice, and one all-reduce sums that slice over the data
+    axis. XLA:TPU compiles an int32 reduce-scatter as an all-reduce of
+    the whole table and a local slice (a float32 one stays a
+    reduce-scatter), so the first may read either way."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    app, _, _ = superstep_v5e_2x2
+    rebuild = app._build_blocked_rebuild()
+    assert telemetry.gauge("lda.rebuild.lanes_per_chip").value == 307_200
+    lanes, table = 4 * 600 * 512, 50_002 * 8 * 128
+
+    def sds(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(app.mesh, spec))
+
+    text = rebuild.trace(sds((4, 600, 512), app._z_sharding.spec),
+                         sds((lanes,), P()), sds((lanes,), P())) \
+        .lower().compile().as_text()
+    lines = text.splitlines()
+    assert not [ln for ln in lines if re.search(r" all-gather(-start)?\(",
+                                                ln)]
+    # the computation that holds the scatter, and the sort in front of
+    # it, take the chip's quarter of the lanes
+    head, scatters = None, []
+    for ln in lines:
+        if re.match(r"%\S+ \(", ln):
+            head = ln
+        elif re.search(rf"= s32\[{table}\]\S* scatter\(", ln):
+            scatters.append(head)
+    assert scatters and all(f"s32[{lanes // 4}]" in h
+                            for h in scatters), scatters
+    assert re.search(rf"\(s32\[{lanes // 4}\]\S*, s32\[{lanes // 4}\]\S*\) "
+                     r"sort\(", text)
+    reductions = [ln for ln in lines if re.search(
+        r" (all-reduce|reduce-scatter)(-start)?\(", ln)]
+    model = [ln for ln in reductions
+             if re.search(r"= s32\[50002,8,128\]\S* all-reduce", ln)
+             or re.search(r"= s32\[25001,8,128\]\S* reduce-scatter", ln)]
+    data = [ln for ln in reductions
+            if re.search(r"= s32\[25001,8,128\]\S* all-reduce", ln)]
+    assert len(model) == len(data) == 1 and len(reductions) == 2, reductions
 
 
 @pytest.mark.parametrize("bad", [-1, "V"])
