@@ -35,7 +35,15 @@ tokens each masked with the block's probability ``t ~ U(noise_eps,
 (copy, block of ``block_length``, document)
 (:func:`multiverso_tpu.ops.latent_attention.attend_blocks`), and the loss
 is the masked positions' cross-entropy against their clean tokens,
-unshifted, weighted ``1 / t`` and over the real tokens.
+unshifted, weighted ``1 / t`` and over the real tokens. In a
+``nemotron_h`` block a layer is ONE part behind one pre-norm, named by
+its letter of ``hybrid_override_pattern``: ``M`` the Mamba-2 mixer
+(:mod:`multiverso_tpu.ops.ssd`, arXiv:2405.21060), ``*`` grouped
+attention with neither QK-norm nor rotary embedding, ``E`` the routed
+experts beside a shared one, ``-`` a dense feed-forward; the
+feed-forward is ``relu2`` (``mlp_hidden_act``: ``down(relu(up x)^2)``,
+two matrices where SwiGLU has three) and the router a sigmoid whose
+choice a bias table always steers.
 ``tie_word_embeddings``: no ``head`` table, ``embed`` is gathered from
 AND multiplied by and takes ONE optimizer step on the sum of its two
 gradients. ``use_expert_bias``: an expert
@@ -64,22 +72,29 @@ as wide as the key-value heads; ``w_o`` stored out x in); a dense layer
 ``l{i}.router`` [D, E], ``l{i}.expert_bias`` [E] (updater ``sgd``: its
 delta is no derivative), ``l{i}.shared`` [3, D, shared F],
 ``l{i}.experts`` [held, 3, D, F] — the leading dimension is the expert,
-which is what a table shards over the model axis. :func:`table_layout`
-says where every tensor of a published role lies.
+which is what a table shards over the model axis; a relu2 feed-forward
+holds 2 where SwiGLU holds 3 (up, down). An ssm layer: ``l{i}.ssm_in``
+[D, z | x | B | C | dt], ``l{i}.ssm_conv`` [taps + 1, x | B | C] (the
+bias the last row), ``l{i}.ssm_decay`` [3, H] (``A_log``, ``dt_bias``,
+``D``), ``l{i}.ssm_norm`` [H P] (the gated norm's weight),
+``l{i}.ssm_out`` [D, H P] (out x in). :func:`table_layout` says where
+every tensor of a published role lies.
 
 Precision: tables, gradients and Adam's moments float32; matrix products
 on bfloat16 operands with float32 accumulation; norms, rotary, router
 logits and scores, the selection bias, attention softmax, the
 recurrence's decay, gates and state between chunks, the short
-convolution's gates and taps, and the loss float32. The forward
+convolution's gates and taps, the ssm's ``dt``, ``A``, decay sums, state
+between chunks and gated norm, and the loss float32. The forward
 pass keeps only the residual entering each layer; the backward pass
 goes a layer at a time, recomputes the layer, and folds each table's
 gradient into the table (Adam) before the layer below starts, so no more
 than a layer's gradients are alive at once. Inside a layer the attention
 is a Pallas kernel over blocks of queries and keys whose scores stay in
 VMEM (forward and backward, :mod:`multiverso_tpu.ops.latent_attention`),
-the dense and shared feed-forward and the output head go a group of
-sequences at a time, the routed experts a block of sorted rows at a time.
+the dense and shared feed-forward, the output head and the Mamba-2
+mixer go a group of sequences at a time, the routed experts a block of
+sorted rows at a time.
 """
 
 from __future__ import annotations
@@ -103,6 +118,7 @@ from multiverso_tpu.ops import interpret_mode
 from multiverso_tpu.ops import latent_attention as mla
 from multiverso_tpu.ops import moe
 from multiverso_tpu.ops import short_conv as sconv
+from multiverso_tpu.ops import ssd
 from multiverso_tpu.tables import MatrixTable
 from multiverso_tpu.tables.base import Table
 from multiverso_tpu.tables.superstep import make_superstep
@@ -114,12 +130,16 @@ PROBE_ROWS = 1024       # embedding rows whose gradient a step returns
 AUX_KEEP = 4            # the last steps whose whole aux stays on the device
 PREFETCH_STEPS = 4      # packed steps the input thread runs ahead
 _SMALL_AUX = ("ce", "balance", "moe", "imbalance", "attend", "gdn", "conv",
-              "bias", "bd")
+              "ssm", "bias", "bd")
 # what a step's noise is drawn from besides the seed (a table's start
 # takes the table's index, far below it)
 NOISE_STREAM = 2 ** 31 - 1
 LATENT, LINEAR, FULL, CONV = ("latent", "linear_attention",
                               "full_attention", "conv")
+# the kinds of a block whose layer is ONE part (``_Block.single``)
+SSM, EXPERTS, MLP = "mamba", "moe", "mlp"
+# ``hybrid_override_pattern``'s letters: a layer's kind
+PATTERN = {"M": SSM, "*": FULL, "E": EXPERTS, "-": MLP}
 
 
 class _Block(NamedTuple):
@@ -137,16 +157,27 @@ class _Block(NamedTuple):
     every: Optional[str] = None
     # trained by block diffusion, not by the next token
     diffusion: bool = False
+    # a layer is ONE part — a mixer, experts or a dense feed-forward —
+    # behind one pre-norm, its kind a letter of hybrid_override_pattern;
+    # full attention has neither QK-norm nor rotary embedding
+    single: bool = False
+    act: str = "silu"           # the feed-forward's (moe.PARTS)
+    # the router's choice is always steered by a bias table
+    bias: bool = False
 
 
 _BLOCKS = {"deepseek_v2": _Block(False, False, "softmax", ()),
            "olmo_hybrid": _Block(True, False, "softmax", (LINEAR, FULL)),
            "lfm2_moe": _Block(False, True, "sigmoid", (CONV, FULL)),
-           "sdar_moe": _Block(False, True, "softmax", (FULL,), FULL, True)}
+           "sdar_moe": _Block(False, True, "softmax", (FULL,), FULL, True),
+           "nemotron_h": _Block(False, False, "sigmoid",
+                                tuple(PATTERN.values()), single=True,
+                                act="relu2", bias=True)}
 # published names of keys this class holds under an older model's
 _PUBLISHED_NAMES = {"num_experts": "n_routed_experts",
                     "num_dense_layers": "first_k_dense_replace",
-                    "norm_eps": "rms_norm_eps"}
+                    "norm_eps": "rms_norm_eps",
+                    "layer_norm_epsilon": "rms_norm_eps"}
 
 
 @dataclasses.dataclass
@@ -194,9 +225,36 @@ class LMConfig:
     # the routing's counts at expert_bias_rate (arXiv:2408.15664)
     use_expert_bias: bool = False
     expert_bias_rate: float = 1e-3
+    # a layer's kind a letter (nemotron_h): M, *, E or -
+    hybrid_override_pattern: Optional[str] = None
+    # the Mamba-2 mixer (ops/ssd.py)
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    mamba_hidden_act: str = "silu"
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # the ssm's output projection starts at init_std / sqrt(layers)
+    rescale_prenorm_residual: bool = False
+    # the feed-forward's activation; None: hidden_act's
+    mlp_hidden_act: Optional[str] = None
+    # the shared expert's width; None: n_shared_experts x the experts'
+    moe_shared_expert_intermediate_size: Optional[int] = None
+    # group-limited routing (1 / 1: none)
+    n_group: int = 1
+    topk_group: int = 1
     # published keys that change the model and are built one way only
     attention_bias: bool = False
     hidden_act: str = "silu"
+    mamba_proj_bias: bool = False
+    use_bias: bool = False
+    mlp_bias: bool = False
+    residual_in_fp32: bool = False
     tie_word_embeddings: bool = False
     use_sliding_window: bool = False
     decoder_sparse_step: int = 1        # an expert layer every n
@@ -234,6 +292,7 @@ class LMConfig:
     mlp_chunks: int = 1
     head_chunks: int = 1
     attention_chunks: int = 1   # full attention, a group of sequences
+    ssm_chunks: int = 1         # the Mamba-2 mixer, a group of sequences
 
     gdn_chunk: int = 64         # tokens the recurrence solves at once
     compute_dtype: str = "bfloat16"     # operands of the matrix products
@@ -241,6 +300,8 @@ class LMConfig:
     def __post_init__(self) -> None:
         if self.scoring_func is None and self.model_type in _BLOCKS:
             self.scoring_func = self.block.score
+        if self.model_type in _BLOCKS and self.block.bias:
+            self.use_expert_bias = True
         if self.head_dim is None \
                 and self.hidden_size % self.num_attention_heads == 0:
             self.head_dim = self.hidden_size // self.num_attention_heads
@@ -275,14 +336,36 @@ class LMConfig:
         return self.learning_rate * min(1.0, (step + 1) / self.warmup_steps)
 
     def is_dense(self, layer: int) -> bool:
+        if self.block.single:
+            return self.mixer(layer) == MLP
         return layer < self.first_k_dense_replace \
             or self.n_routed_experts == 0
 
+    def is_expert(self, layer: int) -> bool:
+        if self.block.single:
+            return self.mixer(layer) == EXPERTS
+        return not self.is_dense(layer)
+
     def mixer(self, layer: int) -> str:
-        """``LATENT``, ``LINEAR``, ``CONV`` or ``FULL``."""
+        """``LATENT``, ``LINEAR``, ``CONV`` or ``FULL``; in a block of
+        one part a layer (``_Block.single``) ``SSM``, ``FULL``,
+        ``EXPERTS`` or ``MLP``."""
+        if self.block.single:
+            return PATTERN[self.hybrid_override_pattern[layer]]
         if self.layer_types is None:
             return self.block.every or LATENT
         return self.layer_types[layer]
+
+    @property
+    def mlp_act(self) -> str:
+        return self.mlp_hidden_act or self.hidden_act
+
+    @property
+    def shared_width(self) -> int:
+        return self.moe_shared_expert_intermediate_size \
+            * self.n_shared_experts \
+            if self.moe_shared_expert_intermediate_size is not None \
+            else self.n_shared_experts * self.moe_intermediate_size
 
     def layers_of(self, kind: str) -> int:
         return sum(self.mixer(i) == kind
@@ -318,8 +401,7 @@ class LMConfig:
 
     @property
     def expert_layers(self) -> int:
-        return sum(not self.is_dense(i)
-                   for i in range(self.num_hidden_layers))
+        return sum(self.is_expert(i) for i in range(self.num_hidden_layers))
 
     def check(self) -> None:
         if self.model_type not in _BLOCKS:
@@ -332,10 +414,30 @@ class LMConfig:
         if self.hidden_act != "silu":
             raise NotImplementedError(f"hidden_act {self.hidden_act!r}: "
                                       "only silu (SwiGLU) is built")
+        if self.mlp_act != self.block.act:
+            raise NotImplementedError(
+                f"mlp_hidden_act {self.mlp_act!r} in a {self.model_type} "
+                f"block: its feed-forward is built with {self.block.act}")
+        if self.mamba_proj_bias or self.use_bias or self.mlp_bias:
+            raise NotImplementedError("mamba_proj_bias / use_bias / "
+                                      "mlp_bias: no projection has a bias "
+                                      "here")
+        if self.residual_in_fp32:
+            raise NotImplementedError(
+                "residual_in_fp32: the residual is float32 throughout")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise NotImplementedError("n_group / topk_group: group-limited "
+                                      "routing is not built")
         if self.q_lora_rank is not None:
             raise NotImplementedError(
                 "q_lora_rank: only the direct query projection is built")
-        if self.layer_types is None and self.block.every:
+        if self.block.single:
+            self._check_pattern()
+        elif self.hybrid_override_pattern is not None:
+            raise NotImplementedError(
+                f"hybrid_override_pattern in a {self.model_type} block: "
+                "its layers are a mixer and a feed-forward each")
+        elif self.layer_types is None and self.block.every:
             if self.kv_lora_rank is not None:
                 raise NotImplementedError(
                     f"kv_lora_rank in a {self.model_type} block: its "
@@ -371,7 +473,8 @@ class LMConfig:
                              "uint8: at most 256 router outputs")
         for n, name in ((self.mlp_chunks, "mlp_chunks"),
                         (self.head_chunks, "head_chunks"),
-                        (self.attention_chunks, "attention_chunks")):
+                        (self.attention_chunks, "attention_chunks"),
+                        (self.ssm_chunks, "ssm_chunks")):
             if self.sequences % n:
                 raise ValueError(f"{name} {n} does not divide "
                                  f"{self.sequences} sequences")
@@ -409,6 +512,49 @@ class LMConfig:
         if not 0.0 < self.noise_eps < 1.0:
             raise ValueError(f"noise_eps {self.noise_eps}: in (0, 1)")
 
+    def _check_pattern(self) -> None:
+        """A block of one part a layer: its pattern names every layer
+        with a letter it builds, without QK-norm or rotary embedding in
+        attention and with a selection bias in every expert layer."""
+        pattern = self.hybrid_override_pattern or ""
+        if len(pattern) < self.num_hidden_layers:
+            raise ValueError(f"hybrid_override_pattern names "
+                             f"{len(pattern)} of {self.num_hidden_layers} "
+                             "layers")
+        for letter in pattern[:self.num_hidden_layers]:
+            if letter not in PATTERN:
+                raise NotImplementedError(
+                    f"hybrid_override_pattern letter {letter!r}: built "
+                    f"are {' '.join(PATTERN)}")
+        if self.layer_types is not None or self.kv_lora_rank is not None:
+            raise NotImplementedError(
+                "layer_types / kv_lora_rank beside hybrid_override_pattern: "
+                "a config names its layers one way")
+        if self.first_k_dense_replace:
+            raise NotImplementedError(
+                "first_k_dense_replace beside hybrid_override_pattern: a "
+                "dense layer is a '-' of the pattern")
+        if self.layers_of(FULL):
+            self._check_full_attention()
+        if self.layers_of(EXPERTS) and not self.n_routed_experts:
+            raise ValueError("an E layer wants n_routed_experts")
+        if self.layers_of(SSM):
+            if self.mamba_hidden_act != "silu" or not self.use_conv_bias:
+                raise NotImplementedError(
+                    "mamba_hidden_act / use_conv_bias: the convolution is "
+                    "built with a bias and silu")
+            if min(self.mamba_num_heads, self.mamba_head_dim,
+                   self.ssm_state_size, self.n_groups, self.conv_kernel,
+                   self.chunk_size) < 1:
+                raise ValueError("an M layer wants its mamba_* / ssm_* sizes")
+            if self.mamba_num_heads % self.n_groups:
+                raise ValueError(f"n_groups {self.n_groups} do not divide "
+                                 f"{self.mamba_num_heads} heads")
+            if self.sequence_length % min(self.chunk_size,
+                                          self.sequence_length):
+                raise ValueError(f"chunk_size {self.chunk_size} does not "
+                                 f"divide {self.sequence_length}")
+
     def _check_full_attention(self) -> None:
         heads, kv = self.num_attention_heads, self.kv_heads
         if self.head_dim is None:
@@ -417,6 +563,13 @@ class LMConfig:
         if heads % kv:
             raise ValueError(f"num_key_value_heads {kv} do not divide "
                              f"{heads} heads")
+        if self.block.single:
+            if self.attention_theta is not None or self.rope_scaling:
+                raise NotImplementedError(
+                    "rope_parameters / rope_scaling: in a block of one part "
+                    "a layer, full attention is built without a rotary "
+                    "embedding")
+            return
         if self.block.head_norm:
             if self.attention_theta is None or self.rope_scaling:
                 raise NotImplementedError(
@@ -512,16 +665,24 @@ def gdn_shape(c: LMConfig) -> gdn.GatedDeltaShape:
         c.compute_dtype)
 
 
+def ssd_shape(c: LMConfig) -> ssd.SSDShape:
+    return ssd.SSDShape(
+        c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size, c.n_groups,
+        c.conv_kernel, c.rms_norm_eps, c.chunk_size, c.compute_dtype)
+
+
 def norm_offsets(c: LMConfig) -> List[int]:
     """Row of ``norms`` at which each layer's rows start, and (last) the
     final norm's: a latent layer has 3 (attention, latent, feed-forward),
     a linear-attention layer 3 (mixer, feed-forward, the gated output
     norm), a full-attention layer 4 (mixer, feed-forward, q, k), a
-    short-convolution layer 2 (mixer, feed-forward)."""
+    short-convolution layer 2 (mixer, feed-forward); a layer of a block
+    of one part a layer 1 (its pre-norm)."""
     per = {FULL: 4, CONV: 2}
     rows = [0]
     for i in range(c.num_hidden_layers):
-        rows.append(rows[-1] + per.get(c.mixer(i), 3))
+        rows.append(rows[-1] + (1 if c.block.single
+                                else per.get(c.mixer(i), 3)))
     return rows
 
 
@@ -539,8 +700,17 @@ def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
     if not c.tie_word_embeddings:
         shapes["head"] = (c.vocab_size, D)
     shapes["norms"] = (norm_offsets(c)[L] + 1, D)
+    parts = len(moe.PARTS[c.mlp_act])
     for i in range(L):
         kind = c.mixer(i)
+        if kind == SSM:
+            g = ssd_shape(c)
+            shapes[f"l{i}.ssm_in"] = (D, g.in_width)
+            shapes[f"l{i}.ssm_conv"] = (g.taps + 1, g.conv_width)
+            shapes[f"l{i}.ssm_decay"] = (3, g.heads)
+            shapes[f"l{i}.ssm_norm"] = (g.inner,)
+            shapes[f"l{i}.ssm_out"] = (D, g.inner)
+            continue
         if kind == LATENT:
             shapes[f"l{i}.attn"] = (D, sum(_attn_columns(c)))
             shapes[f"l{i}.kv_b"] = (
@@ -557,18 +727,18 @@ def table_shapes(c: LMConfig) -> Dict[str, Tuple[int, ...]]:
             shapes[f"l{i}.conv_in"] = (D, 3 * D)
             shapes[f"l{i}.conv_taps"] = (c.conv_L_cache, D)
             shapes[f"l{i}.conv_out"] = (D, D)
-        else:
+        elif kind == FULL:
             shapes[f"l{i}.attn"] = (D, sum(_full_columns(c)))
         if c.is_dense(i):
-            shapes[f"l{i}.mlp"] = (3, D, c.intermediate_size)
-        else:
+            shapes[f"l{i}.mlp"] = (parts, D, c.intermediate_size)
+        elif c.is_expert(i):
             F = c.moe_intermediate_size
             shapes[f"l{i}.router"] = (D, c.router_width)
             if c.use_expert_bias:
                 shapes[f"l{i}.expert_bias"] = (c.router_width,)
             if c.n_shared_experts:
-                shapes[f"l{i}.shared"] = (3, D, c.n_shared_experts * F)
-            shapes[f"l{i}.experts"] = (c.n_routed_experts, 3, D, F)
+                shapes[f"l{i}.shared"] = (parts, D, c.shared_width)
+            shapes[f"l{i}.experts"] = (c.n_routed_experts, parts, D, F)
     return shapes
 
 
@@ -592,8 +762,13 @@ def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
     [D, B | C | X], ``conv_taps`` [taps, D], ``conv_out`` [D, D] (in x
     out); ``w_gate`` / ``w_up`` / ``w_down`` [D, F]; ``router``,
     ``expert_bias`` [E], ``shared_gate`` / ``_up`` / ``_down``,
-    ``exp_gate`` / ``_up`` / ``_down`` [held, D, F]. A tied model has no
-    ``head``: its head is ``embed``."""
+    ``exp_gate`` / ``_up`` / ``_down`` [held, D, F] (a relu2
+    feed-forward has no ``gate``). A layer of a block of one part a
+    layer has ``norm``, its pre-norm, and an ssm layer ``w_z``, ``w_x``,
+    ``w_B``, ``w_C``, ``w_dt`` (columns of ``ssm_in``), ``conv_w`` [taps,
+    x | B | C], ``conv_b``, ``a_log``, ``dt_bias``, ``d_skip`` [H],
+    ``ssm_norm`` [H P], ``w_out`` [D, H P] (out x in). A tied model has
+    no ``head``: its head is ``embed``."""
     every = slice(None)
     L = c.num_hidden_layers
     rows = norm_offsets(c)
@@ -609,8 +784,25 @@ def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
         return {f"l{i}.{n}": (every, slice(int(e - w), int(e)))
                 for n, w, e in zip(names, widths, ends)}
 
+    parts = moe.PARTS[c.mlp_act]
     for i in range(L):
         kind, r = c.mixer(i), rows[i]
+        if c.block.single:
+            layout["norms"][f"l{i}.norm"] = (r,)
+        if kind == SSM:
+            g = ssd_shape(c)
+            state = g.groups * g.state
+            layout[f"l{i}.ssm_in"] = columns(
+                ("w_z", "w_x", "w_B", "w_C", "w_dt"),
+                (g.inner, g.inner, state, state, g.heads))
+            layout[f"l{i}.ssm_conv"] = {f"l{i}.conv_w": (slice(0, g.taps),),
+                                        f"l{i}.conv_b": (g.taps,)}
+            layout[f"l{i}.ssm_decay"] = {f"l{i}.a_log": (0,),
+                                         f"l{i}.dt_bias": (1,),
+                                         f"l{i}.d_skip": (2,)}
+            layout[f"l{i}.ssm_norm"] = {f"l{i}.ssm_norm": (every,)}
+            layout[f"l{i}.ssm_out"] = {f"l{i}.w_out": (every,)}
+            continue
         if kind == LATENT:
             layout["norms"].update({
                 f"l{i}.attn_norm": (r,),
@@ -637,17 +829,18 @@ def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
                 f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,)})
             for part in ("conv_in", "conv_taps", "conv_out"):
                 layout[f"l{i}.{part}"] = {f"l{i}.{part}": (every,)}
-        else:
-            layout["norms"].update({
-                f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,),
-                f"l{i}.q_norm": (r + 2, *qk_norm),
-                f"l{i}.k_norm": (r + 3, *qk_norm)})
+        elif kind == FULL:
+            if not c.block.single:
+                layout["norms"].update({
+                    f"l{i}.mixer_norm": (r,), f"l{i}.ffn_norm": (r + 1,),
+                    f"l{i}.q_norm": (r + 2, *qk_norm),
+                    f"l{i}.k_norm": (r + 3, *qk_norm)})
             layout[f"l{i}.attn"] = columns(("w_q", "w_k", "w_v", "w_o"),
                                            _full_columns(c))
         if c.is_dense(i):
             layout[f"l{i}.mlp"] = {f"l{i}.w_{part}": (j,) for j, part in
-                                   enumerate(("gate", "up", "down"))}
-        else:
+                                   enumerate(parts)}
+        elif c.is_expert(i):
             layout[f"l{i}.router"] = {f"l{i}.router": (every,)}
             if c.use_expert_bias:
                 layout[f"l{i}.expert_bias"] = {
@@ -655,10 +848,10 @@ def table_layout(c: LMConfig) -> Dict[str, Dict[str, tuple]]:
             if c.n_shared_experts:
                 layout[f"l{i}.shared"] = {
                     f"l{i}.shared_{part}": (j,) for j, part in
-                    enumerate(("gate", "up", "down"))}
+                    enumerate(parts)}
             layout[f"l{i}.experts"] = {
                 f"l{i}.exp_{part}": (every, j) for j, part in
-                enumerate(("gate", "up", "down"))}
+                enumerate(parts)}
     return layout
 
 
@@ -672,9 +865,13 @@ def named_parameters(c: LMConfig, tables: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def start_std(c: LMConfig, name: str) -> float:
-    """The standard deviation table ``name`` starts from."""
+    """The standard deviation table ``name`` starts from: an ssm
+    layer's output projection's divided by the root of the layers where
+    ``rescale_prenorm_residual`` says so."""
     if name == "embed" and c.embed_init_std is not None:
         return c.embed_init_std
+    if name.endswith(".ssm_out") and c.rescale_prenorm_residual:
+        return c.init_std / float(np.sqrt(c.num_hidden_layers))
     return c.init_std
 
 
@@ -717,6 +914,33 @@ def decay_start(c: LMConfig, index, heads: int):
                       dt + jnp.log(-jnp.expm1(-dt))])
 
 
+def conv_start(c: LMConfig, index, shape):
+    """An ssm layer's filter and bias as torch's ``Conv1d`` starts them
+    (the family leaves them so): ``U(-b, b)`` with ``b = 1 /
+    sqrt(conv_kernel)``, one tap a channel's whole fan-in; from the seed
+    and the table's index."""
+    bound = float(c.conv_kernel) ** -0.5
+    return jax.random.uniform(_table_key(c, index), tuple(shape),
+                              jnp.float32, -bound, bound)
+
+
+def ssm_decay_start(c: LMConfig, index, heads: int):
+    """An ssm layer's ``[A_log; dt_bias; D]`` [3, H] as the Mamba-2
+    family starts them: ``A_log = log U(1, 16)``, ``dt_bias`` the
+    inverse softplus of ``exp(U(log time_step_min, log time_step_max))``
+    floored at ``time_step_floor``, ``D = 1``; from the seed and the
+    table's index."""
+    key = _table_key(c, index)
+    a = jax.random.uniform(jax.random.fold_in(key, 0), (heads,),
+                           jnp.float32, 1.0, 16.0)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        jax.random.fold_in(key, 1), (heads,), jnp.float32,
+        float(np.log(c.time_step_min)), float(np.log(c.time_step_max)))),
+        c.time_step_floor)
+    return jnp.stack([jnp.log(a), dt + jnp.log(-jnp.expm1(-dt)),
+                      jnp.ones((heads,), jnp.float32)])
+
+
 # -- the phases of a step, each under a program scope --------------------------
 
 @telemetry.scope("lm.embed_gather")
@@ -748,6 +972,16 @@ def _swiglu(h, w):
                            preferred_element_type=jnp.float32)
 
 
+def _relu2(h, w):
+    """``h`` in the products' dtype; ``w`` [2, D, F] float32: up, and
+    down stored [D, F]; ``down(relu(up h)^2)``."""
+    w = w.astype(h.dtype)
+    up = jnp.dot(h, w[0], preferred_element_type=jnp.float32)
+    return lax.dot_general(jnp.square(jax.nn.relu(up)).astype(h.dtype), w[1],
+                           (((h.ndim - 1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
 def _over_sequences(fn: Callable, chunks: int, x, *rest):
     """``fn(x_group, *rest)`` a group of sequences at a time, each group
     recomputed in the backward pass; the groups' results stacked."""
@@ -763,9 +997,24 @@ def _dense_mlp_group(h, w):
     return _swiglu(h, w)
 
 
+@telemetry.scope("lm.dense_mlp")
+def _dense_relu2_group(h, w):
+    return _relu2(h, w)
+
+
 @telemetry.scope("lm.moe.shared")
 def _shared_experts_group(h, w):
     return _swiglu(h, w)
+
+
+@telemetry.scope("lm.moe.shared")
+def _shared_relu2_group(h, w):
+    return _relu2(h, w)
+
+
+# the dense and the shared feed-forward by their activation (moe.PARTS)
+_DENSE = {"silu": _dense_mlp_group, "relu2": _dense_relu2_group}
+_SHARED = {"silu": _shared_experts_group, "relu2": _shared_relu2_group}
 
 
 @telemetry.scope("lm.block_norm")
@@ -841,6 +1090,8 @@ class TransformerLM:
                 theta=float(c.rope_theta), scaling=c.rope_scaling)
         elif c.layers_of(LINEAR):
             self._gdn = gdn_shape(c)
+        if c.layers_of(SSM):
+            self._ssd = ssd_shape(c)
         self._norm_rows = norm_offsets(c)
         option = AddOption(learning_rate=c.learning_rate, momentum=c.beta1,
                            rho=c.beta2, lam=c.adam_eps)
@@ -852,6 +1103,10 @@ class TransformerLM:
                     if start in ("ones", "zeros")
                     else decay_start(c, index, shape[1])
                     if start == "decay"
+                    else ssm_decay_start(c, index, shape[1])
+                    if start == "ssm_decay"
+                    else conv_start(c, index, shape)
+                    if start == "ssm_conv"
                     else start_values(c, index, shape, std), pad),
                 static_argnums=(2, 3, 4))       # one program a shape
             for index, (tname, shape) in enumerate(
@@ -874,8 +1129,11 @@ class TransformerLM:
                     else Table(f"{name}.{tname}", shape, "float32", **kw)
                 # the start is drawn on the device and installed as it
                 # is: 2.5 GB of it never pass the host
-                start = "ones" if tname == "norms" else "decay" \
-                    if tname.endswith(".gdn_decay") else "zeros" \
+                start = "ones" if tname == "norms" \
+                    or tname.endswith(".ssm_norm") else "decay" \
+                    if tname.endswith(".gdn_decay") else "ssm_decay" \
+                    if tname.endswith(".ssm_decay") else "ssm_conv" \
+                    if tname.endswith(".ssm_conv") else "zeros" \
                     if is_bias(tname) else "normal"
                 table.put_raw(draw(index, start_std(c, tname), start,
                                    shape, pad))
@@ -925,7 +1183,11 @@ class TransformerLM:
         q_end, k_end, v_end, _ = np.cumsum(_full_columns(c))
         w_q, w_k, w_v = (attn[:, :q_end], attn[:, q_end:k_end],
                          attn[:, k_end:v_end])
-        if c.block.head_norm:
+        if c.block.single:
+            q, k, v = mla.project_plain(x, w_q, w_k, w_v,
+                                        c.num_attention_heads, c.kv_heads,
+                                        c.compute_dtype)
+        elif c.block.head_norm:
             d = c.head_dim
             q, k, v = mla.project_grouped(
                 x, pos, w_q, w_k, w_v, q_norm[:d], k_norm[:d],
@@ -949,12 +1211,39 @@ class TransformerLM:
             sconv.mix(sconv.project_in(u, w_in, dtype), taps, doc), w_out,
             dtype)
 
+    def _ssm(self, u, doc, t):
+        """The Mamba-2 mixer's addition to the residual, from the
+        block's normed input."""
+        return ssd.mix(u, doc, t["ssm_in"], t["ssm_conv"], t["ssm_decay"],
+                       t["ssm_norm"], t["ssm_out"], self._ssd,
+                       interpret=self._interpret)
+
+    def _single_layer(self, i: int, x, t, norms, doc, pos, real):
+        """A layer of a block of one part a layer: its pre-norm, then
+        the ssm mixer, attention, the experts or the dense feed-forward
+        on its output, added to the residual."""
+        c = self.config
+        kind = c.mixer(i)
+        u = _input_norm(x, norms[0], jnp.float32(c.rms_norm_eps))
+        if kind in (EXPERTS, MLP):
+            return self._feed_forward(i, x, u, t, real)
+        if kind == SSM:
+            y = _over_sequences(lambda g, w: self._ssm(*g, w), c.ssm_chunks,
+                                (u, doc), t)
+        else:
+            y = _over_sequences(
+                lambda g, w: self._attention(*g, w, None, None),
+                c.attention_chunks, (u, doc, pos), t["attn"])
+        return (x + y.reshape(x.shape), jnp.zeros(())), None
+
     def _layer(self, i: int, x, t, norms, doc, pos, real):
         """One decoder layer on the residual ``x`` [B, S, D] float32,
         from the layer's tables ``t`` and its rows of ``norms``;
         returns ``(new residual, the layer's balance loss)`` and what an
         expert layer routed (counts, chosen experts, rows computed)."""
         c = self.config
+        if c.block.single:
+            return self._single_layer(i, x, t, norms, doc, pos, real)
         kind, eps = c.mixer(i), jnp.float32(c.rms_norm_eps)
         if kind == LATENT:
             x = x + self._latent(x, t, norms, doc, pos)
@@ -987,10 +1276,17 @@ class TransformerLM:
                     jnp.zeros(())), None
         # the feed-forward's norm: a latent layer's third row, else the
         # second
-        h = _input_norm(x, norms[2 if kind == LATENT else 1], eps)
+        return self._feed_forward(
+            i, x, _input_norm(x, norms[2 if kind == LATENT else 1], eps), t,
+            real)
+
+    def _feed_forward(self, i: int, x, h, t, real):
+        """The dense or expert feed-forward on the normed ``h``, added
+        to the residual ``x``: :meth:`_layer`'s returns."""
+        c = self.config
         hb = h.astype(c.compute_dtype)
         if c.is_dense(i):
-            y = _over_sequences(_dense_mlp_group, c.mlp_chunks, hb,
+            y = _over_sequences(_DENSE[c.mlp_act], c.mlp_chunks, hb,
                                 t["mlp"])
             return (x + y.reshape(x.shape), jnp.zeros(())), None
         B, S, D = x.shape
@@ -1005,9 +1301,9 @@ class TransformerLM:
         row_w = moe.row_weights(routing.top_s, plan.row_src)
         y, rows = moe.routed_experts(h.reshape(B * S, D), t["experts"],
                                      row_w, plan, c.expert_chunk_rows,
-                                     jnp.dtype(c.compute_dtype))
+                                     jnp.dtype(c.compute_dtype), c.mlp_act)
         if c.n_shared_experts:
-            x = x + _over_sequences(_shared_experts_group, c.mlp_chunks, hb,
+            x = x + _over_sequences(_SHARED[c.mlp_act], c.mlp_chunks, hb,
                                     t["shared"]).reshape(x.shape)
         return (x + y.reshape(x.shape), routing.balance), (
             routing.counts, routing.top_e.astype(jnp.uint8), rows)
@@ -1135,7 +1431,7 @@ class TransformerLM:
         out["embed"] = consume("embed", _embed_scatter(
             tables["embed"], tokens, d_x, d_head if tied else None))
         aux = {"ce": ce, "balance": balance}
-        attending = L - c.layers_of(LINEAR) - c.layers_of(CONV)
+        attending = c.layers_of(FULL) + c.layers_of(LATENT)
         if c.diffusion:
             aux["bd"] = bd
             aux["attend"] = attending * mla.diffusion_key_blocks(
@@ -1154,6 +1450,13 @@ class TransformerLM:
         if c.layers_of(CONV):
             # documents whose first tokens read zeroed taps, a layer
             aux["conv"] = c.layers_of(CONV) * gdn.doc_starts(doc)
+        if c.layers_of(SSM):
+            # (sequence, ssm layer, chunk) triples scanned, and the
+            # restarts of their states: one a document and layer
+            B, S = doc.shape
+            aux["ssm"] = c.layers_of(SSM) * jnp.stack([
+                jnp.asarray(B * (S // min(c.chunk_size, S)), jnp.int32),
+                gdn.doc_starts(doc)])
         if routed:
             aux.update(zip(("counts", "chosen", "rows"),
                            (jnp.stack(a) for a in zip(*routed))))
@@ -1176,7 +1479,7 @@ class TransformerLM:
         probe_rows = min(PROBE_ROWS, c.vocab_size)
         probe_expert = next((f"l{i}.experts" for i in
                              range(c.num_hidden_layers)
-                             if not c.is_dense(i)), None)
+                             if c.is_expert(i)), None)
         # the first recurrence's key projection, entry by entry
         probe_gdn = next((f"l{i}.gdn_in" for i in
                           range(c.num_hidden_layers)
@@ -1187,6 +1490,10 @@ class TransformerLM:
                  if c.mixer(i) == CONV]
         probe_conv = next((f"l{i}.conv_in" for i in sorted(
             convs, key=c.is_dense)), None)
+        # the first ssm layer's A_log, dt_bias and D, entry by entry
+        probe_ssm = next((f"l{i}.ssm_decay" for i in
+                          range(c.num_hidden_layers)
+                          if c.mixer(i) == SSM), None)
         biases = [n for n in names if is_bias(n)]
 
         def body(params, states, locals_, options, batch):
@@ -1206,6 +1513,8 @@ class TransformerLM:
                     report["probe_gdn_k"] = grad[:, gdn_keys:2 * gdn_keys]
                 elif name == probe_conv:
                     report["probe_conv_in"] = grad
+                elif name == probe_ssm:
+                    report["probe_ssm_decay"] = grad
                 return apply(p, s, grad, o), report
 
             aux, done = self._sweep(dict(zip(names, params)), batch, fold,
@@ -1217,6 +1526,8 @@ class TransformerLM:
                 aux["probe_gdn_k"] = done[probe_gdn][1]["probe_gdn_k"]
             if probe_conv is not None:
                 aux["probe_conv_in"] = done[probe_conv][1]["probe_conv_in"]
+            if probe_ssm is not None:
+                aux["probe_ssm_decay"] = done[probe_ssm][1]["probe_ssm_decay"]
             if biases:      # the largest |bias| the step leaves
                 aux["bias"] = jnp.max(jnp.abs(jnp.stack(
                     [done[n][0][0] for n in biases])))
@@ -1299,6 +1610,7 @@ class TransformerLM:
                                        "lm.attend.key_blocks_computed")),
                            ("gdn", ("lm.gdn.chunks", "lm.gdn.doc_starts")),
                            ("conv", ("lm.conv.doc_starts",)),
+                           ("ssm", ("lm.ssm.chunks", "lm.ssm.doc_starts")),
                            ("bd", ("lm.bd.masked_tokens", "lm.bd.real_tokens",
                                    "lm.bd.blocks"))):
             if small and key in small[-1]:

@@ -762,6 +762,20 @@ def project_grouped(x, pos, w_q, w_k, w_v, q_norm_w, k_norm_w, heads: int,
     return run(x, pos, w_q, w_k, w_v, q_norm_w, k_norm_w)
 
 
+def project_plain(x, w_q, w_k, w_v, heads: int, kv_heads: int, dtype):
+    """From the block's normed input ``x`` [B, S, D] float32: ``q``
+    [B, S, H, d], ``k``, ``v`` [B, S, G, d] in ``dtype``, the bare
+    products: no QK-norm, nothing rotated."""
+    @telemetry.scope("lm.attn.project")
+    def run(x, w_q, w_k, w_v):
+        B, S, _ = x.shape
+        heads_of = lambda a, n: a.astype(dtype).reshape(B, S, n, -1)
+        return (heads_of(_dot(x, w_q, dtype), heads),
+                heads_of(_dot(x, w_k, dtype), kv_heads),
+                heads_of(_dot(x, w_v, dtype), kv_heads))
+    return run(x, w_q, w_k, w_v)
+
+
 def attend_heads(q, k, v, doc, *, scale: float, block: int,
                  interpret: Optional[bool] = None):
     """:func:`attend` for keys of one depth a head and no rotary part:

@@ -16,10 +16,13 @@ is that of one block.
 On one chip the layer runs without its exchange: what the absent
 experts would add is left out, and nothing stands in for them.
 
-Weights of the experts held are ONE array ``[held, 3, hidden, width]``
-(gate, up, and down stored ``[hidden, width]`` like the other two: the
-down product contracts over ``width``), so the leading dimension is the
-expert, which is what a table shards over the model axis.
+Weights of the experts held are ONE array ``[held, parts, hidden,
+width]``, each part stored ``[hidden, width]`` (the down product
+contracts over ``width``), so the leading dimension is the expert,
+which is what a table shards over the model axis. The parts follow the
+expert's activation, a static choice (:data:`PARTS`): ``silu`` (SwiGLU,
+``down(silu(gate x) * up x)``) has three, gate, up and down; ``relu2``
+(``down(relu(up x)^2)``, the Nemotron-H family's) has two, up and down.
 """
 
 from __future__ import annotations
@@ -32,6 +35,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from multiverso_tpu import telemetry
+
+# an expert's parts a stored matrix each, by its activation
+PARTS = {"silu": ("gate", "up", "down"), "relu2": ("up", "down")}
 
 
 class Routing(NamedTuple):
@@ -205,6 +211,29 @@ def _experts_backward(x, wb, sizes, g, u, a, rw, d_rows):
 
 
 @telemetry.scope("lm.moe.experts")
+def _relu2_forward(x, wb, sizes):
+    u = _gmm(x, wb[:, 0], sizes)
+    a = jnp.square(jax.nn.relu(u)).astype(x.dtype)
+    return u, a, _gmm(a, wb[:, 1], sizes, transpose_rhs=True)
+
+
+@telemetry.scope("lm.moe.experts")
+def _relu2_backward(x, wb, sizes, u, a, rw, d_rows):
+    """``d_u = 2 relu(u) d_a``, written out."""
+    d_out = (rw * d_rows).astype(x.dtype)
+    d_a = _gmm(d_out, wb[:, 1], sizes)
+    d_u = (2.0 * jax.nn.relu(u) * d_a).astype(x.dtype)
+    return _gmm(d_u, wb[:, 0], sizes, transpose_rhs=True), (
+        _tgmm(x, d_u, sizes), _tgmm(d_out, a, sizes))
+
+
+# an activation's expert forward (its last output the expert's rows)
+# and backward, the forward's other outputs handed to the backward
+_EXPERTS = {"silu": (_experts_forward, _experts_backward),
+            "relu2": (_relu2_forward, _relu2_backward)}
+
+
+@telemetry.scope("lm.moe.experts")
 def _held_rows(valid, rows, weight=None):
     """``rows`` (times their ``weight``) where an expert held here owns
     them, zero elsewhere: on the chip the select is fused with the
@@ -223,8 +252,8 @@ def _cast_held(w, dtype):
 @telemetry.scope("lm.moe.accumulate")
 def _accumulate(d_w, d_rw, new_w, out, d_rows, r0):
     """A block's gradients added to what the blocks before it left: the
-    three weight accumulators (read and written whole, every block) and
-    the block's rows of the row weights' gradient."""
+    weight accumulators, one a part (read and written whole, every
+    block), and the block's rows of the row weights' gradient."""
     return (tuple(acc + new for acc, new in zip(d_w, new_w)),
             lax.dynamic_update_slice(d_rw, jnp.sum(out * d_rows, -1),
                                      (r0,)))
@@ -239,14 +268,16 @@ def _chunks(plan_, chunk_rows):
     return (plan_.offsets[-1] + chunk_rows - 1) // chunk_rows
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def routed_experts(h, w, row_w, plan_, chunk_rows, dtype=jnp.bfloat16):
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def routed_experts(h, w, row_w, plan_, chunk_rows, dtype=jnp.bfloat16,
+                   activation="silu"):
     """What the experts held here add to every token: ``y`` [T, D]
     float32 with ``y[t] = sum over t's assignments held here of
     weight * expert(h[t])``, and the number of rows that went through
     (the assignments held here, every one of them).
 
-    ``h`` [T, D] float32, ``w`` [held, 3, D, F] float32, ``row_w`` [R]
+    ``h`` [T, D] float32, ``w`` [held, parts, D, F] float32 (the
+    parts of ``activation``, :data:`PARTS`), ``row_w`` [R]
     float32 the weight of each sorted row (``top_s.reshape(-1)[plan_.
     row_src]``); the products take operands of ``dtype``. Differentiable
     in ``h``, ``w`` and ``row_w``; the backward pass recomputes each
@@ -263,17 +294,19 @@ def routed_experts(h, w, row_w, plan_, chunk_rows, dtype=jnp.bfloat16):
     scatter-adds ``lm.moe.permute``, the products with the valid-row
     selects around them ``lm.moe.experts``, the cast of the held weights
     and the gradients' accumulators ``lm.moe.accumulate``."""
-    return _routed_forward(h, w, row_w, plan_, chunk_rows, dtype)
+    return _routed_forward(h, w, row_w, plan_, chunk_rows, dtype,
+                           activation)
 
 
-def _routed_forward(h, w, row_w, plan_, chunk_rows, dtype):
+def _routed_forward(h, w, row_w, plan_, chunk_rows, dtype, activation):
     wb = _cast_held(w, dtype)
+    forward = _EXPERTS[activation][0]
 
     def body(c, carry):
         y, done = carry
         tok, rw, valid, sizes = _block(c, chunk_rows, plan_, row_w)
         x = _gather_rows(h, tok).astype(dtype)
-        out = _experts_forward(x, wb, sizes)[3]
+        out = forward(x, wb, sizes)[-1]
         return (_scatter_rows(y, tok, _held_rows(valid, out, rw)),
                 done + jnp.sum(sizes))
 
@@ -282,23 +315,24 @@ def _routed_forward(h, w, row_w, plan_, chunk_rows, dtype):
                           jnp.zeros((), jnp.int32)))
 
 
-def _routed_fwd(h, w, row_w, plan_, chunk_rows, dtype):
-    return (_routed_forward(h, w, row_w, plan_, chunk_rows, dtype),
-            (h, w, row_w, plan_))
+def _routed_fwd(h, w, row_w, plan_, chunk_rows, dtype, activation):
+    return (_routed_forward(h, w, row_w, plan_, chunk_rows, dtype,
+                            activation), (h, w, row_w, plan_))
 
 
-def _routed_bwd(chunk_rows, dtype, saved, cotangent):
+def _routed_bwd(chunk_rows, dtype, activation, saved, cotangent):
     h, w, row_w, plan_ = saved
     d_y, _ = cotangent
     wb = _cast_held(w, dtype)
+    forward, backward = _EXPERTS[activation]
 
     def body(c, carry):
         d_h, d_w, d_rw = carry
         tok, rw, valid, sizes = _block(c, chunk_rows, plan_, row_w)
         x = _gather_rows(h, tok).astype(dtype)
-        g, u, a, out = _experts_forward(x, wb, sizes)
+        *kept, out = forward(x, wb, sizes)
         d_rows = _held_rows(valid, _gather_rows(d_y, tok))
-        d_x, d_wc = _experts_backward(x, wb, sizes, g, u, a, rw, d_rows)
+        d_x, d_wc = backward(x, wb, sizes, *kept, rw, d_rows)
         d_w, d_rw = _accumulate(d_w, d_rw, d_wc, _held_rows(valid, out),
                                 d_rows, c * chunk_rows)
         return _scatter_rows(d_h, tok, _held_rows(valid, d_x)), d_w, d_rw
@@ -306,7 +340,7 @@ def _routed_bwd(chunk_rows, dtype, saved, cotangent):
     one = jnp.zeros(w.shape[:1] + w.shape[2:], jnp.float32)
     d_h, d_w, d_rw = lax.fori_loop(
         0, _chunks(plan_, chunk_rows), body,
-        (jnp.zeros(h.shape, jnp.float32), (one, one, one),
+        (jnp.zeros(h.shape, jnp.float32), (one,) * w.shape[1],
          jnp.zeros(row_w.shape, jnp.float32)))
     return d_h, _stack_gradients(d_w), d_rw, None
 
