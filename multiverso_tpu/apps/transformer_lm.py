@@ -1092,6 +1092,8 @@ class TransformerLM:
             self._gdn = gdn_shape(c)
         if c.layers_of(SSM):
             self._ssd = ssd_shape(c)
+            telemetry.gauge("lm.ssm.scan.heads_per_step").set(
+                ssd.heads_per_step(self._ssd))
         self._norm_rows = norm_offsets(c)
         option = AddOption(learning_rate=c.learning_rate, momentum=c.beta1,
                            rho=c.beta2, lam=c.adam_eps)

@@ -30,17 +30,22 @@ and leaves ``exp(L_C) S + sum_j exp(L_C - L_j) B_j (dt_j x_j)^T``; the
 terms of a token whose document started inside the chunk take no part
 of ``S``, which is all a restart is. Unlike the delta rule
 (:mod:`multiverso_tpu.ops.gated_delta`) a chunk needs no triangular
-solve: its own term is one masked product of plain XLA over every
-chunk at once (:func:`chunk_terms`). What crosses from chunk to chunk
-(:func:`across_chunks`) is two Pallas kernels, differentiated by hand
-(``jax.custom_vjp``), the delta rule's scheme without its write
-correction: a grid step is one (block of heads, chunk), the chunks
-innermost and in order, the state [heads a block, N, P] float32 in VMEM
-from a head block's first chunk to its last; the forward kernel also
-writes the state ENTERING each chunk when a backward pass will follow,
-and the backward kernel walks the chunks in reverse with the state's
-cotangent in VMEM. Mosaic compiles them on a TPU, Pallas's interpreter
-runs the same kernels anywhere else.
+solve, and all of it is one pair of Pallas kernels, differentiated by
+hand (``jax.custom_vjp``). A grid step is one (sequence, group of heads
+that read one ``B`` and one ``C``, chunk), the chunks innermost and in
+order. It reads ``x`` [B, S, H P], ``B | C`` [B, S, 2 G N] and the
+running sums and steps [B, H, S] where they lie and forms in VMEM
+``C B^T`` once for the group, each head's decay and masked product, the
+state's read ``exp(L_i) (C S)`` and write ``B^T (exp(L_C - L_j) u)``
+with ``u = dt x``; the group's state [N, heads P] stays float32 in VMEM
+from its first chunk to its last, and ``y`` is written once, [B, S,
+H P]. The forward kernel also writes the state ENTERING each chunk when
+a backward pass will follow; the backward kernel walks the chunks in
+reverse with the state's cotangent in VMEM, forms ``C B^T``, the decay
+and ``u`` again, and puts out ``du``, the running sums' cotangent and
+``dB``, ``dC`` summed over the group's heads. The running sums, ``dt``
+and the chain through ``u`` are XLA's. Mosaic compiles the kernels on a
+TPU, Pallas's interpreter runs the same kernels anywhere else.
 
 Products take operands of ``SSDShape.dtype`` (bfloat16) and accumulate
 in float32; ``dt``, ``A``, the decay and its running sums, the gated
@@ -109,187 +114,255 @@ def conv(xbc, taps_bias, doc):
                        + taps_bias[-1])
 
 
-# -- the state from chunk to chunk: two Pallas kernels -------------------------
+# -- the scan: one Pallas kernel pair over (sequence, group, chunk) -----------
 
-# what a grid step's blocks may hold, each of them twice (the pipeline's
-# two buffers): sets the heads a step
-_STEP_BYTES = 24 * 2 ** 20
+def heads_per_step(shape: SSDShape) -> int:
+    """Heads a grid step of the scan holds: one group's, the heads that
+    read one ``B`` and one ``C``."""
+    return shape.heads // shape.groups
 
 
-def _forward_kernel(q_ref, k_ref, u_ref, keep_ref, o_ref, *rest):
-    """One (block of heads, chunk) step: the state [heads, N, P] float32
-    stays in the scratch from a head block's first chunk to its last.
-    ``rest``: the entering states' output, when a backward pass will
-    follow, and the scratch."""
-    *entering_ref, state = rest
-    dtype = q_ref.dtype
+class _Chunk(NamedTuple):
+    """What a grid step forms once for its group and chunk: ``B``,
+    ``C`` [C, N] as products' operands, ``C B^T`` [C, C] float32, the
+    mask ``j <= i`` of one document, whether each token takes the
+    entering state and shares the last token's document [C, 1], the
+    running sums of the log decay ``L`` as rows [heads, C] and columns
+    [C, heads], and the steps ``dt`` as columns."""
+    b: jax.Array
+    c: jax.Array
+    cb: jax.Array
+    mask: jax.Array
+    carried: jax.Array
+    to_last: jax.Array
+    l_rows: jax.Array
+    l_cols: jax.Array
+    dt_cols: jax.Array
 
-    @pl.when(pl.program_id(1) == 0)
+
+def _chunk(b_ref, c_ref, l_ref, dt_ref, doc_ref, dtype) -> _Chunk:
+    b, c = b_ref[...].astype(dtype), c_ref[...].astype(dtype)
+    doc = doc_ref[...]              # [2, C]: segment ids, carried
+    cols = doc.T
+    seg = cols[:, :1]
+    C = seg.shape[0]
+    i = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    j = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    l_rows = l_ref[...]
+    return _Chunk(b, c, gdn._mm(c, b, gdn._NT),
+                  (seg == doc[:1]) & (j <= i), cols[:, 1:] > 0,
+                  seg == seg[C - 1:], l_rows, l_rows.T, dt_ref[...].T)
+
+
+def _head(k: int, ch: _Chunk, x_ref, P: int):
+    """Head ``k``'s chunk: ``u = dt x`` float32 [C, P], the decay
+    ``exp(L_i - L_j)`` [C, C] (0 across a document start and above the
+    diagonal), and [C, 1] columns ``from_state`` (``exp(L_i)`` where the
+    entering state reaches token ``i``, else 0) and ``to_end``
+    (``exp(L_C - L_j)`` inside the last token's document, else 0);
+    ``keep`` [1, 1] is the last ``from_state``."""
+    li, lj = ch.l_cols[:, k:k + 1], ch.l_rows[k:k + 1]
+    C = li.shape[0]
+    u = ch.dt_cols[:, k:k + 1] * x_ref[:, k * P:(k + 1) * P]
+    decay = jnp.exp(jnp.where(ch.mask, li - lj, gdn._GONE))
+    from_state = jnp.exp(jnp.where(ch.carried, li, gdn._GONE))
+    to_end = jnp.exp(jnp.where(ch.to_last, lj[:, C - 1:] - li, gdn._GONE))
+    # a sum, not a slice: Mosaic broadcasts no slice [1, 1] in both axes
+    last = lax.broadcasted_iota(jnp.int32, (C, 1), 0) == C - 1
+    keep = jnp.sum(jnp.where(last, from_state, 0.0), 0, keepdims=True)
+    return u, decay, from_state, to_end, keep
+
+
+def _forward_kernel(x_ref, b_ref, c_ref, l_ref, dt_ref, doc_ref, y_ref,
+                    *rest):
+    """One (sequence, group, chunk) step: the group's state
+    [N, heads P] float32 stays in the scratch from its first chunk to
+    its last. ``rest``: the entering states' output, when a backward
+    pass will follow, then the scratch for the state and for the
+    write's operand ``to_end u`` [C, heads P]."""
+    *entering_ref, state, write = rest
+    dtype = write.dtype
+    P = x_ref.shape[1] // l_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
-    @gdn._each_head(state.shape[0])
-    def _(h):
-        s = state[h]
-        if entering_ref:
-            entering_ref[0][h] = s
-        o_ref[h] = gdn._mm(q_ref[h], s.astype(dtype))
-        state[h] = keep_ref[h] * s + gdn._mm(k_ref[h], u_ref[h], gdn._TN)
+    ch = _chunk(b_ref, c_ref, l_ref, dt_ref, doc_ref, dtype)
+    s = state[...]
+    if entering_ref:
+        entering_ref[0][...] = s
+    read = gdn._mm(ch.c, s.astype(dtype))          # C S [C, heads P]
+    for k in range(l_ref.shape[0]):
+        at = slice(k * P, (k + 1) * P)
+        u, decay, from_state, to_end, keep = _head(k, ch, x_ref, P)
+        attn = (ch.cb * decay).astype(dtype)
+        y_ref[:, at] = (gdn._mm(attn, u.astype(dtype))
+                        + from_state * read[:, at])
+        write[:, at] = (to_end * u).astype(dtype)
+        state[:, at] = keep * s[:, at]
+    state[...] += gdn._mm(ch.b, write[...], gdn._TN)
 
 
-def _backward_kernel(q_ref, k_ref, u_ref, keep_ref, entering_ref, do_ref,
-                     dq_ref, dk_ref, du_ref, dkeep_ref, d_state):
+def _backward_kernel(x_ref, b_ref, c_ref, l_ref, dt_ref, doc_ref,
+                     entering_ref, dy_ref, du_ref, dl_ref, db_ref, dc_ref,
+                     d_state, write, read_dy, cols, rows):
     """The same step with the chunks in reverse: ``d_state`` holds the
     cotangent of the state LEAVING the chunk on entry and of the state
-    entering it on exit. A cotangent is cast to the operands' dtype
-    before a product, as XLA's transpose of a product with float32
-    accumulation rounds it."""
-    dtype = q_ref.dtype
+    entering it on exit. ``C B^T``, the decay and ``u`` are formed again
+    from the operands. A cotangent is cast to the operands' dtype before
+    a product, and a product's operand's cotangent is rounded to it, as
+    XLA's transpose of a product with float32 accumulation does. The
+    log decay's cotangent is gathered as columns and rows [heads, C]
+    and leaves as rows."""
+    dtype = write.dtype
+    P = x_ref.shape[1] // l_ref.shape[0]
+    rounded = lambda a: a.astype(dtype).astype(jnp.float32)
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _():
         d_state[...] = jnp.zeros_like(d_state)
 
-    @gdn._each_head(d_state.shape[0])
-    def _(h):
-        s, d_out = entering_ref[h], d_state[h]
-        held, d_outb = s.astype(dtype), d_out.astype(dtype)
-        do = do_ref[h].astype(dtype)
-        dq_ref[h] = gdn._mm(do, held, gdn._NT).astype(dq_ref.dtype)
-        dk_ref[h] = gdn._mm(u_ref[h], d_outb, gdn._NT).astype(dk_ref.dtype)
-        du_ref[h] = gdn._mm(k_ref[h], d_outb).astype(du_ref.dtype)
-        dkeep_ref[h] = jnp.sum(jnp.sum(s * d_out, 1, keepdims=True), 0,
-                               keepdims=True)
-        d_state[h] = keep_ref[h] * d_out + gdn._mm(q_ref[h], do, gdn._TN)
+    ch = _chunk(b_ref, c_ref, l_ref, dt_ref, doc_ref, dtype)
+    s = entering_ref[...]
+    held = s.astype(dtype)
+    d_out = d_state[...]
+    d_outb = d_out.astype(dtype)
+    read = gdn._mm(ch.c, held)                     # C S [C, heads P]
+    d_write = rounded(gdn._mm(ch.b, d_outb))       # [C, heads P]
+    C = read.shape[0]
+    last = lax.broadcasted_iota(jnp.int32, (C, 1), 0) == C - 1
+    d_cb = jnp.zeros_like(ch.cb)
+    for k in range(l_ref.shape[0]):
+        at = slice(k * P, (k + 1) * P)
+        u, decay, from_state, to_end, keep = _head(k, ch, x_ref, P)
+        dy = dy_ref[:, at]
+        dyb = dy.astype(dtype)
+        attn = (ch.cb * decay).astype(dtype)
+        d_attn = rounded(gdn._mm(dyb, u.astype(dtype), gdn._NT))
+        d_cb = d_cb + d_attn * decay
+        d_exp = d_attn * ch.cb * decay      # of the decay's exponent
+        du_ref[:, at] = (rounded(gdn._mm(attn, dyb, gdn._TN))
+                         + to_end * d_write[:, at])
+        write[:, at] = (to_end * u).astype(dtype)
+        read_dy[:, at] = (from_state * dy).astype(dtype)
+        # the exponents' cotangents of from_state, to_end and keep
+        d_from = from_state * jnp.sum(dy * read[:, at], 1, keepdims=True)
+        d_to = to_end * jnp.sum(d_write[:, at] * u, 1, keepdims=True)
+        d_keep = keep * jnp.sum(s[:, at] * d_out[:, at], keepdims=True)
+        cols[:, k:k + 1] = (jnp.sum(d_exp, 1, keepdims=True) + d_from
+                            - d_to + jnp.where(
+                                last, jnp.sum(d_to, keepdims=True)
+                                + d_keep, 0.0))
+        rows[k:k + 1] = -jnp.sum(d_exp, 0, keepdims=True)
+        d_state[:, at] = keep * d_out[:, at]
+    dl_ref[...] = rows[...] + cols[...].T
+    read_dy, write, d_cb = read_dy[...], write[...], d_cb.astype(dtype)
+    d_state[...] += gdn._mm(ch.c, read_dy, gdn._TN)
+    dc_ref[...] = gdn._mm(d_cb, ch.b) + gdn._mm(read_dy, held, gdn._NT)
+    db_ref[...] = (gdn._mm(d_cb, ch.c, gdn._TN)
+                   + gdn._mm(write, d_outb, gdn._NT))
 
 
-def _head_block(heads: int, C: int, N: int, P: int) -> int:
-    """Heads a grid step: the most that divide ``heads`` and whose
-    blocks of the backward pass (the larger set: the operands, the
-    entering state, ``do`` and four cotangents, float32 counted for
-    all) fit ``_STEP_BYTES``."""
-    lanes = lambda d: -(-d // 128) * 128
-    a_head = 4 * (C * (4 * lanes(N) + 3 * lanes(P)) + N * lanes(P))
-    most = max(1, _STEP_BYTES // (2 * a_head))
-    return max(n for n in range(1, heads + 1)
-               if heads % n == 0 and n <= most)
-
-
-def _call(kernel, interpret, operands, outs, backward):
-    """``kernel`` over the grid (blocks of heads, chunks), chunks
-    innermost and in order (``backward``: last to first). Every operand
-    and output is [Nc, heads, rows, cols] and a block all of one chunk's
-    rows and cols for a block of heads."""
-    q, _, u = operands[:3]
-    Nc, heads, C, N = q.shape
-    P = u.shape[3]
-    hb = _head_block(heads, C, N, P)
-    at = (lambda i, n: (Nc - 1 - n, i, 0, 0)) if backward else (
-        lambda i, n: (n, i, 0, 0))
-    spec = lambda a: pl.BlockSpec((None, hb) + a.shape[2:], at)
+def _call(kernel, shape: SSDShape, interpret, operands, ins, outs,
+          scratch, backward):
+    """``kernel`` over the grid (sequence, group, chunk), chunks
+    innermost and in order (``backward``: last to first); a block is one
+    chunk of one group. ``ins`` names each operand's block and ``outs``
+    pairs each output with its block's name: ``x`` the group's heads'
+    columns of [B, S, H P]; ``b`` / ``c`` the group's ``N`` columns of
+    ``B`` / ``C`` in ``bc`` [B, S, 2 G N] (``b`` also of a cotangent
+    [B, S, G N]); ``rows`` the group's rows of [B, H, S]; ``docs`` of
+    [B, 2, S]; ``states`` the group's columns of [B, Nc, N, H P]."""
+    Bs, S, _ = operands[0].shape
+    G, N = shape.groups, shape.state
+    hb = heads_per_step(shape)
+    C = min(shape.chunk, S)
+    Nc = S // C
+    t = (lambda n: Nc - 1 - n) if backward else (lambda n: n)
+    specs = {
+        "x": pl.BlockSpec((None, C, hb * shape.head_dim),
+                          lambda b, g, n: (b, t(n), g)),
+        "b": pl.BlockSpec((None, C, N), lambda b, g, n: (b, t(n), g)),
+        "c": pl.BlockSpec((None, C, N), lambda b, g, n: (b, t(n), G + g)),
+        "rows": pl.BlockSpec((None, hb, C), lambda b, g, n: (b, g, t(n))),
+        "docs": pl.BlockSpec((None, 2, C), lambda b, g, n: (b, 0, t(n))),
+        "states": pl.BlockSpec((None, None, N, hb * shape.head_dim),
+                               lambda b, g, n: (b, t(n), 0, g))}
     return pl.pallas_call(
-        kernel, grid=(heads // hb, Nc), in_specs=[spec(a) for a in operands],
-        out_specs=[spec(a) for a in outs], out_shape=outs,
-        scratch_shapes=[pltpu.VMEM((hb, N, P), jnp.float32)],
+        kernel, grid=(Bs, G, Nc), in_specs=[specs[k] for k in ins],
+        out_specs=[specs[k] for _, k in outs],
+        out_shape=[o for o, _ in outs], scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=gdn.VMEM_LIMIT),
         interpret=interpret)(*operands)
 
 
-def _forward(q_in, k_out, u, keep, interpret, residuals: bool):
-    """``o`` [Nc, heads, C, P] float32 and, with ``residuals``, the
-    state entering every chunk [Nc, heads, N, P] float32."""
-    Nc, heads, _, N = q_in.shape
-    outs = [jax.ShapeDtypeStruct(u.shape, jnp.float32)]
+_OPERANDS = ("x", "b", "c", "rows", "rows", "docs")
+
+
+def _scratch(shape: SSDShape, C: int, *shapes):
+    hp = heads_per_step(shape) * shape.head_dim
+    return [pltpu.VMEM((shape.state, hp), jnp.float32),
+            pltpu.VMEM((C, hp), jnp.dtype(shape.dtype))] + [
+        pltpu.VMEM(s, d) for s, d in shapes]
+
+
+def _forward(x, bc, l, dt, docs, shape: SSDShape, interpret,
+             residuals: bool):
+    """``y`` [B, S, H P] float32 and, with ``residuals``, the state
+    entering every chunk [B, Nc, N, H P] float32."""
+    Bs, S, HP = x.shape
+    C = min(shape.chunk, S)
+    outs = [(jax.ShapeDtypeStruct((Bs, S, HP), jnp.float32), "x")]
     if residuals:
-        outs.append(jax.ShapeDtypeStruct((Nc, heads, N, u.shape[3]),
-                                         jnp.float32))
-    out = _call(_forward_kernel, interpret, (q_in, k_out, u, keep), outs,
-                False)
+        outs.append((jax.ShapeDtypeStruct((Bs, S // C, shape.state, HP),
+                                          jnp.float32), "states"))
+    out = _call(_forward_kernel, shape, interpret, (x, bc, bc, l, dt, docs),
+                _OPERANDS, outs, _scratch(shape, C), False)
     return out if residuals else (out[0], None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def across_chunks(q_in, k_out, u, keep, interpret):
-    """What crosses from chunk to chunk, for operands [Nc, heads, C, ·]
-    (``keep`` [Nc, heads, 1, 1]) in chunk order: from a state ``S``
-    [N, P] that starts at 0, a chunk puts out ``o = q_in S`` and leaves
-    ``keep S + k_out^T u``. Products take ``q_in``'s dtype and
-    accumulate in float32; the state is float32 and is rounded only as a
-    product's operand."""
-    return _forward(q_in, k_out, u, keep, interpret, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _chunked(x, bc, l, dt, docs, shape: SSDShape, interpret):
+    """The chunked recurrence on ``x`` [B, S, H P], ``bc`` [B, S, 2 G N],
+    the running sums of the log decay ``l`` and the steps ``dt`` as rows
+    [B, H, S] float32, and the documents [B, 2, S] int32 (each token's
+    segment id, and 1 where the state entering its chunk reaches it).
+    Returns ``y`` [B, S, H P] float32."""
+    return _forward(x, bc, l, dt, docs, shape, interpret, False)[0]
 
 
-def _across_chunks_fwd(q_in, k_out, u, keep, interpret):
-    o, entering = _forward(q_in, k_out, u, keep, interpret, True)
-    return o, (q_in, k_out, u, keep, entering)
+def _chunked_fwd(x, bc, l, dt, docs, shape, interpret):
+    y, entering = _forward(x, bc, l, dt, docs, shape, interpret, True)
+    return y, (x, bc, l, dt, docs, entering)
 
 
-def _across_chunks_bwd(interpret, saved, do):
-    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
-    return tuple(_call(_backward_kernel, interpret, saved + (do,),
-                       [like(a) for a in saved[:4]], True))
+def _chunked_bwd(shape, interpret, saved, dy):
+    x, bc, l, dt, docs, entering = saved
+    Bs, S, HP = x.shape
+    H, P, C = shape.heads, shape.head_dim, min(shape.chunk, S)
+    hb = heads_per_step(shape)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32)
+    half = jax.ShapeDtypeStruct((Bs, S, shape.groups * shape.state),
+                                jnp.float32)
+    du, dl, db, dc = _call(
+        _backward_kernel, shape, interpret,
+        (x, bc, bc, l, dt, docs, entering, dy),
+        _OPERANDS + ("states", "x"),
+        [(like(x), "x"), (like(l), "rows"), (half, "b"), (half, "b")],
+        _scratch(shape, C, ((C, hb * P), jnp.dtype(shape.dtype)),
+                 ((C, hb), jnp.float32), ((hb, C), jnp.float32)), True)
+    # the chain through u = dt x, in XLA
+    du = du.reshape(Bs, S, H, P)
+    dx = (du * jnp.swapaxes(dt, 1, 2)[..., None]).reshape(Bs, S, HP)
+    d_dt = jnp.swapaxes(jnp.sum(du * x.reshape(Bs, S, H, P), -1), 1, 2)
+    return (dx.astype(x.dtype), jnp.concatenate([db, dc], -1).astype(
+        bc.dtype), dl, d_dt, None)
 
 
-across_chunks.defvjp(_across_chunks_fwd, _across_chunks_bwd)
-
-
-def chunk_terms(x, bc, dt, a_log, doc, shape: SSDShape):
-    """What :func:`scan` computes for every chunk at once, in plain XLA,
-    before anything crosses a chunk: each chunk's own term ``y_local``
-    [Nc, B, H, C, P] float32, and :func:`across_chunks`'s four operands
-    ``q_in``, ``k_out`` [Nc, B H, C, N], ``u`` [Nc, B H, C, P] and
-    ``keep`` [Nc, B H, 1, 1]. ``x`` [B, S, H P], ``bc`` [B, S, 2 G N]
-    (``B | C``), ``dt`` [B, S, H] float32."""
-    H, P, N, G = shape.heads, shape.head_dim, shape.state, shape.groups
-    dtype = shape.dtype
-    Bs, S, _ = x.shape
-    C = min(shape.chunk, S)
-    if S % C:
-        raise ValueError(f"sequence {S} is no multiple of the scan's "
-                         f"chunk {C}")
-    Nc = S // C
-
-    def chunks(a):      # [B, S, n, ...] -> [Nc, B, n, C, ...]
-        a = a.reshape(Bs, Nc, C, *a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
-
-    xs = chunks(x.reshape(Bs, S, H, P))
-    b = chunks(bc[..., :G * N].reshape(Bs, S, G, N))
-    c = chunks(bc[..., G * N:].reshape(Bs, S, G, N))
-    dt_c = chunks(dt)                                   # [Nc, B, H, C]
-    in_chunks = lambda a: jnp.moveaxis(a.reshape(Bs, Nc, C), 1, 0)
-    seg = in_chunks(gdn.segments(doc))                  # [Nc, B, C]
-    # the segment the entering state belongs to: the last token's of
-    # the chunk before (none before the first chunk)
-    entering = jnp.concatenate(
-        [jnp.zeros_like(seg[:1, :, -1]), seg[:-1, :, -1]], 0)
-    carried = (seg == entering[..., None])[:, :, None]  # [Nc, B, 1, C]
-    same = (seg[..., :, None] == seg[..., None, :])[:, :, None]
-    at = jnp.arange(C)
-    upto = at[:, None] >= at[None]                      # j <= i
-    # the log decay from the chunk's or the document's start
-    a = dt_c * -jnp.exp(a_log)[:, None]
-    Lsum = gdn._running_sum(a, in_chunks(gdn.starts(doc))[:, :, None])
-    decay = jnp.exp(jnp.where(same & upto,
-                              Lsum[..., :, None] - Lsum[..., None, :],
-                              gdn._GONE))
-    from_state = jnp.where(carried, jnp.exp(Lsum), 0.0)
-    to_end = decay[..., -1, :]      # exp(L_C - L_j) inside the last
-    # token's document, else 0
-    per_head = lambda a: jnp.repeat(a, H // G, axis=2)
-    cb = jnp.einsum("...in,...jn->...ij", c.astype(dtype), b.astype(dtype),
-                    preferred_element_type=jnp.float32)  # [Nc, B, G, C, C]
-    u = (dt_c[..., None] * xs).astype(dtype)
-    attn = (per_head(cb) * decay).astype(dtype)
-    y_local = jnp.einsum("...ij,...jp->...ip", attn, u,
-                         preferred_element_type=jnp.float32)
-    q_in = (per_head(c) * from_state[..., None]).astype(dtype)
-    k_out = (per_head(b) * to_end[..., None]).astype(dtype)
-    keep = from_state[..., -1, None, None]              # [Nc, B, H, 1, 1]
-    flat = lambda a: a.reshape(Nc, Bs * H, *a.shape[3:])
-    return y_local, tuple(map(flat, (q_in, k_out, u, keep)))
+_chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 def scan(x, bc, dt, a_log, doc, shape: SSDShape,
@@ -305,12 +378,24 @@ def scan(x, bc, dt, a_log, doc, shape: SSDShape,
 
     @telemetry.scope("lm.ssm.scan")
     def run(x, bc, dt, a_log, doc):
-        y_local, operands = chunk_terms(x, bc, dt, a_log, doc, shape)
-        Nc, Bs, H, C, P = y_local.shape
-        y = y_local + across_chunks(*operands, bool(interpret)).reshape(
-            Nc, Bs, H, C, P)
-        return jnp.moveaxis(jnp.moveaxis(y, 0, 2), 1, 3).reshape(
-            Bs, Nc * C, H, P)
+        Bs, S, _ = x.shape
+        C = min(shape.chunk, S)
+        if S % C:
+            raise ValueError(f"sequence {S} is no multiple of the scan's "
+                             f"chunk {C}")
+        in_chunks = lambda a: a.reshape(*a.shape[:-1], S // C, C)
+        dt = jnp.swapaxes(dt, 1, 2)                     # [B, H, S]
+        # the log decay from the chunk's or the document's start
+        l = gdn._running_sum(in_chunks(dt * -jnp.exp(a_log)[:, None]),
+                             in_chunks(gdn.starts(doc))[:, None])
+        seg = in_chunks(gdn.segments(doc))              # [B, Nc, C]
+        # the segment the entering state belongs to: the last token's of
+        # the chunk before (none before the first chunk)
+        entering = jnp.pad(seg[:, :-1, -1:], ((0, 0), (1, 0), (0, 0)))
+        docs = jnp.stack([seg, (seg == entering).astype(jnp.int32)], 1)
+        y = _chunked(x, bc, l.reshape(dt.shape), dt,
+                     docs.reshape(Bs, 2, S), shape, bool(interpret))
+        return y.reshape(Bs, S, shape.heads, shape.head_dim)
     return run(x, bc, dt, a_log, doc)
 
 

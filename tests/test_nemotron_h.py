@@ -184,10 +184,10 @@ SHAPE = ssd.SSDShape(heads=4, head_dim=8, state=16, groups=2, taps=4,
                      eps=1e-5, chunk=16, dtype="float32")
 
 
-def scan_operands(seed=2, S=64):
+def scan_operands(seed=2, S=64, shape=SHAPE):
     rng = np.random.default_rng(seed)
     f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
-    H, P, N, G = SHAPE.heads, SHAPE.head_dim, SHAPE.state, SHAPE.groups
+    H, P, N, G = shape.heads, shape.head_dim, shape.state, shape.groups
     # steps of the family's range and decay rates of its start: a
     # chunk's log decay runs to tens, its decay to 1e-9
     return {"x": f32(2, S, H * P), "bc": f32(2, S, 2 * G * N) * 0.5,
@@ -253,6 +253,125 @@ def test_a_decay_summed_in_bfloat16_fails_the_scan_s_tolerance(monkeypatch):
         monkeypatch.setattr(gdn, "_running_sum", lambda g, start: summed(
             g, start).astype(jnp.bfloat16).astype(jnp.float32))
         assert gap(our_scan(o, doc), want) > 1e-3
+
+
+def chunk_terms(x, bc, dt, a_log, doc, shape):
+    """The chunked scan's own terms in plain XLA over every chunk at
+    once, as the program formed them before its kernels did: each
+    chunk's ``y_local`` [Nc, B, H, C, P] float32, and what crosses from
+    chunk to chunk — ``q_in``, ``k_out`` [Nc, B, H, C, N], ``u``
+    [Nc, B, H, C, P] and ``keep`` [Nc, B, H, 1, 1]."""
+    H, P, N, G = shape.heads, shape.head_dim, shape.state, shape.groups
+    dtype = shape.dtype
+    Bs, S, _ = x.shape
+    C = min(shape.chunk, S)
+    Nc = S // C
+
+    def chunks(a):      # [B, S, n, ...] -> [Nc, B, n, C, ...]
+        a = a.reshape(Bs, Nc, C, *a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
+
+    xs = chunks(x.reshape(Bs, S, H, P))
+    b = chunks(bc[..., :G * N].reshape(Bs, S, G, N))
+    c = chunks(bc[..., G * N:].reshape(Bs, S, G, N))
+    dt_c = chunks(dt)                                   # [Nc, B, H, C]
+    in_chunks = lambda a: jnp.moveaxis(a.reshape(Bs, Nc, C), 1, 0)
+    seg = in_chunks(gdn.segments(doc))                  # [Nc, B, C]
+    entering = jnp.concatenate(
+        [jnp.zeros_like(seg[:1, :, -1]), seg[:-1, :, -1]], 0)
+    carried = (seg == entering[..., None])[:, :, None]  # [Nc, B, 1, C]
+    same = (seg[..., :, None] == seg[..., None, :])[:, :, None]
+    at = jnp.arange(C)
+    upto = at[:, None] >= at[None]                      # j <= i
+    Lsum = gdn._running_sum(dt_c * -jnp.exp(a_log)[:, None],
+                            in_chunks(gdn.starts(doc))[:, :, None])
+    decay = jnp.exp(jnp.where(same & upto,
+                              Lsum[..., :, None] - Lsum[..., None, :],
+                              gdn._GONE))
+    from_state = jnp.where(carried, jnp.exp(Lsum), 0.0)
+    to_end = decay[..., -1, :]
+    per_head = lambda a: jnp.repeat(a, H // G, axis=2)
+    cb = jnp.einsum("...in,...jn->...ij", c.astype(dtype), b.astype(dtype),
+                    preferred_element_type=jnp.float32)  # [Nc, B, G, C, C]
+    u = (dt_c[..., None] * xs).astype(dtype)
+    attn = (per_head(cb) * decay).astype(dtype)
+    y_local = jnp.einsum("...ij,...jp->...ip", attn, u,
+                         preferred_element_type=jnp.float32)
+    q_in = (per_head(c) * from_state[..., None]).astype(dtype)
+    k_out = (per_head(b) * to_end[..., None]).astype(dtype)
+    return y_local, q_in, k_out, u, from_state[..., -1, None, None]
+
+
+def xla_scan(o, doc, shape):
+    """The oracle: :func:`chunk_terms`, then a plain scan over the
+    chunks with the state [B, H, N, P] float32 — a chunk puts out
+    ``q_in S`` and leaves ``keep S + k_out^T u``."""
+    y_local, *crossing = chunk_terms(o["x"], o["bc"], o["dt"], o["a_log"],
+                                     doc, shape)
+    Nc, Bs, H, C, P = y_local.shape
+
+    def step(state, chunk):
+        q_in, k_out, u, keep = chunk
+        read = jnp.einsum("...in,...np->...ip", q_in,
+                          state.astype(q_in.dtype),
+                          preferred_element_type=jnp.float32)
+        return keep * state + jnp.einsum(
+            "...jn,...jp->...np", k_out, u,
+            preferred_element_type=jnp.float32), read
+
+    _, read = jax.lax.scan(
+        step, jnp.zeros((Bs, H, shape.state, P), jnp.float32), crossing)
+    y = y_local + read
+    return jnp.moveaxis(jnp.moveaxis(y, 0, 2), 1, 3).reshape(
+        Bs, Nc * C, H, P)
+
+
+# bfloat16 products: the kernels round a cotangent before a product and
+# fold the decay into the other operand than the XLA form, within a
+# rounding of it (output 1.1e-3, gradients 3.8e-3 and a_log's 5.7e-3 at
+# most); the decay's running sums rounded to bfloat16 read 5.8e-3,
+# 5.7e-3 and 1.9e-2 at least
+BF16_TOLERANCE = {"y": 3e-3, "x": 5e-3, "bc": 5e-3, "dt": 5e-3,
+                  "a_log": 1e-2}
+
+
+@pytest.mark.parametrize("docs, dtype, groups", [
+    (d, t, 2) for d in sorted(DOCS) for t in ("float32", "bfloat16")]
+    + [("three_in_a_chunk", "float32", 4)])
+def test_the_scan_s_kernels_are_the_xla_chunk_form(docs, dtype, groups,
+                                                    monkeypatch):
+    """The scan's kernels against the XLA chunk form they replace, the
+    output and every operand's gradient: float32 to the order of sums;
+    bfloat16 within a tolerance the control (the decay summed in
+    bfloat16) fails; and one head a group, a grid step's fewest."""
+    shape = SHAPE._replace(dtype=dtype, groups=groups)
+    doc = jnp.asarray(DOCS[docs], jnp.int32)
+    o = scan_operands(shape=shape)
+    weight = jnp.asarray(np.random.default_rng(5).normal(
+        size=(2, 64, 4, 8)), jnp.float32)
+    ours = lambda o: ssd.scan(o["x"], o["bc"], o["dt"], o["a_log"], doc,
+                              shape, interpret=True)
+
+    def gaps(want, want_grad):
+        g = jax.grad(lambda o: jnp.sum(ours(o) * weight))(o)
+        return dict(y=gap(ours(o), want),
+                    **{k: gap(g[k], want_grad[k]) for k in o})
+
+    with jax.default_matmul_precision("highest"):
+        want = xla_scan(o, doc, shape)
+        want_grad = jax.grad(lambda o: jnp.sum(xla_scan(o, doc, shape)
+                                               * weight))(o)
+        got = gaps(want, want_grad)
+        if dtype == "bfloat16":
+            summed = gdn._running_sum
+            monkeypatch.setattr(gdn, "_running_sum", lambda g, start: summed(
+                g, start).astype(jnp.bfloat16).astype(jnp.float32))
+            control = gaps(want, want_grad)
+    for name, value in got.items():
+        if dtype == "float32":
+            assert value < (1e-6 if name == "y" else 1e-5), name
+        else:
+            assert value < BF16_TOLERANCE[name] < control[name], name
 
 
 def mixer_operands(seed=3, D=24):
@@ -782,6 +901,8 @@ def test_spans_scopes_and_counters_of_a_training_call(trained):
     # two ssm layers: 4 chunks of 16 a sequence, a state restart a
     # document
     assert counters["lm.ssm.chunks"] == 3 * 2 * 4 * 4
+    # a grid step of the scan holds one group's heads
+    assert snap["gauges"]["lm.ssm.scan.heads_per_step"] == 2
     assert counters["lm.ssm.doc_starts"] == 2 * documents_trained
     assert counters["lm.attend.key_blocks"] == len(batches) * 4 * 10
     assert counters["moe.bias_steps"] == 3 * 2
